@@ -15,8 +15,8 @@ def resnet50_train_flops_per_example(height: int = 224, width: int = 224) -> flo
     over the zoo graph's conv shapes, = 3.86 GMACs) plus the fc layer and
     change. The widely quoted torchvision/fvcore "4.09 GFLOPs" counts
     MACs, i.e. HALF this convention; rounds 1-4 used it directly, which
-    undercounted achieved TFLOP/s and MFU by ~1.9x (fixed round 5 — see
-    ROUND5_NOTES.md). Scales with spatial area for other input sizes.
+    undercounted achieved TFLOP/s and MFU by ~1.9x (fixed round 5).
+    Scales with spatial area for other input sizes.
     Train = 3x forward."""
     forward = 7.75e9 * (height * width) / (224.0 * 224.0)
     return 3.0 * forward

@@ -91,3 +91,25 @@ class Environment:
 
 def get_environment() -> Environment:
     return Environment.instance()
+
+
+# A compile cache only hits when the next process looks in the same place,
+# so the default is one fixed, git-ignored path under the checkout.
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    Entry points (``chip_smoke.py``, ``bench.py``'s measurement child,
+    ``__graft_entry__.py``) call this before their first compile; the
+    library and the tests never do. Where ``JAX_COMPILATION_CACHE_DIR`` is
+    set JAX has already read it and nothing here overrides it; otherwise
+    the cache lives in ``<checkout>/.jax_cache``."""
+    import jax
+
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          os.path.join(_CHECKOUT, ".jax_cache"))
+    return jax.config.jax_compilation_cache_dir

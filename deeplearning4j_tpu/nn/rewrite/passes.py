@@ -1,10 +1,11 @@
 """The shipped rewrite passes: space-to-depth stem, conv+BN fold, BN affine.
 
-Round-5 calibration (BENCH_latest.json) located ResNet-50's two remaining
-step-time losses precisely: the 7×7/2 conv1 stem runs at 8.3 TF/s against a
-183–191 TF/s body because a 3-channel input pads the 128×128 MXU to 2.3%
-occupancy, and ~5.6 ms/step of BatchNorm/elementwise HBM traffic rides on
-every step. Google's MLPerf TPU submissions ("Scale MLPerf-0.6 models on
+A 2026-07 calibration on another machine's v5e (a claim to check, ROADMAP
+S1) located ResNet-50's two remaining step-time losses: the 7×7/2 conv1
+stem runs at 8.3 TF/s against a 183–191 TF/s body because a 3-channel input
+pads the 128×128 MXU to 2.3% occupancy, and ~5.6 ms/step of
+BatchNorm/elementwise HBM traffic rides on every step. Google's MLPerf TPU
+submissions ("Scale MLPerf-0.6 models on
 Google TPU-v3 Pods", PAPERS.md) close exactly this gap with the
 space-to-depth stem transform implemented here; the BN passes remove or
 collapse the elementwise chain so XLA fuses it into the conv epilogue.

@@ -28,14 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-
-try:  # TPU memory spaces — absent on some CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG = -1e30  # finite "-inf": keeps exp/max well-defined for fully-masked rows
 
@@ -191,10 +184,6 @@ def _pad_to(x: jax.Array, axis: int, multiple: int, value=0.0) -> jax.Array:
 
 def _flash_forward(q, k, v, mask, causal, scale, block_q, block_k, interpret,
                    with_lse: bool = False):
-    if _VMEM is None:  # jaxlib without pallas TPU support: same math via XLA
-        out = mha_attention_reference(q, k, v, mask=mask, causal=causal,
-                                      scale=scale)
-        return (out, None) if with_lse else out
     b, h, tq, d = q.shape
     tk, dv = k.shape[2], v.shape[3]
     block_q = min(block_q, max(tq, 1))
@@ -218,7 +207,7 @@ def _flash_forward(q, k, v, mask, causal, scale, block_q, block_k, interpret,
     kern = functools.partial(
         _flash_kernel, scale=scale, block_q=block_q, block_k=block_k,
         causal=causal, tk_offset=tk - tq)
-    kwargs = dict(memory_space=_VMEM)
+    kwargs = dict(memory_space=pltpu.VMEM)
     scratch = [
         pltpu.VMEM((block_q, 1), jnp.float32),
         pltpu.VMEM((block_q, 1), jnp.float32),
@@ -249,6 +238,7 @@ def _flash_forward(q, k, v, mask, causal, scale, block_q, block_k, interpret,
         ],
         scratch_shapes=scratch,
         interpret=interpret,
+        name="flash_fwd",
     )(qp, kp, vp, mask)
     out = out.reshape(b, h, tq_p, dv)[:, :, :tq, :]
     if not with_lse:
@@ -271,7 +261,7 @@ def _flash_fwd(q, k, v, mask, causal, scale, block_q, block_k, bwd_block_q,
 
 
 def _mea_bwd_single(q, k, v, mask_k, g, out, lse_rows, *, causal, scale,
-                    tk_off, bq, bk, have_lse):
+                    tk_off, bq, bk):
     """Memory-efficient attention backward for ONE head (Dao et al. alg. 4,
     the XLA spelling): two-level ``lax.scan`` over (q-chunk, k-chunk)
     recomputes score blocks instead of materializing the [tq, tk] matrix —
@@ -313,28 +303,7 @@ def _mea_bwd_single(q, k, v, mask_k, g, out, lse_rows, *, causal, scale,
 
     def outer(carry, xs):
         dk_acc, dv_acc = carry
-        qi, qch, gch, och, lch = xs
-
-        if have_lse:
-            lse = lch  # saved by the forward kernel: no recompute pass
-        else:
-            # XLA-fallback forward saved no lse: rebuild it blockwise
-            def p1(c, ys):
-                m, l = c
-                ki, kch, mch = ys
-                s = scores(qch, kch, mch, qi, ki)
-                m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-                p = jnp.where(s > neg * 0.5, jnp.exp(s - m_new), 0.0)
-                l = l * jnp.exp(m - m_new) + jnp.sum(p, axis=-1,
-                                                     keepdims=True)
-                return (m_new, l), None
-
-            (m, l), _ = lax.scan(
-                p1, (jnp.full((bq, 1), neg), jnp.zeros((bq, 1), jnp.float32)),
-                (jnp.arange(nk), kc, mc))
-            # fully-masked rows: force P = 0 downstream, not exp(s+inf)
-            lse = jnp.where(l > 0, m + jnp.log(jnp.maximum(l, 1e-30)),
-                            jnp.float32(-_NEG))
+        qi, qch, gch, och, lse = xs  # lse: saved by the forward kernel
         delta = jnp.sum(gch.astype(jnp.float32) * och.astype(jnp.float32),
                         axis=-1, keepdims=True)  # D_i
 
@@ -513,7 +482,7 @@ def _flash_bwd_pallas(q, k, v, mask, out, lse, g, causal, scale, bq, bk):
     lp = lp.reshape(b * h, tq_p, 1)
     dp_ = dp_.reshape(b * h, tq_p, 1)
 
-    kw = dict(memory_space=_VMEM)
+    kw = dict(memory_space=pltpu.VMEM)
     kern_q = functools.partial(
         _dq_kernel, scale=scale, block_q=bq, block_k=bk, causal=causal,
         tk_offset=tk - tq)
@@ -534,6 +503,7 @@ def _flash_bwd_pallas(q, k, v, mask, out, lse, g, causal, scale, bq, bk):
                                **kw),
         out_shape=jax.ShapeDtypeStruct((b * h, tq_p, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        name="flash_bwd_dq",
     )(qp, kp, vp, gp, lp, dp_, mp)
 
     kern_kv = functools.partial(
@@ -565,6 +535,7 @@ def _flash_bwd_pallas(q, k, v, mask, out, lse, g, causal, scale, bq, bk):
         ],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, dv), jnp.float32)],
+        name="flash_bwd_dkv",
     )(qp, kp, vp, gp, lp_row, dp_row, mp)
 
     dq = dq.reshape(b, h, tq_p, d)[:, :, :tq].astype(q.dtype)
@@ -578,7 +549,7 @@ def _flash_bwd(causal, scale, block_q, block_k, bwd_block_q, bwd_block_k,
     q, k, v, mask, out, lse = res
     b, h, tq, d = q.shape
     tk, dv = k.shape[2], v.shape[3]
-    if _VMEM is not None and not interpret and lse is not None:
+    if not interpret:
         # compiled path: the two-kernel Pallas backward
         dq, dk, dv_g = _flash_bwd_pallas(
             q, k, v, mask, out, lse, g, causal, scale,
@@ -599,15 +570,11 @@ def _flash_bwd(causal, scale, block_q, block_k, bwd_block_q, bwd_block_k,
     kp = _pad_to(k, 2, bk)
     vp = _pad_to(v, 2, bk)
     mp = _pad_to(mask_k, 1, bk, 0.0)
-    have_lse = lse is not None
-    if have_lse:
-        lp = _pad_to(lse.astype(jnp.float32)[..., None], 2, bq, -_NEG)
-    else:  # placeholder so the vmap structure stays uniform
-        lp = jnp.zeros((b, h, qp.shape[2], 1), jnp.float32)
+    lp = _pad_to(lse.astype(jnp.float32)[..., None], 2, bq, -_NEG)
 
     single = functools.partial(
         _mea_bwd_single, causal=causal, scale=scale, tk_off=tk - tq,
-        bq=bq, bk=bk, have_lse=have_lse)
+        bq=bq, bk=bk)
     # vmap heads (mask is per-batch), then batch
     per_batch = jax.vmap(single, in_axes=(0, 0, 0, None, 0, 0, 0))
     dq, dk, dv = jax.vmap(per_batch)(qp, kp, vp, mp, gp, op, lp)
@@ -639,12 +606,11 @@ def flash_attention(
     key-padding mask (1 = keep). Runs the Pallas kernel compiled on TPU and
     in interpreter mode elsewhere (the CPU test path).
 
-    Blocks are tuned on TPU v5e (d=64, bf16; forward sweep in
-    ROUND4_NOTES.md, backward sweep in ROUND5_NOTES.md): forward
-    block_q=256 with block_k adaptive on sequence length — 512 up to 4k
-    and 1024 beyond. The scan-based backward defaults to LARGER tiles
-    (bwd 1024x1024) because each scan step's five matmuls must fill the
-    MXU on their own; operands stay bf16 with f32 accumulation."""
+    Block defaults come from sweeps at d=64, bf16 on another machine's
+    v5e (2026-07, not re-measured since): forward block_q=256 with
+    block_k adaptive on sequence length — 512 up to 4k and 1024 beyond;
+    backward 1024x1024, so that each step's five matmuls fill the MXU on
+    their own. Operands stay bf16 with f32 accumulation."""
     if block_k is None:
         block_k = 512 if k.shape[2] < 8192 else 1024
     if bwd_block_q is None:
@@ -695,17 +661,21 @@ def decode_attention_reference(
 
 
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
-                   acc_scr, *, scale, block_k):
+                   acc_scr, *, scale, block_k, heads):
     """One (batch·head, k-block) grid step of single-query flash decode.
 
     The k axis is the innermost (sequential) grid dim so the VMEM online-
     softmax accumulators carry across k blocks, exactly like the training
     forward kernel — but the q block is a single row (the token being
-    decoded) and the valid cache length arrives as an SMEM scalar, so
-    k-blocks entirely past the decode frontier skip their matmuls: the
-    per-step work is O(position), not O(max_len)."""
+    decoded) and the valid cache lengths arrive as a scalar-prefetch SMEM
+    vector, so k-blocks entirely past the decode frontier skip their
+    compute: the per-step work is O(position), not O(max_len).
+
+    A one-row query gives the MXU nothing to do (M=1), so scores and the
+    weighted value sum are VPU broadcasts with lane / sublane reductions:
+    scores live as a [block_k, 1] column, which needs no transposes."""
     ki = pl.program_id(1)
-    length = len_ref[0, 0]  # valid cache entries = start_pos + 1
+    length = len_ref[pl.program_id(0) // heads]  # valid entries = pos + 1
 
     @pl.when(ki == 0)
     def _():
@@ -718,21 +688,17 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
         q = q_ref[0].astype(jnp.float32) * scale    # [1, d]
         ks = k_ref[0].astype(jnp.float32)           # [block_k, d]
         vs = v_ref[0].astype(jnp.float32)           # [block_k, dv]
-        s = jax.lax.dot_general(
-            q, ks, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)     # [1, block_k]
+        s = jnp.sum(ks * q, axis=-1, keepdims=True)  # [block_k, 1]
         k_ids = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), 1)
+            jnp.int32, (block_k, 1), 0)
         s = jnp.where(k_ids < length, s, _NEG)
         m, l, acc = m_scr[...], l_scr[...], acc_scr[...]
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))  # [1, 1]
         p = jnp.where(s > _NEG * 0.5, jnp.exp(s - m_new), 0.0)
         alpha = jnp.exp(m - m_new)
         m_scr[...] = m_new
-        l_scr[...] = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[...] = acc * alpha + jax.lax.dot_general(
-            p, vs, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        l_scr[...] = l * alpha + jnp.sum(p, axis=0, keepdims=True)
+        acc_scr[...] = acc * alpha + jnp.sum(p * vs, axis=0, keepdims=True)
 
     @pl.when(ki == pl.num_programs(1) - 1)
     def _():
@@ -758,8 +724,6 @@ def flash_decode_attention(
         scale = 1.0 / math.sqrt(q.shape[-1])
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    if _VMEM is None:  # jaxlib without pallas TPU support
-        return decode_attention_reference(q, k, v, start_pos, scale=scale)
     b, h, _, d = q.shape
     L, dv = k.shape[2], v.shape[3]
     block_k = min(block_k, max(L, 1))
@@ -769,29 +733,35 @@ def flash_decode_attention(
     qp = q.reshape(b * h, 1, d)
     kp = kp.reshape(b * h, L_p, d)
     vp = vp.reshape(b * h, L_p, dv)
-    lengths = (start_pos.astype(jnp.int32) + 1).reshape(b, 1)
+    lengths = start_pos.astype(jnp.int32) + 1  # [b], scalar-prefetched
 
     kern = functools.partial(_decode_kernel, scale=float(scale),
-                             block_k=block_k)
-    kw = dict(memory_space=_VMEM)
+                             block_k=block_k, heads=h)
+    kw = dict(memory_space=pltpu.VMEM)
     out = pl.pallas_call(
         kern,
-        grid=(b * h, L_p // block_k),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda bh, ki, _h=h: (bh // _h, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, d), lambda bh, ki: (bh, 0, 0), **kw),
-            pl.BlockSpec((1, block_k, d), lambda bh, ki: (bh, ki, 0), **kw),
-            pl.BlockSpec((1, block_k, dv), lambda bh, ki: (bh, ki, 0), **kw),
-        ],
-        out_specs=pl.BlockSpec((1, 1, dv), lambda bh, ki: (bh, 0, 0), **kw),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b * h, L_p // block_k),
+            in_specs=[
+                pl.BlockSpec((1, 1, d), lambda bh, ki, lens: (bh, 0, 0),
+                             **kw),
+                pl.BlockSpec((1, block_k, d),
+                             lambda bh, ki, lens: (bh, ki, 0), **kw),
+                pl.BlockSpec((1, block_k, dv),
+                             lambda bh, ki, lens: (bh, ki, 0), **kw),
+            ],
+            out_specs=pl.BlockSpec((1, 1, dv),
+                                   lambda bh, ki, lens: (bh, 0, 0), **kw),
+            scratch_shapes=[
+                pltpu.VMEM((1, 1), jnp.float32),
+                pltpu.VMEM((1, 1), jnp.float32),
+                pltpu.VMEM((1, dv), jnp.float32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((b * h, 1, dv), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, dv), jnp.float32),
-        ],
         interpret=interpret,
+        name="flash_decode",
     )(lengths, qp, kp, vp)
     return out.reshape(b, h, 1, dv)
 

@@ -35,14 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:  # TPU memory spaces — absent on some CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 # ---------------------------------------------------------------------------
 # helper-impl seam
@@ -142,12 +135,13 @@ def grouped_matmul_reference(
 
 
 def _gmm_kernel(size_ref, lhs_ref, rhs_ref, out_ref, *, block_m):
-    """One (group, m-tile) grid step. The group's row count arrives as an
-    SMEM scalar; tiles wholly past the group frontier skip the matmul and
-    just zero their output block (padded input rows are already zero, so
-    partially-filled tiles need no extra masking)."""
-    j = pl.program_id(1)
-    size = size_ref[0, 0]
+    """One (group, n-tile, m-tile) grid step. The per-group row counts
+    arrive as a scalar-prefetch SMEM vector; tiles wholly past the group
+    frontier skip the matmul and just zero their output block (padded
+    input rows are already zero, so partially-filled tiles need no extra
+    masking)."""
+    j = pl.program_id(2)
+    size = size_ref[pl.program_id(0)]
 
     @pl.when(j * block_m >= size)
     def _():
@@ -160,27 +154,58 @@ def _gmm_kernel(size_ref, lhs_ref, rhs_ref, out_ref, *, block_m):
             preferred_element_type=jnp.float32).astype(out_ref.dtype)
 
 
+# One rhs tile [d, block_n] stays resident while the m-tiles of its group
+# stream past it, so it is sized in bytes: small enough that two buffers
+# of it plus the lhs/out tiles sit well inside VMEM at any expert width.
+_RHS_TILE_BYTES = 2 << 20
+
+
+def _block_n(d: int, h: int, itemsize: int) -> int:
+    """Widest multiple of 128 that divides ``h`` and keeps the rhs tile
+    under ``_RHS_TILE_BYTES``; the whole of ``h`` when it is not a
+    multiple of 128 (a block equal to the array dim is always legal)."""
+    if h % 128:
+        return h
+    cap = max(128, _RHS_TILE_BYTES // (d * itemsize) // 128 * 128)
+    bn = min(h, cap)
+    while h % bn:
+        bn -= 128
+    return bn
+
+
 def _gmm_pallas(lhs, rhs, group_sizes, m_pad, block_m, interpret):
     e, d, h = rhs.shape
     out_dtype = jnp.promote_types(lhs.dtype, rhs.dtype)
+    itemsize = jnp.dtype(out_dtype).itemsize
     buf = _to_groups(lhs, group_sizes, m_pad).astype(out_dtype)
-    sizes = group_sizes.astype(jnp.int32).reshape(e, 1)
+    block_n = _block_n(d, h, itemsize)
     kern = functools.partial(_gmm_kernel, block_m=block_m)
-    kw = dict(memory_space=_VMEM)
+    kw = dict(memory_space=pltpu.VMEM)
+    # double-buffered lhs / rhs / out tiles plus the f32 product
+    vmem = (2 * itemsize * (block_m * d + d * block_n + block_m * block_n)
+            + 4 * block_m * block_n)
     out = pl.pallas_call(
         kern,
-        grid=(e, m_pad // block_m),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda ge, j: (ge, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, block_m, d), lambda ge, j: (ge, j, 0), **kw),
-            pl.BlockSpec((1, d, h), lambda ge, j: (ge, 0, 0), **kw),
-        ],
-        out_specs=pl.BlockSpec((1, block_m, h), lambda ge, j: (ge, j, 0),
-                               **kw),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            # m innermost: the rhs tile's block index does not change
+            # across it, so each [d, block_n] slab is fetched once
+            grid=(e, h // block_n, m_pad // block_m),
+            in_specs=[
+                pl.BlockSpec((1, block_m, d),
+                             lambda ge, n, j, sizes: (ge, j, 0), **kw),
+                pl.BlockSpec((1, d, block_n),
+                             lambda ge, n, j, sizes: (ge, 0, n), **kw),
+            ],
+            out_specs=pl.BlockSpec((1, block_m, block_n),
+                                   lambda ge, n, j, sizes: (ge, j, n), **kw),
+        ),
         out_shape=jax.ShapeDtypeStruct((e, m_pad, h), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=max(32 << 20, 2 * vmem)),
         interpret=interpret,
-    )(sizes, buf, rhs.astype(out_dtype))
+        name="grouped_matmul",
+    )(group_sizes.astype(jnp.int32), buf, rhs.astype(out_dtype))
     return _from_groups(out, group_sizes, lhs.shape[0])
 
 
@@ -190,7 +215,7 @@ def _gmm_pallas(lhs, rhs, group_sizes, m_pad, block_m, interpret):
 
 
 def _gmm_any(lhs, rhs, group_sizes, m_pad, block_m, use_pallas, interpret):
-    if use_pallas and _VMEM is not None:
+    if use_pallas:
         return _gmm_pallas(lhs, rhs, group_sizes, m_pad, block_m, interpret)
     return _gmm_xla(lhs, rhs, group_sizes, m_pad)
 
@@ -246,10 +271,15 @@ def _check_shapes(lhs, group_sizes, rhs):
             f"group_sizes {group_sizes.shape}, rhs {rhs.shape}")
 
 
-def _tiling(n: int, max_group_size: Optional[int], block_m: int):
+def _tiling(n: int, max_group_size: Optional[int], block_m: int,
+            itemsize: int = 4):
+    """(m_pad, block_m): the padded per-group tile height and the m-block,
+    both multiples of the dtype's sublane count — one TPU register tile
+    is 8 rows of 32 bits, so 8 rows of f32 and 16 of bf16."""
+    sublanes = 8 * max(1, 4 // itemsize)
     m = n if max_group_size is None else int(max_group_size)
     m = max(1, min(m, max(n, 1)))
-    bm = min(block_m, _round_up(m, 8))
+    bm = min(_round_up(block_m, sublanes), _round_up(m, sublanes))
     return _round_up(m, bm), bm
 
 
@@ -270,15 +300,16 @@ def grouped_matmul(
     exceeding the bound have their overflow rows zeroed — callers must
     guarantee the bound. Defaults to ``N`` (always safe)."""
     _check_shapes(lhs, group_sizes, rhs)
-    m_pad, bm = _tiling(lhs.shape[0], max_group_size, block_m)
+    if not jnp.issubdtype(rhs.dtype, jnp.inexact):  # e.g. int8 expert slabs
+        rhs = rhs.astype(lhs.dtype)
+    itemsize = jnp.dtype(jnp.promote_types(lhs.dtype, rhs.dtype)).itemsize
+    m_pad, bm = _tiling(lhs.shape[0], max_group_size, block_m, itemsize)
     impl = _IMPL
     if impl == "auto":
-        use_pallas = jax.default_backend() == "tpu" and _VMEM is not None
+        use_pallas = jax.default_backend() == "tpu"
     else:
         use_pallas = impl == "pallas"
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    if not jnp.issubdtype(rhs.dtype, jnp.inexact):  # e.g. int8 expert slabs
-        rhs = rhs.astype(lhs.dtype)
     return _gmm(lhs, rhs, group_sizes.astype(jnp.int32), m_pad, bm,
                 use_pallas, bool(interpret))

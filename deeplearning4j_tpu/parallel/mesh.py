@@ -76,14 +76,14 @@ def make_mesh(
     names = tuple(sizes)
     shape = tuple(sizes[n] for n in names)
     if devices is None:
-        try:
-            # Auto axis types: shardings propagate GSPMD-style and XLA
-            # derives the collectives (jax 0.9's make_mesh defaults to
-            # Explicit, which demands out_sharding annotations everywhere).
-            auto = (jax.sharding.AxisType.Auto,) * len(names)
-            return jax.make_mesh(shape, names, axis_types=auto)
-        except Exception:  # older jaxlib or restricted device sets
-            pass
+        # Auto axis types: shardings propagate GSPMD-style and XLA
+        # derives the collectives (jax.make_mesh defaults to Explicit,
+        # which demands out_sharding annotations everywhere). A failure
+        # here propagates: reshaping jax.devices() in list order instead
+        # is how a mesh ends up with a bad ICI order silently.
+        auto = (jax.sharding.AxisType.Auto,) * len(names)
+        return jax.make_mesh(shape, names, axis_types=auto)
+    # an explicit device list is the caller's order, taken as given
     arr = np.array(devs).reshape(shape)
     return Mesh(arr, axis_names=names)
 
@@ -97,22 +97,16 @@ def initialize_distributed(
     handshake, SURVEY.md §3.4 — here it is one call into the JAX
     coordination service; on Cloud TPU the arguments are auto-detected).
 
-    Safe to call when already initialized (no-op) or single-process
-    (when no coordinator can be inferred).
+    A no-op when already initialized. A failed initialize raises: a job
+    that meant to span hosts must not carry on as one process.
     """
-    if getattr(jax.distributed, "is_initialized", lambda: False)():
+    if jax.distributed.is_initialized():
         return
-    try:
-        jax.distributed.initialize(
-            coordinator_address=coordinator_address,
-            num_processes=num_processes,
-            process_id=process_id,
-        )
-    except Exception:
-        # Single-process / no cluster env: run standalone, like the
-        # reference running ParallelWrapper without Spark.
-        if num_processes not in (None, 1):
-            raise
+    jax.distributed.initialize(
+        coordinator_address=coordinator_address,
+        num_processes=num_processes,
+        process_id=process_id,
+    )
 
 
 def local_batch_slice(global_batch: int, mesh: Mesh, axis: str = "data") -> slice:
@@ -177,23 +171,8 @@ def force_host_device_count(n: int) -> None:
     os.environ[_ENV_FLAG] = str(n)
 
 
-# ---- shard_map compatibility shim (single home; jax renamed check_rep ->
-# check_vma across versions, and moved shard_map out of experimental) ------
-try:  # jax >= 0.6 public API
-    from jax import shard_map as _shard_map  # type: ignore
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map  # type: ignore
-
-
 def shmap(fn, mesh, in_specs, out_specs):
-    """shard_map with replication checking off, across jax API versions."""
-    try:
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
-    except TypeError:
-        try:
-            return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_vma=False)
-        except TypeError:
-            return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs)
+    """``jax.shard_map`` with the varying-manual-axes check off (the
+    strategies mix replicated and per-shard values by construction)."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -34,7 +34,7 @@ from .mesh import make_mesh, shmap, zero1_partition_spec
 from .strategies import GradientSyncStrategy, SyncAllReduce
 
 
-_shmap = shmap  # single-home compatibility shim (parallel/mesh.py)
+_shmap = shmap  # parallel/mesh.py
 
 
 def moe_expert_parallel_rules(axis: str = "model",

@@ -160,7 +160,7 @@ def test_input_pipeline_throughput_vs_resnet_step(tmp_path, capsys):
     rate = seen / (time.perf_counter() - start)
     assert seen == n
     assert rate > 30  # single slow core; TPU feeding needs parallel workers
-    resnet_tpu_sps = 1794.89  # BENCH_latest.json, round 4
+    resnet_tpu_sps = 1794.89  # 2026-07, another machine's v5e (ROADMAP S1)
     with capsys.disabled():
         print(f"\n[input-pipeline] {rate:.0f} img/s host vs "
               f"{resnet_tpu_sps:.0f} samples/s ResNet-50/TPU -> "

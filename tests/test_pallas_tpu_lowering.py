@@ -1,0 +1,84 @@
+"""Every ``pallas_call`` in ``ops/`` must get through JAX's own Pallas-TPU
+lowering at the shapes ``chip_smoke.py`` runs on the chip — bf16 and f32,
+forward and gradient. ``interpret=True`` (the CPU test path everywhere
+else) skips that lowering and its block-shape rules, which is how two
+kernels with illegal SMEM block specs went unnoticed until a chip ran
+them. Lowering for ``("tpu",)`` needs no TPU; what Mosaic then makes of
+the kernel only ``tests_tpu/`` and the smoke can say.
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from deeplearning4j_tpu.ops.flash_attention import (
+    flash_attention, flash_decode_attention)
+from deeplearning4j_tpu.ops.grouped_matmul import _gmm, _tiling
+
+DTYPES = [jnp.bfloat16, jnp.float32]
+
+
+def _kernels(fn, *args):
+    """Names of the Pallas TPU kernels in ``fn``'s program lowered for TPU
+    (with x64 off, as on the chip: tests/conftest.py turns it on, and
+    Mosaic has no float64)."""
+    with jax.enable_x64(False):
+        text = jax.jit(fn).trace(*args).lower(
+            lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text
+    return sorted(re.findall(r'kernel_name = "([^"]+)"', text))
+
+
+def _spec(*shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("b,h,t,d,causal", [
+    (4, 12, 512, 64, False),    # the BERT-base 4 x 512 step
+    (2, 12, 1024, 64, True),    # GPT-2-small training length, causal+mask
+    (2, 12, 600, 64, True),     # no block size divides it
+])
+def test_flash_forward_and_backward_lower(dtype, b, h, t, d, causal):
+    x = _spec(b, h, t, d, dtype=dtype)
+    mask = _spec(b, t, dtype=jnp.float32) if causal else None
+
+    def loss(q, k, v, mask):
+        return jnp.sum(flash_attention(q, k, v, mask=mask, causal=causal,
+                                       interpret=False).astype(jnp.float32))
+
+    assert _kernels(jax.grad(loss, argnums=(0, 1, 2)), x, x, x, mask) == [
+        "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("L", [1024, 600])
+def test_flash_decode_lowers(dtype, L):
+    b, h, d = 8, 12, 64
+    names = _kernels(
+        lambda q, k, v, p: flash_decode_attention(q, k, v, p,
+                                                  interpret=False),
+        _spec(b, h, 1, d, dtype=dtype), _spec(b, h, L, d, dtype=dtype),
+        _spec(b, h, L, d, dtype=dtype), _spec(b, dtype=jnp.int32))
+    assert names == ["flash_decode"]
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("n,d,e,h,cap", [
+    (16384, 768, 8, 1536, 2560),   # ROADMAP S2, first expert matmul
+    (16384, 1536, 8, 768, None),   # second one, no capacity bound
+    (300, 16, 4, 32, 100),         # widths no tile divides
+])
+def test_grouped_matmul_forward_and_grad_lower(dtype, n, d, e, h, cap):
+    m_pad, bm = _tiling(n, cap, 128, jnp.dtype(dtype).itemsize)
+
+    def loss(lhs, rhs, sizes):  # the Pallas path, as "auto" picks on a TPU
+        return jnp.sum(_gmm(lhs, rhs, sizes, m_pad, bm, True,
+                            False).astype(jnp.float32))
+
+    names = _kernels(jax.value_and_grad(loss, argnums=(0, 1)),
+                     _spec(n, d, dtype=dtype), _spec(e, d, h, dtype=dtype),
+                     _spec(e, dtype=jnp.int32))
+    assert names == ["grouped_matmul", "grouped_matmul"]  # forward + dgrad

@@ -1,0 +1,111 @@
+"""Compiled parity for the two Pallas kernels that post-date the flash
+forward/backward: ``flash_decode_attention`` and ``grouped_matmul``
+(forward + grad), plus flash attention at a sequence length that is not a
+multiple of any block. bf16 inputs; references in float32 at "highest"
+matmul precision (on a TPU an f32 einsum otherwise multiplies in bf16).
+
+Tolerances: one bf16 rounding of an O(1) output is 2^-8 = 0.4% and the
+backward rounds p/ds to bf16 before its matmuls, so errors are bounded at
+2% (forward) and 4% (gradients) of the reference's largest magnitude —
+computing below bf16, or dropping a term, lands far outside.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from deeplearning4j_tpu.ops.flash_attention import (
+    decode_attention_reference, flash_attention, flash_decode_attention,
+    mha_attention_reference)
+from deeplearning4j_tpu.ops.grouped_matmul import (
+    grouped_matmul, grouped_matmul_reference)
+
+FWD_TOL, GRAD_TOL = 2e-2, 4e-2
+
+
+def _rand(seed, *shape):
+    return jax.random.normal(jax.random.PRNGKey(seed), shape, jnp.bfloat16)
+
+
+def _highest(fn, *args):
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(fn)(*(
+            a.astype(jnp.float32) if jnp.issubdtype(a.dtype, jnp.floating)
+            else a for a in args))
+
+
+def _rel(got, ref):
+    got = np.asarray(got, np.float32)
+    ref = np.asarray(ref, np.float32)
+    assert got.shape == ref.shape and np.isfinite(got).all()
+    return float(np.abs(got - ref).max() / (np.abs(ref).max() + 1e-30))
+
+
+@pytest.mark.parametrize("L,block_k", [(1024, 512), (600, 512), (64, 512)])
+def test_flash_decode_compiled_matches_reference(tpu_device, L, block_k):
+    b, h, d = 8, 12, 64
+    q, k, v = _rand(0, b, h, 1, d), _rand(1, b, h, L, d), _rand(2, b, h, L, d)
+    pos = jnp.asarray([0, L // 2 - 1, L // 2, L - 1, -1, 5, L // 3, L - 2],
+                      jnp.int32)
+    got = jax.jit(lambda *a: flash_decode_attention(
+        *a, block_k=block_k, interpret=False))(q, k, v, pos)
+    ref = _highest(decode_attention_reference, q, k, v, pos)
+    assert _rel(got, ref) < FWD_TOL
+    # the inactive row (pos -1) attends nothing and outputs exactly 0
+    assert not np.asarray(got, np.float32)[4].any()
+
+
+@pytest.mark.parametrize("cap", [2560, None])
+def test_grouped_matmul_compiled_matches_reference(tpu_device, cap):
+    n, d, e, h = 16384, 768, 8, 1536
+    lhs, w = _rand(3, n, d), _rand(4, n, h)
+    rhs = _rand(5, e, d, h) * 0.05
+    rs = np.random.RandomState(0)
+    sizes = rs.multinomial(n - n // 16, rs.dirichlet(np.ones(e) * 2.0))
+    if cap is not None:
+        sizes = np.minimum(sizes, cap)
+    sizes = jnp.asarray(sizes, jnp.int32)
+
+    def loss(fn, lhs, rhs, **kw):
+        o = fn(lhs, sizes, rhs, max_group_size=cap, **kw)
+        return jnp.sum(o.astype(jnp.float32) * w.astype(jnp.float32)), o
+
+    step = jax.jit(jax.grad(
+        lambda a, b: loss(grouped_matmul, a, b, interpret=False),
+        argnums=(0, 1), has_aux=True))
+    # "auto" must have picked the Pallas kernel here, forward and dgrad
+    assert step.lower(lhs, rhs).as_text().count(
+        'kernel_name = "grouped_matmul"') == 2
+    (dl, dr), out = step(lhs, rhs)
+    (rdl, rdr), rout = _highest(jax.grad(
+        lambda a, b: loss(grouped_matmul_reference, a, b),
+        argnums=(0, 1), has_aux=True), lhs, rhs)
+    assert _rel(out, rout) < FWD_TOL
+    assert _rel(dl, rdl) < GRAD_TOL
+    assert _rel(dr, rdr) < GRAD_TOL
+    # rows parked past the frontier come back as exact zeros
+    assert not np.asarray(out, np.float32)[int(sizes.sum()):].any()
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_unaligned_length_compiled(tpu_device, causal):
+    """t=600: no block size divides it, so every kernel pads."""
+    b, h, t, d = 2, 12, 600, 64
+    q, k, v, g = (_rand(10 + i, b, h, t, d) for i in range(4))
+    mask = jnp.ones((b, t), jnp.float32).at[-1, t // 2:].set(0.0)
+
+    def loss(fn, q, k, v, **kw):
+        o = fn(q, k, v, mask=mask, causal=causal, **kw)
+        return jnp.sum(o.astype(jnp.float32) * g.astype(jnp.float32)), o
+
+    grads, out = jax.jit(jax.grad(
+        lambda *a: loss(flash_attention, *a, interpret=False),
+        argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    rgrads, rout = _highest(jax.grad(
+        lambda *a: loss(mha_attention_reference, *a),
+        argnums=(0, 1, 2), has_aux=True), q, k, v)
+    assert _rel(out, rout) < FWD_TOL
+    for name, a, r in zip("qkv", grads, rgrads):
+        assert _rel(a, r) < GRAD_TOL, f"d{name}"
