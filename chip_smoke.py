@@ -22,8 +22,11 @@ entry points a user calls, and checks what comes out:
 Without a TPU it exits 2 and prints no result. ``--dry-run-cpu`` is the CPU
 rehearsal: toy sizes, interpreted kernels, ``"dry_run": true`` in the
 result — it proves the script's own control flow and nothing about a chip.
-A phase that fails raises; nothing is caught and summarised. The last
-stdout line is one JSON object. A chip belongs to one process, so no child
+A phase that fails raises; nothing is caught and summarised. Stdout ends
+with two JSON lines: the report (versions, compile-cache directory,
+per-phase seconds, environment facts), then the verdict the driver reads,
+``{"ok": true, "device": {"platform", "kind", "count"}}`` with exactly
+those keys. A chip belongs to one process, so no child
 process ever touches JAX: the only one there can be is the host-side
 native build that ``native.available()`` runs to completion when the
 library is absent, after the phases.
@@ -576,10 +579,10 @@ def main(argv=None) -> int:
     except ImportError:
         libtpu_version = None
     stats = dev.memory_stats() or {}
-    result = dict(
-        ok=True,
-        device=dict(platform=dev.platform, kind=dev.device_kind,
-                    count=len(jax.devices())),
+    device = dict(platform=dev.platform, kind=dev.device_kind,
+                  count=len(jax.devices()))
+    report = dict(
+        device=device,
         dry_run=bool(args.dry_run_cpu),
         jax=jax.__version__, jaxlib=jaxlib.__version__,
         libtpu=libtpu_version,
@@ -588,7 +591,9 @@ def main(argv=None) -> int:
         peak_bytes_in_use=stats.get("peak_bytes_in_use"),
         wall_s=round(time.perf_counter() - t_start, 2),
         phases=phases, env_facts=facts)
-    print(json.dumps(result))
+    print(json.dumps(report))
+    # the verdict: these keys and no others (the driver checks the set)
+    print(json.dumps(dict(ok=True, device=device)), flush=True)
     return 0
 
 

@@ -18,9 +18,12 @@ def test_dry_mode_passes_and_labels_itself(tmp_path):
     rc, stdout, stderr = finish(
         start([SMOKE, "--dry-run-cpu"], cache_dir=tmp_path, devices=4))
     assert rc == 0, stderr[-2000:]
-    result = json.loads(stdout.strip().splitlines()[-1])
-    assert result["ok"] is True and result["dry_run"] is True
-    assert result["device"] == {"platform": "cpu", "kind": "cpu", "count": 4}
+    report_line, verdict_line = stdout.strip().splitlines()[-2:]
+    # the last line holds exactly the keys the driver's chip check reads
+    device = {"platform": "cpu", "kind": "cpu", "count": 4}
+    assert json.loads(verdict_line) == {"ok": True, "device": device}
+    result = json.loads(report_line)
+    assert result["dry_run"] is True and result["device"] == device
     assert result["compile_cache_dir"] == str(tmp_path)
     for phase in ("train", "serve", "kernels", "four_chips"):
         assert result["phases"][phase]["ok"] is True

@@ -35,7 +35,7 @@ if [ "$1" != "fast" ]; then
 
   echo "== chip smoke, CPU rehearsal (toy sizes, interpreted kernels)"
   smoke_out=$(JAX_PLATFORMS=cpu python chip_smoke.py --dry-run-cpu)
-  echo "$smoke_out" | tail -1
+  echo "$smoke_out" | tail -2
 
   echo "== benchmark artifact smoke (lstm row, cpu config)"
   # no pipe: POSIX sh has no pipefail, and `| tail` would mask a crash
