@@ -195,6 +195,7 @@ class ComputationGraph:
                 lstate = dict(state.get(spec.name, {}))
                 y, lstate_out = _apply_layer(
                     spec.layer, params.get(spec.name, {}), lstate, x, ctx,
+                    name=spec.name,
                     remat=self.conf.gradient_checkpointing and train)
                 persistent = self._persistent_keys.get(spec.name, ())
                 new_state[spec.name] = {k: v for k, v in lstate_out.items() if k in persistent}
@@ -252,12 +253,16 @@ class ComputationGraph:
                     and spec.name in label_by_output
                 )
                 if is_loss_output:
-                    losses[spec.name] = spec.layer.compute_loss(
-                        params.get(spec.name, {}), x, label_by_output[spec.name],
-                        ctx, label_mask=lmask_by_output.get(spec.name),
-                    )
+                    # under the layer's name, as apply_layer puts the others
+                    with jax.named_scope(spec.name):
+                        losses[spec.name] = spec.layer.compute_loss(
+                            params.get(spec.name, {}), x,
+                            label_by_output[spec.name], ctx,
+                            label_mask=lmask_by_output.get(spec.name),
+                        )
                 y, lstate_out = _apply_layer(
                     spec.layer, params.get(spec.name, {}), lstate, x, ctx,
+                    name=spec.name,
                     remat=self.conf.gradient_checkpointing and train)
                 persistent = self._persistent_keys.get(spec.name, ())
                 new_state[spec.name] = {k: v for k, v in lstate_out.items() if k in persistent}

@@ -171,17 +171,24 @@ def apply_input_dropout(cfg: Layer, x: jax.Array, ctx: LayerContext) -> jax.Arra
     return jax.numpy.where(keep, x / retain, 0.0).astype(x.dtype)
 
 
-def apply_layer(layer, lparams, lstate, x, ctx, *, remat: bool = False):
-    """Layer apply, optionally under jax.checkpoint: the backward then
-    recomputes this layer's intermediates (attention probs, FFN hidden)
-    instead of holding them in HBM — SURVEY §7's remat trade. Homed here
-    next to LayerContext so both network classes import it cycle-free."""
-    if not remat:
-        return layer.apply(lparams, lstate, x, ctx)
+def apply_layer(layer, lparams, lstate, x, ctx, *, name: str,
+                remat: bool = False):
+    """Layer apply under ``jax.named_scope(name)`` (the layer's name in its
+    network: metadata on every operation it lowers to, which a profile
+    groups device time by), optionally under jax.checkpoint: the backward
+    then recomputes this layer's intermediates (attention probs, FFN
+    hidden) instead of holding them in HBM — SURVEY §7's remat trade. Homed
+    here next to LayerContext so both network classes import it
+    cycle-free."""
+    with jax.named_scope(name):
+        if not remat:
+            return layer.apply(lparams, lstate, x, ctx)
 
-    def fn(p, s, xx, key, mask):
-        # dist is static config (axis name / group sizes), safe to close over
-        c = LayerContext(train=ctx.train, rng=key, mask=mask, dist=ctx.dist)
-        return layer.apply(p, s, xx, c)
+        def fn(p, s, xx, key, mask):
+            # dist is static config (axis name / group sizes), safe to
+            # close over
+            c = LayerContext(train=ctx.train, rng=key, mask=mask,
+                             dist=ctx.dist)
+            return layer.apply(p, s, xx, c)
 
-    return jax.checkpoint(fn)(lparams, lstate, x, ctx.rng, ctx.mask)
+        return jax.checkpoint(fn)(lparams, lstate, x, ctx.rng, ctx.mask)

@@ -199,7 +199,7 @@ class MultiLayerNetwork:
             key = jax.random.fold_in(rng, i) if rng is not None else None
             ctx = LayerContext(train=train, rng=key, mask=cur_mask, dist=dist)
             y, lstate_out = _apply_layer(
-                layer, params.get(name, {}), lstate, x, ctx,
+                layer, params.get(name, {}), lstate, x, ctx, name=name,
                 remat=self.conf.gradient_checkpointing and train)
             persistent = self._persistent_keys.get(name, ())
             new_state[name] = {k: v for k, v in lstate_out.items() if k in persistent}
@@ -253,7 +253,9 @@ class MultiLayerNetwork:
         name = self.conf.layer_name(len(self.layers) - 1)
         key = jax.random.fold_in(rng, len(self.layers) - 1) if rng is not None else None
         ctx = LayerContext(train=train, rng=key, mask=cur_mask)
-        loss = out_layer.compute_loss(params.get(name, {}), feat, labels, ctx, label_mask=label_mask)
+        with jax.named_scope(name):  # as apply_layer names the others
+            loss = out_layer.compute_loss(params.get(name, {}), feat, labels,
+                                          ctx, label_mask=label_mask)
         # output layer state passes through unchanged (loss layers are stateless)
         new_state[name] = dict(state.get(name, {}))
         # score in >= float32 precision; float64 models keep float64 (gradcheck)

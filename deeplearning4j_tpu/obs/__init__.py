@@ -13,7 +13,10 @@ naming convention and the ``stats()`` ↔ metrics mapping.
 Tracing (``obs/tracing.py``): :class:`Tracer`/:class:`TraceSpan` give
 requests identity (W3C ``traceparent``) and parent/child structure across
 the client→server→engine hop, exported to a bounded :class:`TraceStore`
-served by ``GET /v1/traces``. :class:`StepProfiler`
+served by ``GET /v1/traces``; engine loop turns and training steps are
+traces of the same tracer, mirrored into a ``jax.profiler`` session while
+one collects, and ``obs/compiles.py`` counts backend compiles where they
+happen. :class:`StepProfiler`
 (``obs/step_profiler.py``) attributes training step time to
 data_wait/h2d/compute/host phases with sampled device fencing. README
 "Tracing & step-time attribution".

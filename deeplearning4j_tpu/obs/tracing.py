@@ -25,6 +25,16 @@ the wire format):
   no spans stored (``tools/check_trace_contract.py`` enforces this and
   the <3% enabled overhead bound in bench's ``tracing_overhead`` row).
 
+Not only requests are traces: a turn of ``DecodeEngine._loop``
+(``loop.turn``) and a training step (``fit.step``) are roots of the same
+tracer, head-sampled like any request. While a ``jax.profiler`` session
+collects (:func:`profiling`) every root is sampled and marked
+``profiled``, and every span entered as a context manager is also a
+``jax.profiler.TraceAnnotation``: the same spans on the host plane of the
+profiler's trace, on the clock the device's operations are on
+(``tools/host_gaps.py`` reads them there). Each span of an assembled
+trace carries ``self_ms``, its duration less what its children cover.
+
 Timestamps: every span timestamp is ``perf_counter`` anchored to one
 process-wide wall-clock epoch, so timestamps are strictly monotonic
 across threads (wall-clock steps can never reorder a parent after its
@@ -36,6 +46,7 @@ from __future__ import annotations
 import contextvars
 import os
 import queue
+import random
 import threading
 import time
 from collections import OrderedDict
@@ -52,6 +63,7 @@ __all__ = [
     "decode_traceparent",
     "encode_traceparent",
     "get_tracer",
+    "profiling",
     "set_tracer",
     "trace_now",
 ]
@@ -66,12 +78,42 @@ def trace_now() -> float:
     return _EPOCH + time.perf_counter()
 
 
+_annotation_cls = None  # jax.profiler.TraceAnnotation, once imported
+
+
+def profiling() -> bool:
+    """True while a ``jax.profiler`` session is collecting (a static query
+    of the profiler's flag, some 0.1 us). JAX is imported lazily, and
+    without it (the remote client imports this module alone) nothing ever
+    collects."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            return False
+        _annotation_cls = TraceAnnotation
+    return _annotation_cls.is_enabled()
+
+
+# Ids and sampling draw from one generator in user space, seeded from the
+# system's (and again in a forked child), and not from ``os.urandom``, so
+# that no span costs a system call: that one releases the interpreter
+# lock, and on an engine loop's thread, between one step's emit and the
+# next step's dispatch, the lock then goes to whichever of the woken client
+# threads is runnable (PERF.md §6, PR 26: suspected of half a percent of
+# the serve cell's step; the runs did not resolve it).
+_rng = random.Random()
+if hasattr(os, "register_at_fork"):
+    os.register_at_fork(after_in_child=_rng.seed)
+
+
 def _new_trace_id() -> str:
-    return os.urandom(16).hex()
+    return f"{_rng.getrandbits(128):032x}"
 
 
 def _new_span_id() -> str:
-    return os.urandom(8).hex()
+    return f"{_rng.getrandbits(64):016x}"
 
 
 class TraceContext:
@@ -174,7 +216,7 @@ class TraceSpan:
 
     __slots__ = ("tracer", "name", "trace_id", "span_id", "parent_id",
                  "sampled", "attributes", "start_time", "end_time", "error",
-                 "_token", "_finished")
+                 "_token", "_finished", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, trace_id: str,
                  span_id: str, parent_id: Optional[str], sampled: bool,
@@ -191,6 +233,7 @@ class TraceSpan:
         self.error = False
         self._token = None
         self._finished = False
+        self._annotation = None
 
     @property
     def context(self) -> TraceContext:
@@ -211,6 +254,13 @@ class TraceSpan:
 
     def __enter__(self) -> "TraceSpan":
         self._token = _current_span.set(self)
+        if profiling():
+            # the same span in the profiler's trace, on this thread's line
+            # and on the clock the device's operations are on
+            self._annotation = _annotation_cls(self.name, **{
+                k: v for k, v in self.attributes.items()
+                if isinstance(v, (bool, int, float, str))})
+            self._annotation.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
@@ -220,6 +270,13 @@ class TraceSpan:
         if self._token is not None:
             _current_span.reset(self._token)
             self._token = None
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
+            if not profiling():
+                # the session stopped under this span: it is not one of
+                # the spans that lay inside the traced slice
+                self.attributes.pop("profiled", None)
         if exc is not None:
             self.record_exception(exc)
         elif exc_type is not None:
@@ -298,6 +355,25 @@ NULL_SPAN = _NullSpan()
 # ---------------------------------------------------------------------------
 # store
 # ---------------------------------------------------------------------------
+def _self_ms(spans: List[dict]) -> List[float]:
+    """Each span's self time in ms: its duration less the part of its own
+    interval that its children cover (the union of their intervals, so
+    children that overlap count once). ``spans`` is sorted by start."""
+    children: Dict[str, List[dict]] = {}
+    for s in spans:
+        children.setdefault(s["parent_id"], []).append(s)
+    out = []
+    for s in spans:
+        covered, upto = 0.0, s["start"]
+        for c in children.get(s["span_id"], ()):
+            lo, hi = max(c["start"], upto), min(c["end"], s["end"])
+            if hi > lo:
+                covered += hi - lo
+                upto = hi
+        out.append(round((s["end"] - s["start"] - covered) * 1e3, 6))
+    return out
+
+
 class TraceStore:
     """Bounded in-memory index of completed spans, grouped by trace.
 
@@ -369,6 +445,8 @@ class TraceStore:
         end = max((s["end"] for s in spans), default=0.0)
         routes = sorted({s["attrs"]["route"] for s in spans
                          if "route" in s["attrs"]})
+        spans = [dict(s, self_ms=ms)
+                 for s, ms in zip(spans, _self_ms(spans))]
         return {
             "trace_id": trace_id,
             "root": root["name"] if root else None,
@@ -451,9 +529,8 @@ class Tracer:
             return True
         if self.sample_rate <= 0.0:
             return False
-        # 53 random bits -> uniform [0, 1); no global random state touched
-        return (int.from_bytes(os.urandom(7), "big") >> 3) < \
-            self.sample_rate * (1 << 53)
+        # uniform [0, 1); no global random state touched, no system call
+        return _rng.random() < self.sample_rate
 
     def span(self, name: str, *,
              parent: Union[TraceContext, TraceSpan, None, str] = "current",
@@ -464,7 +541,11 @@ class Tracer:
         explicit :class:`TraceContext` (e.g. decoded from ``traceparent``)
         to continue a remote trace, or ``None`` to force a new root. A
         head-unsampled root — and any child of an unsampled context —
-        returns :data:`NULL_SPAN`, the zero-cost path.
+        returns :data:`NULL_SPAN`, the zero-cost path. While a
+        ``jax.profiler`` session collects (:func:`profiling`) every root
+        is sampled and carries ``attrs["profiled"] = True``, and every
+        span entered as a context manager is also a
+        ``jax.profiler.TraceAnnotation`` of the same name.
         """
         if not self.enabled:
             return NULL_SPAN
@@ -473,7 +554,11 @@ class Tracer:
         if isinstance(parent, TraceSpan):
             parent = parent.context
         if parent is None:
-            if not self._sample():
+            # a profiler session takes every root, so that the slice it
+            # traces is whole in the store too; the mark tells those apart
+            if profiling():
+                attrs = dict(attrs or (), profiled=True)
+            elif not self._sample():
                 return NULL_SPAN
             return TraceSpan(self, name, _new_trace_id(), _new_span_id(),
                              None, True, attrs)

@@ -42,8 +42,14 @@ instead of queueing behind a broken jit.
 
 Observability: ``dl4j_tpu_generate_tokens_total``, per-token decode
 latency + prefill latency histograms and an in-flight-sequences gauge in
-the registry; traced requests get ``engine.prefill`` and
-``engine.decode`` child spans in ``/v1/traces``.
+the registry; traced requests get ``engine.queue_wait``,
+``engine.prefill`` and ``engine.decode`` child spans in ``/v1/traces``.
+Every pass of the loop that has work is itself a ``loop.turn`` trace of
+the engine's tracer (children ``loop.admit`` > ``loop.prefill`` >
+``loop.prefill.dispatch``/``.sync``/``loop.install``; ``loop.step`` >
+``loop.upload``/``loop.dispatch``/``loop.fetch``/``loop.emit``;
+``loop.sweep``), head-sampled like a request and taken whole while a
+``jax.profiler`` session collects (README "Tracing").
 """
 
 from __future__ import annotations
@@ -80,7 +86,9 @@ from ..generate.sampling import sample_tokens
 from ..generate.session import GenerationSession, SpeculativeGenerationSession
 from ..ops.paged_attention import pack_row_blocks
 from ..obs.metrics import MetricsRegistry, get_registry
-from ..obs.tracing import Tracer, current_context, get_tracer, trace_now
+from ..obs.compiles import watch_compiles
+from ..obs.tracing import (NULL_SPAN, Tracer, current_context, get_tracer,
+                           trace_now)
 
 _engine_seq = itertools.count()
 
@@ -172,7 +180,7 @@ class GenerationHandle:
 class _Request:
     __slots__ = ("prompt", "max_tokens", "eos_id", "handle", "seed",
                  "greedy", "temp", "top_k", "top_p", "spec_k", "trace_ctx",
-                 "t_submit", "t_decode_start", "prefilled")
+                 "t_submit", "t_decode_start", "prefilled", "seq")
 
     def __init__(self, prompt, max_tokens, eos_id, handle, seed, greedy,
                  temp, top_k, top_p, spec_k, trace_ctx,
@@ -189,8 +197,9 @@ class _Request:
         self.spec_k = spec_k  # None = follow the engine's adaptive k
         self.prefilled = prefilled  # disagg handoff payload (or None)
         self.trace_ctx = trace_ctx
-        self.t_submit = trace_now() if trace_ctx is not None else 0.0
+        self.t_submit = trace_now()
         self.t_decode_start = 0.0
+        self.seq = -1  # per-engine sequence number, stamped at enqueue
 
 
 class DecodeEngine:
@@ -339,6 +348,12 @@ class DecodeEngine:
         self._next_adjust = clock() + self._adjust_interval
 
         self._pending: "deque[_Request]" = deque()
+        # the loop spans' counts: requests enqueued (``req``), passes of
+        # the loop that had work (``turn``), requests finished (``retired``)
+        self._n_submitted = 0
+        self._n_turns = 0
+        self._n_finished = 0
+        watch_compiles()
         self._lock = threading.Lock()
         self._wake = threading.Event()
         self._shutdown = False
@@ -504,6 +519,8 @@ class DecodeEngine:
                                     gflag, temp, k, p)
                 return new_rnn, tok[0]
 
+            # the profiler's "XLA Modules" line shows jit_<name>
+            fn.__name__ = f"prefill_{tb}"
             self._fns[key] = jax.jit(fn)
         return self._fns[key]
 
@@ -524,6 +541,7 @@ class DecodeEngine:
                     mask=mask, rnn_state=row_carry)
                 return new_rnn
 
+            fn.__name__ = f"draft_prefill_{tb}"
             self._fns[key] = jax.jit(fn)
         return self._fns[key]
 
@@ -532,27 +550,31 @@ class DecodeEngine:
             sess = self.session
             model = sess.model
 
-            def fn(params, state, carry, tokens, active, seeds, steps,
-                   gmask, temps, ks, ps):
+            def decode_step(params, state, carry, tokens, active, seeds,
+                            steps, gmask, temps, ks, ps):
                 # paged carries: inactive rows write the trash block, not
                 # their own live blocks (the fused step writes every row)
                 fwd = redirect_inactive_writes(carry, active)
-                out, _, new_rnn = model.forward_pure(
-                    params, state, sess._prep(tokens[:, None]), train=False,
-                    rng=None, mask=None, rnn_state=fwd)
-                logits = sess._logits(out)[:, :, 0]
-                toks = sample_tokens(logits, seeds, steps, gmask, temps, ks,
-                                     ps)
+                with jax.named_scope("forward"):
+                    out, _, new_rnn = model.forward_pure(
+                        params, state, sess._prep(tokens[:, None]),
+                        train=False, rng=None, mask=None, rnn_state=fwd)
+                with jax.named_scope("logits"):
+                    logits = sess._logits(out)[:, :, 0]
+                with jax.named_scope("sample"):
+                    toks = sample_tokens(logits, seeds, steps, gmask, temps,
+                                         ks, ps)
                 # idle/finished slots must not advance their cache or (h, c)
-                new_rnn = freeze_rows(new_rnn, carry, active)
+                with jax.named_scope("freeze_rows"):
+                    new_rnn = freeze_rows(new_rnn, carry, active)
                 return new_rnn, jnp.where(active, toks, 0)
 
-            self._fns["decode"] = jax.jit(fn)
+            self._fns["decode"] = jax.jit(decode_step)
         return self._fns["decode"]
 
     def _write_row_fn(self):
         if "write" not in self._fns:
-            def fn(carry, row, i):
+            def install_row(carry, row, i):
                 def put(c, r):
                     z = jnp.zeros((), i.dtype)
                     idx = (i,) + (z,) * (c.ndim - 1)
@@ -561,7 +583,7 @@ class DecodeEngine:
 
                 return jax.tree_util.tree_map(put, carry, row)
 
-            self._fns["write"] = jax.jit(fn)
+            self._fns["write"] = jax.jit(install_row)
         return self._fns["write"]
 
     def _paged_install_fn(self):
@@ -573,7 +595,7 @@ class DecodeEngine:
         if "paged_install" not in self._fns:
             bs = self.block_size
 
-            def fn(carry, row, dest, slot):
+            def paged_install(carry, row, dest, slot):
                 out = {}
                 for name, st in carry.items():
                     r = row[name]
@@ -589,7 +611,7 @@ class DecodeEngine:
                     out[name] = new_st
                 return out
 
-            self._fns["paged_install"] = jax.jit(fn)
+            self._fns["paged_install"] = jax.jit(paged_install)
         return self._fns["paged_install"]
 
     def _install_row(self, slot: int, row) -> None:
@@ -670,6 +692,8 @@ class DecodeEngine:
                 self._c["shed"].inc()
                 raise
             self._g_inflight.inc()
+            req.seq = self._n_submitted
+            self._n_submitted += 1
             self._pending.append(req)
         self._wake.set()
         return handle
@@ -742,6 +766,8 @@ class DecodeEngine:
                 self._c["shed"].inc()
                 raise
             self._g_inflight.inc()
+            req.seq = self._n_submitted
+            self._n_submitted += 1
             self._pending.append(req)
         self._wake.set()
         return handle
@@ -750,6 +776,7 @@ class DecodeEngine:
     def _finish(self, req: _Request, reason: str,
                 error: Optional[str] = None) -> None:
         req.handle._finish(reason, error)
+        self._n_finished += 1
         outcome = reason if reason in _OUTCOMES else "completed"
         self._c[outcome].inc()
         self._admission.release()
@@ -768,19 +795,24 @@ class DecodeEngine:
                 return i
         return None
 
-    def _admit(self) -> None:
+    def _admit(self, parent=NULL_SPAN) -> int:
+        """Prefill pending requests into free slots; returns how many were
+        admitted. ``parent`` is the turn's ``loop.admit`` span: every span
+        under the loop takes its parent explicitly, because an unsampled
+        root is ``NULL_SPAN``, which never becomes the current span."""
+        admitted = 0
         while True:
             # AIMD admission pacing: fill at most slot_target cache slots
             # even when more are free (the controller shrinks the target
             # when per-token p95 breaches the budget)
             if int(self._active.sum()) >= self._slot_target:
-                return
+                return admitted
             slot = self._free_slot()
             with self._lock:
                 if not self._pending:
-                    return
+                    return admitted
                 if slot is None:
-                    return
+                    return admitted
                 req = self._pending.popleft()
             if req.handle.cancelled:
                 self._finish(req, "cancelled")
@@ -788,8 +820,20 @@ class DecodeEngine:
             if req.handle.deadline.expired():
                 self._finish(req, "deadline")
                 continue
+            t_pop = trace_now()
+            tracer = self.tracer
+            if req.trace_ctx is not None:
+                tracer.record_span(
+                    "engine.queue_wait", parent=req.trace_ctx,
+                    start_time=req.t_submit, end_time=t_pop,
+                    attrs={"engine": self.name})
             try:
-                self._prefill_into(slot, req)
+                with tracer.span("loop.prefill", parent=parent, attrs={
+                        "req": req.seq, "slot": slot,
+                        "queue_wait_ms": (t_pop - req.t_submit) * 1e3,
+                        "prompt_len": len(req.prompt)}) as span:
+                    self._prefill_into(slot, req, span)
+                admitted += 1
             except OutOfBlocksError as e:
                 # transient when rows are mid-flight (their blocks free at
                 # retire): requeue and retry next wake. Terminal when the
@@ -797,49 +841,58 @@ class DecodeEngine:
                 if self._active.any():
                     with self._lock:
                         self._pending.appendleft(req)
-                    return
+                    return admitted
                 self._finish(req, "failed", error=str(e))
             except Exception as e:  # noqa: BLE001 — fail the request, not the loop
                 self._breaker.record_failure()
                 self._finish(req, "failed", error=str(e))
 
-    def _prefill_into(self, slot: int, req: _Request) -> None:
+    def _prefill_into(self, slot: int, req: _Request,
+                      parent=NULL_SPAN) -> None:
         sess = self.session
         if self._allocator is not None:
             # reserve blocks for the committed prompt BEFORE any compute:
             # OutOfBlocksError here is cheap and leaves nothing to undo
             self._ensure_blocks(slot, len(req.prompt))
         try:
-            self._prefill_into_reserved(slot, req)
+            self._prefill_into_reserved(slot, req, parent)
         except Exception:
             self._release_blocks(slot)
             raise
 
-    def _prefill_into_reserved(self, slot: int, req: _Request) -> None:
+    def _prefill_into_reserved(self, slot: int, req: _Request,
+                               parent=NULL_SPAN) -> None:
         sess = self.session
+        span = self.tracer.span
         tb = min(
             next(s for s in sess.bucket_sizes() if s >= len(req.prompt)),
             self.max_len)
-        ids = np.zeros((1, tb), np.int32)
-        ids[0, : len(req.prompt)] = req.prompt
-        t0 = time.perf_counter()
-        tt0 = trace_now() if req.trace_ctx is not None else 0.0
-        if req.prefilled is not None:
-            # disaggregated handoff: the prefill tier already ran the
-            # bucketed prefill and sampled the first token — install its
-            # shipped cache slice instead of recomputing
-            row, first = self._handoff_row(req.prefilled)
-        else:
-            row, tok = self._prefill_fn(tb)(
-                sess.model.params, sess.model.state, self._row_template,
-                jnp.asarray(ids), jnp.asarray([len(req.prompt)], jnp.int32),
-                jnp.asarray([req.seed], jnp.uint32),
-                jnp.asarray([req.greedy], bool),
-                jnp.asarray([req.temp], jnp.float32),
-                jnp.asarray([req.top_k], jnp.int32),
-                jnp.asarray([req.top_p], jnp.float32))
-            first = int(tok)
-        self._install_row(slot, row)
+        parent.set_attribute("bucket", tb)
+        with span("loop.prefill.dispatch", parent=parent):
+            ids = np.zeros((1, tb), np.int32)
+            ids[0, : len(req.prompt)] = req.prompt
+            t0 = time.perf_counter()
+            tt0 = trace_now() if req.trace_ctx is not None else 0.0
+            if req.prefilled is not None:
+                # disaggregated handoff: the prefill tier already ran the
+                # bucketed prefill and sampled the first token — install
+                # its shipped cache slice instead of recomputing
+                row, first = self._handoff_row(req.prefilled)
+            else:
+                row, tok = self._prefill_fn(tb)(
+                    sess.model.params, sess.model.state, self._row_template,
+                    jnp.asarray(ids),
+                    jnp.asarray([len(req.prompt)], jnp.int32),
+                    jnp.asarray([req.seed], jnp.uint32),
+                    jnp.asarray([req.greedy], bool),
+                    jnp.asarray([req.temp], jnp.float32),
+                    jnp.asarray([req.top_k], jnp.int32),
+                    jnp.asarray([req.top_p], jnp.float32))
+        if req.prefilled is None:
+            with span("loop.prefill.sync", parent=parent):
+                first = int(tok)
+        with span("loop.install", parent=parent):
+            self._install_row(slot, row)
         cap = -1 if req.spec_k is None else min(req.spec_k,
                                                 self.max_speculative_k)
         if self._spec is not None and cap != 0:
@@ -949,11 +1002,14 @@ class DecodeEngine:
                 self._finish(req, "failed", error=str(e))
         self._g_active.set(0)
 
-    def _step(self, rows: Optional[np.ndarray] = None) -> None:
+    def _step(self, rows: Optional[np.ndarray] = None,
+              parent=NULL_SPAN) -> None:
         """One plain [B, 1] decode step over ``rows`` (default: every
         active slot — the non-speculative path, and the boundary fallback
-        for rows whose remaining cache room cannot hold a k+1 window)."""
+        for rows whose remaining cache room cannot hold a k+1 window).
+        ``parent`` is the turn's ``loop.step`` span."""
         sess = self.session
+        span = self.tracer.span
         rows = self._active if rows is None else rows
         if self._allocator is not None:
             rows = self._reserve_rows(rows, 1)
@@ -961,34 +1017,39 @@ class DecodeEngine:
                 return
         t0 = time.perf_counter()
         try:
-            self._carry, toks = self._decode_step_fn()(
-                sess.model.params, sess.model.state, self._carry,
-                jnp.asarray(self._last), jnp.asarray(rows),
-                jnp.asarray(self._seeds), jnp.asarray(self._steps),
-                jnp.asarray(self._greedy), jnp.asarray(self._temps),
-                jnp.asarray(self._ks), jnp.asarray(self._ps))
-            toks_h = np.asarray(toks)
+            with span("loop.upload", parent=parent):
+                args = (
+                    jnp.asarray(self._last), jnp.asarray(rows),
+                    jnp.asarray(self._seeds), jnp.asarray(self._steps),
+                    jnp.asarray(self._greedy), jnp.asarray(self._temps),
+                    jnp.asarray(self._ks), jnp.asarray(self._ps))
+            with span("loop.dispatch", parent=parent):
+                self._carry, toks = self._decode_step_fn()(
+                    sess.model.params, sess.model.state, self._carry, *args)
+            with span("loop.fetch", parent=parent):
+                toks_h = np.asarray(toks)
         except Exception as e:  # noqa: BLE001 — poisoned step: fail active requests
             self._fail_active(e)
             return
         dt = time.perf_counter() - t0
         self._h_decode.observe(dt)
         self._breaker.record_success()
-        for slot in np.nonzero(rows)[0]:
-            req = self._requests[slot]
-            tok = int(toks_h[slot])
-            emitted = len(req.handle.tokens)
-            req.handle._emit(emitted, tok)
-            self._last[slot] = tok
-            self._steps[slot] += 1
-            self._pos[slot] += 1
-            self._c_tokens.inc()
-            self._h_token.observe(dt)
-            self._retire_if_done(slot, tok, emitted + 1)
+        with span("loop.emit", parent=parent):
+            for slot in np.nonzero(rows)[0]:
+                req = self._requests[slot]
+                tok = int(toks_h[slot])
+                emitted = len(req.handle.tokens)
+                req.handle._emit(emitted, tok)
+                self._last[slot] = tok
+                self._steps[slot] += 1
+                self._pos[slot] += 1
+                self._c_tokens.inc()
+                self._h_token.observe(dt)
+                self._retire_if_done(slot, tok, emitted + 1)
         if self._step_hook is not None:
             self._step_hook()
 
-    def _spec_step(self) -> None:
+    def _spec_step(self, parent=NULL_SPAN) -> None:
         """One speculative engine turn: propose/verify/accept for every
         row with cache room for the full k+1 window, then a plain [B, 1]
         step for the remainder (rows near ``max_len``, and requests with
@@ -1013,19 +1074,23 @@ class DecodeEngine:
                 except OutOfBlocksError:
                     spec_rows[slot] = False
         plain_rows = self._active & ~spec_rows
+        span = self.tracer.span
         if spec_rows.any():
             t0 = time.perf_counter()
             try:
-                (self._carry, self._draft_carry, toks, n_acc,
-                 n_emit) = self._spec.step(
-                    self._carry, self._draft_carry, self._last, self._steps,
-                    spec_rows, jnp.asarray(self._seeds),
-                    jnp.asarray(self._greedy), jnp.asarray(self._temps),
-                    jnp.asarray(self._ks), jnp.asarray(self._ps),
-                    np.where(spec_rows, caps, 0), k=k)
-                toks_h = np.asarray(toks)
-                acc_h = np.asarray(n_acc)
-                ne_h = np.asarray(n_emit)
+                # propose, verify and accept are dispatched and fetched
+                # inside the session's step: one span round all of it
+                with span("loop.fetch", parent=parent):
+                    (self._carry, self._draft_carry, toks, n_acc,
+                     n_emit) = self._spec.step(
+                        self._carry, self._draft_carry, self._last,
+                        self._steps, spec_rows, jnp.asarray(self._seeds),
+                        jnp.asarray(self._greedy), jnp.asarray(self._temps),
+                        jnp.asarray(self._ks), jnp.asarray(self._ps),
+                        np.where(spec_rows, caps, 0), k=k)
+                    toks_h = np.asarray(toks)
+                    acc_h = np.asarray(n_acc)
+                    ne_h = np.asarray(n_emit)
             except Exception as e:  # noqa: BLE001
                 self._fail_active(e)
                 return
@@ -1033,30 +1098,31 @@ class DecodeEngine:
             self._h_decode.observe(dt)
             self._breaker.record_success()
             self._c_spec_steps.inc()
-            for slot in np.nonzero(spec_rows)[0]:
-                req = self._requests[slot]
-                if req is None:
-                    continue
-                self._c_spec_proposed.inc(int(caps[slot]))
-                self._c_spec_accepted.inc(int(acc_h[slot]))
-                committed = 0
-                for j in range(int(ne_h[slot])):
-                    tok = int(toks_h[slot, j])
-                    emitted = len(req.handle.tokens)
-                    req.handle._emit(emitted, tok)
-                    self._last[slot] = tok
-                    self._steps[slot] += 1
-                    self._pos[slot] += 1
-                    self._c_tokens.inc()
-                    committed += 1
-                    self._retire_if_done(slot, tok, emitted + 1)
-                    if self._requests[slot] is None:
-                        break  # retired mid-window: drop the tail
-                self._h_token.observe(dt / max(1, committed))
+            with span("loop.emit", parent=parent):
+                for slot in np.nonzero(spec_rows)[0]:
+                    req = self._requests[slot]
+                    if req is None:
+                        continue
+                    self._c_spec_proposed.inc(int(caps[slot]))
+                    self._c_spec_accepted.inc(int(acc_h[slot]))
+                    committed = 0
+                    for j in range(int(ne_h[slot])):
+                        tok = int(toks_h[slot, j])
+                        emitted = len(req.handle.tokens)
+                        req.handle._emit(emitted, tok)
+                        self._last[slot] = tok
+                        self._steps[slot] += 1
+                        self._pos[slot] += 1
+                        self._c_tokens.inc()
+                        committed += 1
+                        self._retire_if_done(slot, tok, emitted + 1)
+                        if self._requests[slot] is None:
+                            break  # retired mid-window: drop the tail
+                    self._h_token.observe(dt / max(1, committed))
             if self._step_hook is not None:
                 self._step_hook()
         if plain_rows.any():
-            self._step(plain_rows)
+            self._step(plain_rows, parent)
 
     def _sweep_pending(self) -> None:
         """Fail pending requests that died in the queue (cancel/expiry)
@@ -1072,32 +1138,63 @@ class DecodeEngine:
 
     def _loop(self) -> None:
         while True:
-            if not self._active.any():
+            if not self._active.any() and not self._pending \
+                    and not self._wait_for_work():
+                return
+            self._turn()
+
+    def _wait_for_work(self) -> bool:
+        """Park until a request is pending; False once the engine is shut
+        down. One ``loop.wait`` root per idle period, however many times
+        the wait polls."""
+        with self.tracer.span("loop.wait", parent=None,
+                              attrs={"engine": self.name}):
+            while True:
                 with self._lock:
-                    has_pending = bool(self._pending)
-                if not has_pending:
-                    if self._shutdown:
-                        return
-                    self._wake.wait(timeout=0.05)
-                    self._wake.clear()
-                    continue
-            self._admit()
+                    if self._pending:
+                        return True
+                if self._shutdown:
+                    return False
+                self._wake.wait(timeout=0.05)
+                self._wake.clear()
+
+    def _turn(self) -> None:
+        """One pass of the loop that has work: admit, step, sweep. The
+        pass is a ``loop.turn`` trace of the engine's tracer (head-sampled
+        at its rate; every pass while a profiler session collects), whose
+        children say where the pass's time went on the host."""
+        span = self.tracer.span
+        self._n_turns += 1
+        finished = self._n_finished
+        with span("loop.turn", parent=None, attrs={
+                "engine": self.name, "turn": self._n_turns,
+                "pending": len(self._pending)}) as turn:
+            with span("loop.admit", parent=turn) as admit:
+                turn.set_attribute("admitted", self._admit(admit))
+            turn.set_attribute("rows", int(self._active.sum()))
             if self._active.any():
-                if self._spec is not None:
-                    self._spec_step()
-                else:
-                    self._step()
-            # also sweep cancelled requests on slots that produced nothing
-            for slot in range(self.slots):
-                req = self._requests[slot]
-                if req is not None and (req.handle.cancelled or
-                                        req.handle.deadline.expired()):
-                    self._retire_if_done(slot, -1, len(req.handle.tokens))
-            self._sweep_pending()
-            if (self._adaptive and self._adjust_interval > 0
-                    and self._clock() >= self._next_adjust):
-                self.adjust()
-                self._next_adjust = self._clock() + self._adjust_interval
+                spec = self._spec is not None
+                with span("loop.step", parent=turn,
+                          attrs={"spec": spec}) as step:
+                    if spec:
+                        self._spec_step(step)
+                    else:
+                        self._step(parent=step)
+            with span("loop.sweep", parent=turn):
+                # also sweep cancelled requests on slots that produced
+                # nothing
+                for slot in range(self.slots):
+                    req = self._requests[slot]
+                    if req is not None and (req.handle.cancelled or
+                                            req.handle.deadline.expired()):
+                        self._retire_if_done(slot, -1,
+                                             len(req.handle.tokens))
+                self._sweep_pending()
+                if (self._adaptive and self._adjust_interval > 0
+                        and self._clock() >= self._next_adjust):
+                    self.adjust()
+                    self._next_adjust = self._clock() + self._adjust_interval
+            turn.set_attribute("retired", self._n_finished - finished)
 
     # ----- decode-side AIMD control -----------------------------------
     @property

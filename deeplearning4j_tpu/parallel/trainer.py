@@ -29,6 +29,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.dtypes import as_input, as_input_np
 from ..nn.layers.base import DistContext
+from ..obs.compiles import watch_compiles
+from ..obs.tracing import get_tracer
 from ..train.solver import LayerOptimizers, _normalize_gradients
 from .mesh import make_mesh, shmap, zero1_partition_spec
 from .strategies import GradientSyncStrategy, SyncAllReduce
@@ -227,6 +229,7 @@ class DistributedTrainer:
         self.strat_state = self._put_tree(strat0, self._replicated)
         self.iteration = 0
         self._step = None
+        watch_compiles()
         self.metrics_every = int(metrics_every)
         self._init_metrics(registry)
 
@@ -428,9 +431,11 @@ class DistributedTrainer:
             dist = DistContext(axis=None, n_shards=self.n_data_shards,
                                bn_group_size=self.bn_group_size)
 
-            def step(params, opt_state, state, strat_state, x, y, rng, it):
-                score, new_state, grads = local_grads(
-                    params, state, x, y, rng, dist)
+            def train_step(params, opt_state, state, strat_state, x, y, rng,
+                           it):
+                with jax.named_scope("loss_and_grad"):
+                    score, new_state, grads = local_grads(
+                        params, state, x, y, rng, dist)
                 grads = _normalize_gradients(
                     grads, conf.gradient_normalization, conf.gradient_normalization_threshold
                 )
@@ -446,7 +451,7 @@ class DistributedTrainer:
                 return new_params, new_opt, new_state, strat_state, score
 
             return jax.jit(
-                step,
+                train_step,
                 in_shardings=(
                     self._param_shardings(), self._opt_shardings, self._replicated,
                     self._replicated, self._data_sharding, self._data_sharding,
@@ -479,11 +484,13 @@ class DistributedTrainer:
                            bn_group_size=self.bn_group_size,
                            ep_axis=self._ep_axis, ep_shards=self.ep_shards)
 
-        def shard_step(params, opt_state, state, strat_state, x, y, rng, it):
+        def train_step(params, opt_state, state, strat_state, x, y, rng, it):
             rng = jax.random.fold_in(rng, jax.lax.axis_index(axis))
-            score, new_state, grads = local_grads(
-                params, state, x, y, rng, dist)
-            grads, new_strat = strategy.sync(grads, strat_state, axis)
+            with jax.named_scope("loss_and_grad"):
+                score, new_state, grads = local_grads(
+                    params, state, x, y, rng, dist)
+            with jax.named_scope("grad_sync"):
+                grads, new_strat = strategy.sync(grads, strat_state, axis)
             grads = _normalize_gradients(
                 grads, conf.gradient_normalization, conf.gradient_normalization_threshold
             )
@@ -534,7 +541,7 @@ class DistributedTrainer:
         else:
             param_specs = rep
         mapped = _shmap(
-            shard_step,
+            train_step,
             self.mesh,
             in_specs=(param_specs, opt_specs, rep, rep, data, data, rep, rep),
             out_specs=(param_specs, opt_specs, rep, rep, rep),
@@ -613,42 +620,58 @@ class DistributedTrainer:
         return jax.tree_util.tree_map(put_one, tree)
 
     def fit_batch(self, x, y) -> float:
+        # one ``fit.step`` trace of the process's tracer per call, as the
+        # single-device solvers give (train/solver.py)
+        span = get_tracer().span
+        with span("fit.step", parent=None,
+                  attrs={"step": self.iteration + 1}) as step:
+            return self._fit_batch(x, y, span, step)
+
+    def _fit_batch(self, x, y, span, step) -> float:
         if self._step is None:
             self._step = self._build_step()
         model = self.model
         # keep host arrays host-side until device_put so each row goes
         # host->owning-shard once (jnp.asarray first would commit to the
         # default device and pay a second device->device scatter)
-        x, y = self._prep_inputs(x, y)
-        first = x[0] if isinstance(x, tuple) else x
-        n = self.n_data_shards
-        if self._is_presharded(first):
-            # already a GLOBAL array assembled by the sharded input tier
-            if first.shape[0] % n:
+        with span("fit.h2d", parent=step):
+            x, y = self._prep_inputs(x, y)
+            first = x[0] if isinstance(x, tuple) else x
+            n = self.n_data_shards
+            if self._is_presharded(first):
+                # already a GLOBAL array assembled by the sharded input tier
+                if first.shape[0] % n:
+                    raise ValueError(
+                        f"global batch {first.shape[0]} not divisible by "
+                        f"data axis {n}")
+            elif self._multiprocess:
+                # each process feeds its LOCAL rows; the global batch is the
+                # concatenation across processes (local_rows * process_count)
+                global_rows = first.shape[0] * jax.process_count()
+                if global_rows % n:
+                    raise ValueError(
+                        f"global batch {global_rows} not divisible by data "
+                        f"axis {n}")
+            elif first.shape[0] % n:
                 raise ValueError(
-                    f"global batch {first.shape[0]} not divisible by "
-                    f"data axis {n}")
-        elif self._multiprocess:
-            # each process feeds its LOCAL rows; the global batch is the
-            # concatenation across processes (local_rows * process_count)
-            global_rows = first.shape[0] * jax.process_count()
-            if global_rows % n:
-                raise ValueError(
-                    f"global batch {global_rows} not divisible by data axis {n}")
-        elif first.shape[0] % n:
-            raise ValueError(
-                f"batch {first.shape[0]} not divisible by data axis {n}")
-        model.last_batch_size = int(first.shape[0])  # PerformanceListener/
-        # MetricsListener read examples-per-iteration off the model
-        x = self._put_data(x)
-        y = self._put_data(y)
+                    f"batch {first.shape[0]} not divisible by data axis {n}")
+            # PerformanceListener/MetricsListener read examples-per-iteration
+            # off the model
+            model.last_batch_size = int(first.shape[0])
+            x = self._put_data(x)
+            y = self._put_data(y)
+        step.set_attribute("batch", model.last_batch_size)
         rng = model._rng.next_key()
         self.iteration += 1
         it = jnp.asarray(self.iteration, jnp.int32)
-        self.params, self.opt_state, self.state, self.strat_state, score = self._step(
-            self.params, self.opt_state, self.state, self.strat_state, x, y, rng, it
-        )
-        self._record_compression()
+        with span("fit.dispatch", parent=step):
+            out = self._step(
+                self.params, self.opt_state, self.state, self.strat_state,
+                x, y, rng, it)
+        with span("fit.host", parent=step):
+            (self.params, self.opt_state, self.state, self.strat_state,
+             score) = out
+            self._record_compression()
         return score
 
     def fit(self, data, labels=None, *, epochs: int = 1) -> float:
@@ -1243,7 +1266,8 @@ class PipelineParallelTrainer:
         ctx = LayerContext(train=True, rng=k, mask=None, dist=None)
         if preproc is not None:
             h, _ = preproc.apply({}, {}, h, ctx)
-        y, _ = apply_layer(layer, params_by_name.get(name, {}), {}, h, ctx)
+        y, _ = apply_layer(layer, params_by_name.get(name, {}), {}, h, ctx,
+                           name=name)
         return y
 
     def _fold_prelude(self, aux, xmb, key):
@@ -1258,7 +1282,7 @@ class PipelineParallelTrainer:
         differs between block instances."""
         from ..nn.layers.base import LayerContext, apply_layer
         for j, i0 in enumerate(self.partition.blocks[0]):
-            _, layer, preproc = self._units[i0]
+            name, layer, preproc = self._units[i0]
             pj = jax.tree_util.tree_map(
                 lambda a: jax.lax.dynamic_index_in_dim(a, kb, 0, False),
                 body[j])
@@ -1268,7 +1292,7 @@ class PipelineParallelTrainer:
             ctx = LayerContext(train=True, rng=k, mask=None, dist=None)
             if preproc is not None:
                 h, _ = preproc.apply({}, {}, h, ctx)
-            h, _ = apply_layer(layer, pj, {}, h, ctx)
+            h, _ = apply_layer(layer, pj, {}, h, ctx, name=name)
         return h
 
     def _fold_body(self, body, h, key, n_active, g0):

@@ -16,6 +16,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..data.dataset import DataSet, MultiDataSet
+from ..obs.compiles import watch_compiles
+from ..obs.tracing import get_tracer
 from .solver import LayerOptimizers, _normalize_gradients
 
 
@@ -43,6 +45,8 @@ class GraphSolver:
         self.optim = LayerOptimizers(model)
         self.opt_state = self.optim.init(model.params)
         self._step_cache: Dict[Any, Any] = {}
+        self._n_steps = 0  # fit.step's sequence number
+        watch_compiles()
 
     def _step_fn(self, n_in: int, n_out: int, return_grads: bool = False):
         key = ("step", n_in, n_out, return_grads)
@@ -50,11 +54,13 @@ class GraphSolver:
             model = self.model
             conf = model.conf
 
-            def step(params, opt_state, state, xs, ys, rng):
+            def train_step(params, opt_state, state, xs, ys, rng):
                 def loss_fn(p):
                     return model.loss_pure(p, state, xs, ys, rng=rng, train=True)
 
-                (score, new_state), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
+                with jax.named_scope("loss_and_grad"):
+                    (score, new_state), grads = jax.value_and_grad(
+                        loss_fn, has_aux=True)(params)
                 grads = _normalize_gradients(
                     grads, conf.gradient_normalization, conf.gradient_normalization_threshold
                 )
@@ -64,7 +70,8 @@ class GraphSolver:
                 return new_params, new_opt, new_state, score
 
             donate = (0, 1, 2) + ((3, 4) if self.donate_inputs else ())
-            self._step_cache[key] = jax.jit(step, donate_argnums=donate)
+            self._step_cache[key] = jax.jit(train_step,
+                                            donate_argnums=donate)
         return self._step_cache[key]
 
     def _scan_fn(self):
@@ -98,14 +105,26 @@ class GraphSolver:
         return self._step_cache[key]
 
     def fit_batch(self, xs: Tuple, ys: Tuple):
+        # one ``fit.step`` trace of the process's tracer per call: what the
+        # host spends to enqueue a step (head-sampled at the tracer's rate;
+        # every step while a profiler session collects)
+        span = get_tracer().span
+        self._n_steps += 1
+        with span("fit.step", parent=None,
+                  attrs={"step": self._n_steps}) as step:
+            return self._fit_batch(xs, ys, span, step)
+
+    def _fit_batch(self, xs: Tuple, ys: Tuple, span, step):
         model = self.model
         # StepProfiler phase attribution; mirrors Solver.fit_batch (device
         # phases fenced only on sampled steps). prof=None costs nothing.
         prof = self.profiler
         fence = prof.begin_step() if prof is not None else False
         t0 = time.perf_counter() if prof is not None else 0.0
-        xs = model._as_inputs(xs)
-        ys = tuple(jnp.asarray(y) for y in ys)
+        with span("fit.h2d", parent=step):
+            xs = model._as_inputs(xs)
+            ys = tuple(jnp.asarray(y) for y in ys)
+        step.set_attribute("batch", int(xs[0].shape[0]))
         if prof is not None and (fence or prof.sync_every == 0):
             if fence:
                 jax.block_until_ready((xs, ys))
@@ -114,26 +133,29 @@ class GraphSolver:
         fn = self._step_fn(len(xs), len(ys), want_grads)
         rng = model._rng.next_key()
         tc = time.perf_counter() if prof is not None else 0.0
-        out = fn(
-            model.params, self.opt_state, model.state, xs, ys, rng
-        )
+        with span("fit.dispatch", parent=step):
+            out = fn(
+                model.params, self.opt_state, model.state, xs, ys, rng
+            )
         if prof is not None and (fence or prof.sync_every == 0):
             if fence:
                 jax.block_until_ready(out)
             prof.record("compute", time.perf_counter() - tc, sampled=fence)
         th = time.perf_counter() if prof is not None else 0.0
-        grads = None
-        if want_grads:
-            params, opt_state, state, score, grads = out
-        else:
-            params, opt_state, state, score = out
-        model.params = params
-        model.state = state
-        self.opt_state = opt_state
-        model.last_batch_size = int(xs[0].shape[0])
-        if grads is not None:
-            # after reassignment: pre-step buffers were donated to the step
-            model.listeners.gradient_calculation(model, grads)
+        with span("fit.host", parent=step):
+            grads = None
+            if want_grads:
+                params, opt_state, state, score, grads = out
+            else:
+                params, opt_state, state, score = out
+            model.params = params
+            model.state = state
+            self.opt_state = opt_state
+            model.last_batch_size = int(xs[0].shape[0])
+            if grads is not None:
+                # after reassignment: pre-step buffers were donated to the
+                # step
+                model.listeners.gradient_calculation(model, grads)
         if prof is not None:
             # sampled: post-fence host time is honest (see Solver)
             prof.record("host", time.perf_counter() - th, sampled=fence)

@@ -29,15 +29,19 @@ import optax
 from ..core.dtypes import as_input
 from ..nn.conf import BackpropType, GradientNormalization
 from ..nn.layers.base import Layer
+from ..obs.compiles import watch_compiles
+from ..obs.tracing import get_tracer
 from .updaters import IUpdater, NoOp, Sgd, updater_from_any
 
 
+@jax.named_scope("grad_norm")
 def _normalize_gradients(
     grads: Dict[str, Dict[str, jax.Array]],
     mode: GradientNormalization,
     threshold: float,
 ) -> Dict[str, Dict[str, jax.Array]]:
-    """Reference: GradientNormalization applied before the updater."""
+    """Reference: GradientNormalization applied before the updater. Its
+    operations carry the scope ``grad_norm`` in every train step."""
     eps = 1e-8
     if mode is GradientNormalization.NONE:
         return grads
@@ -119,13 +123,16 @@ class LayerOptimizers:
     def init(self, params) -> Dict[str, Any]:
         return {name: tx.init(params[name]) for name, tx in self.txs.items()}
 
+    @jax.named_scope("optimizer")
     def update(self, grads, opt_state, params):
         new_params = {}
         new_opt = {}
         for name, p in params.items():
             if name in self.txs:
-                updates, new_opt[name] = self.txs[name].update(grads[name], opt_state[name], p)
-                new_params[name] = optax.apply_updates(p, updates)
+                with jax.named_scope(name):
+                    updates, new_opt[name] = self.txs[name].update(
+                        grads[name], opt_state[name], p)
+                    new_params[name] = optax.apply_updates(p, updates)
             else:
                 new_params[name] = p
         return new_params, new_opt
@@ -172,20 +179,25 @@ class Solver:
         self.optim = LayerOptimizers(model)
         self.opt_state = self.optim.init(model.params)
         self._step_cache: Dict[Any, Any] = {}
+        self._n_steps = 0  # fit.step's sequence number
+        watch_compiles()
 
     def _make_step(self, has_mask: bool, has_label_mask: bool, stateful: bool,
                    return_grads: bool = False):
         model = self.model
         conf = model.conf
 
-        def step(params, opt_state, state, rnn_state, x, y, rng, mask, label_mask):
+        def train_step(params, opt_state, state, rnn_state, x, y, rng, mask,
+                       label_mask):
             def loss_fn(p):
                 return model.loss_pure(
                     p, state, x, y, rng=rng, mask=mask, label_mask=label_mask,
                     rnn_state=rnn_state if stateful else None, train=True,
                 )
 
-            (score, (new_state, new_rnn)), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
+            with jax.named_scope("loss_and_grad"):
+                (score, (new_state, new_rnn)), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True)(params)
             grads = _normalize_gradients(
                 grads, conf.gradient_normalization, conf.gradient_normalization_threshold
             )
@@ -197,7 +209,7 @@ class Solver:
         donate = (0, 1, 2)
         if self.donate_inputs:
             donate += (4, 5)  # x, y (masks excluded: commonly reused)
-        return jax.jit(step, donate_argnums=donate)
+        return jax.jit(train_step, donate_argnums=donate)
 
     def _step_fn(self, has_mask, has_label_mask, stateful, return_grads=False):
         key = (has_mask, has_label_mask, stateful, return_grads)
@@ -206,6 +218,17 @@ class Solver:
         return self._step_cache[key]
 
     def fit_batch(self, x, y, mask=None, label_mask=None, rnn_state=None) -> Tuple[float, Optional[dict]]:
+        # one ``fit.step`` trace of the process's tracer per call: what the
+        # host spends to enqueue a step (head-sampled at the tracer's rate;
+        # every step while a profiler session collects)
+        span = get_tracer().span
+        self._n_steps += 1
+        with span("fit.step", parent=None,
+                  attrs={"step": self._n_steps}) as step:
+            return self._fit_batch(x, y, mask, label_mask, rnn_state, span,
+                                   step)
+
+    def _fit_batch(self, x, y, mask, label_mask, rnn_state, span, step):
         model = self.model
         # phase attribution (StepProfiler): h2d / compute measured under a
         # block_until_ready fence ONLY on the profiler's sampled steps so
@@ -214,10 +237,13 @@ class Solver:
         prof = self.profiler
         fence = prof.begin_step() if prof is not None else False
         t0 = time.perf_counter() if prof is not None else 0.0
-        x = as_input(x, model.dtype, model.keeps_int_input())
-        y = jnp.asarray(y)
-        mask_a = None if mask is None else jnp.asarray(mask, model.dtype)
-        lmask_a = None if label_mask is None else jnp.asarray(label_mask, model.dtype)
+        with span("fit.h2d", parent=step):
+            x = as_input(x, model.dtype, model.keeps_int_input())
+            y = jnp.asarray(y)
+            mask_a = None if mask is None else jnp.asarray(mask, model.dtype)
+            lmask_a = None if label_mask is None else jnp.asarray(
+                label_mask, model.dtype)
+        step.set_attribute("batch", int(x.shape[0]))
         if prof is not None and (fence or prof.sync_every == 0):
             if fence:
                 jax.block_until_ready((x, y))
@@ -228,28 +254,30 @@ class Solver:
                            want_grads)
         rng = model._rng.next_key()
         tc = time.perf_counter() if prof is not None else 0.0
-        out = fn(
-            model.params, self.opt_state, model.state,
-            rnn_state if stateful else {}, x, y, rng, mask_a, lmask_a,
-        )
+        with span("fit.dispatch", parent=step):
+            out = fn(
+                model.params, self.opt_state, model.state,
+                rnn_state if stateful else {}, x, y, rng, mask_a, lmask_a,
+            )
         if prof is not None and (fence or prof.sync_every == 0):
             if fence:
                 jax.block_until_ready(out)
             prof.record("compute", time.perf_counter() - tc, sampled=fence)
         th = time.perf_counter() if prof is not None else 0.0
-        grads = None
-        if want_grads:
-            params, opt_state, state, new_rnn, score, grads = out
-        else:
-            params, opt_state, state, new_rnn, score = out
-        model.params = params
-        model.state = state
-        self.opt_state = opt_state
-        model.last_batch_size = int(x.shape[0])
-        if grads is not None:
-            # after reassignment: the pre-step buffers were donated to the
-            # jitted step, so listeners must see the NEW params
-            model.listeners.gradient_calculation(model, grads)
+        with span("fit.host", parent=step):
+            grads = None
+            if want_grads:
+                params, opt_state, state, new_rnn, score, grads = out
+            else:
+                params, opt_state, state, new_rnn, score = out
+            model.params = params
+            model.state = state
+            self.opt_state = opt_state
+            model.last_batch_size = int(x.shape[0])
+            if grads is not None:
+                # after reassignment: the pre-step buffers were donated to
+                # the jitted step, so listeners must see the NEW params
+                model.listeners.gradient_calculation(model, grads)
         if prof is not None:
             # sampled: after the fence the device is idle, so this host
             # segment's wall time is honest (unfenced steps share the

@@ -1,0 +1,449 @@
+"""Loop-turn and training-step spans (ISSUE 26): ``DecodeEngine._loop`` and
+``fit_batch`` as traces of the one ``Tracer``, every span mirrored into a
+``jax.profiler`` session while one collects, self times in the store, the
+compile listener, and the names the device side carries (module names and
+``jax.named_scope``s)."""
+
+import glob
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deeplearning4j_tpu.model.zoo import TransformerLM
+from deeplearning4j_tpu.nn import MultiLayerNetwork, NeuralNetConfiguration
+from deeplearning4j_tpu.nn.layers import DenseLayer, OutputLayer
+from deeplearning4j_tpu.obs import MetricsRegistry, get_registry
+from deeplearning4j_tpu.obs.tracing import (TraceStore, Tracer, profiling,
+                                            set_tracer)
+from deeplearning4j_tpu.parallel import DecodeEngine
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+STEP_PARTS = ("loop.upload", "loop.dispatch", "loop.fetch", "loop.emit")
+
+
+@pytest.fixture(scope="module")
+def lm():
+    return TransformerLM(vocab_size=23, hidden=32, n_layers=2, n_heads=4,
+                         max_len=32).init()
+
+
+def _serve(lm, tracer, n_requests=5, max_tokens=6):
+    """More requests than the two slots, so that some wait in the queue."""
+    reg = MetricsRegistry()
+    eng = DecodeEngine(lm, max_len=32, slots=2, tracer=tracer, registry=reg)
+    try:
+        handles = [eng.submit([1 + i, 2, 3], max_tokens=max_tokens)
+                   for i in range(n_requests)]
+        tokens = [h.result(timeout=120) for h in handles]
+        stats = eng.stats()
+        steps = eng._h_decode.count
+    finally:
+        eng.shutdown()
+    assert tracer.flush()
+    return {"tokens": tokens, "stats": stats, "steps": steps,
+            "traces": tracer.store.traces(limit=10_000)}
+
+
+def _root(trace):
+    return next(s for s in trace["spans"] if s["parent_id"] is None)
+
+
+def _turns(traces):
+    return sorted((t for t in traces if t["root"] == "loop.turn"),
+                  key=lambda t: _root(t)["attrs"]["turn"])
+
+
+@pytest.fixture(scope="module")
+def served(lm):
+    return _serve(lm, Tracer(TraceStore(max_traces=4096), sample_rate=1.0))
+
+
+def test_turn_children_and_self_time_add_up_to_the_turn(served):
+    turns = _turns(served["traces"])
+    assert turns
+    for t in turns:
+        root = _root(t)
+        direct = [s for s in t["spans"] if s["parent_id"] == root["span_id"]]
+        assert {s["name"] for s in direct} <= {"loop.admit", "loop.step",
+                                               "loop.sweep"}
+        assert sum(s["duration_ms"] for s in direct) + root["self_ms"] == \
+            pytest.approx(root["duration_ms"], abs=1e-3)
+        for s in t["spans"]:
+            assert 0.0 <= s["self_ms"] <= s["duration_ms"] + 1e-6
+    stepped = [t for t in turns
+               if any(s["name"] == "loop.step" for s in t["spans"])]
+    assert stepped
+    for t in stepped:
+        step = next(s for s in t["spans"] if s["name"] == "loop.step")
+        parts = [s for s in t["spans"] if s["parent_id"] == step["span_id"]]
+        assert [s["name"] for s in parts] == list(STEP_PARTS)
+        assert step["attrs"]["spec"] is False
+
+
+def test_turn_numbers_are_consecutive_and_count_the_work(served):
+    roots = [_root(t) for t in _turns(served["traces"])]
+    numbers = [r["attrs"]["turn"] for r in roots]
+    assert numbers == list(range(1, len(numbers) + 1))
+    assert sum(r["attrs"]["admitted"] for r in roots) == 5
+    assert sum(r["attrs"]["retired"] for r in roots) == 5
+    assert all(0 <= r["attrs"]["rows"] <= 2 for r in roots)
+    assert roots[0]["attrs"]["pending"] >= 1
+    assert {r["attrs"]["engine"] for r in roots} == {roots[0]["attrs"]["engine"]}
+
+
+def test_every_prefill_has_its_request_number_and_queue_wait(served):
+    prefills = [s for t in served["traces"] for s in t["spans"]
+                if s["name"] == "loop.prefill"]
+    assert sorted(s["attrs"]["req"] for s in prefills) == list(range(5))
+    assert all(s["attrs"]["queue_wait_ms"] >= 0.0 for s in prefills)
+    # two slots: the later requests waited for a whole request to finish
+    assert max(s["attrs"]["queue_wait_ms"] for s in prefills) > \
+        min(s["attrs"]["queue_wait_ms"] for s in prefills)
+    for s in prefills:
+        assert s["attrs"]["prompt_len"] == 3 and s["attrs"]["bucket"] >= 3
+        assert s["attrs"]["slot"] in (0, 1)
+    by_parent = {}
+    for t in served["traces"]:
+        for s in t["spans"]:
+            by_parent.setdefault(s["parent_id"], []).append(s["name"])
+    for s in prefills:
+        kids = [n for n in by_parent[s["span_id"]] if n != "xla.compile"]
+        assert kids == ["loop.prefill.dispatch", "loop.prefill.sync",
+                        "loop.install"]
+
+
+def test_decode_histogram_counts_the_step_spans(served):
+    steps = [s for t in served["traces"] for s in t["spans"]
+             if s["name"] == "loop.step"]
+    assert len(steps) == served["steps"] > 0
+    assert all(t["root"] in ("loop.turn", "loop.wait")
+               for t in served["traces"])
+
+
+def test_children_of_an_unsampled_turn_never_root_a_trace(lm):
+    out = _serve(lm, Tracer(TraceStore(max_traces=4096), sample_rate=0.5),
+                 n_requests=6, max_tokens=8)
+    assert {t["root"] for t in out["traces"]} <= {"loop.turn", "loop.wait"}
+    numbers = [_root(t)["attrs"]["turn"] for t in _turns(out["traces"])]
+    assert len(numbers) < max(numbers)  # some turns were not sampled
+    none = _serve(lm, Tracer(sample_rate=0.0))
+    assert none["traces"] == []
+
+
+def test_disabled_tracer_stores_nothing_and_serves_the_same(lm, served):
+    tracer = Tracer(enabled=False)
+    off = _serve(lm, tracer)
+    assert len(tracer.store) == 0 and tracer.store.span_count() == 0
+    assert off["tokens"] == served["tokens"]
+    timing = {"per_token_p95_s"}  # a latency: the one key that may differ
+    assert {k: v for k, v in off["stats"].items() if k not in timing} == \
+        {k: v for k, v in served["stats"].items() if k not in timing}
+    assert off["steps"] == served["steps"]
+
+
+def test_a_profiler_session_takes_every_turn_and_mirrors_the_spans(
+        lm, tmp_path):
+    assert profiling() is False
+    tracer = Tracer(TraceStore(max_traces=4096), sample_rate=0.0)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        assert profiling() is True
+        out = _serve(lm, tracer, n_requests=3, max_tokens=4)
+        with tracer.span("manager.deploy", parent=None,
+                         attrs={"model": "m", "blob": object()}):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    assert profiling() is False
+    roots = [_root(t) for t in _turns(out["traces"])]
+    numbers = [r["attrs"]["turn"] for r in roots]
+    assert numbers == list(range(1, len(numbers) + 1))
+    assert all(r["attrs"]["profiled"] is True for r in roots)
+    # off the profiler the same tracer samples nothing again
+    assert tracer.span("x", parent=None).context is None
+
+    from jax.profiler import ProfileData
+
+    path = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                     recursive=True)[0]
+    names = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith(("loop.", "manager.")):
+                        names.setdefault(ev.name, []).append(dict(ev.stats))
+    n_steps = sum(s["name"] == "loop.fetch"
+                  for t in out["traces"] for s in t["spans"])
+    assert len(names["loop.fetch"]) == n_steps > 0
+    assert {"loop.turn", "loop.admit", "loop.prefill", "loop.step",
+            "loop.upload", "loop.dispatch", "loop.emit",
+            "loop.sweep"} <= set(names)
+    assert sorted(e["turn"] for e in names["loop.turn"]) == numbers
+    # every span of the repo is mirrored, scalar attributes only
+    assert names["manager.deploy"] == [{"model": "m", "profiled": True}]
+
+
+def test_a_root_that_outlives_the_session_is_not_profiled(tmp_path):
+    tracer = Tracer(sample_rate=0.0)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tracer.span("inside", parent=None):
+            pass
+        straddles = tracer.span("straddles", parent=None)
+        straddles.__enter__()
+    finally:
+        jax.profiler.stop_trace()
+    straddles.__exit__(None, None, None)
+    assert tracer.flush()
+    attrs = {t["root"]: _root(t)["attrs"] for t in tracer.store.traces()}
+    assert attrs == {"inside": {"profiled": True}, "straddles": {}}
+
+
+def test_a_traced_request_gets_its_queue_wait_record(lm):
+    tracer = Tracer(TraceStore(max_traces=4096), sample_rate=1.0)
+    eng = DecodeEngine(lm, max_len=32, slots=1, tracer=tracer,
+                       registry=MetricsRegistry())
+    try:
+        with tracer.span("request", parent=None) as req:
+            handle = eng.submit([1, 2, 3], max_tokens=3)
+        handle.result(timeout=120)
+    finally:
+        eng.shutdown()
+    assert tracer.flush()
+    spans = {s["name"]: s for s in tracer.store.get(req.trace_id)["spans"]}
+    assert {"engine.queue_wait", "engine.prefill", "engine.decode"} <= \
+        set(spans)
+    wait = spans["engine.queue_wait"]
+    assert wait["parent_id"] == req.span_id
+    assert wait["end"] <= spans["engine.prefill"]["start"]
+    assert wait["start"] >= spans["request"]["start"]
+
+
+def test_self_time_takes_the_union_of_overlapping_children():
+    def span(sid, parent, start, end):
+        return {"trace_id": "t", "span_id": sid, "parent_id": parent,
+                "name": sid, "start": start, "end": end,
+                "duration_ms": (end - start) * 1e3, "error": False,
+                "attrs": {}}
+
+    store = TraceStore()
+    for s in (span("root", None, 0.0, 1.0), span("a", "root", 0.1, 0.5),
+              span("b", "root", 0.3, 0.6),      # overlaps a: union 0.1-0.6
+              span("c", "root", 0.2, 0.4),      # inside the union
+              span("d", "root", 0.9, 1.2),      # runs past its parent
+              span("a1", "a", 0.1, 0.2)):
+        store.add(s)
+    got = {s["name"]: s["self_ms"] for s in store.get("t")["spans"]}
+    assert got["root"] == pytest.approx(400.0)   # 1.0 - 0.5 - 0.1
+    assert got["a"] == pytest.approx(300.0)
+    assert got["b"] == pytest.approx(300.0) and got["a1"] == pytest.approx(100.0)
+    # the stored records are not rewritten by reading them
+    assert "self_ms" not in store._traces["t"]["spans"][0]
+
+
+def _mln(seed=5):
+    conf = (NeuralNetConfiguration.builder().seed(seed).list()
+            .layer(DenseLayer(n_in=4, n_out=8))
+            .layer(OutputLayer(n_in=8, n_out=3))
+            .build())
+    return MultiLayerNetwork(conf).init()
+
+
+def _graph(seed=7):
+    from deeplearning4j_tpu.nn.graph import ComputationGraph
+
+    conf = (NeuralNetConfiguration.builder().seed(seed).graph_builder()
+            .add_inputs("in")
+            .add_layer("d", DenseLayer(n_in=4, n_out=8), "in")
+            .add_layer("out", OutputLayer(n_in=8, n_out=3), "d")
+            .set_outputs("out").build())
+    return ComputationGraph(conf).init()
+
+
+def _batch(n=8):
+    rng = np.random.RandomState(0)
+    return (rng.randn(n, 4).astype(np.float32),
+            np.eye(3, dtype=np.float32)[rng.randint(0, 3, n)])
+
+
+def _fit_three(kind):
+    x, y = _batch()
+    if kind == "solver":
+        from deeplearning4j_tpu.train.solver import Solver
+
+        solver = Solver(_mln())
+        return [solver.fit_batch(x, y)[0] for _ in range(3)]
+    if kind == "graph_solver":
+        from deeplearning4j_tpu.train.graph_solver import GraphSolver
+
+        solver = GraphSolver(_graph())
+        return [solver.fit_batch((x,), (y,)) for _ in range(3)]
+    from deeplearning4j_tpu.parallel import DistributedTrainer, make_mesh
+
+    trainer = DistributedTrainer(_mln(), mesh=make_mesh(data=-1))
+    return [trainer.fit_batch(x, y) for _ in range(3)]
+
+
+@pytest.mark.parametrize("kind", ["solver", "graph_solver", "trainer"])
+def test_fit_batch_is_a_fit_step_trace(kind):
+    tracer = Tracer(sample_rate=1.0)
+    prev = set_tracer(tracer)
+    try:
+        scores = _fit_three(kind)
+    finally:
+        set_tracer(prev)
+    assert all(np.isfinite(float(s)) for s in scores)
+    assert tracer.flush()
+    traces = sorted(tracer.store.traces(),
+                    key=lambda t: _root(t)["attrs"]["step"])
+    assert [t["root"] for t in traces] == ["fit.step"] * 3
+    assert [_root(t)["attrs"]["step"] for t in traces] == [1, 2, 3]
+    for t in traces:
+        root = _root(t)
+        assert root["attrs"]["batch"] == 8
+        # (a small program compiled between the phases, a key split say,
+        # is an xla.compile child of the root itself)
+        kids = [s for s in t["spans"] if s["parent_id"] == root["span_id"]
+                and s["name"] != "xla.compile"]
+        assert [s["name"] for s in kids] == ["fit.h2d", "fit.dispatch",
+                                             "fit.host"]
+        assert sum(s["duration_ms"] for s in kids) + root["self_ms"] <= \
+            root["duration_ms"] + 1e-3
+    # the first step compiled under its dispatch span; the others did not
+    compiles = [[s["parent_id"] for s in t["spans"]
+                 if s["name"] == "xla.compile"] for t in traces]
+    dispatch = next(s for s in traces[0]["spans"]
+                    if s["name"] == "fit.dispatch")
+    assert dispatch["span_id"] in compiles[0] and compiles[2] == []
+
+
+def test_compiles_are_counted_where_they_happen():
+    from deeplearning4j_tpu.obs.compiles import watch_compiles
+
+    watch_compiles()
+    watch_compiles()  # once for the process, however often called
+    listeners = jax.monitoring.get_event_duration_listeners() \
+        if hasattr(jax.monitoring, "get_event_duration_listeners") else \
+        jax._src.monitoring.get_event_duration_listeners()
+    assert sum(getattr(cb, "__module__", "") ==
+               "deeplearning4j_tpu.obs.compiles" for cb in listeners) == 1
+    reg = get_registry()
+    count = reg.counter("dl4j_tpu_xla_compiles_total")
+    seconds = reg.counter("dl4j_tpu_xla_compile_seconds_total")
+    n0, s0 = count.value, seconds.value
+    tracer = Tracer(sample_rate=1.0)
+    fn = jax.jit(lambda a: a * 3 + 1)
+    x = np.ones((7, 3), np.float32)
+    with tracer.span("caller", parent=None) as caller:
+        fn(x).block_until_ready()
+        n1 = count.value
+        fn(x).block_until_ready()  # compiled already
+    assert n1 == n0 + 1 and count.value == n1 and seconds.value > s0
+    assert tracer.flush()
+    spans = tracer.store.get(caller.trace_id)["spans"]
+    compiled = [s for s in spans if s["name"] == "xla.compile"]
+    assert len(compiled) == 1 and compiled[0]["parent_id"] == caller.span_id
+    assert compiled[0]["start"] >= spans[0]["start"]
+    fn2 = jax.jit(lambda a: a * 5)
+    fn2(np.ones((3,), np.float32)).block_until_ready()  # no span: counted only
+    assert count.value == n1 + 1
+
+
+def test_decode_programs_carry_their_names_and_scopes(lm):
+    eng = DecodeEngine(lm, max_len=32, slots=2, registry=MetricsRegistry(),
+                       tracer=Tracer(enabled=False))
+    try:
+        sess = eng.session
+        lowered = eng._decode_step_fn().lower(
+            sess.model.params, sess.model.state, eng._carry,
+            jnp.asarray(eng._last), jnp.asarray(eng._active),
+            jnp.asarray(eng._seeds), jnp.asarray(eng._steps),
+            jnp.asarray(eng._greedy), jnp.asarray(eng._temps),
+            jnp.asarray(eng._ks), jnp.asarray(eng._ps))
+        text = lowered.as_text(debug_info=True)
+        assert "module @jit_decode_step" in text
+        layer = sess.model.conf.layer_name(1)
+        for scope in ("forward", "logits", "sample", "freeze_rows",
+                      f"forward/{layer}"):
+            assert f"jit(decode_step)/{scope}" in text, scope
+        hlo = lowered.compile().as_text()
+        assert f"jit(decode_step)/forward/{layer}" in hlo
+        assert eng._prefill_fn(8).__name__ == "prefill_8"
+        assert eng._write_row_fn().__name__ == "install_row"
+    finally:
+        eng.shutdown()
+
+
+@pytest.mark.parametrize("kind", ["solver", "graph_solver", "trainer"])
+def test_train_steps_carry_their_name_and_scopes(kind):
+    x, y = _batch()
+    rng = jax.random.PRNGKey(0)
+    if kind == "solver":
+        from deeplearning4j_tpu.train.solver import Solver
+
+        s = Solver(_mln())
+        lowered = s._step_fn(False, False, False).lower(
+            s.model.params, s.opt_state, s.model.state, {}, x, y, rng,
+            None, None)
+        layer = s.model.conf.layer_name(0)
+    elif kind == "graph_solver":
+        from deeplearning4j_tpu.train.graph_solver import GraphSolver
+
+        s = GraphSolver(_graph())
+        lowered = s._step_fn(1, 1).lower(
+            s.model.params, s.opt_state, s.model.state, (x,), (y,), rng)
+        layer = "d"
+    else:
+        from deeplearning4j_tpu.parallel import DistributedTrainer, make_mesh
+
+        s = DistributedTrainer(_mln(), mesh=make_mesh(data=-1))
+        lowered = s._build_step().lower(
+            s.params, s.opt_state, s.state, s.strat_state, x, y, rng,
+            jnp.asarray(1, jnp.int32))
+        layer = s.model.conf.layer_name(0)
+    text = lowered.as_text(debug_info=True)
+    assert "module @jit_train_step" in text
+    out = "out" if kind == "graph_solver" else s.model.conf.layer_name(1)
+    for scope in ("loss_and_grad", "optimizer", f"loss_and_grad/jvp({layer})",
+                  f"loss_and_grad/transpose(jvp({layer}))",
+                  f"loss_and_grad/jvp({out})"):
+        assert f"jit(train_step)/{scope}" in text, scope
+
+
+def _host_gaps():
+    spec = importlib.util.spec_from_file_location(
+        "host_gaps", os.path.join(ROOT, "tools", "host_gaps.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_host_gaps_splits_each_gap_over_the_innermost_spans():
+    hg = _host_gaps()
+    ms = 1e6  # ns
+    ops = [("a", 0, 10 * ms), ("b", 10 * ms, 20 * ms),       # no gap
+           ("c", 30 * ms, 40 * ms),                          # 10 ms after b
+           ("w", 30 * ms, 60 * ms), ("d", 45 * ms, 50 * ms),  # inside w
+           ("e", 75 * ms, 80 * ms)]                          # 15 ms after w
+    spans = [("loop.turn", 0, 70 * ms), ("loop.step", 5 * ms, 65 * ms),
+             ("loop.fetch", 15 * ms, 28 * ms), ("loop.emit", 28 * ms, 31 * ms),
+             ("loop.fetch", 41 * ms, 62 * ms)]
+    assert hg.idle_gaps(ops) == [(20 * ms, 30 * ms), (60 * ms, 75 * ms)]
+    assert [seg[2] for seg in hg.timeline(spans)] == [
+        "(no span)", "loop.turn", "loop.step", "loop.fetch", "loop.emit",
+        "loop.step", "loop.fetch", "loop.step", "loop.turn", "(no span)"]
+    got = hg.by_span(spans, ops)
+    # gap 20-30: fetch to 28, emit to 30; gap 60-75: fetch to 62, step to
+    # 65, turn to 70, nothing open to 75. Both began under a fetch.
+    assert {k: [round(x, 6) for x in v] for k, v in got.items()} == {
+        "loop.fetch": [0.010, 0.025], "loop.emit": [0.002, 0.0],
+        "loop.step": [0.003, 0.0], "loop.turn": [0.005, 0.0],
+        "(no span)": [0.005, 0.0]}
+    assert sum(v[0] for v in got.values()) == pytest.approx(0.025)
+    offs = hg.fetch_offsets(spans, ops, n=5)
+    assert offs == [pytest.approx(2.0)]   # 62 - 60, the second fetch
