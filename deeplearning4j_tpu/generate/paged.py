@@ -139,13 +139,32 @@ def is_paged(carry) -> bool:
                for st in carry.values())
 
 
+def attach_block_table(carry, table):
+    """Put the ONE shared ``[b, max_len // block_size]`` table under every
+    pool-holding layer of a paged carry that is kept without it (the
+    engine holds the table beside the carry, so that donating the carry
+    gives each buffer once). ``table=None`` (a static carry) passes
+    through."""
+    if table is None:
+        return carry
+    return {name: ({**st, "block_table": table} if "cache_k" in st else st)
+            for name, st in carry.items()}
+
+
+def detach_block_table(carry):
+    """The carry without its ``block_table`` leaves (the inverse of
+    :func:`attach_block_table`; a static carry passes through)."""
+    return {name: {k: v for k, v in st.items() if k != "block_table"}
+            for name, st in carry.items()}
+
+
 def redirect_inactive_writes(carry, active):
     """Point inactive rows' block tables at the trash block before a
     fused batch forward: the static-shape step writes EVERY row's K/V,
     and without redirection an inactive-but-allocated row's write would
     land inside its own live blocks (spec/plain row splits advance the
     two groups at different rates). Unpaged layers pass through — their
-    per-row rows are restored wholesale by :func:`freeze_rows`."""
+    per-row rows are restored by :func:`freeze_rows`."""
     out = {}
     for name, st in carry.items():
         if "block_table" in st:
@@ -156,14 +175,37 @@ def redirect_inactive_writes(carry, active):
     return out
 
 
+def mask_inactive_writes(carry, active):
+    """:func:`redirect_inactive_writes`, and the static layout's
+    counterpart of it: every unpaged K/V layer gets a ``write_mask``
+    leaf (``active``), under which the layer's cache write drops the
+    update of a row that is masked off (``ops.masked_cache_write``, through
+    ``_cached_attention`` in nn/layers/attention.py). An inactive row then leaves its row of
+    every cache plane as it was by what it writes, and
+    :func:`freeze_rows` need not select over the planes. Layers without
+    K/V planes (recurrent ``h``/``c``, ``cache_x``) pass through and keep
+    the whole-leaf select."""
+    out = {}
+    for name, st in redirect_inactive_writes(carry, active).items():
+        if "cache_k" in st and "block_table" not in st:
+            st = {**st, "write_mask": active}
+        out[name] = st
+    return out
+
+
 def freeze_rows(new, old, active):
     """Keep carry rows where ``active`` is False unchanged after a fused
-    batch step. Paged layers: pool planes take the step's result (the
-    inactive rows' writes went to trash — nothing of theirs changed),
-    ``block_table`` is restored from ``old`` (undoing the write
-    redirect), and per-row leaves (``pos``) are where'd by the mask.
-    Unpaged layers keep the original per-leaf where (shapes are per-row
-    there, so a row-select is well defined on every leaf)."""
+    batch step. ``old`` is the state the step's forward ran on. Paged
+    layers: pool planes take the step's result (the inactive rows' writes
+    went to trash — nothing of theirs changed), ``block_table`` is taken
+    from ``old``, and per-row leaves (``pos``) are where'd by the mask.
+    Static K/V layers whose ``old`` state carries a ``write_mask``
+    (:func:`mask_inactive_writes`): the cache planes take the step's
+    result too (a masked row wrote nothing) and only the
+    per-row leaves are where'd. Every other layer keeps the per-leaf
+    where (shapes are per-row there, so a row-select is well defined on
+    every leaf) — among them the K/V layers of a multi-token window
+    that is rewound afterwards, which no caller masks."""
     def sel(n, o):
         a = active.reshape((-1,) + (1,) * (n.ndim - 1))
         return jnp.where(a, n, o)
@@ -171,7 +213,7 @@ def freeze_rows(new, old, active):
     out = {}
     for name, n_st in new.items():
         o_st = old[name]
-        if "block_table" in o_st:
+        if "block_table" in o_st or "write_mask" in o_st:
             st = {}
             for k, v in n_st.items():
                 if k in _POOL_KEYS:

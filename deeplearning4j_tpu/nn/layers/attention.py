@@ -16,6 +16,7 @@ are split internally.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Optional, Tuple
 
@@ -96,7 +97,7 @@ def _cached_attention(q, k_new, v_new, state, mask):
     :func:`~deeplearning4j_tpu.ops.decode_attention`'s reference path —
     the resident cache holds ~1/2 the bytes of an fp16 cache (1/4 of
     f32), so the same HBM budget fits ~2× the concurrent sequences."""
-    from ...ops import decode_attention
+    from ...ops import decode_attention, masked_cache_write
 
     t = q.shape[2]
     pos = state["pos"].astype(jnp.int32)
@@ -127,21 +128,29 @@ def _cached_attention(q, k_new, v_new, state, mask):
         new_state = {"cache_k": cache_k, "cache_v": cache_v,
                      "block_table": table, "pos": pos + valid}
         return o, new_state
+    # a fused batch step's idle rows (generate/paged.py
+    # mask_inactive_writes) write nothing
+    keep = state.get("write_mask")
+    if keep is None:
+        write, write_scale = _cache_write, _scale_write
+    else:
+        write = write_scale = functools.partial(masked_cache_write,
+                                                write_mask=keep)
     if "cache_k_scale" in state:  # int8 KV cache
         kq, ks = quantize_kv_rows(k_new)
         vq, vs = quantize_kv_rows(v_new)
-        cache_k = _cache_write(state["cache_k"], kq, pos)
-        cache_v = _cache_write(state["cache_v"], vq, pos)
-        k_scale = _scale_write(state["cache_k_scale"], ks, pos)
-        v_scale = _scale_write(state["cache_v_scale"], vs, pos)
+        cache_k = write(state["cache_k"], kq, pos)
+        cache_v = write(state["cache_v"], vq, pos)
+        k_scale = write_scale(state["cache_k_scale"], ks, pos)
+        v_scale = write_scale(state["cache_v_scale"], vs, pos)
         o = decode_attention(q, cache_k, cache_v, pos,
                              k_scale=k_scale, v_scale=v_scale)
         new_state = {"cache_k": cache_k, "cache_v": cache_v,
                      "cache_k_scale": k_scale, "cache_v_scale": v_scale,
                      "pos": pos + valid}
         return o, new_state
-    cache_k = _cache_write(state["cache_k"], k_new, pos)
-    cache_v = _cache_write(state["cache_v"], v_new, pos)
+    cache_k = write(state["cache_k"], k_new, pos)
+    cache_v = write(state["cache_v"], v_new, pos)
     # query i at absolute position pos+i attends cache [0, pos+i]; the
     # single-token hot path (t == 1) dispatches to the flash decode kernel
     o = decode_attention(q, cache_k, cache_v, pos)

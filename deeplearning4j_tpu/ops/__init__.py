@@ -22,6 +22,9 @@ from .flash_attention import (
     decode_attention_reference,
     flash_attention,
     flash_decode_attention,
+    flash_masked_cache_write,
+    masked_cache_write,
+    masked_cache_write_reference,
     mha_attention,
     mha_attention_reference,
     set_attention_impl,
@@ -52,6 +55,9 @@ __all__ = [
     "decode_attention",
     "decode_attention_reference",
     "flash_decode_attention",
+    "flash_masked_cache_write",
+    "masked_cache_write",
+    "masked_cache_write_reference",
     "available_helpers",
     "get_helper",
     "helper_name",

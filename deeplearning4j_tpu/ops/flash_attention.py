@@ -671,9 +671,13 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
     vector, so k-blocks entirely past the decode frontier skip their
     compute: the per-step work is O(position), not O(max_len).
 
-    A one-row query gives the MXU nothing to do (M=1), so scores and the
-    weighted value sum are VPU broadcasts with lane / sublane reductions:
-    scores live as a [block_k, 1] column, which needs no transposes."""
+    K and V blocks arrive POSITION-MINOR, ``[d, block_k]``: that is how
+    the chip stores a ``[b, h, L, d]`` cache whose ``d`` is narrower than
+    its 128 lanes, so the kernel reads the cache where it lies and no
+    step transposes it. A one-row query gives the MXU nothing to do
+    (M=1), so scores and the weighted value sum are VPU broadcasts with
+    sublane / lane reductions: q is a ``[d, 1]`` column, scores a
+    ``[1, block_k]`` row with every lane in use."""
     ki = pl.program_id(1)
     length = len_ref[pl.program_id(0) // heads]  # valid entries = pos + 1
 
@@ -685,20 +689,20 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
 
     @pl.when(ki * block_k < length)
     def _():
-        q = q_ref[0].astype(jnp.float32) * scale    # [1, d]
-        ks = k_ref[0].astype(jnp.float32)           # [block_k, d]
-        vs = v_ref[0].astype(jnp.float32)           # [block_k, dv]
-        s = jnp.sum(ks * q, axis=-1, keepdims=True)  # [block_k, 1]
+        q = q_ref[0].astype(jnp.float32) * scale    # [d, 1]
+        kt = k_ref[0].astype(jnp.float32)           # [d, block_k]
+        vt = v_ref[0].astype(jnp.float32)           # [dv, block_k]
+        s = jnp.sum(kt * q, axis=0, keepdims=True)  # [1, block_k]
         k_ids = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_k, 1), 0)
+            jnp.int32, (1, block_k), 1)
         s = jnp.where(k_ids < length, s, _NEG)
         m, l, acc = m_scr[...], l_scr[...], acc_scr[...]
-        m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))  # [1, 1]
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))  # [1, 1]
         p = jnp.where(s > _NEG * 0.5, jnp.exp(s - m_new), 0.0)
         alpha = jnp.exp(m - m_new)
         m_scr[...] = m_new
-        l_scr[...] = l * alpha + jnp.sum(p, axis=0, keepdims=True)
-        acc_scr[...] = acc * alpha + jnp.sum(p * vs, axis=0, keepdims=True)
+        l_scr[...] = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = acc * alpha + jnp.sum(vt * p, axis=1, keepdims=True)
 
     @pl.when(ki == pl.num_programs(1) - 1)
     def _():
@@ -716,7 +720,9 @@ def flash_decode_attention(
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Pallas single-query-block decode attention (same contract as
-    :func:`decode_attention_reference` with ``tq == 1``)."""
+    :func:`decode_attention_reference` with ``tq == 1``). K and V go to
+    the kernel as ``[b*h, d, L]``: for a cache the chip keeps
+    position-minor that transpose is a relabelling, not a copy."""
     if q.shape[2] != 1:
         raise ValueError("flash_decode_attention is the tq=1 kernel; use "
                          "decode_attention for multi-row queries")
@@ -730,9 +736,9 @@ def flash_decode_attention(
     kp = _pad_to(k, 2, block_k)
     vp = _pad_to(v, 2, block_k)
     L_p = kp.shape[2]
-    qp = q.reshape(b * h, 1, d)
-    kp = kp.reshape(b * h, L_p, d)
-    vp = vp.reshape(b * h, L_p, dv)
+    qp = q.reshape(b * h, d, 1)
+    kp = jnp.swapaxes(kp, 2, 3).reshape(b * h, d, L_p)
+    vp = jnp.swapaxes(vp, 2, 3).reshape(b * h, dv, L_p)
     lengths = start_pos.astype(jnp.int32) + 1  # [b], scalar-prefetched
 
     kern = functools.partial(_decode_kernel, scale=float(scale),
@@ -744,26 +750,143 @@ def flash_decode_attention(
             num_scalar_prefetch=1,
             grid=(b * h, L_p // block_k),
             in_specs=[
-                pl.BlockSpec((1, 1, d), lambda bh, ki, lens: (bh, 0, 0),
+                pl.BlockSpec((1, d, 1), lambda bh, ki, lens: (bh, 0, 0),
                              **kw),
-                pl.BlockSpec((1, block_k, d),
-                             lambda bh, ki, lens: (bh, ki, 0), **kw),
-                pl.BlockSpec((1, block_k, dv),
-                             lambda bh, ki, lens: (bh, ki, 0), **kw),
+                pl.BlockSpec((1, d, block_k),
+                             lambda bh, ki, lens: (bh, 0, ki), **kw),
+                pl.BlockSpec((1, dv, block_k),
+                             lambda bh, ki, lens: (bh, 0, ki), **kw),
             ],
-            out_specs=pl.BlockSpec((1, 1, dv),
+            out_specs=pl.BlockSpec((1, dv, 1),
                                    lambda bh, ki, lens: (bh, 0, 0), **kw),
             scratch_shapes=[
                 pltpu.VMEM((1, 1), jnp.float32),
                 pltpu.VMEM((1, 1), jnp.float32),
-                pltpu.VMEM((1, dv), jnp.float32),
+                pltpu.VMEM((dv, 1), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b * h, 1, dv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b * h, dv, 1), q.dtype),
         interpret=interpret,
         name="flash_decode",
     )(lengths, qp, kp, vp)
     return out.reshape(b, h, 1, dv)
+
+
+# ---------------------------------------------------------------------------
+# KV-cache write of a fused batch decode step (idle rows masked off)
+# ---------------------------------------------------------------------------
+
+
+def masked_cache_write_reference(
+    cache: jax.Array,        # [b, h, L, d], or a scale plane [b, h, L]
+    new: jax.Array,          # [b, h, t, d], or [b, h, t]
+    pos: jax.Array,          # [b] int32
+    write_mask: jax.Array,   # [b] bool
+) -> jax.Array:
+    """Builtin XLA spelling: write ``new`` into the static-shape cache at
+    per-row positions ``pos + [0, t)`` for the rows ``write_mask`` keeps,
+    and leave the other rows of the cache as they were. It is the scatter
+    that the layers' vmapped ``dynamic_update_slice`` becomes, with one
+    difference: a row that is masked off gets a position past the cache,
+    and the scatter drops an update that is out of range. A row that
+    writes is clamped as ``dynamic_update_slice`` clamps it. Nothing of
+    the cache is read or selected: the cost stays that of the positions
+    written."""
+    L, t = cache.shape[2], new.shape[2]
+    p = jnp.where(write_mask, jnp.clip(pos.astype(jnp.int32), 0, L - t), L)
+    z = jnp.zeros_like(p)
+    idx = jnp.stack([z, p] + [z] * (cache.ndim - 3), axis=1)  # [b, ndim-1]
+    dims = tuple(range(1, cache.ndim))
+    return jax.lax.scatter(
+        cache, idx, new.astype(cache.dtype),
+        jax.lax.ScatterDimensionNumbers(
+            update_window_dims=dims, inserted_window_dims=(),
+            scatter_dims_to_operand_dims=dims, operand_batching_dims=(0,),
+            scatter_indices_batching_dims=(0,)),
+        indices_are_sorted=True, unique_indices=True,
+        mode=jax.lax.GatherScatterMode.FILL_OR_DROP)
+
+
+def _cache_write_kernel(pos_ref, keep_ref, new_ref, cache_ref, out_ref, *,
+                        block):
+    """One row of the batch: the ``block`` positions round the row's own
+    come in position-minor, the lane at the row's position takes the new
+    entry (every lane stays as it was for a row that is masked off), and
+    the block goes back where it came from."""
+    r = pl.program_id(0)
+    c = cache_ref[0]                                # [h, d, block] / [h, block]
+    wide = (jnp.float32 if jnp.issubdtype(c.dtype, jnp.floating)
+            else jnp.int32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, c.shape, c.ndim - 1)
+    hit = (lane == pos_ref[r] % block) & (keep_ref[r] != 0)
+    out_ref[0] = jnp.where(hit, new_ref[0].astype(wide),
+                           c.astype(wide)).astype(c.dtype)
+
+
+def flash_masked_cache_write(
+    cache: jax.Array,
+    new: jax.Array,
+    pos: jax.Array,
+    write_mask: jax.Array,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """Pallas one-token cache write (same contract as
+    :func:`masked_cache_write_reference` with ``t == 1``), in place: the
+    cache goes through the kernel position-minor, ``[b, h, d, L]`` — for a
+    cache the chip keeps that way a relabelling, not a copy — aliased to
+    its output, and each grid step moves only the 128-position block that
+    holds its row's position. XLA's scatter does the same write as a
+    loop of one small update a row, seven operations each."""
+    if new.shape[2] != 1:
+        raise ValueError("flash_masked_cache_write is the t=1 kernel")
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    b, L = cache.shape[0], cache.shape[2]
+    block = 128 if L % 128 == 0 else L
+    p = jnp.clip(pos.astype(jnp.int32), 0, L - 1)
+    keep = write_mask.astype(jnp.int32)
+    planes = cache.ndim == 4
+    if planes:  # [b, h, L, d] -> [b, h, d, L]; new [b, h, 1, d] -> [b, h, d, 1]
+        cache, new = jnp.swapaxes(cache, 2, 3), jnp.swapaxes(new, 2, 3)
+    lead = cache.shape[1:-1]
+    zeros = (0,) * len(lead)
+    kw = dict(memory_space=pltpu.VMEM)
+    out = pl.pallas_call(
+        functools.partial(_cache_write_kernel, block=block),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b,),
+            in_specs=[
+                pl.BlockSpec((1,) + lead + (1,),
+                             lambda r, pos, keep: (r,) + zeros + (0,), **kw),
+                pl.BlockSpec((1,) + lead + (block,),
+                             lambda r, pos, keep: (r,) + zeros
+                             + (pos[r] // block,), **kw),
+            ],
+            out_specs=pl.BlockSpec((1,) + lead + (block,),
+                                   lambda r, pos, keep: (r,) + zeros
+                                   + (pos[r] // block,), **kw),
+        ),
+        out_shape=jax.ShapeDtypeStruct(cache.shape, cache.dtype),
+        input_output_aliases={3: 0},  # operands: pos, keep, new, cache
+        interpret=interpret,
+        name="kv_cache_write",
+    )(p, keep, new.astype(cache.dtype), cache)
+    return jnp.swapaxes(out, 2, 3) if planes else out
+
+
+def masked_cache_write(cache: jax.Array, new: jax.Array, pos: jax.Array,
+                       write_mask: jax.Array) -> jax.Array:
+    """Helper-seam dispatch for the cache write of a fused batch decode
+    step (mirrors :func:`decode_attention`): the Pallas in-place kernel
+    when "flash" is selected (or automatically on TPU) and one token is
+    written, the builtin scatter otherwise."""
+    impl = _IMPL
+    if impl == "auto":
+        impl = "flash" if jax.default_backend() == "tpu" else "xla"
+    if impl == "flash" and new.shape[2] == 1:
+        return flash_masked_cache_write(cache, new, pos, write_mask)
+    return masked_cache_write_reference(cache, new, pos, write_mask)
 
 
 def decode_attention(

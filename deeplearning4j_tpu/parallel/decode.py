@@ -76,11 +76,13 @@ from ..core.resilience import (
 from ..generate.paged import (
     BlockAllocator,
     OutOfBlocksError,
+    attach_block_table,
     block_bytes,
     blocks_needed,
+    detach_block_table,
     freeze_rows,
+    mask_inactive_writes,
     paged_decode_state,
-    redirect_inactive_writes,
 )
 from ..generate.sampling import sample_tokens
 from ..generate.session import GenerationSession, SpeculativeGenerationSession
@@ -295,22 +297,25 @@ class DecodeEngine:
         self._slot_target = self.slots
         self._init_metrics(registry if registry is not None else get_registry())
 
-        # device-side batch state: one preallocated carry, per-row specs
+        # device-side batch state: one preallocated carry, per-row specs.
+        # The carry is DONATED to every program that takes one and returns
+        # one (decode step, both installs), so it is updated in place; the
+        # paged layout's block table is held beside it (``_table``), never
+        # inside: one buffer under twelve layers cannot be donated twelve
+        # times
+        self._table = None
         if self.block_size is not None:
-            self._carry = paged_decode_state(
-                self.session, self.slots, block_size=self.block_size,
-                num_blocks=self.num_kv_blocks)
             self._allocator = BlockAllocator(self.num_kv_blocks)
             # host image of every row's block list (pushed to the device
-            # carry as one shared [slots, max_len/bs] leaf on change)
+            # as one shared [slots, max_len/bs] array on change)
             self._block_tables = np.zeros(
                 (self.slots, self.max_len // self.block_size), np.int32)
             self._nblocks = np.zeros((self.slots,), np.int32)
             self._block_bytes = block_bytes(self.session, self.block_size)
             self._push_tables()
         else:
-            self._carry = self.session.decode_state(self.slots)
             self._allocator = None
+        self._carry = self._fresh_carry()
         self._row_template = self.session.decode_state(1)
         # the draft cache stays static (slot×max_len): proposals run every
         # slot each turn, and the draft rows rewind with the target's
@@ -417,6 +422,11 @@ class DecodeEngine:
             "AIMD active-slot target (admission fills at most this many "
             "cache slots)", ("instance",)).labels(inst)
         self._g_slot_target.set(self._slot_target)
+        self._c_rebuilds = reg.counter(
+            "dl4j_tpu_decode_carry_rebuilds_total",
+            "Decode carries rebuilt after a donated step or install "
+            "raised at run time and took the carry with it (0 in a sound "
+            "window)", ("instance",)).labels(inst)
         self._g_kv_bytes = reg.gauge(
             "dl4j_tpu_generate_kv_cache_bytes",
             "Live resident bytes of the decode KV cache: the full "
@@ -436,14 +446,20 @@ class DecodeEngine:
         self._g_kv_bytes.set(self._kv_cache_bytes)
 
     def _push_tables(self) -> None:
-        """Mirror the host block tables into the device carry as ONE
-        shared ``[slots, max_len/bs]`` leaf (same shape/dtype every push
-        — no recompiles)."""
-        tbl = jnp.asarray(self._block_tables)
-        self._carry = {
-            name: ({**st, "block_table": tbl} if "block_table" in st
-                   else st)
-            for name, st in self._carry.items()}
+        """Mirror the host block tables onto the device as ONE shared
+        ``[slots, max_len/bs]`` array (same shape/dtype every push — no
+        recompiles), which the step attaches under every paged layer."""
+        self._table = jnp.asarray(self._block_tables)
+
+    def _fresh_carry(self):
+        """A zeroed batch carry: the static per-layer caches, or the
+        paged pools without their block tables (``_table`` holds the one
+        shared table)."""
+        if self._allocator is None:
+            return self.session.decode_state(self.slots)
+        return detach_block_table(paged_decode_state(
+            self.session, self.slots, block_size=self.block_size,
+            num_blocks=self.num_kv_blocks))
 
     def _ensure_blocks(self, slot: int, upto: int) -> None:
         """Grow ``slot``'s block list to cover positions ``[0, upto)``.
@@ -551,10 +567,12 @@ class DecodeEngine:
             model = sess.model
 
             def decode_step(params, state, carry, tokens, active, seeds,
-                            steps, gmask, temps, ks, ps):
-                # paged carries: inactive rows write the trash block, not
-                # their own live blocks (the fused step writes every row)
-                fwd = redirect_inactive_writes(carry, active)
+                            steps, gmask, temps, ks, ps, table=None):
+                # the fused step writes every row: an inactive row of a
+                # paged carry writes the trash block, not its own live
+                # blocks, and one of a static carry writes nothing
+                fwd = mask_inactive_writes(
+                    attach_block_table(carry, table), active)
                 with jax.named_scope("forward"):
                     out, _, new_rnn = model.forward_pure(
                         params, state, sess._prep(tokens[:, None]),
@@ -564,12 +582,14 @@ class DecodeEngine:
                 with jax.named_scope("sample"):
                     toks = sample_tokens(logits, seeds, steps, gmask, temps,
                                          ks, ps)
-                # idle/finished slots must not advance their cache or (h, c)
+                # idle/finished slots must not advance their pos or (h, c);
+                # their cache planes the masked write left as they were
                 with jax.named_scope("freeze_rows"):
-                    new_rnn = freeze_rows(new_rnn, carry, active)
+                    new_rnn = detach_block_table(
+                        freeze_rows(new_rnn, fwd, active))
                 return new_rnn, jnp.where(active, toks, 0)
 
-            self._fns["decode"] = jax.jit(decode_step)
+            self._fns["decode"] = jax.jit(decode_step, donate_argnums=2)
         return self._fns["decode"]
 
     def _write_row_fn(self):
@@ -583,7 +603,7 @@ class DecodeEngine:
 
                 return jax.tree_util.tree_map(put, carry, row)
 
-            self._fns["write"] = jax.jit(install_row)
+            self._fns["write"] = jax.jit(install_row, donate_argnums=0)
         return self._fns["write"]
 
     def _paged_install_fn(self):
@@ -604,14 +624,15 @@ class DecodeEngine:
                         if key == "pos":
                             new_st[key] = jax.lax.dynamic_update_slice(
                                 pool, r["pos"].astype(pool.dtype), (slot,))
-                        elif key != "block_table":
+                        else:
                             packed = pack_row_blocks(r[key][0], bs)
                             new_st[key] = pool.at[dest].set(
                                 packed.astype(pool.dtype))
                     out[name] = new_st
                 return out
 
-            self._fns["paged_install"] = jax.jit(paged_install)
+            self._fns["paged_install"] = jax.jit(paged_install,
+                                                 donate_argnums=0)
         return self._fns["paged_install"]
 
     def _install_row(self, slot: int, row) -> None:
@@ -844,7 +865,12 @@ class DecodeEngine:
                     return admitted
                 self._finish(req, "failed", error=str(e))
             except Exception as e:  # noqa: BLE001 — fail the request, not the loop
-                self._breaker.record_failure()
+                if self._carry_lost():
+                    # a donated install died at run time: every row's
+                    # cache went with it
+                    self._fail_active(e)
+                else:
+                    self._breaker.record_failure()
                 self._finish(req, "failed", error=str(e))
 
     def _prefill_into(self, slot: int, req: _Request,
@@ -991,8 +1017,10 @@ class DecodeEngine:
 
     def _fail_active(self, e: Exception) -> None:
         """Poisoned device step: fail every active request, open-circuit
-        accounting, clear the batch."""
+        accounting, clear the batch — and leave the engine with a carry
+        the next admitted request can run on."""
         self._breaker.record_failure()
+        self._rebuild_lost_carry()  # before any caller hears of the failure
         for slot in range(self.slots):
             req = self._requests[slot]
             if req is not None:
@@ -1001,6 +1029,22 @@ class DecodeEngine:
                 self._release_blocks(slot)
                 self._finish(req, "failed", error=str(e))
         self._g_active.set(0)
+
+    def _carry_lost(self) -> bool:
+        """A donated program that raised at run time took its carry with
+        it; one that failed while tracing consumed nothing."""
+        return any(l.is_deleted() for l in jax.tree_util.tree_leaves(
+            (self._carry, self._draft_carry)))
+
+    def _rebuild_lost_carry(self) -> None:
+        """Replace a carry that a failed donated call consumed by a zeroed
+        one (no row outlives it: the caller fails them all)."""
+        if not self._carry_lost():
+            return
+        self._carry = self._fresh_carry()
+        if self._spec is not None:
+            self._draft_carry = self._spec.draft.decode_state(self.slots)
+        self._c_rebuilds.inc()
 
     def _step(self, rows: Optional[np.ndarray] = None,
               parent=NULL_SPAN) -> None:
@@ -1025,7 +1069,8 @@ class DecodeEngine:
                     jnp.asarray(self._ks), jnp.asarray(self._ps))
             with span("loop.dispatch", parent=parent):
                 self._carry, toks = self._decode_step_fn()(
-                    sess.model.params, sess.model.state, self._carry, *args)
+                    sess.model.params, sess.model.state, self._carry, *args,
+                    self._table)
             with span("loop.fetch", parent=parent):
                 toks_h = np.asarray(toks)
         except Exception as e:  # noqa: BLE001 — poisoned step: fail active requests
@@ -1081,13 +1126,17 @@ class DecodeEngine:
                 # propose, verify and accept are dispatched and fetched
                 # inside the session's step: one span round all of it
                 with span("loop.fetch", parent=parent):
-                    (self._carry, self._draft_carry, toks, n_acc,
+                    # the session's step takes (and returns) the tables
+                    # inside the carry, and donates nothing
+                    (carry, self._draft_carry, toks, n_acc,
                      n_emit) = self._spec.step(
-                        self._carry, self._draft_carry, self._last,
+                        attach_block_table(self._carry, self._table),
+                        self._draft_carry, self._last,
                         self._steps, spec_rows, jnp.asarray(self._seeds),
                         jnp.asarray(self._greedy), jnp.asarray(self._temps),
                         jnp.asarray(self._ks), jnp.asarray(self._ps),
                         np.where(spec_rows, caps, 0), k=k)
+                    self._carry = detach_block_table(carry)
                     toks_h = np.asarray(toks)
                     acc_h = np.asarray(n_acc)
                     ne_h = np.asarray(n_emit)
@@ -1277,6 +1326,7 @@ class DecodeEngine:
             "kv_blocks_free": (None if self._allocator is None
                                else self._allocator.free_blocks),
             "circuit_state": self._breaker.state.value,
+            "carry_rebuilds": int(self._c_rebuilds.value),
             "draining": self._draining,
             # zero-guarded (PR-7 convention): derived ratios are None, not
             # 0.0, before any speculative traffic

@@ -14,7 +14,7 @@ import jax.numpy as jnp
 import pytest
 
 from deeplearning4j_tpu.ops.flash_attention import (
-    flash_attention, flash_decode_attention)
+    flash_attention, flash_decode_attention, flash_masked_cache_write)
 from deeplearning4j_tpu.ops.grouped_matmul import _gmm, _tiling
 
 DTYPES = [jnp.bfloat16, jnp.float32]
@@ -63,6 +63,19 @@ def test_flash_decode_lowers(dtype, L):
         _spec(b, h, 1, d, dtype=dtype), _spec(b, h, L, d, dtype=dtype),
         _spec(b, h, L, d, dtype=dtype), _spec(b, dtype=jnp.int32))
     assert names == ["flash_decode"]
+
+
+@pytest.mark.parametrize("shape", [(8, 12, 1024, 64), (8, 12, 1024),
+                                   (8, 12, 600, 64)])
+@pytest.mark.parametrize("dtype", DTYPES + [jnp.int8])
+def test_kv_cache_write_lowers(dtype, shape):
+    new = shape[:2] + (1,) + shape[3:]
+    names = _kernels(
+        lambda c, n, p, m: flash_masked_cache_write(c, n, p, m,
+                                                    interpret=False),
+        _spec(*shape, dtype=dtype), _spec(*new, dtype=dtype),
+        _spec(shape[0], dtype=jnp.int32), _spec(shape[0], dtype=jnp.bool_))
+    assert names == ["kv_cache_write"]
 
 
 @pytest.mark.parametrize("dtype", DTYPES)

@@ -353,6 +353,53 @@ class TestSpeculativeEngine:
                        "dl4j_tpu_generate_token_latency_seconds"):
             assert series in text, f"missing {series}"
 
+    def test_plain_step_leaves_spec_rows_committed_cache(self, lm, draft_lm):
+        """ISSUE 27: a turn that splits its rows — one near ``max_len``
+        (no room for a k+1 window) or with ``speculative_k=0`` takes the
+        plain step, the others the speculative one — runs the plain step
+        with the speculative rows live but inactive. The plain step
+        donates the carry and freezes idle rows by dropping their writes:
+        a speculative row's committed cache ``[0, pos)`` and its ``pos``
+        come out of it bit-identical, and every stream is the session's."""
+        eng, _ = self._engine(lm, draft_lm, speculative_k=3, slots=3,
+                              name="spec-split")
+        real, seen = eng._step, []
+
+        def host(rows):
+            return [(name, k, r, np.array(v[r, :, :int(eng._pos[r])]))
+                    for name, st in eng._carry.items()
+                    for k, v in st.items() if k != "pos"
+                    for r in rows], [
+                        np.array(st["pos"]) for st in eng._carry.values()]
+
+        def step(rows=None, parent=None):
+            idle = [r for r in range(eng.slots)
+                    if eng._requests[r] is not None and not rows[r]]
+            before, pos = host(idle)
+            real(rows, parent)
+            after, pos2 = host(idle)
+            same = all(np.array_equal(a[3], b[3])
+                       for a, b in zip(before, after)) and all(
+                np.array_equal(a[idle], b[idle])
+                for a, b in zip(pos, pos2))
+            seen.append((len(idle), int(rows.sum()), same))
+
+        eng._step = step
+        near = list(range(1, MAX_LEN - 4))  # 11 tokens: plain from pos 13
+        try:
+            handles = [eng.submit(near, max_tokens=MAX_LEN),
+                       eng.submit([1, 2, 3], max_tokens=9),
+                       eng.submit([4, 5], max_tokens=9, speculative_k=0)]
+            got = [h.result(timeout=180) for h in handles]
+        finally:
+            eng.shutdown()
+        # some plain step ran beside a live speculative row, none moved one
+        assert any(n_idle and n_rows for n_idle, n_rows, _ in seen), seen
+        assert all(same for _, _, same in seen), seen
+        sess = GenerationSession(lm, max_len=MAX_LEN)
+        exp = sess.generate([near, [1, 2, 3], [4, 5]], MAX_LEN, greedy=True)
+        assert got == [exp[0], exp[1][:9], exp[2][:9]]
+
     def test_plain_engine_unchanged(self, lm):
         """No draft model: speculative surface reports disabled and the
         engine path is the PR-9 one."""

@@ -57,6 +57,37 @@ def test_flash_decode_compiled_matches_reference(tpu_device, L, block_k):
     assert not np.asarray(got, np.float32)[4].any()
 
 
+@pytest.mark.parametrize("t,dtype", [(1, jnp.bfloat16), (1, jnp.int8),
+                                     (4, jnp.bfloat16)])
+def test_masked_cache_write_compiled_drops_idle_rows(tpu_device, t, dtype):
+    """The decode step's masked cache write on the chip (``t == 1``: the
+    in-place Pallas kernel; more: the scatter): a row that is masked off
+    writes nothing, a row that writes lands where the vmapped
+    ``dynamic_update_slice`` puts it, the clamp at the cache's end too."""
+    from deeplearning4j_tpu.nn.layers.attention import _cache_write
+    from deeplearning4j_tpu.ops import masked_cache_write
+
+    b, h, L, d = 8, 12, 1024, 64
+    cache = (_rand(6, b, h, L, d) * 40).astype(dtype)
+    new = (_rand(7, b, h, t, d) * 40).astype(dtype)
+    pos = jnp.asarray([0, 5, L - t, L - 1, L, 17, L // 2, L + 3], jnp.int32)
+    mask = jnp.asarray([True, False, True, True, False, True, False, True])
+    text = jax.jit(masked_cache_write).lower(cache, new, pos, mask).as_text()
+    assert ("kv_cache_write" in text) == (t == 1)
+    got = jax.jit(masked_cache_write)(cache, new, pos, mask)
+    want = jnp.where(mask[:, None, None, None],
+                     jax.jit(_cache_write)(cache, new, pos), cache)
+    assert (np.asarray(got, np.float32) == np.asarray(want, np.float32)).all()
+    scales, ns = _rand(8, b, h, L).astype(jnp.float32), _rand(
+        9, b, h, t).astype(jnp.float32)
+    from deeplearning4j_tpu.nn.layers.attention import _scale_write
+
+    got = jax.jit(masked_cache_write)(scales, ns, pos, mask)
+    want = jnp.where(mask[:, None, None],
+                     jax.jit(_scale_write)(scales, ns, pos), scales)
+    assert (np.asarray(got) == np.asarray(want)).all()
+
+
 @pytest.mark.parametrize("cap", [2560, None])
 def test_grouped_matmul_compiled_matches_reference(tpu_device, cap):
     n, d, e, h = 16384, 768, 8, 1536
