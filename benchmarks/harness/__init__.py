@@ -1,3 +1,6 @@
-"""The benchmark's own yardstick: traffic, work and peaks, the trace
-reduction, the plain references and the comparison that decides ``correct``.
-Nothing here is imported by the program, and only the two drivers import it."""
+"""The benchmark's own yardstick: traffic, peaks, the trace reduction, what
+the plain references share and the comparison that decides ``correct``.
+What depends on a model's architecture (its sizes, weight tree, reference,
+work counts, cache's bytes) is in ``../families/``, one file a family.
+Nothing here is imported by the program; the two drivers and the families
+import it."""

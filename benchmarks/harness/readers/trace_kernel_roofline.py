@@ -1,19 +1,20 @@
 """``device_trace``: a kernel's share of its roofline, in percent.
 
 The least time the chip could take for one call - the larger of FLOPs over
-peak FLOP/s and bytes over peak bytes/s, both from ``work.py`` (what the
-algorithm needs, from shapes) - over the mean device time of the kernel's
-events in the traced slice. ``kernels`` lists the kernel names taken
-together (their times per call add up); ``work`` names the function in
-``work.py``. A trace without the kernel gives nothing, never 0."""
+peak FLOP/s and bytes over peak bytes/s, both counted by the family of the
+cell being run (what the algorithm needs, from shapes) - over the mean
+device time of the kernel's events in the traced slice. ``kernels`` lists
+the kernel names taken together (their times per call add up); ``work``
+names the function of the family's file (``record["family"]``), so a kernel
+that two families share is counted by each for its own shapes. A trace
+without the kernel gives nothing, never 0."""
 
-from .. import work as work_fns
 from ..peaks import chip_peaks
 
 
 def _least_seconds(record: dict, work: str):
     """(seconds at peak FLOP/s, seconds at peak bytes/s) of one call."""
-    flops, nbytes = getattr(work_fns, work)(record["slice"])
+    flops, nbytes = getattr(record["family"], work)(record["slice"])
     pk = chip_peaks(record["device_kind"])
     return (flops / pk["bf16_flops_per_s"],
             (nbytes or 0.0) / pk["hbm_bytes_per_s"])

@@ -1,10 +1,12 @@
-"""What both drivers share: the run's description, the device's report, the
-traced slice, and the result line."""
+"""What both drivers share: the run's description with its family, the
+device's report, the traced slice, the program's counters over it, and the
+result line."""
 
 from __future__ import annotations
 
 import dataclasses
 import glob
+import importlib.util
 import math
 import os
 import shutil
@@ -20,6 +22,8 @@ class Run:
     cell: dict
     config: dict
     traffic: dict
+    family: object          # the module families/<config["family"]>.py
+    kernel_names: tuple     # the kernels the cell's per-layer metrics read
     seed: int
     seconds: float
     trace: bool
@@ -40,11 +44,55 @@ class Run:
               file=sys.stderr, flush=True)
 
 
-def model_dims(config: dict) -> dict:
-    """The sizes the yardstick needs, under its own names."""
-    m = config["model"]
-    return {k: int(m[k]) for k in ("vocab_size", "hidden", "n_layers",
-                                   "n_heads", "ffn_size", "max_len")}
+def family_file(data_dir: str, config: dict) -> str:
+    """Where the configuration's family lives: ``<data-dir>/families/
+    <family>.py``. There is no default family and no fallback: a
+    configuration that names none, or one whose file is missing, ends the
+    run before it prints anything."""
+    name = config.get("family")
+    if not name:
+        raise SystemExit(f"benchmark: configuration {config.get('name')!r} "
+                         "has no \"family\" key; every configuration names "
+                         "the file under families/ that knows its model")
+    path = os.path.join(os.path.abspath(data_dir), "families", name + ".py")
+    if not os.path.isfile(path):
+        raise SystemExit(f"benchmark: configuration {config.get('name')!r} "
+                         f"names the family {name!r}, and there is no {path}")
+    return path
+
+
+def load_family(path: str):
+    """Import a family's file by its path (once JAX is set up: a family's
+    reference imports it). See ``run.py``'s docstring for what it gives."""
+    name = "benchmarks_family_" + os.path.splitext(os.path.basename(path))[0]
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def read_counters(run: Run) -> dict:
+    """``{name: {"label,values": value}}`` of the program's counters that
+    the configuration lists under ``slice_counters``, from the process's
+    registry (a name it does not have yet reads as empty). A family counts
+    work that only the run knows (tokens routed to the experts held, say)
+    from these: a driver reads them where the traced slice starts and where
+    it stops."""
+    from deeplearning4j_tpu.obs.metrics import get_registry
+
+    registry, out = get_registry(), {}
+    for name in run.config.get("slice_counters", ()):
+        fam = registry.get(name)
+        out[name] = {} if fam is None else {
+            ",".join(labels): child.value for labels, child in fam.items()}
+    return out
+
+
+def counters_between(lo: dict, hi: dict) -> dict:
+    """What each counter rose by between two ``read_counters``."""
+    return {name: {k: v - lo.get(name, {}).get(k, 0.0)
+                   for k, v in children.items()}
+            for name, children in hi.items()}
 
 
 def dtype_bytes(name: str) -> int:
@@ -93,6 +141,7 @@ class TraceSlice:
         self.dir = os.path.join(run.out_dir, "trace")
         shutil.rmtree(self.dir, ignore_errors=True)
         self.chips = run.chips
+        self.kernel_names = run.kernel_names
         self.t1 = self.t_untraced = 0.0
 
     def start(self) -> None:
@@ -117,7 +166,7 @@ class TraceSlice:
         if not paths:
             return None
         out = trace_reduce.reduce(trace_reduce.load_events(paths[0]),
-                                  chips=self.chips)
+                                  self.chips, self.kernel_names)
         shutil.rmtree(self.dir, ignore_errors=True)  # write little to disk
         return out
 

@@ -10,8 +10,13 @@ fraction of its length that spreads evenly over the clients, and to the mix's
 started at times spread over the round and not in one burst). At the close no new
 request is sent; those in flight drain outside the rate's denominator.
 
-``correct``: once the window has closed and the engine is gone, the plain
-reference runs once over each sampled request's prompt and served tokens.
+Nothing here knows the model: the sizes, the seed's weight tree, the plain
+reference, the work counts and the cache's bytes are the family's
+(``run.family``, the file the configuration names).
+
+``correct``: once the window has closed and the engine is gone, the family's
+plain reference runs once over each sampled request's prompt and served
+tokens.
 Compared: the widest gap by which a served (greedy) token's logit lies below
 the reference's best at its position, which one wrong token fails, and the
 mean gap over all compared tokens, which a lower precision fails.
@@ -27,9 +32,9 @@ import time
 
 import numpy as np
 
-from . import reference, weights
-from .runtime import (Run, TraceSlice, device_report, devices_for,
-                      dtype_bytes, model_dims, percentile)
+from . import weights
+from .runtime import (Run, TraceSlice, counters_between, device_report,
+                      devices_for, dtype_bytes, percentile, read_counters)
 from .traffic import RequestSource, poisson_due_times, size_set
 
 HISTS = ("dl4j_tpu_generate_decode_latency_seconds",
@@ -40,7 +45,8 @@ DRAIN_S = 60.0
 class ServeRun:
     def __init__(self, run: Run) -> None:
         self.run = run
-        self.dims = model_dims(run.config)
+        self.family = run.family
+        self.dims = run.family.dims(run.config)
         self.traffic = run.traffic
         self.records: list = []
         self.lock = threading.Lock()
@@ -63,9 +69,10 @@ class ServeRun:
         model = MultiLayerNetwork(getattr(zoo, cfg["model_class"])(
             **cfg["model"], seed=run.seed & 0x7FFFFFFF,
             dtype=cfg["dtype"]).conf())
-        w = weights.make_weights(self.dims, run.seed, cfg["dtype"])
+        w = weights.make_weights(self.family, self.dims, run.seed,
+                                 cfg["dtype"])
         weights.install(model, weights.program_tree(
-            w, cfg["layout"], self.dims["n_layers"]))
+            self.family, self.dims, w, cfg["layout"]))
         del w
         self.engine = DecodeEngine(model, **cfg["engine"])
         run.log("weights from the seed installed, engine built")
@@ -174,13 +181,14 @@ class ServeRun:
         sl = None
         if tracer is not None:
             time.sleep(min(1.0, seconds / 4))
-            h0 = self.hist()
+            h0, c0 = self.hist(), read_counters(self.run)
             tracer.start()
             time.sleep(min(slice_s, seconds / 2))
-            h1 = self.hist()
+            h1, c1 = self.hist(), read_counters(self.run)
             tracer.stop()
             sl = {"t1": tracer.t1, "t_untraced": tracer.t_untraced,
-                  "decode_steps": h1[HISTS[0]][1] - h0[HISTS[0]][1]}
+                  "decode_steps": h1[HISTS[0]][1] - h0[HISTS[0]][1],
+                  "counters": counters_between(c0, c1)}
         rest = t_open + seconds - time.perf_counter()
         if rest > 0:
             time.sleep(rest)
@@ -247,21 +255,25 @@ class ServeRun:
                 "kind": "serve", "model": self.dims,
                 "dtype_bytes": dtype_bytes(self.run.config["dtype"]),
                 "decode_attended": attended, "prefill_lengths": prefills,
-                "decode_steps": sl["decode_steps"]}
+                "decode_steps": sl["decode_steps"],
+                "counters": sl["counters"]}
         return out
 
     def _kv_filled_bytes(self, recs: list, t_open: float,
                          t_close: float) -> float:
-        """The cache entries the traffic really fills, as bytes, averaged
-        over the window: a request at position p holds p entries for as long
-        as its next token takes (the engine reserves slots x max_len)."""
+        """The decode state the traffic really fills, as bytes, averaged
+        over the window: a request at position p holds what the family says
+        it holds there (``cache_bytes``: p entries of a cache, a constant
+        state) for as long as its next token takes (the engine reserves
+        slots x max_len)."""
+        width = dtype_bytes(self.run.config["dtype"])
         held = 0.0
         for r, i, share in token_shares(recs, t_open, t_close):
             since = r["times"][i - 1] if i else r["t_submit"]
-            held += (len(r["prompt"]) + i) * share * (r["times"][i] - since)
-        d = self.dims
-        return held / (t_close - t_open) * 2 * d["n_layers"] * d["hidden"] \
-            * dtype_bytes(self.run.config["dtype"])
+            held += self.family.cache_bytes(
+                self.dims, len(r["prompt"]) + i, width) \
+                * share * (r["times"][i] - since)
+        return held / (t_close - t_open)
 
     def free(self) -> None:
         self.engine.shutdown(drain=False)
@@ -292,14 +304,16 @@ class ServeRun:
         import jax.numpy as jnp
 
         cfg, dims = self.run.config, self.dims
-        w = weights.make_weights(dims, self.run.seed, cfg["dtype"])
-        length = dims["max_len"]
+        w = weights.make_weights(self.family, dims, self.run.seed,
+                                 cfg["dtype"])
+        length = int(cfg["engine"]["max_len"])
+        logits_of = self.family.decoder_logits
 
         @jax.jit
         def gaps(w, ids, targets, mask):
-            logits = reference.decoder_logits(w, ids[None], dims)[0]
+            logits = logits_of(w, ids[None], dims)[0]
             if quant is not None:
-                targets = jnp.argmax(reference.decoder_logits(
+                targets = jnp.argmax(logits_of(
                     w, ids[None], dims, quant=quant)[0], axis=-1)
             at = jnp.take_along_axis(logits, targets[:, None], axis=-1)[:, 0]
             gap = jnp.where(mask, jnp.max(logits, axis=-1) - at, 0.0)

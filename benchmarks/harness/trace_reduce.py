@@ -8,6 +8,10 @@ Two stages, so that the arithmetic is tested without a chip:
                        into plain ``Event`` tuples;
 ``reduce(events)``     is pure Python over those tuples.
 
+Which kernels to look for is the cell's own list: the names its per-layer
+metric files give under ``params.kernels`` (``run.py`` gathers them), so a
+model that brings a kernel brings its name in a data file.
+
 Device planes are named ``/device:TPU:<n>``. On each, the line ``XLA Ops``
 holds one event per executed operation, named by its whole HLO instruction
 (``%jvp_flash_fwd_.23 = (bf16[384,512,64]...) custom-call(...)``): the
@@ -27,8 +31,6 @@ from typing import NamedTuple
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
-KERNEL_NAMES = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_decode",
-                "grouped_matmul")
 
 
 class Event(NamedTuple):
@@ -90,20 +92,21 @@ def label(name: str) -> str:
     return instruction(head) + (" " + shape.group(0) if shape else "")
 
 
-def kernel_of(name: str):
-    """The kernel an event belongs to: its instruction's name carries the
+def kernel_of(name: str, kernels):
+    """The kernel of ``kernels`` an event belongs to: its instruction's name
+    carries the
     kernel's between underscores, dots or the ends (``flash_bwd_dq`` is in
     ``transpose_jvp_flash_bwd_dq__.12`` and not in ``flash_bwd_dq2``). The
     operands' text is not searched: a reduce that reads a kernel's output is
     not the kernel."""
     ins = instruction(name)
-    for k in sorted(KERNEL_NAMES, key=len, reverse=True):
+    for k in sorted(kernels, key=len, reverse=True):
         if re.search(rf"(?<![A-Za-z0-9]){re.escape(k)}(?![A-Za-z0-9])", ins):
             return k
     return None
 
 
-def reduce(events: list, chips: int = 1, top: int = 10) -> dict | None:
+def reduce(events: list, chips: int, kernel_names, top: int = 10):
     """``window_s``: first operation's start to last operation's end, the
     widest over the chips; ``busy_s``: the union of operation intervals,
     averaged over the chips used; ``ops``/``kernels``: ``name -> [seconds,
@@ -138,7 +141,7 @@ def reduce(events: list, chips: int = 1, top: int = 10) -> dict | None:
             o = ops.setdefault(label(e.name), [0.0, 0])
             o[0] += e.dur_ns * 1e-9
             o[1] += 1
-            k = kernel_of(e.name)
+            k = kernel_of(e.name, kernel_names)
             if k is not None:
                 kk = kernels.setdefault(k, [0.0, 0])
                 kk[0] += e.dur_ns * 1e-9

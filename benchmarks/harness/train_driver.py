@@ -8,6 +8,10 @@ mesh=make_mesh(**mesh), zero1=...)`` with the batch put on
 ``trainer.data_sharding``. Which one is the configuration file's ``mesh``,
 so a cell on four chips is a configuration file and a cell file.
 
+Nothing here knows the model: the sizes, the seed's weight tree, the loss of
+the plain reference and the work counts are the family's (``run.family``,
+the file the configuration names).
+
 Set-up builds ONE object, the compiled step with its state, drives it from
 the seed through its first three steps (through the window's own call and
 feed; the reference follows them), and hands that same object to the window.
@@ -23,8 +27,8 @@ from collections import deque
 import numpy as np
 
 from . import compare, reference, weights
-from .runtime import (Run, TraceSlice, device_report, devices_for,
-                      dtype_bytes, model_dims)
+from .runtime import (Run, TraceSlice, counters_between, device_report,
+                      devices_for, dtype_bytes, read_counters)
 from .traffic import token_batches
 
 CHECK_STEPS = 3
@@ -48,10 +52,10 @@ def _adam_mu(opt_state) -> dict:
 class TrainRun:
     def __init__(self, run: Run) -> None:
         self.run = run
-        self.dims = model_dims(run.config)
+        self.family = run.family
+        self.dims = run.family.dims(run.config)
         self.adam = dict(run.config["updater"]["adam"])
         self.layout = run.config["layout"]
-        self.n_layers = self.dims["n_layers"]
         self.batch = int(run.traffic["batch"])
         self.seq = int(run.traffic["seq"])
         self.check_batches: list = []
@@ -78,10 +82,11 @@ class TrainRun:
             **cfg["model"], seed=run.seed & 0x7FFFFFFF,
             updater=Adam(**self.adam), dtype=cfg["dtype"],
             compute_dtype=cfg["compute_dtype"]).conf())
-        w = weights.make_weights(self.dims, run.seed, cfg["dtype"])
-        start = weights.program_tree(w, self.layout, self.n_layers)
+        w = weights.make_weights(self.family, self.dims, run.seed,
+                                 cfg["dtype"])
+        start = weights.program_tree(self.family, self.dims, w, self.layout)
         weights.install(model, weights.program_tree(
-            w, self.layout, self.n_layers))
+            self.family, self.dims, w, self.layout))
         del w
         if n_mesh > 1:
             from deeplearning4j_tpu.parallel import (DistributedTrainer,
@@ -132,8 +137,8 @@ class TrainRun:
         dnorm = diff_norms(self._state()[0], start)
         del start
         def names(tree, scale=1.0):
-            flat = weights.canonical_names(jax.device_get(tree), self.layout,
-                                           self.n_layers)
+            flat = weights.canonical_names(self.family, self.dims,
+                                           jax.device_get(tree), self.layout)
             return {k: scale * v for k, v in flat.items()}
 
         run.log("first steps done")
@@ -172,6 +177,7 @@ class TrainRun:
             if tracer is not None and slice_info is None and elapsed >= 1.0:
                 drain()
                 k = max(3, int(round(slice_s / (elapsed / steps))))
+                c0 = read_counters(self.run)
                 tracer.start()
                 for _ in range(k):
                     one()
@@ -181,7 +187,9 @@ class TrainRun:
                 slice_info = {"kind": "train", "steps": k,
                               "batch": self.batch, "seq": self.seq,
                               "model": self.dims, "dtype_bytes": dtype_bytes(
-                                  self.run.config["compute_dtype"])}
+                                  self.run.config["compute_dtype"]),
+                              "counters": counters_between(
+                                  c0, read_counters(self.run))}
         drain()
         t1 = time.perf_counter()
         return {"steps": steps, "seconds": t1 - t0,
@@ -203,14 +211,18 @@ class TrainRun:
                           state_unchanged=False) -> dict:
         """The plain reference over the same first steps, from the seed."""
         cfg = self.run.config
-        w = weights.make_weights(self.dims, self.run.seed, cfg["dtype"])
+        w = weights.make_weights(self.family, self.dims, self.run.seed,
+                                 cfg["dtype"])
+        stacked = weights.stacked_keys(self.family, self.dims)
         losses, gnorm, dnorm = reference.train_steps(
-            w, self.check_batches, self.dims, self.adam,
+            self.family.loss, w, self.check_batches, self.dims, self.adam,
+            stacked=stacked,
             row_block=int(self.run.cell.get("reference_row_block", 8)),
             quant=quant, half_batch=half_batch,
             state_unchanged=state_unchanged)
-        return {"losses": losses, "gnorm": weights.stacked_names(gnorm),
-                "dnorm": weights.stacked_names(dnorm)}
+        return {"losses": losses,
+                "gnorm": weights.stacked_names(gnorm, stacked),
+                "dnorm": weights.stacked_names(dnorm, stacked)}
 
 
 def run(run: Run) -> dict:

@@ -1,61 +1,44 @@
 """Weights from ``--seed``, made by the benchmark and handed to both sides.
 
-One jitted call makes the whole canonical tree on the device, blocks stacked
-on a leading axis, in the type the configuration serves or trains in. The
-program gets it re-cut to its own parameter tree by the configuration's
-``layout`` (data: canonical key -> [layer name, parameter name]); the plain
-reference makes the same tree again from the same seed once the program's
-state is freed. The program's own ``init()`` runs for its tree's shapes and
-its state; every weight it made is replaced.
+One jitted call makes the whole canonical tree on the device, in the type
+the configuration serves or trains in. WHICH tree is the family's
+(``families/<family>.py``): ``leaves(dims)`` gives every key with its group
+and the shape of one member, ``groups(dims)`` how many members each group
+stacks on a leading axis (a model with two kinds of block has two groups),
+``init_scale(key, shape)`` the mean and the standard deviation of the key's
+normal draw. The program gets the tree re-cut to its own parameter tree by
+the configuration's ``layout`` (data: canonical key -> path in the program's
+tree); the plain reference makes the same tree again from the same seed once
+the program's state is freed. The program's own ``init()`` runs for its
+tree's shapes and its state; every weight it made is replaced.
 
-Matrices are Xavier-normal as in the zoo models; gains and biases get a small
-random part (the zoo's are exactly 1 and 0), so that a dropped bias or gain
-shows in the comparison.
+The order of the draws is part of the contract: key number ``i`` of the
+family's keys, SORTED, draws from ``fold_in(seed's key, i)``. So the same
+seed gives a family the same weights for as long as its set of keys stands;
+a key added to a family that a cell runs moves the draws of the keys sorted
+after it, and is a new family.
 """
 
 from __future__ import annotations
 
-import math
 
-BLOCK_KEYS = ("ln1_g", "ln1_b", "wq", "wk", "wv", "wo",
-              "ln2_g", "ln2_b", "w1", "b1", "w2", "b2")
-TOP_KEYS = ("tok_emb", "pos_emb", "lnf_g", "lnf_b", "head_w", "head_b")
-
-
-def shapes(model: dict) -> dict:
-    h, f, v = model["hidden"], model["ffn_size"], model["vocab_size"]
-    L, t = model["n_layers"], model["max_len"]
-    return {
-        "tok_emb": (v, h), "pos_emb": (t, h),
-        "ln1_g": (L, h), "ln1_b": (L, h),
-        "wq": (L, h, h), "wk": (L, h, h), "wv": (L, h, h), "wo": (L, h, h),
-        "ln2_g": (L, h), "ln2_b": (L, h),
-        "w1": (L, h, f), "b1": (L, f), "w2": (L, f, h), "b2": (L, h),
-        "lnf_g": (h,), "lnf_b": (h,), "head_w": (h, v), "head_b": (v,),
-    }
-
-
-def make_weights(model: dict, seed: int, dtype: str):
-    """The canonical tree for ``model`` from ``seed``, one jitted call."""
+def make_weights(family, dims: dict, seed: int, dtype: str):
+    """The family's canonical tree for ``dims`` from ``seed``: a flat dict,
+    a group's keys stacked on a leading axis. One jitted call."""
     import jax
     import jax.numpy as jnp
 
-    shp = shapes(model)
+    counts = family.groups(dims)
+    shp = {key: (shape if group is None else (counts[group],) + tuple(shape),
+                 family.init_scale(key, tuple(shape)))
+           for key, (group, shape) in family.leaves(dims).items()}
 
     def make(key):
         out = {}
-        for i, (name, shape) in enumerate(sorted(shp.items())):
-            k = jax.random.fold_in(key, i)
-            noise = jax.random.normal(k, shape, jnp.float32)
-            if name == "pos_emb":
-                w = 0.02 * noise
-            elif name.endswith("_g"):
-                w = 1.0 + 0.02 * noise
-            elif len(shape) == 1 or name in ("ln1_b", "ln2_b", "b1", "b2"):
-                w = 0.02 * noise
-            else:
-                fan_in, fan_out = shape[-2], shape[-1]
-                w = math.sqrt(2.0 / (fan_in + fan_out)) * noise
+        for i, (name, (shape, (mean, std))) in enumerate(sorted(shp.items())):
+            noise = jax.random.normal(jax.random.fold_in(key, i), shape,
+                                      jnp.float32)
+            w = std * noise if mean == 0.0 else mean + std * noise
             out[name] = w.astype(dtype)
         return out
 
@@ -65,40 +48,46 @@ def make_weights(model: dict, seed: int, dtype: str):
     return jax.jit(make)(key)
 
 
-def layout_paths(layout: dict, n_layers: int):
-    """``[(canonical key, block index or None, layer name, param name)]``
-    for every leaf of the program's tree. ``layout`` maps the top keys to
-    ``[layer, param]`` and carries the block keys under ``"block"``; names may
-    use ``{i}`` (block index), ``{j}`` (``i + block_offset``), ``{n}``
-    (``n_layers + block_offset``) and ``{m}`` (``n + 1``)."""
-    off = int(layout.get("block_offset", 0))
-    n = n_layers + off
+def layout_paths(family, dims: dict, layout: dict):
+    """``[(canonical key, member index or None, path in the program's tree)]``
+    for every leaf of the program's tree. ``layout`` maps an ungrouped key
+    to its path ``[layer, ..., param]`` and carries a group's keys under the
+    group's name. A path's names are format strings over ``{i}`` (the
+    member's index in its group), every size in ``dims``, and the sums the
+    layout defines under ``"index"``: ``{"j": ["i", 2]}`` makes ``{j}`` mean
+    ``i + 2``."""
+    counts = family.groups(dims)
 
-    def fmt(s, i=0):
-        return s.format(i=i, j=i + off, n=n, m=n + 1)
+    def path(entry, i=0):
+        env = dict(dims, i=i)
+        for var, terms in layout.get("index", {}).items():
+            env[var] = sum(env[t] if isinstance(t, str) else t for t in terms)
+        return tuple(s.format(**env) for s in entry)
 
     out = []
-    for key in TOP_KEYS:
-        layer, param = layout[key]
-        out.append((key, None, fmt(layer), param))
-    for i in range(n_layers):
-        for key in BLOCK_KEYS:
-            layer, param = layout["block"][key]
-            out.append((key, i, fmt(layer, i), param))
+    for key, (group, _) in sorted(family.leaves(dims).items()):
+        if group is None:
+            out.append((key, None, path(layout[key])))
+        else:
+            out.extend((key, i, path(layout[group][key], i))
+                       for i in range(counts[group]))
     return out
 
 
-def program_tree(weights: dict, layout: dict, n_layers: int) -> dict:
-    """The canonical tree re-cut to the program's ``{layer: {param: array}}``
-    in one jitted call."""
+def program_tree(family, dims: dict, weights: dict, layout: dict) -> dict:
+    """The canonical tree re-cut to the program's nested dict in one jitted
+    call."""
     import jax
 
-    paths = layout_paths(layout, n_layers)
+    paths = layout_paths(family, dims, layout)
 
     def cut(w):
         out: dict = {}
-        for key, i, layer, param in paths:
-            out.setdefault(layer, {})[param] = w[key] if i is None else w[key][i]
+        for key, i, path in paths:
+            node = out
+            for name in path[:-1]:
+                node = node.setdefault(name, {})
+            node[path[-1]] = w[key] if i is None else w[key][i]
         return out
 
     return jax.jit(cut)(weights)
@@ -120,20 +109,29 @@ def install(model, tree: dict) -> None:
     model.params = tree
 
 
-def canonical_names(tree: dict, layout: dict, n_layers: int) -> dict:
+def canonical_names(family, dims: dict, tree: dict, layout: dict) -> dict:
     """A program-shaped tree of per-leaf numbers -> ``{"wq.3": x, ...}``."""
     out = {}
-    for key, i, layer, param in layout_paths(layout, n_layers):
-        out[key if i is None else f"{key}.{i}"] = float(tree[layer][param])
+    for key, i, path in layout_paths(family, dims, layout):
+        node = tree
+        for name in path:
+            node = node[name]
+        out[key if i is None else f"{key}.{i}"] = float(node)
     return out
 
 
-def stacked_names(tree: dict) -> dict:
-    """A canonical tree of per-leaf numbers (vectors over the blocks for the
-    stacked keys) -> the same flat names."""
+def stacked_keys(family, dims: dict) -> frozenset:
+    """The canonical keys that stack a group's members on a leading axis."""
+    return frozenset(key for key, (group, _) in family.leaves(dims).items()
+                     if group is not None)
+
+
+def stacked_names(tree: dict, stacked: frozenset) -> dict:
+    """A canonical tree of per-leaf numbers (vectors over the members for
+    the stacked keys) -> the same flat names."""
     out = {}
     for key, val in tree.items():
-        if key in BLOCK_KEYS:
+        if key in stacked:
             for i, x in enumerate(val):
                 out[f"{key}.{i}"] = float(x)
         else:

@@ -44,14 +44,16 @@ def main(argv=None) -> list:
     from benchmarks.harness import compare, runtime
     from benchmarks.harness import serve_driver, train_driver
 
-    cell, config, traffic = bench_run.load_cell(HERE, args.workload,
-                                                args.rehearse)
+    cell, config, traffic, family_file = bench_run.load_cell(
+        HERE, args.workload, args.rehearse)
+    family = runtime.load_family(family_file)
     quant = config["control_quant"]
     rows = []
     for i, seed in enumerate(int(s) for s in args.seeds.split(",")):
         t0 = time.perf_counter()
         run = runtime.Run(cell=cell, config=config, traffic=traffic,
-                          seed=seed, seconds=args.seconds, trace=False,
+                          family=family, kernel_names=(), seed=seed,
+                          seconds=args.seconds, trace=False,
                           rehearse=args.rehearse, t_start=t0,
                           out_dir=os.path.join(ROOT, ".bench_out", "limits"))
         row = {"seed": seed}

@@ -64,9 +64,13 @@ def test_control_in_lower_precision_fails_the_serve_cell():
         lim["served_logit_gap"]
 
 
-def _rehearse(cell, seed="3000000023"):
+TEST_DATA = ["--data-dir", os.path.join(HERE, "data"), "--manifest",
+             os.path.join(HERE, "data", "manifest.json")]
+
+
+def _rehearse(cell, seed="3000000023", where=()):
     return bench_run.main(["--workload", cell, "--seed", seed, "--seconds",
-                           "1", "--trace", "0", "--rehearse"])
+                           "1", "--trace", "0", "--rehearse", *where])
 
 
 def test_sound_rehearsals_are_correct():
@@ -109,7 +113,12 @@ def test_fault_half_of_the_batch_left_out(monkeypatch):
     assert _rehearse("bert-base-seq512")["correct"] is False
 
 
-def test_fault_token_altered_where_it_is_produced(monkeypatch):
+@pytest.mark.parametrize("cell, vocab, where", [
+    ("gpt2s-chat-closed128", 50, ()),
+    ("tiny-lstm-closed", 40, TEST_DATA),  # the fixture's second family
+])
+def test_fault_token_altered_where_it_is_produced(monkeypatch, cell, vocab,
+                                                  where):
     """One token of ONE request, sent in the window: the widest gap sees it
     whatever the mean over all compared tokens says. (A rehearsal compares
     every request the window finished, so the altered one is among them.)"""
@@ -136,12 +145,12 @@ def test_fault_token_altered_where_it_is_produced(monkeypatch):
 
     def altered(self, index, token):
         wrong = self is seen["handle"] and index == 1
-        emit(self, index, (token + 1) % 50 if wrong else token)
+        emit(self, index, (token + 1) % vocab if wrong else token)
 
     monkeypatch.setattr(ServeRun, "window", opened)
     monkeypatch.setattr(DecodeEngine, "submit", marking)
     monkeypatch.setattr(GenerationHandle, "_emit", altered)
-    line = _rehearse("gpt2s-chat-closed128")
+    line = _rehearse(cell, where=where)
     assert seen["handle"] is not None
     assert line["correct"] is False
     check = line["checks"]["served_logit_gap"]

@@ -6,6 +6,7 @@ import glob
 import json
 import os
 import re
+import shutil
 import subprocess
 import sys
 
@@ -17,8 +18,9 @@ MANIFEST = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device", "checks"}
-TEST_DATA = ["--data-dir", os.path.join(BENCH, "tests", "data"), "--manifest",
-             os.path.join(BENCH, "tests", "data", "manifest.json")]
+DATA = os.path.join(BENCH, "tests", "data")
+TEST_DATA = ["--data-dir", DATA, "--manifest",
+             os.path.join(DATA, "manifest.json")]
 
 
 def _names(kind):
@@ -74,6 +76,35 @@ def test_every_entry_has_its_file_and_the_reverse():
         assert f["reader"] in readers
 
 
+@pytest.mark.parametrize("data", [BENCH, DATA], ids=["benchmark", "tests"])
+def test_every_configuration_names_a_family_that_is_there_and_the_reverse(data):
+    named = {json.load(open(p)).get("family") for p in glob.glob(
+        os.path.join(data, "configs", "*.json"))}
+    files = {os.path.splitext(os.path.basename(p))[0] for p in glob.glob(
+        os.path.join(data, "families", "*.py"))}
+    assert None not in named, "a configuration without a family"
+    assert named == files
+    if data == BENCH:  # the manifest's configurations are those files
+        assert all("family" in _load("configs", c["name"])
+                   for c in MANIFEST["configs"])
+
+
+def test_no_file_of_the_harness_knows_a_familys_keys_or_names():
+    """The acceptance criterion's grep, with the fixture's family beside it:
+    outside docstrings nothing under ``harness/`` or in ``run.py`` names a
+    family, its keys or its sizes."""
+    words = re.compile(r"BLOCK_KEYS|ffn_size|n_heads|pos_emb|\bwq\b|n_layers"
+                       r"|lstm|LSTM|preln|first_rw|encoder_loss")
+    paths = glob.glob(os.path.join(BENCH, "harness", "**", "*.py"),
+                      recursive=True) + [os.path.join(BENCH, "run.py"),
+                                         os.path.join(BENCH, "limits.py")]
+    assert len(paths) > 15
+    for path in paths:
+        code = re.sub(r'"""[\s\S]*?"""', "", open(path).read())
+        code = re.sub(r"#.*", "", code)
+        assert not words.search(code), (path, words.search(code).group(0))
+
+
 def test_each_layer_metric_moves_a_metric_its_cells_report():
     cells = set(_names("workloads"))
     reports = {e["name"]: set(e.get("workloads", cells))
@@ -116,6 +147,50 @@ def test_rehearsal_ends_in_the_contracts_line(cell, trace):
     # every number compared is printed beside its limit, last on stderr
     tail = p.stderr.strip().splitlines()[-len(line["checks"]):]
     assert all(t.startswith("check ") and "limit" in t for t in tail)
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+def test_a_second_family_is_rehearsed_from_the_test_data_alone(trace):
+    """The zoo's TextGenerationLSTM behind the real DecodeEngine: recurrent
+    state, two stacked groups, its own reference and counts, all in files
+    under ``tests/data`` (no file of ``harness/`` or ``run.py`` knows it:
+    the test above)."""
+    p = _run(["--workload", "tiny-lstm-closed", "--seed", "3000000029",
+              "--seconds", "2", "--trace", str(trace), "--rehearse"]
+             + TEST_DATA)
+    assert p.returncode == 0, p.stderr[-2000:]
+    line = json.loads(p.stdout.strip().splitlines()[-1])
+    assert set(line) - {"breakdown"} == RESULT_KEYS
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["attempted"] > 10
+    assert set(line["checks"]) == {"served_logit_gap",
+                                   "served_mean_logit_gap"}
+    if trace == 0:
+        assert set(line["metrics"]) == {"serve_tokens_per_s", "tpot_p95_ms",
+                                        "setup_s"}
+    else:  # the CPU has no device trace: the program's histogram is read
+        assert set(line["metrics"]) == {"lstm_decode_step_ms"}
+    # 3 layers x (h, c) x 24 x 4 bytes a request, whatever its position
+    held = float(re.search(r"kv_filled_bytes[^:]*: ([0-9.e+-]+)",
+                           p.stderr).group(1))
+    assert 0 < held <= 4 * 576
+
+
+@pytest.mark.parametrize("family", [None, "no-such-family"])
+def test_a_configuration_without_its_family_prints_no_result(tmp_path, family):
+    data = shutil.copytree(DATA, tmp_path / "data")
+    path = data / "configs" / "tiny-gpt.json"
+    config = json.load(open(path))
+    if family is None:
+        del config["family"]
+    else:
+        config["family"] = family
+    json.dump(config, open(path, "w"))
+    p = _run(["--workload", "tiny-gpt-open", "--seed", "5", "--seconds", "1",
+              "--rehearse", "--data-dir", str(data), "--manifest",
+              str(data / "manifest.json")])
+    assert p.returncode != 0 and not p.stdout.strip()
+    assert "family" in p.stderr.splitlines()[-1]
 
 
 def _losses(stderr):

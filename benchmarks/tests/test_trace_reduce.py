@@ -34,6 +34,8 @@ from benchmarks.harness import trace_reduce as tr
 from benchmarks.harness.readers import trace_idle_share
 
 FIXTURE = os.path.join(os.path.dirname(__file__), "trace_fixture.txt")
+# as run.py gathers them from the cells' per-layer metric files
+KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_decode")
 
 
 @pytest.fixture(scope="module")
@@ -43,7 +45,7 @@ def events():
 
 @pytest.fixture(scope="module")
 def summary(events):
-    return tr.reduce(events, chips=1)
+    return tr.reduce(events, 1, KERNELS)
 
 
 def test_only_the_ops_line_of_device_planes_is_read(events):
@@ -105,10 +107,11 @@ def test_top_operations_and_longest_gap(summary):
     ("%fusion.308 = f32[768,30522]{0,1} fusion()", None),
 ])
 def test_kernel_names(name, kernel):
-    assert tr.kernel_of(name) == kernel
+    assert tr.kernel_of(name, KERNELS) == kernel
+    assert tr.kernel_of(name, ()) is None
 
 
 def test_no_device_operation_gives_nothing():
-    assert tr.reduce([], chips=1) is None
+    assert tr.reduce([], 1, KERNELS) is None
     host_only = [tr.Event("/host:CPU", "python", "fit_batch", 0.0, 9.0)]
-    assert tr.reduce(host_only, chips=1) is None
+    assert tr.reduce(host_only, 1, KERNELS) is None

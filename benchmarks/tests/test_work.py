@@ -1,11 +1,25 @@
-"""``work.py`` and ``peaks.py``: the numbers written out here were worked
-out by hand from the shapes."""
+"""The work counts of the families and ``peaks.py``: the numbers written
+out here were worked out by hand from the shapes. A count is looked up in the
+family of the cell being run, so the readers get it from the record."""
+
+import json
+import os
 
 import pytest
 
-from benchmarks.harness import work
+from benchmarks.harness import runtime
 from benchmarks.harness.peaks import chip_peaks
 from benchmarks.harness.readers import trace_kernel_roofline, trace_step_mfu
+
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _family(data_dir, config):
+    cfg = json.load(open(os.path.join(data_dir, "configs", config + ".json")))
+    return runtime.load_family(runtime.family_file(data_dir, cfg)), cfg
+
+
+work, _ = _family(BENCH, "bert-base")
 
 BERT = {"vocab_size": 30522, "hidden": 768, "n_layers": 12, "n_heads": 12,
         "ffn_size": 3072, "max_len": 512}
@@ -78,9 +92,9 @@ def test_peaks_and_unknown_kind():
         chip_peaks("TPU v9 imaginary")
 
 
-def _record(kernels, slice_, window_s=1.0):
+def _record(kernels, slice_, window_s=1.0, family=work):
     return {"trace": {"kernels": kernels, "window_s": window_s,
-                      "busy_s": window_s}, "slice": slice_,
+                      "busy_s": window_s}, "slice": slice_, "family": family,
             "device_kind": "TPU v5 lite", "chips": 1}
 
 
@@ -116,11 +130,8 @@ def test_kernel_roofline_reader():
                                "decode_attended": [[250, 1.0]] * 128}),
 ])
 def test_each_roofline_metrics_file_says_which_bound(metric, slice_):
-    import json
-    import os
-
-    f = json.load(open(os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "layer_metrics", metric + ".json")))
+    f = json.load(open(os.path.join(BENCH, "layer_metrics",
+                                    metric + ".json")))
     assert f["bound"] == trace_kernel_roofline.bound(
         _record({}, slice_), f["params"]["work"])
 
@@ -141,3 +152,67 @@ def test_step_mfu_reader():
         trace_step_mfu.read(rec, "encoder_train_step_slice")
     assert trace_step_mfu.read({"trace": None, "slice": s},
                                "encoder_train_step_slice") is None
+
+
+def test_the_configurations_sizes_come_from_their_family():
+    for name, want in (("bert-base", BERT), ("gpt2-small", GPT2)):
+        family, cfg = _family(BENCH, name)
+        assert family.dims(cfg) == want
+    # a request at position 220 holds 220 keys and values a layer
+    assert work.cache_bytes(GPT2, 220, 2) == 220 * 2 * 12 * 768 * 2
+
+
+def test_a_second_familys_counts_are_found_in_its_own_file():
+    """The fixture's LSTM: three layers of 24, vocabulary 40. A token costs
+    3 ``h RW`` + 2 ``x W`` of 24 x 96 and the 24 x 40 head, whatever its
+    position; its state is ``h`` and ``c`` a layer."""
+    lstm, cfg = _family(os.path.join(BENCH, "tests", "data"), "tiny-lstm")
+    d = lstm.dims(cfg)
+    assert d == {"vocab_size": 40, "hidden": 24, "layers": 3}
+    assert lstm.flops_per_token(d) == 2 * (5 * 24 * 96 + 24 * 40) == 24_960
+    assert lstm.cache_bytes(d, 7, 4) == lstm.cache_bytes(d, 700, 4) == 576
+    s = {"model": d, "decode_attended": [[9, 1.0], [30, 0.5]],
+         "prefill_lengths": [[8, 0.25]], "counters": {
+             "dl4j_tpu_generate_tokens_total": {"decode": 3.0}}}
+    rec = _record({}, s, window_s=0.5, family=lstm)
+    assert trace_step_mfu.read(rec, "lstm_serve_slice") == pytest.approx(
+        100 * 3.5 * 24_960 / (0.5 * 197e12))
+    # work that only the run knows: the program's own counter over the slice
+    assert trace_step_mfu.read(rec, "lstm_emitted_slice") == pytest.approx(
+        100 * 3 * 24_960 / (0.5 * 197e12))
+    # the transformer's counts are not this family's
+    with pytest.raises(AttributeError):
+        trace_step_mfu.read(rec, "decoder_serve_slice")
+
+
+def test_a_traced_slice_carries_the_programs_counters_to_the_family(
+        monkeypatch):
+    """A whole traced rehearsal of the fixture's cell, with a device trace
+    stood in (the CPU has none): the slice the serve driver records holds
+    what the program's counter rose by (``slice_counters`` in the
+    configuration), and both readings of the step's share reach the
+    family's own functions. The shares are of a made-up window and are
+    compared with each other only."""
+    from benchmarks import run as bench_run
+    from benchmarks.harness import serve_driver
+
+    data = os.path.join(BENCH, "tests", "data")
+    fake = {"window_s": 0.25, "busy_s": 0.2, "chips_traced": 1, "ops": {},
+            "kernels": {}, "top_ops": [["fusion.1", 0.1]], "idle_gaps": []}
+    report = serve_driver.device_report
+    monkeypatch.setattr(runtime.TraceSlice, "summary", lambda self: fake)
+    monkeypatch.setattr(serve_driver, "device_report", lambda *a: dict(
+        report(*a), kind="TPU v5 lite"))
+    line = bench_run.main([
+        "--workload", "tiny-lstm-closed", "--seed", "3000000033",
+        "--seconds", "2", "--trace", "1", "--rehearse", "--data-dir", data,
+        "--manifest", os.path.join(data, "manifest.json")])
+    assert line["correct"] is True
+    m = line["metrics"]
+    assert set(m) == {"lstm_step_mfu", "lstm_emitted_mfu",
+                      "lstm_decode_step_ms"}
+    # by shares with the prefills, or whole from the program's counter: the
+    # same tokens to within the slice's edges and the prompts prefilled
+    assert 0 < m["lstm_emitted_mfu"]["value"] < 3 * m["lstm_step_mfu"]["value"]
+    assert m["lstm_step_mfu"]["value"] < 3 * m["lstm_emitted_mfu"]["value"]
+    assert line["device"]["busy_s"] == 0.2
