@@ -54,6 +54,12 @@ FULL = dict(
     long_batch=(4, 512),
     lm=dict(vocab_size=50257, hidden=768, n_layers=12, n_heads=12,
             ffn_size=3072, max_len=1024, dtype="bfloat16"),
+    # EvaByte's published widths (two of its 32 layers): one stream whose
+    # prompt ends 8 bytes short of the 2,048-byte window's edge
+    eva=dict(vocab_size=320, hidden=4096, n_layers=2, n_heads=32,
+             ffn_size=11008, window=2048, chunk=16, n_pred_heads=8,
+             max_len=4096, dtype="bfloat16"),
+    eva_prompt=2040, eva_tokens=24,
     slots=8, block_size=16, max_tokens=32,
     # prefill buckets are powers of two: 16 | 17 and 32 | 33 straddle two
     prompt_lens=(12, 16, 17, 30, 32, 33),
@@ -70,6 +76,10 @@ DRY = dict(
     train_batch=(4, 8), train_steps=8, long_batch=(2, 32),
     lm=dict(vocab_size=50, hidden=32, n_layers=1, n_heads=2, ffn_size=64,
             max_len=32, dtype="float32"),
+    eva=dict(vocab_size=40, hidden=32, n_layers=1, n_heads=2, ffn_size=64,
+             window=16, chunk=4, n_pred_heads=2, max_len=64,
+             dtype="float32"),
+    eva_prompt=12, eva_tokens=8,
     slots=4, block_size=16, max_tokens=4, prompt_lens=(4, 5),
     flash_shapes=((1, 2, 64, 16, True),),
     decode_shape=(2, 2, 64, 16),
@@ -294,6 +304,46 @@ def phase_serve(cfg, on_chip: bool, out: dict) -> None:
                tokens=2 * len(prompts) * cfg["max_tokens"],
                decode_kernel=("flash_decode" if on_chip else
                               "not checked (dry run: XLA reference path)"))
+    _serve_evabyte(cfg, on_chip, out)
+
+
+def _serve_evabyte(cfg, on_chip: bool, out: dict) -> None:
+    """One EvaByte stream that crosses a window's edge (its terminal event
+    checked like the others: the engine swallows a step that fails to
+    trace), and the step's program holding the EVA kernel."""
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.model.zoo import EvaByteLM
+    from deeplearning4j_tpu.parallel import DecodeEngine
+
+    lm = EvaByteLM(**cfg["eva"])
+    model = lm.init()
+    prompt = np.random.RandomState(3).randint(
+        0, lm.vocab_size, cfg["eva_prompt"]).tolist()
+    engine = DecodeEngine(model, max_len=lm.max_len, slots=2)
+    try:
+        (tokens,) = _generate_all(engine, [prompt], cfg["eva_tokens"])
+        stats = engine.stats()
+        _check(stats["failed"] == 0 and stats["completed"] == 1,
+               f"EvaByte engine stats {stats}")
+        crossed = (cfg["eva_prompt"] + cfg["eva_tokens"]) // lm.window \
+            - cfg["eva_prompt"] // lm.window
+        _check(crossed >= 1, "the EvaByte stream crossed no window's edge")
+        if on_chip:
+            e = engine
+            text = e._decode_step_fn().lower(
+                model.params, model.state, e._carry,
+                jnp.asarray(e._last), jnp.asarray(e._active),
+                jnp.asarray(e._seeds), jnp.asarray(e._steps),
+                jnp.asarray(e._greedy), jnp.asarray(e._temps),
+                jnp.asarray(e._ks), jnp.asarray(e._ps)).as_text()
+            _check({"eva_decode", "kv_cache_write"} <= _kernel_names(text),
+                   "EvaByte's decode step does not hold its Pallas kernels")
+    finally:
+        engine.shutdown(drain=False)
+    out.update(evabyte_tokens=len(tokens), evabyte_windows_crossed=crossed,
+               evabyte_kernel=("eva_decode" if on_chip else
+                               "not checked (dry run: XLA reference path)"))
 
 
 # ---------------------------------------------------------------------------

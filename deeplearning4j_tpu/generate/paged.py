@@ -2,7 +2,13 @@
 and the batch-step helpers (freeze / write-redirect) shared by the plain
 and speculative decode paths.
 
-Layout (see ops/paged_attention.py): each attention layer's cache keys
+Which leaves of a layer's decode state are planes written in place is the
+layer's to say (``Layer.decode_planes``); the helpers here take that
+declaration as ``planes``, ``{layer name: names of its planes}``
+(``GenerationSession.planes``), and know no leaf by name but ``pos``,
+``block_table`` and ``write_mask``.
+
+Layout (see ops/paged_attention.py): each pageable attention layer's planes
 (``cache_k``/``cache_v`` and, for int8, their scale planes) become
 shared pools ``[num_blocks, h, block_size, ...]``; the per-layer state
 gains a ``block_table`` leaf ``[b, max_len // block_size]`` int32. Block
@@ -22,10 +28,6 @@ from typing import Dict, List, Sequence
 
 import jax
 import jax.numpy as jnp
-
-_POOL_KEYS = frozenset({"cache_k", "cache_v",
-                        "cache_k_scale", "cache_v_scale"})
-_PAGEABLE_KEYS = _POOL_KEYS | {"pos"}
 
 
 class OutOfBlocksError(RuntimeError):
@@ -80,10 +82,12 @@ def blocks_needed(tokens: int, block_size: int) -> int:
 def paged_decode_state(session, batch: int, *, block_size: int,
                        num_blocks: int) -> Dict[str, dict]:
     """Paged decode carry for ``batch`` rows: the session's per-layer
-    static carry with every cache plane replaced by a shared block pool
-    and a zero (= all-trash) block table added. Layers whose carry is not
-    position-indexed (recurrent ``h``/``c``, input caches) cannot be
-    paged — their state has no block structure to page."""
+    static carry with every plane of a layer that pages its planes
+    (``Layer.pages_decode_planes``: K/V caches indexed by absolute position)
+    replaced by a shared block pool and a zero (= all-trash) block table
+    added. A layer whose carry is not of that kind (recurrent ``h``/``c``,
+    input caches, a mixer's bounded state of windows and summaries) cannot
+    be paged: its state has no block structure to page."""
     bs = int(block_size)
     if bs < 1:
         raise ValueError("block_size must be >= 1")
@@ -94,8 +98,9 @@ def paged_decode_state(session, batch: int, *, block_size: int,
     out: Dict[str, dict] = {}
     for name, st in base.items():
         keys = set(st.keys())
-        if "cache_k" not in keys:
-            # no K/V planes (e.g. a position-counter-only carry): nothing
+        pools = keys & set(session.planes.get(name, ()))
+        if not pools:
+            # no planes (e.g. a position-counter-only carry): nothing
             # to page — keep the per-row state as-is
             if keys <= {"pos"}:
                 out[name] = st
@@ -104,12 +109,18 @@ def paged_decode_state(session, batch: int, *, block_size: int,
                 f"layer {name!r} carries state {sorted(keys)} which is not "
                 "pageable — paged decode needs position-indexed K/V caches "
                 "(recurrent h/c carries have no block structure)")
-        if not keys <= _PAGEABLE_KEYS:
+        if name not in session.paged_layers:
+            raise ValueError(
+                f"layer {name!r} keeps a bounded decode state of its own "
+                f"({sorted(pools)}) which is not a K/V cache indexed by "
+                "position and is not paged — serve this model with the "
+                "static layout (block_size=None)")
+        if not keys <= pools | {"pos"}:
             raise ValueError(
                 f"layer {name!r} mixes cache planes with unpageable state "
-                f"{sorted(keys - _PAGEABLE_KEYS)}")
+                f"{sorted(keys - pools - {'pos'})}")
         new_st = {}
-        for key in keys & _POOL_KEYS:
+        for key in pools:
             c = st[key]  # [b, h, L, d] or [b, h, L]
             new_st[key] = jnp.zeros(
                 (int(num_blocks), c.shape[1], bs) + c.shape[3:], c.dtype)
@@ -126,8 +137,8 @@ def block_bytes(session, block_size: int) -> int:
     allocated block count."""
     bs = int(block_size)
     total = 0
-    for st in session.decode_state(1).values():
-        for key in set(st.keys()) & _POOL_KEYS:
+    for name, st in session.decode_state(1).items():
+        for key in set(st.keys()) & set(session.planes.get(name, ())):
             c = st[key]
             per_pos = int(c.size // c.shape[2]) * c.dtype.itemsize
             total += per_pos * bs
@@ -143,11 +154,11 @@ def attach_block_table(carry, table):
     """Put the ONE shared ``[b, max_len // block_size]`` table under every
     pool-holding layer of a paged carry that is kept without it (the
     engine holds the table beside the carry, so that donating the carry
-    gives each buffer once). ``table=None`` (a static carry) passes
-    through."""
+    gives each buffer once): the layers whose state is more than a
+    position counter. ``table=None`` (a static carry) passes through."""
     if table is None:
         return carry
-    return {name: ({**st, "block_table": table} if "cache_k" in st else st)
+    return {name: ({**st, "block_table": table} if set(st) - {"pos"} else st)
             for name, st in carry.items()}
 
 
@@ -175,37 +186,41 @@ def redirect_inactive_writes(carry, active):
     return out
 
 
-def mask_inactive_writes(carry, active):
+def mask_inactive_writes(carry, active, planes=None):
     """:func:`redirect_inactive_writes`, and the static layout's
-    counterpart of it: every unpaged K/V layer gets a ``write_mask``
-    leaf (``active``), under which the layer's cache write drops the
-    update of a row that is masked off (``ops.masked_cache_write``, through
-    ``_cached_attention`` in nn/layers/attention.py). An inactive row then leaves its row of
-    every cache plane as it was by what it writes, and
-    :func:`freeze_rows` need not select over the planes. Layers without
-    K/V planes (recurrent ``h``/``c``, ``cache_x``) pass through and keep
-    the whole-leaf select."""
+    counterpart of it: every unpaged layer that declares planes
+    (``planes``: ``{layer name: names}``, ``GenerationSession.planes``)
+    gets a ``write_mask`` leaf (``active``), under which the layer's own
+    write drops the update of a row that is masked off
+    (``ops.masked_cache_write``). An inactive row then leaves its row of
+    every plane as it was by what it writes, and :func:`freeze_rows` need
+    not select over the planes. Layers that declare none (recurrent
+    ``h``/``c``, ``cache_x``) pass through and keep the whole-leaf
+    select."""
+    planes = planes or {}
     out = {}
     for name, st in redirect_inactive_writes(carry, active).items():
-        if "cache_k" in st and "block_table" not in st:
+        if planes.get(name) and "block_table" not in st:
             st = {**st, "write_mask": active}
         out[name] = st
     return out
 
 
-def freeze_rows(new, old, active):
+def freeze_rows(new, old, active, planes=None):
     """Keep carry rows where ``active`` is False unchanged after a fused
     batch step. ``old`` is the state the step's forward ran on. Paged
     layers: pool planes take the step's result (the inactive rows' writes
     went to trash — nothing of theirs changed), ``block_table`` is taken
     from ``old``, and per-row leaves (``pos``) are where'd by the mask.
-    Static K/V layers whose ``old`` state carries a ``write_mask``
-    (:func:`mask_inactive_writes`): the cache planes take the step's
-    result too (a masked row wrote nothing) and only the
-    per-row leaves are where'd. Every other layer keeps the per-leaf
-    where (shapes are per-row there, so a row-select is well defined on
-    every leaf) — among them the K/V layers of a multi-token window
-    that is rewound afterwards, which no caller masks."""
+    Static layers whose ``old`` state carries a ``write_mask``
+    (:func:`mask_inactive_writes`): the planes the layer declares
+    (``planes``) take the step's result too (a masked row wrote nothing)
+    and only the per-row leaves are where'd. Every other layer keeps the
+    per-leaf where (shapes are per-row there, so a row-select is well
+    defined on every leaf) — among them the K/V layers of a multi-token
+    window that is rewound afterwards, which no caller masks."""
+    planes = planes or {}
+
     def sel(n, o):
         a = active.reshape((-1,) + (1,) * (n.ndim - 1))
         return jnp.where(a, n, o)
@@ -214,9 +229,10 @@ def freeze_rows(new, old, active):
     for name, n_st in new.items():
         o_st = old[name]
         if "block_table" in o_st or "write_mask" in o_st:
+            mine = planes.get(name, ())
             st = {}
             for k, v in n_st.items():
-                if k in _POOL_KEYS:
+                if k in mine:
                     st[k] = v
                 elif k == "block_table":
                     st[k] = o_st[k]

@@ -95,6 +95,27 @@ class GenerationSession:
         act = last.activation or Activation.SOFTMAX
         self._out_is_probs = act == Activation.SOFTMAX
         self._layer_names = model.layer_names()
+        #: what each layer declares of its decode state
+        #: (``Layer.decode_planes``): ``{layer name: names of the leaves
+        #: that are planes written in place}``; the carry's masking,
+        #: freezing and paging (generate/paged.py) work from it
+        named = list(zip(self._layer_names, model.layers))
+        self.planes: Dict[str, frozenset] = {
+            name: frozenset(layer.decode_planes())
+            for name, layer in named if layer.decode_planes()}
+        #: the layers among them whose planes the paged layout can page
+        self.paged_layers = frozenset(
+            name for name, layer in named
+            if name in self.planes and layer.pages_decode_planes)
+        #: the window after which the model's mixers fold their entries
+        #: into summaries (``Layer.decode_window``; the shortest, if layers
+        #: differ), or None: a longer prompt is then prefilled a window at
+        #: a time (:meth:`prefill_logits`)
+        self.window = min((l.decode_window() for l in model.layers
+                           if l.decode_window()), default=None)
+        # an output layer that knows its own next-token logits (several
+        # prediction heads, of which decoding reads the first)
+        self._head = getattr(last, "decode_logits", None)
         self._fns: Dict = {}
         # at least one layer must expose decode state, otherwise "decode"
         # would silently re-run from scratch each step
@@ -145,40 +166,83 @@ class GenerationSession:
         oh = jax.nn.one_hot(ids, self.vocab_size, dtype=self.model.dtype)
         return oh.transpose(0, 2, 1)
 
-    def _logits(self, out: jax.Array) -> jax.Array:
-        """Model output [b, V, t] -> per-position logits [b, V, t] (log of
-        probs for softmax outputs — equivalent under temperature scaling,
-        truncation and argmax; see sampling.py)."""
+    def _forward(self, params, state, x, mask, carry):
+        """The model's forward on the decode carry -> ``(out, new carry)``:
+        the model's output, or, where the output layer knows its own
+        next-token logits (``decode_logits``: several prediction heads),
+        that layer's INPUT, for :meth:`_logits` to hand to it."""
+        model = self.model
+        out, _, new = model.forward_pure(
+            params, state, x, train=False, rng=None, mask=mask,
+            rnn_state=carry,
+            upto=None if self._head is None else len(model.layers) - 1)
+        return out, new
+
+    def _logits(self, out: jax.Array, params=None) -> jax.Array:
+        """:meth:`_forward`'s output [b, ., t] -> per-position logits
+        [b, V, t] (log of probs for softmax outputs — equivalent under
+        temperature scaling, truncation and argmax; see sampling.py)."""
+        if self._head is not None:
+            if params is None:
+                raise ValueError(
+                    "an output layer with decode_logits reads its own "
+                    "parameters: pass the model's params")
+            params, _ = self.model._to_compute(params, out)
+            return self._head(params[self._layer_names[-1]], out)
         if self._out_is_probs:
             return jnp.log(jnp.maximum(out, 1e-30))
         return out
+
+    def prefill_logits(self, params, state, carry, ids, lengths):
+        """The prompts ``ids`` [b, t] (right-padded, ``lengths`` [b] valid)
+        through the model on a fresh ``carry`` -> ``(new carry, logits at
+        each row's last valid position [b, V])``. A model whose mixers fold
+        windows (``self.window``) takes a prompt longer than one window a
+        window at a time, through all its layers, up to the longest row's
+        last window: what is alive is a window's, and the windows that are
+        only padding are not computed."""
+        t, w = ids.shape[1], self.window
+        lengths = lengths.astype(jnp.int32)
+
+        def piece(carry, ids, start):
+            n = ids.shape[1]
+            at = start + jnp.arange(n, dtype=jnp.int32)[None, :]
+            mask = (at < lengths[:, None]).astype(self.model.dtype)
+            out, new = self._forward(params, state, self._prep(ids), mask,
+                                     carry)
+            logits = self._logits(out, params)  # [b, V, n]
+            idx = jnp.clip(lengths - 1 - start, 0, n - 1)
+            return new, jnp.take_along_axis(
+                logits, idx[:, None, None], axis=2)[:, :, 0]  # [b, V]
+
+        if w is None or t <= w:
+            return piece(carry, ids, 0)
+        ids = jnp.pad(ids, ((0, 0), (0, (-t) % w)))
+
+        def window(i, val):
+            carry, last = val
+            new, here = piece(carry, jax.lax.dynamic_slice_in_dim(
+                ids, i * w, w, axis=1), i * w)
+            mine = ((lengths - 1) // w == i)[:, None]
+            return new, jnp.where(mine, here, last)
+
+        first, last = piece(carry, ids[:, :w], 0)  # fixes the loop's types
+        return jax.lax.fori_loop(
+            1, (jnp.max(lengths) + w - 1) // w, window, (first, last))
 
     # ----- jitted steps -----------------------------------------------
     def _prefill_fn(self, t_bucket: int):
         key = ("prefill", t_bucket)
         if key not in self._fns:
-            def fn(params, state, carry, ids, lengths):
-                mask = (jnp.arange(t_bucket, dtype=jnp.int32)[None, :]
-                        < lengths[:, None]).astype(self.model.dtype)
-                out, _, new_rnn = self.model.forward_pure(
-                    params, state, self._prep(ids), train=False, rng=None,
-                    mask=mask, rnn_state=carry)
-                logits = self._logits(out)  # [b, V, t]
-                last = jnp.take_along_axis(
-                    logits, (lengths - 1)[:, None, None].astype(jnp.int32),
-                    axis=2)[:, :, 0]  # [b, V]
-                return new_rnn, last
-
-            self._fns[key] = jax.jit(fn)
+            self._fns[key] = jax.jit(self.prefill_logits)
         return self._fns[key]
 
     def _decode_fn(self):
         if "decode" not in self._fns:
             def fn(params, state, carry, tokens):
-                out, _, new_rnn = self.model.forward_pure(
-                    params, state, self._prep(tokens[:, None]), train=False,
-                    rng=None, mask=None, rnn_state=carry)
-                return new_rnn, self._logits(out)[:, :, 0]
+                out, new_rnn = self._forward(
+                    params, state, self._prep(tokens[:, None]), None, carry)
+                return new_rnn, self._logits(out, params)[:, :, 0]
 
             self._fns["decode"] = jax.jit(fn)
         return self._fns["decode"]
@@ -207,7 +271,9 @@ class GenerationSession:
         if "freeze" not in self._fns:
             from .paged import freeze_rows
 
-            self._fns["freeze"] = jax.jit(freeze_rows)
+            self._fns["freeze"] = jax.jit(
+                lambda new, old, active: freeze_rows(new, old, active,
+                                                     self.planes))
         return self._fns["freeze"]
 
     # ----- host API ----------------------------------------------------
@@ -417,8 +483,8 @@ class SpeculativeGenerationSession:
                     d_toks, d_logits, t_logits, seeds, steps, spec_ks,
                     gmask, temps, ks, ps)
 
-                tnew = freeze_rows(tnew, tcarry, active)
-                dnew = freeze_rows(cur, dcarry, active)
+                tnew = freeze_rows(tnew, tcarry, active, tsess.planes)
+                dnew = freeze_rows(cur, dcarry, active, dsess.planes)
                 delta = jnp.where(active, (k + 1) - n_emit, 0)
                 return (rewind_carry(tnew, delta),
                         rewind_carry(dnew, delta), otoks, n_acc, n_emit)

@@ -7,6 +7,7 @@ construction-parity with the reference and train from scratch.
 from ...generate.sampling import greedy, temperature, top_k, top_p
 from .bert import BertEncoder
 from .darknet import Darknet19, TinyYOLO
+from .evabyte import EvaByteLM
 from .inception_resnet import InceptionResNetV1
 from .lenet import LeNet
 from .misc import FaceNetNN4Small2, SimpleCNN, YOLO2
@@ -23,6 +24,7 @@ __all__ = [
     "AlexNet",
     "BertEncoder",
     "Darknet19",
+    "EvaByteLM",
     "FaceNetNN4Small2",
     "InceptionResNetV1",
     "LeNet",

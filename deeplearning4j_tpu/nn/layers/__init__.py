@@ -28,11 +28,14 @@ from .norm import (
     BatchNormalizationLayer,
     LayerNormLayer,
     LocalResponseNormalizationLayer,
+    RMSNormLayer,
+    rms_norm,
 )
 from .output import (
     BaseOutputLayer,
     CnnLossLayer,
     LossLayer,
+    MultiTokenRnnOutputLayer,
     OutputLayer,
     RnnLossLayer,
     RnnOutputLayer,
@@ -59,6 +62,7 @@ from .preprocessors import (
     RnnToCnnPreProcessor,
     RnnToFeedForwardPreProcessor,
 )
+from .eva import EvaDecoderBlockLayer, gated_silu_ffn, rotary_positions
 from .moe import MixtureOfExpertsLayer
 from .samediff_layer import SameDiffLambdaLayer, SameDiffLayer
 from .recurrent import (

@@ -158,6 +158,11 @@ def _cached_attention(q, k_new, v_new, state, mask):
     return o, new_state
 
 
+#: the K/V layers' decode planes: the caches and, for an int8 cache
+#: (``generate/session.py`` ``quantize_decode_state``), their scale planes
+KV_PLANES = ("cache_k", "cache_v", "cache_k_scale", "cache_v_scale")
+
+
 def _split_heads(x: jax.Array, n_heads: int) -> jax.Array:
     b, t, f = x.shape
     return x.reshape(b, t, n_heads, f // n_heads).transpose(0, 2, 1, 3)
@@ -224,6 +229,11 @@ class SelfAttentionLayer(Layer):
             "Wv": init_weights(ks[2], (self.n_in, hs), wi, self.n_in, hs, None, dtype),
             "Wo": init_weights(ks[3], (hs, self.n_out), wi, hs, self.n_out, None, dtype),
         }
+
+    pages_decode_planes = True
+
+    def decode_planes(self) -> Tuple[str, ...]:
+        return KV_PLANES if self.causal else ()
 
     def decode_state(self, batch: int, max_len: int, dtype: Any) -> State:
         if not self.causal:
@@ -506,6 +516,11 @@ class TransformerDecoderBlockLayer(Layer):
             "W2": init_weights(ks[5], (ffn, h), wi, ffn, h, None, dtype),
             "b2": jnp.zeros((h,), dtype),
         }
+
+    pages_decode_planes = True
+
+    def decode_planes(self) -> Tuple[str, ...]:
+        return KV_PLANES
 
     def decode_state(self, batch: int, max_len: int, dtype: Any) -> State:
         d = self.n_in // self.n_heads

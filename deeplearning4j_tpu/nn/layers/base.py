@@ -136,6 +136,36 @@ class Layer:
         applied per step as-is)."""
         return {}
 
+    def decode_planes(self) -> Tuple[str, ...]:
+        """What the layer declares of its :meth:`decode_state`: the names
+        of the leaves that are PLANES, ``[batch, ..., entries, ...]`` arrays
+        written in place entry by entry, where a fused batch step's idle row
+        writes nothing when the state carries a ``write_mask`` leaf
+        (``generate/paged.py`` ``mask_inactive_writes``). Nothing selects
+        over a plane or copies one; every other leaf is per-row state and
+        takes the row select of ``freeze_rows``. The carry's masking,
+        freezing and paging work from this declaration."""
+        return ()
+
+    #: True on layers whose planes are K/V caches indexed by absolute
+    #: position (``[b, h, max_len, ...]``), which the paged layout can cut
+    #: into blocks; a layer that keeps a bounded state of another shape
+    #: leaves it False and the paged engine refuses the model.
+    pages_decode_planes: ClassVar[bool] = False
+
+    def decode_window(self) -> Optional[int]:
+        """The length of the aligned window after which the layer's decode
+        state folds its entries into summaries; ``None`` for a layer that
+        keeps every entry (or none)."""
+        return None
+
+    def decode_live_bytes(self, position: int, itemsize: int) -> Dict[str, int]:
+        """Bytes of the decode state that a row standing at ``position``
+        has made valid, by kind of entry (host arithmetic, for the engine's
+        ``dl4j_tpu_decode_state_bytes`` gauge); ``{}`` where the layer does
+        not say."""
+        return {}
+
     def has_params(self) -> bool:
         return False
 

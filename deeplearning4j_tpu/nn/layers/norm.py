@@ -277,3 +277,56 @@ class LayerNormLayer(Layer):
              + params["beta"].astype(stat_dtype).reshape(bshape))
         act = self.activation or Activation.IDENTITY
         return act(y).astype(x.dtype), state
+
+
+def rms_norm(x: jax.Array, gain: jax.Array, eps: float = 1e-5,
+             unit_offset: bool = False, axis: int = -1) -> jax.Array:
+    """``x / sqrt(mean(x^2) + eps) * g`` over ``axis``, with ``g = 1 +
+    gain`` under ``unit_offset`` (the gain is then stored round 0). The
+    statistics and the result are float32 at least: the caller casts."""
+    x32 = x.astype(jnp.promote_types(x.dtype, jnp.float32))
+    inv = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=axis, keepdims=True) + eps)
+    g = gain.astype(x32.dtype)
+    if unit_offset:
+        g = 1.0 + g
+    shape = [1] * x.ndim
+    shape[axis] = -1
+    return x32 * inv * g.reshape(shape)
+
+
+@register_config
+@dataclasses.dataclass(frozen=True, kw_only=True)
+class RMSNormLayer(Layer):
+    """Root-mean-square normalization over the feature axis (no mean, no
+    bias). ``unit_offset`` stores the gain round 0 and applies ``1 +
+    gamma``. The output keeps the input's type (a float32 residual stream
+    stays float32)."""
+
+    n_out: int = 0
+    eps: float = 1e-5
+    unit_offset: bool = False
+
+    def with_input(self, input_type: InputType) -> "RMSNormLayer":
+        if self.n_out:
+            return self
+        n = input_type.size if isinstance(input_type, RecurrentType) else input_type.flat_size()
+        return dataclasses.replace(self, n_out=n)
+
+    def has_params(self) -> bool:
+        return True
+
+    def trainable_param_names(self) -> Tuple[str, ...]:
+        return ("gamma",)
+
+    def weight_param_names(self) -> Tuple[str, ...]:
+        return ()
+
+    def init(self, key: jax.Array, dtype: Any) -> Params:
+        fill = jnp.zeros if self.unit_offset else jnp.ones
+        return {"gamma": fill((self.n_out,), dtype)}
+
+    def apply(self, params: Params, state: State, x: jax.Array, ctx: LayerContext) -> Tuple[jax.Array, State]:
+        y = rms_norm(x, params["gamma"], self.eps, self.unit_offset,
+                     axis=1 if x.ndim == 3 else -1)
+        act = self.activation or Activation.IDENTITY
+        return act(y).astype(x.dtype), state

@@ -180,6 +180,79 @@ class RnnOutputLayer(BaseOutputLayer):
 
 @register_config
 @dataclasses.dataclass(frozen=True, kw_only=True)
+class MultiTokenRnnOutputLayer(BaseOutputLayer):
+    """Per-timestep head of ``n_pred_heads`` x ``n_out`` columns without a
+    bias: head ``j`` predicts the token ``j + 1`` positions ahead (head 0 the
+    next one). ``apply`` gives every head's LOGITS side by side,
+    ``[b, n_pred_heads * n_out, t]`` in float32 (operands in the parameters'
+    type, float32 accumulation). Decoding reads head 0 alone
+    (:meth:`decode_logits`: ``n_out`` is the served vocabulary). Labels are
+    sparse next-token ids ``[b, t]``; the loss is the mean over the heads of
+    head ``j``'s cross-entropy against the labels ``j`` positions on."""
+
+    n_in: int = 0
+    n_out: int = 0
+    n_pred_heads: int = 1
+
+    def output_type(self, input_type: InputType) -> InputType:
+        ts = input_type.timesteps if isinstance(input_type, RecurrentType) else None
+        return RecurrentType(size=self.n_pred_heads * self.n_out, timesteps=ts)
+
+    def with_input(self, input_type: InputType) -> "MultiTokenRnnOutputLayer":
+        if self.n_in or not isinstance(input_type, RecurrentType):
+            return self
+        return dataclasses.replace(self, n_in=input_type.size)
+
+    def has_params(self) -> bool:
+        return True
+
+    def trainable_param_names(self) -> Tuple[str, ...]:
+        return ("W",)
+
+    def init(self, key: jax.Array, dtype: Any) -> Params:
+        cols = self.n_pred_heads * self.n_out
+        return {"W": init_weights(key, (self.n_in, cols),
+                                  self.weight_init or WeightInit.XAVIER,
+                                  self.n_in, cols,
+                                  self.weight_init_distribution, dtype)}
+
+    def _project(self, x: jax.Array, w: jax.Array) -> jax.Array:
+        """x [b, n_in, t] -> float32 logits [b, t, columns of w]."""
+        return jnp.einsum("bft,fo->bto", x.astype(w.dtype), w,
+                          preferred_element_type=jnp.float32)
+
+    def preoutput(self, params: Params, x: jax.Array, ctx: LayerContext) -> jax.Array:
+        x = apply_input_dropout(self, x, ctx)
+        return self._project(x, params["W"])  # [b, t, heads * n_out]
+
+    def apply(self, params: Params, state: State, x: jax.Array, ctx: LayerContext) -> Tuple[jax.Array, State]:
+        return self.preoutput(params, x, ctx).transpose(0, 2, 1), state
+
+    def decode_logits(self, params: Params, x: jax.Array) -> jax.Array:
+        """Head 0's logits [b, n_out, t] from the layer's input: the only
+        columns a served token depends on."""
+        return self._project(x, params["W"][:, :self.n_out]).transpose(0, 2, 1)
+
+    def compute_loss(self, params, x, labels, ctx, label_mask=None):
+        b, _, t = x.shape
+        logits = self.preoutput(params, x, ctx).reshape(
+            b, t, self.n_pred_heads, self.n_out)
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        labels = labels.reshape(b, t).astype(jnp.int32)
+        mask = label_mask if label_mask is not None else ctx.mask
+        mask = (jnp.ones((b, t), logp.dtype) if mask is None
+                else mask.reshape(b, t).astype(logp.dtype))
+        total = 0.0
+        for j in range(min(self.n_pred_heads, t)):
+            picked = jnp.take_along_axis(
+                logp[:, :t - j, j], labels[:, j:, None], axis=-1)[..., 0]
+            m = mask[:, j:]
+            total = total - jnp.sum(picked * m) / jnp.maximum(jnp.sum(m), 1.0)
+        return total / min(self.n_pred_heads, t)
+
+
+@register_config
+@dataclasses.dataclass(frozen=True, kw_only=True)
 class RnnLossLayer(BaseOutputLayer):
     """Per-timestep loss without params (reference: RnnLossLayer)."""
 

@@ -29,6 +29,13 @@ from .flash_attention import (
     mha_attention_reference,
     set_attention_impl,
 )
+from .eva_attention import (
+    chunk_summaries,
+    eva_decode_attention,
+    eva_decode_attention_pallas,
+    eva_decode_attention_reference,
+    eva_prefill_attention,
+)
 from .grouped_matmul import (
     grouped_matmul,
     grouped_matmul_impl,
@@ -52,6 +59,11 @@ from .paged_attention import (
 
 __all__ = [
     "attention_impl",
+    "chunk_summaries",
+    "eva_decode_attention",
+    "eva_decode_attention_pallas",
+    "eva_decode_attention_reference",
+    "eva_prefill_attention",
     "decode_attention",
     "decode_attention_reference",
     "flash_decode_attention",

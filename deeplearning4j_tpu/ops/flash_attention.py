@@ -823,6 +823,22 @@ def _cache_write_kernel(pos_ref, keep_ref, new_ref, cache_ref, out_ref, *,
                            c.astype(wide)).astype(c.dtype)
 
 
+def _cache_write_rows_kernel(pos_ref, keep_ref, new_ref, cache_ref, out_ref,
+                             *, block):
+    """:func:`_cache_write_kernel` for a plane that lies head-dimension-
+    minor: the ``block`` positions round the row's own come in as
+    ``[h, block, d]`` and the sublane at the row's position takes the new
+    entry."""
+    r = pl.program_id(0)
+    c = cache_ref[0]                                # [h, block, d]
+    wide = (jnp.float32 if jnp.issubdtype(c.dtype, jnp.floating)
+            else jnp.int32)
+    row = jax.lax.broadcasted_iota(jnp.int32, c.shape, 1)
+    hit = (row == pos_ref[r] % block) & (keep_ref[r] != 0)
+    out_ref[0] = jnp.where(hit, new_ref[0].astype(wide),
+                           c.astype(wide)).astype(c.dtype)
+
+
 def flash_masked_cache_write(
     cache: jax.Array,
     new: jax.Array,
@@ -832,19 +848,47 @@ def flash_masked_cache_write(
 ) -> jax.Array:
     """Pallas one-token cache write (same contract as
     :func:`masked_cache_write_reference` with ``t == 1``), in place: the
-    cache goes through the kernel position-minor, ``[b, h, d, L]`` — for a
-    cache the chip keeps that way a relabelling, not a copy — aliased to
-    its output, and each grid step moves only the 128-position block that
-    holds its row's position. XLA's scatter does the same write as a
-    loop of one small update a row, seven operations each."""
+    cache goes through the kernel as the chip keeps it, aliased to its
+    output, and each grid step moves only the block of positions that
+    holds its row's position. A ``[b, h, L, d]`` plane whose ``d`` is
+    narrower than the 128 lanes the chip keeps position-minor, so it goes
+    as ``[b, h, d, L]`` (a relabelling, not a copy) in blocks of 128
+    positions; one whose ``d`` fills the lanes lies as it is written and
+    goes in blocks of 32. XLA's scatter does the same write as a loop of
+    one small update a row, seven operations each."""
     if new.shape[2] != 1:
         raise ValueError("flash_masked_cache_write is the t=1 kernel")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, L = cache.shape[0], cache.shape[2]
-    block = 128 if L % 128 == 0 else L
     p = jnp.clip(pos.astype(jnp.int32), 0, L - 1)
     keep = write_mask.astype(jnp.int32)
+    if cache.ndim == 4 and cache.shape[3] % 128 == 0:
+        h, d = cache.shape[1], cache.shape[3]
+        block = 32 if L % 32 == 0 else L
+        kw = dict(memory_space=pltpu.VMEM)
+        return pl.pallas_call(
+            functools.partial(_cache_write_rows_kernel, block=block),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(b,),
+                in_specs=[
+                    pl.BlockSpec((1, h, 1, d),
+                                 lambda r, pos, keep: (r, 0, 0, 0), **kw),
+                    pl.BlockSpec((1, h, block, d),
+                                 lambda r, pos, keep: (r, 0, pos[r] // block,
+                                                       0), **kw),
+                ],
+                out_specs=pl.BlockSpec(
+                    (1, h, block, d),
+                    lambda r, pos, keep: (r, 0, pos[r] // block, 0), **kw),
+            ),
+            out_shape=jax.ShapeDtypeStruct(cache.shape, cache.dtype),
+            input_output_aliases={3: 0},  # operands: pos, keep, new, cache
+            interpret=interpret,
+            name="kv_cache_write",
+        )(p, keep, new.astype(cache.dtype), cache)
+    block = 128 if L % 128 == 0 else L
     planes = cache.ndim == 4
     if planes:  # [b, h, L, d] -> [b, h, d, L]; new [b, h, 1, d] -> [b, h, d, 1]
         cache, new = jnp.swapaxes(cache, 2, 3), jnp.swapaxes(new, 2, 3)

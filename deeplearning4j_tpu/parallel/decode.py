@@ -58,7 +58,7 @@ import itertools
 import queue
 import threading
 import time
-from collections import deque
+from collections import Counter, deque
 from typing import Callable, List, Optional, Sequence
 
 import numpy as np
@@ -295,6 +295,14 @@ class DecodeEngine:
                                   if self._spec is not None else 0)
         self._spec_k = self.max_speculative_k
         self._slot_target = self.slots
+        # what the layers say of their decode state beyond its shape: the
+        # window after which a mixer folds its entries into summaries, and
+        # the layers that count the bytes a row's position has made valid
+        # (a layer is its configuration: equal layers are counted once)
+        self._window = self.session.window
+        self._live_layers = Counter(
+            l for l in self.session.model.layers
+            if l.decode_live_bytes(0, 1))
         self._init_metrics(registry if registry is not None else get_registry())
 
         # device-side batch state: one preallocated carry, per-row specs.
@@ -316,7 +324,7 @@ class DecodeEngine:
         else:
             self._allocator = None
         self._carry = self._fresh_carry()
-        self._row_template = self.session.decode_state(1)
+        self._row = None  # a zeroed one-row carry, made when first asked for
         # the draft cache stays static (slot×max_len): proposals run every
         # slot each turn, and the draft rows rewind with the target's
         self._draft_carry = (None if self._spec is None
@@ -427,6 +435,19 @@ class DecodeEngine:
             "Decode carries rebuilt after a donated step or install "
             "raised at run time and took the carry with it (0 in a sound "
             "window)", ("instance",)).labels(inst)
+        self._c_windows = reg.counter(
+            "dl4j_tpu_decode_windows_closed_total",
+            "Windows that decoding rows closed (a row's position reached a "
+            "multiple of its mixer's window: the window's chunks became "
+            "summaries that later positions attend); prompts' windows are "
+            "not counted (the loop.prefill span has them)",
+            ("engine",)).labels(inst)
+        self._g_state_bytes = reg.gauge(
+            "dl4j_tpu_decode_state_bytes",
+            "Bytes of the decode state that the active rows' positions have "
+            "made valid, by kind of entry as the layers name them (a "
+            "windowed mixer: window, summary); host arithmetic, once a loop "
+            "turn", ("engine", "kind"))
         self._g_kv_bytes = reg.gauge(
             "dl4j_tpu_generate_kv_cache_bytes",
             "Live resident bytes of the decode KV cache: the full "
@@ -444,6 +465,19 @@ class DecodeEngine:
         used = self._allocator.total_blocks - self._allocator.free_blocks
         self._kv_cache_bytes = used * self._block_bytes + self._aux_kv_bytes
         self._g_kv_bytes.set(self._kv_cache_bytes)
+
+    def _update_state_bytes(self) -> None:
+        """The live share of the decode state from the rows' positions."""
+        size = jnp.dtype(self.session.model.dtype).itemsize
+        live: Counter = Counter()
+        for layer, count in self._live_layers.items():
+            live.update(dict.fromkeys(layer.decode_live_bytes(0, size), 0))
+            for slot in np.nonzero(self._active)[0]:
+                for kind, n in layer.decode_live_bytes(
+                        int(self._pos[slot]), size).items():
+                    live[kind] += count * n
+        for kind, n in live.items():
+            self._g_state_bytes.labels(self.name, kind).set(n)
 
     def _push_tables(self) -> None:
         """Mirror the host block tables onto the device as ONE shared
@@ -513,6 +547,16 @@ class DecodeEngine:
                                   f"kv block pool exhausted: {e}")
         return rows
 
+    @property
+    def _row_template(self):
+        """A zeroed one-row carry: the shapes a handoff is held to, and what
+        a test installs. The prefill makes its own inside its program, so an
+        engine that only serves never holds one (a bounded state of windows
+        and summaries is 67 MB a layer at 32,768 positions)."""
+        if self._row is None:
+            self._row = self.session.decode_state(1)
+        return self._row
+
     # ----- jitted steps -----------------------------------------------
     def _prefill_fn(self, tb: int):
         key = ("prefill", tb)
@@ -520,17 +564,10 @@ class DecodeEngine:
             sess = self.session
             model = sess.model
 
-            def fn(params, state, row_carry, ids, lengths, seed, gflag,
-                   temp, k, p):
-                mask = (jnp.arange(tb, dtype=jnp.int32)[None, :]
-                        < lengths[:, None]).astype(model.dtype)
-                out, _, new_rnn = model.forward_pure(
-                    params, state, sess._prep(ids), train=False, rng=None,
-                    mask=mask, rnn_state=row_carry)
-                logits = sess._logits(out)
-                last = jnp.take_along_axis(
-                    logits, (lengths - 1)[:, None, None].astype(jnp.int32),
-                    axis=2)[:, :, 0]
+            def fn(params, state, ids, lengths, seed, gflag, temp, k, p):
+                # a fresh row's carry is zeros: made here, not handed in
+                new_rnn, last = sess.prefill_logits(
+                    params, state, sess.decode_state(1), ids, lengths)
                 tok = sample_tokens(last, seed, jnp.zeros((1,), jnp.int32),
                                     gflag, temp, k, p)
                 return new_rnn, tok[0]
@@ -572,13 +609,12 @@ class DecodeEngine:
                 # paged carry writes the trash block, not its own live
                 # blocks, and one of a static carry writes nothing
                 fwd = mask_inactive_writes(
-                    attach_block_table(carry, table), active)
+                    attach_block_table(carry, table), active, sess.planes)
                 with jax.named_scope("forward"):
-                    out, _, new_rnn = model.forward_pure(
-                        params, state, sess._prep(tokens[:, None]),
-                        train=False, rng=None, mask=None, rnn_state=fwd)
+                    out, new_rnn = sess._forward(
+                        params, state, sess._prep(tokens[:, None]), None, fwd)
                 with jax.named_scope("logits"):
-                    logits = sess._logits(out)[:, :, 0]
+                    logits = sess._logits(out, params)[:, :, 0]
                 with jax.named_scope("sample"):
                     toks = sample_tokens(logits, seeds, steps, gmask, temps,
                                          ks, ps)
@@ -586,7 +622,7 @@ class DecodeEngine:
                 # their cache planes the masked write left as they were
                 with jax.named_scope("freeze_rows"):
                     new_rnn = detach_block_table(
-                        freeze_rows(new_rnn, fwd, active))
+                        freeze_rows(new_rnn, fwd, active, sess.planes))
                 return new_rnn, jnp.where(active, toks, 0)
 
             self._fns["decode"] = jax.jit(decode_step, donate_argnums=2)
@@ -894,6 +930,10 @@ class DecodeEngine:
             next(s for s in sess.bucket_sizes() if s >= len(req.prompt)),
             self.max_len)
         parent.set_attribute("bucket", tb)
+        parent.set_attribute("pad_share",
+                             100.0 * (1.0 - len(req.prompt) / tb))
+        if self._window:
+            parent.set_attribute("windows", len(req.prompt) // self._window)
         with span("loop.prefill.dispatch", parent=parent):
             ids = np.zeros((1, tb), np.int32)
             ids[0, : len(req.prompt)] = req.prompt
@@ -906,8 +946,7 @@ class DecodeEngine:
                 row, first = self._handoff_row(req.prefilled)
             else:
                 row, tok = self._prefill_fn(tb)(
-                    sess.model.params, sess.model.state, self._row_template,
-                    jnp.asarray(ids),
+                    sess.model.params, sess.model.state, jnp.asarray(ids),
                     jnp.asarray([len(req.prompt)], jnp.int32),
                     jnp.asarray([req.seed], jnp.uint32),
                     jnp.asarray([req.greedy], bool),
@@ -1088,6 +1127,8 @@ class DecodeEngine:
                 self._last[slot] = tok
                 self._steps[slot] += 1
                 self._pos[slot] += 1
+                if self._window and self._pos[slot] % self._window == 0:
+                    self._c_windows.inc()
                 self._c_tokens.inc()
                 self._h_token.observe(dt)
                 self._retire_if_done(slot, tok, emitted + 1)
@@ -1239,6 +1280,8 @@ class DecodeEngine:
                         self._retire_if_done(slot, -1,
                                              len(req.handle.tokens))
                 self._sweep_pending()
+                if self._live_layers:
+                    self._update_state_bytes()
                 if (self._adaptive and self._adjust_interval > 0
                         and self._clock() >= self._next_adjust):
                     self.adjust()

@@ -231,20 +231,23 @@ def test_the_cache_write_kernel_is_the_scatter(dtype, L):
                            np.float32))
 
 
-def test_only_kv_layers_take_the_write_mask():
-    """Layers without K/V planes keep the whole-leaf select: no mask is
+def test_only_layers_that_declare_planes_take_the_write_mask():
+    """Layers that declare no planes keep the whole-leaf select: no mask is
     attached to a recurrent carry, and a paged layer gets the redirected
-    table, not a mask."""
+    table, not a mask. The declaration is the layer's (``decode_planes``),
+    handed in as the session has it; no leaf is known by its name."""
     active = jnp.asarray([True, False])
+    planes = {"att": frozenset({"cache_k", "cache_v"})}
     rec = {"lstm": {"h": jnp.zeros((2, 3)), "c": jnp.zeros((2, 3))},
            "cx": {"h": jnp.zeros((2, 3)), "cache_x": jnp.zeros((2, 4, 3)),
                   "pos": jnp.zeros((2,), jnp.int32)},
            "att": {"cache_k": jnp.zeros((2, 1, 4, 2)),
                    "cache_v": jnp.zeros((2, 1, 4, 2)),
                    "pos": jnp.zeros((2,), jnp.int32)}}
-    out = mask_inactive_writes(rec, active)
+    out = mask_inactive_writes(rec, active, planes)
     assert "write_mask" not in out["lstm"] and "write_mask" not in out["cx"]
     assert out["att"]["write_mask"] is active
+    assert "write_mask" not in mask_inactive_writes(rec, active)["att"]
     paged = attach_block_table(
         {"att": {"cache_k": jnp.zeros((3, 1, 2, 2)),
                  "cache_v": jnp.zeros((3, 1, 2, 2)),
@@ -252,7 +255,7 @@ def test_only_kv_layers_take_the_write_mask():
          "posemb": {"pos": jnp.zeros((2,), jnp.int32)}},
         jnp.asarray([[1, 2], [2, 1]], jnp.int32))
     assert "block_table" not in paged["posemb"]
-    out = mask_inactive_writes(paged, active)
+    out = mask_inactive_writes(paged, active, planes)
     assert "write_mask" not in out["att"]
     np.testing.assert_array_equal(out["att"]["block_table"],
                                   [[1, 2], [0, 0]])

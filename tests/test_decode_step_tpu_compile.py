@@ -7,6 +7,12 @@ every plane every step, and a carry that is not donated costs a copy of
 every plane. Both must stay away: every plane aliased, no copy of a plane,
 no select over one.
 
+The same reading for EvaByte's step at its published widths (ISSUE 29: 16
+slots, 32 heads of 128, two planes of 2,048 summaries + 2,048 singletons a
+layer; two blocks instead of eight): the mixer declares its planes, so the
+whole carry is aliased, and a plane whose head dimension fills the lanes
+lies position-major, so both of the step's kernels take it as it lies.
+
 The topology is described inside a fixture (never at import: only one
 process may load the TPU's library, and every xdist worker imports every
 test file); the file's tests are skipped where it cannot be described.
@@ -38,10 +44,10 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture(scope="module")
-def programs(one_chip):
-    """name -> (compiled text, memory analysis, bytes of the cache planes)."""
-    from deeplearning4j_tpu.model.zoo import TransformerLM
+def _compile_step_and_install(model, params, slots, max_len, one_chip):
+    """name -> (compiled text, memory analysis, bytes of the 4-D leaves of
+    the carry) of an engine's decode step and install at ``slots`` rows,
+    lowered from shapes for the described chip."""
     from deeplearning4j_tpu.obs.metrics import MetricsRegistry
     from deeplearning4j_tpu.parallel.decode import DecodeEngine
 
@@ -50,42 +56,47 @@ def programs(one_chip):
                                     a.dtype, sharding=one_chip)
 
     def vec(dtype):
-        return jax.ShapeDtypeStruct((SLOTS,), dtype, sharding=one_chip)
+        return jax.ShapeDtypeStruct((slots,), dtype, sharding=one_chip)
 
     tm = jax.tree_util.tree_map
     out = {}
+    eng = DecodeEngine(model, max_len=max_len, slots=1,
+                       registry=MetricsRegistry())
+    try:
+        carry = tm(lambda a: spec(a, (slots,) + a.shape[1:]), eng._carry)
+        planes = sum(l.size * l.dtype.itemsize
+                     for l in jax.tree_util.tree_leaves(carry) if l.ndim == 4)
+        # the program's own switch takes its TPU branches while lowering
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(jax, "default_backend", lambda: "tpu")
+            lowered = {
+                "decode_step": eng._decode_step_fn().lower(
+                    tm(spec, params), tm(spec, model.state), carry,
+                    vec(jnp.int32), vec(jnp.bool_), vec(jnp.uint32),
+                    vec(jnp.int32), vec(jnp.bool_), vec(jnp.float32),
+                    vec(jnp.int32), vec(jnp.float32)),
+                "install_row": eng._write_row_fn().lower(
+                    carry, tm(spec, eng._row_template),
+                    jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)),
+            }
+        for name, low in lowered.items():
+            c = low.compile()
+            out[name] = (c.as_text(), c.memory_analysis(), planes)
+    finally:
+        eng.shutdown(drain=False)
+    return out
+
+
+@pytest.fixture(scope="module")
+def programs(one_chip):
+    from deeplearning4j_tpu.model.zoo import TransformerLM
+
     with jax.enable_x64(False):  # as on the chip; Mosaic has no float64
         model = TransformerLM(vocab_size=50257, hidden=HIDDEN, n_layers=2,
                               n_heads=HEADS, ffn_size=3072, max_len=MAX_LEN,
                               dtype="bfloat16").init()
-        eng = DecodeEngine(model, max_len=MAX_LEN, slots=1,
-                           registry=MetricsRegistry())
-        try:
-            sess = eng.session
-            carry = tm(lambda a: spec(a, (SLOTS,) + a.shape[1:]), eng._carry)
-            planes = sum(
-                l.size * l.dtype.itemsize
-                for l in jax.tree_util.tree_leaves(carry) if l.ndim == 4)
-            # the program's own switch takes its TPU branches while lowering
-            with pytest.MonkeyPatch.context() as mp:
-                mp.setattr(jax, "default_backend", lambda: "tpu")
-                lowered = {
-                    "decode_step": eng._decode_step_fn().lower(
-                        tm(spec, model.params), tm(spec, model.state), carry,
-                        vec(jnp.int32), vec(jnp.bool_), vec(jnp.uint32),
-                        vec(jnp.int32), vec(jnp.bool_), vec(jnp.float32),
-                        vec(jnp.int32), vec(jnp.float32)),
-                    "install_row": eng._write_row_fn().lower(
-                        carry, tm(spec, eng._row_template),
-                        jax.ShapeDtypeStruct((), jnp.int32,
-                                             sharding=one_chip)),
-                }
-            for name, low in lowered.items():
-                c = low.compile()
-                out[name] = (c.as_text(), c.memory_analysis(), planes)
-        finally:
-            eng.shutdown(drain=False)
-    return out
+        return _compile_step_and_install(model, model.params, SLOTS, MAX_LEN,
+                                         one_chip)
 
 
 @pytest.mark.parametrize("name", ["decode_step", "install_row"])
@@ -109,4 +120,54 @@ def test_the_step_holds_its_kernels_and_no_loop(programs):
     text = programs["decode_step"][0]
     assert text.count("tpu_custom_call") >= 6
     assert "flash_decode" in text and "kv_cache_write" in text
+    assert not re.search(r" while\(", text)
+
+
+# ------------------------------------------------------------------ EvaByte
+EVA_SLOTS, EVA_MAX_LEN = 16, 32768
+EVA_PLANE = rf"bf16\[{EVA_SLOTS},32,4096,128\]"
+
+
+@pytest.fixture(scope="module")
+def eva_programs(one_chip):
+    from deeplearning4j_tpu.model.zoo import EvaByteLM
+    from deeplearning4j_tpu.nn.sequential import MultiLayerNetwork
+
+    tm = jax.tree_util.tree_map
+    with jax.enable_x64(False):
+        model = MultiLayerNetwork(EvaByteLM(
+            vocab_size=320, hidden=4096, n_layers=2, n_heads=32,
+            ffn_size=11008, window=2048, chunk=16, n_pred_heads=8,
+            max_len=EVA_MAX_LEN, dtype="bfloat16").conf())
+        # shapes only: the published widths' weights are never made here
+        params = jax.eval_shape(lambda: model.init().params)
+        model.params = tm(lambda a: jnp.zeros((), a.dtype), params)
+        model._initialized = True
+        model.state = {n: {} for n in model.layer_names()}
+        model._persistent_keys = {n: () for n in model.layer_names()}
+        return _compile_step_and_install(model, params, EVA_SLOTS,
+                                         EVA_MAX_LEN, one_chip)
+
+
+@pytest.mark.parametrize("name", ["decode_step", "install_row"])
+def test_evabyte_carry_is_aliased_whole_and_no_plane_is_copied(eva_programs,
+                                                               name):
+    text, ma, planes = eva_programs[name]
+    # two layers' planes, 2.147 GB, and the open chunks' entries beside them
+    assert planes >= 2 * 2 * EVA_SLOTS * 32 * 4096 * 128 * 2
+    assert ma.alias_size_in_bytes >= planes, (ma.alias_size_in_bytes, planes)
+    assert ma.temp_size_in_bytes < planes // 8, ma.temp_size_in_bytes
+    lines = text.splitlines()
+    for op in ("copy", "select", "transpose"):
+        hits = [l[:160] for l in lines
+                if re.search(rf"= {EVA_PLANE}\S* {op}\(", l)]
+        assert not hits, hits[:3]
+
+
+def test_evabyte_step_holds_its_kernels_and_no_loop(eva_programs):
+    """Two blocks: two calls of ``eva_decode`` and eight in-place writes (a
+    singleton and a summary into each of a block's two planes)."""
+    text = eva_programs["decode_step"][0]
+    assert text.count("tpu_custom_call") >= 10
+    assert "eva_decode" in text and "kv_cache_write" in text
     assert not re.search(r" while\(", text)

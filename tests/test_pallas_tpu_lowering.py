@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from deeplearning4j_tpu.ops.eva_attention import eva_decode_attention_pallas
 from deeplearning4j_tpu.ops.flash_attention import (
     flash_attention, flash_decode_attention, flash_masked_cache_write)
 from deeplearning4j_tpu.ops.grouped_matmul import _gmm, _tiling
@@ -65,8 +66,22 @@ def test_flash_decode_lowers(dtype, L):
     assert names == ["flash_decode"]
 
 
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_eva_decode_lowers(dtype):
+    """EvaByte's step at its published widths: 16 rows, 32 heads of 128,
+    2,048 summaries and 2,048 singletons a plane."""
+    b, h, d, n_sum, w = 16, 32, 128, 2048, 2048
+    plane = _spec(b, h, n_sum + w, d, dtype=dtype)
+    names = _kernels(
+        lambda q, k, v, s, n: eva_decode_attention_pallas(
+            q, k, v, s, n, n_sum, interpret=False),
+        _spec(b, h, 1, d, dtype=dtype), plane, plane,
+        _spec(b, dtype=jnp.int32), _spec(b, dtype=jnp.int32))
+    assert names == ["eva_decode"]
+
+
 @pytest.mark.parametrize("shape", [(8, 12, 1024, 64), (8, 12, 1024),
-                                   (8, 12, 600, 64)])
+                                   (8, 12, 600, 64), (16, 32, 4096, 128)])
 @pytest.mark.parametrize("dtype", DTYPES + [jnp.int8])
 def test_kv_cache_write_lowers(dtype, shape):
     new = shape[:2] + (1,) + shape[3:]
