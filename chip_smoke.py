@@ -264,8 +264,6 @@ def _generate_all(engine, prompts, max_tokens: int):
 
 
 def phase_serve(cfg, on_chip: bool, out: dict) -> None:
-    import jax.numpy as jnp
-
     from deeplearning4j_tpu.model.zoo import TransformerLM
     from deeplearning4j_tpu.parallel import DecodeEngine
 
@@ -290,10 +288,7 @@ def phase_serve(cfg, on_chip: bool, out: dict) -> None:
                 e = engine  # idle now: its carry is whatever the step left
                 text = e._decode_step_fn().lower(
                     model.params, model.state, e._carry,
-                    jnp.asarray(e._last), jnp.asarray(e._active),
-                    jnp.asarray(e._seeds), jnp.asarray(e._steps),
-                    jnp.asarray(e._greedy), jnp.asarray(e._temps),
-                    jnp.asarray(e._ks), jnp.asarray(e._ps)).as_text()
+                    *e._step_args(e._active)).as_text()
                 _check("flash_decode" in _kernel_names(text),
                        "decode step does not hold the Pallas decode kernel")
         finally:
@@ -311,8 +306,6 @@ def _serve_evabyte(cfg, on_chip: bool, out: dict) -> None:
     """One EvaByte stream that crosses a window's edge (its terminal event
     checked like the others: the engine swallows a step that fails to
     trace), and the step's program holding the EVA kernel."""
-    import jax.numpy as jnp
-
     from deeplearning4j_tpu.model.zoo import EvaByteLM
     from deeplearning4j_tpu.parallel import DecodeEngine
 
@@ -333,10 +326,7 @@ def _serve_evabyte(cfg, on_chip: bool, out: dict) -> None:
             e = engine
             text = e._decode_step_fn().lower(
                 model.params, model.state, e._carry,
-                jnp.asarray(e._last), jnp.asarray(e._active),
-                jnp.asarray(e._seeds), jnp.asarray(e._steps),
-                jnp.asarray(e._greedy), jnp.asarray(e._temps),
-                jnp.asarray(e._ks), jnp.asarray(e._ps)).as_text()
+                *e._step_args(e._active)).as_text()
             _check({"eva_decode", "kv_cache_write"} <= _kernel_names(text),
                    "EvaByte's decode step does not hold its Pallas kernels")
     finally:

@@ -18,7 +18,17 @@ batching observable):
 * **step** — ONE ``[B, 1]`` forward advances every active slot (rows at
   completely different positions share the compiled step; idle/finished
   rows are frozen by an active mask), per-row seeded sampling picks each
-  next token, and tokens stream to per-request event queues.
+  next token, and tokens stream to per-request event queues. The loop
+  runs ONE STEP AHEAD of the host (ISSUE 30): the tokens a step samples
+  stay on the device and feed the next step there, and the host fetches
+  and emits step k only after it has dispatched step k+1, so the emit
+  loop, the sweep and the uploads run while the device computes. What the
+  host knows ahead it uses (a row whose request ends with the token in
+  flight sits the next step out); what it cannot know (an eos, a cancel,
+  a deadline) costs that row one step whose token is dropped, never
+  emitted. Where the next action needs the tokens on the host, the loop
+  lands the step in flight first (a speculative turn, a paged preemption,
+  going idle, a failure).
 * **retire** — eos / ``max_tokens`` / ``max_len`` complete a request;
   an expired :class:`Deadline` terminates it cleanly mid-stream with
   partial output (reason "deadline"); a cancelled handle (client
@@ -46,10 +56,12 @@ the registry; traced requests get ``engine.queue_wait``,
 ``engine.prefill`` and ``engine.decode`` child spans in ``/v1/traces``.
 Every pass of the loop that has work is itself a ``loop.turn`` trace of
 the engine's tracer (children ``loop.admit`` > ``loop.prefill`` >
-``loop.prefill.dispatch``/``.sync``/``loop.install``; ``loop.step`` >
-``loop.upload``/``loop.dispatch``/``loop.fetch``/``loop.emit``;
-``loop.sweep``), head-sampled like a request and taken whole while a
-``jax.profiler`` session collects (README "Tracing").
+``loop.prefill.dispatch``/``loop.install``; ``loop.step`` >
+``loop.upload``/``loop.dispatch`` of this turn's step, then
+``loop.fetch``/``loop.emit`` of the step before and again of this turn's
+prefills' first tokens; ``loop.sweep``),
+head-sampled like a request and taken whole while a ``jax.profiler``
+session collects (README "Tracing").
 """
 
 from __future__ import annotations
@@ -204,6 +216,21 @@ class _Request:
         self.seq = -1  # per-engine sequence number, stamped at enqueue
 
 
+class _InFlight:
+    """A decode step dispatched and not yet fetched: its tokens on the
+    device, the rows it stepped, the request that held each row when it was
+    dispatched (a row whose request has ended since is dropped at the emit)
+    and the time its upload began."""
+
+    __slots__ = ("toks", "rows", "reqs", "t0")
+
+    def __init__(self, toks, rows, reqs, t0) -> None:
+        self.toks = toks
+        self.rows = rows
+        self.reqs = reqs
+        self.t0 = t0
+
+
 class DecodeEngine:
     def __init__(
         self,
@@ -284,7 +311,8 @@ class DecodeEngine:
         self.default_max_tokens = int(default_max_tokens)
         self._clock = clock
         self._tracer = tracer  # None -> process-global at call time
-        self._step_hook = step_hook  # test seam: runs after each decode step
+        # test seam: runs after each decode step's tokens were emitted
+        self._step_hook = step_hook
         self.name = name or f"decode-{next(_engine_seq)}"
         self._admission = admission or AdmissionController(
             max_pending=queue_limit, clock=clock)
@@ -344,14 +372,30 @@ class DecodeEngine:
             self._update_kv_bytes()
         self._active = np.zeros((self.slots,), bool)
         self._last = np.zeros((self.slots,), np.int32)
+        # tokens each row's request has emitted or has in flight (the
+        # sampling key's position), advanced when a step is dispatched, and
+        # the count at which the request is complete
         self._steps = np.zeros((self.slots,), np.int32)
+        self._limit = np.zeros((self.slots,), np.int32)
+        # every row's next input token, on the device: what the last step
+        # sampled, and the first token of each prefill since, written there
+        # beside the install. ``_fresh`` marks the rows whose token the host
+        # set instead (``_last``: a speculative turn commits on the host)
+        self._toks = jnp.zeros((self.slots,), jnp.int32)
+        self._fresh = np.zeros((self.slots,), bool)
+        # the step dispatched and not yet fetched, and the first token of
+        # the prefill dispatched and not yet fetched: (slot, request, token
+        # on the device, when its prefill's dispatch began)
+        self._flight: Optional[_InFlight] = None
+        self._first: Optional[tuple] = None
         self._seeds = np.zeros((self.slots,), np.uint32)
         self._greedy = np.ones((self.slots,), bool)
         self._temps = np.ones((self.slots,), np.float32)
         self._ks = np.zeros((self.slots,), np.int32)
         self._ps = np.ones((self.slots,), np.float32)
-        # committed cache frontier (next write position) per slot, and the
-        # per-request speculation cap (-1 = follow the engine's current k)
+        # cache frontier (next write position) per slot, advanced when a
+        # step is dispatched, and the per-request speculation cap (-1 =
+        # follow the engine's current k)
         self._pos = np.zeros((self.slots,), np.int64)
         self._spec_caps = np.full((self.slots,), -1, np.int32)
         self._requests: List[Optional[_Request]] = [None] * self.slots
@@ -397,16 +441,22 @@ class DecodeEngine:
             "Cache slots currently decoding", ("instance",)).labels(inst)
         self._h_decode = reg.histogram(
             "dl4j_tpu_generate_decode_latency_seconds",
-            "Per-token decode latency (one continuous-batched step emits "
-            "one token per active sequence)", ("instance",)).labels(inst)
+            "Per-step decode latency, from the start of the step's upload to "
+            "its tokens on the host (one continuous-batched step emits one "
+            "token per active sequence; the loop runs one step ahead, so the "
+            "emit of the step before lies inside it)",
+            ("instance",)).labels(inst)
         self._h_prefill = reg.histogram(
             "dl4j_tpu_generate_prefill_latency_seconds",
-            "Prompt prefill latency (bucketed length, batch of one)",
+            "Prompt prefill latency (bucketed length, batch of one): from "
+            "its dispatch to its first token on the host, fetched once the "
+            "turn's step is dispatched",
             ("instance",)).labels(inst)
         self._h_token = reg.histogram(
             "dl4j_tpu_generate_token_latency_seconds",
-            "Per-emitted-token latency per sequence (step time divided by "
-            "the tokens that sequence committed — the AIMD control signal)",
+            "Per-emitted-token latency per sequence (a step's upload to "
+            "its tokens on the host, divided by the tokens that sequence "
+            "committed — the AIMD control signal)",
             ("instance",)).labels(inst)
         self._c_spec_steps = reg.counter(
             "dl4j_tpu_generate_spec_steps_total",
@@ -435,6 +485,18 @@ class DecodeEngine:
             "Decode carries rebuilt after a donated step or install "
             "raised at run time and took the carry with it (0 in a sound "
             "window)", ("instance",)).labels(inst)
+        self._c_ahead = reg.counter(
+            "dl4j_tpu_decode_steps_ahead_total",
+            "Decode steps dispatched while the step before was still "
+            "unfetched (over the decode latency histogram's count: the "
+            "share of steps that overlapped the host's work)",
+            ("engine",)).labels(inst)
+        self._c_dropped = reg.counter(
+            "dl4j_tpu_decode_dropped_row_steps_total",
+            "Row-steps computed for a request that had ended (eos, cancel, "
+            "deadline) by the time the step's tokens were fetched: the "
+            "price of running one step ahead; the token is never emitted",
+            ("engine",)).labels(inst)
         self._c_windows = reg.counter(
             "dl4j_tpu_decode_windows_closed_total",
             "Windows that decoding rows closed (a row's position reached a "
@@ -482,8 +544,10 @@ class DecodeEngine:
     def _push_tables(self) -> None:
         """Mirror the host block tables onto the device as ONE shared
         ``[slots, max_len/bs]`` array (same shape/dtype every push — no
-        recompiles), which the step attaches under every paged layer."""
-        self._table = jnp.asarray(self._block_tables)
+        recompiles), which the step attaches under every paged layer. Of a
+        copy: the host image is written again while a step that was given
+        the table may still be in flight."""
+        self._table = jnp.asarray(self._block_tables.copy())
 
     def _fresh_carry(self):
         """A zeroed batch carry: the static per-layer caches, or the
@@ -532,15 +596,30 @@ class DecodeEngine:
         if req is not None:
             self._finish(req, "failed", error=why)
 
-    def _reserve_rows(self, rows: np.ndarray, ahead: int) -> np.ndarray:
+    def _reserve_rows(self, rows: np.ndarray, ahead: int,
+                      parent=NULL_SPAN) -> np.ndarray:
         """Reserve ``ahead`` positions past each row's frontier before a
         fused step. Rows the pool cannot back are preempted (their freed
-        blocks may rescue later rows in the same sweep); returns the
-        surviving row mask."""
+        blocks may rescue later rows in the same sweep), once the step in
+        flight has landed: the rows it ends free their blocks, and the row
+        to preempt has its last token first. Returns the surviving row
+        mask."""
         rows = rows.copy()
         for slot in np.nonzero(rows)[0]:
+            if not rows[slot]:
+                continue  # ended when the step in flight landed
             try:
-                self._ensure_blocks(int(slot), int(self._pos[slot]) + ahead)
+                try:
+                    self._ensure_blocks(int(slot),
+                                        int(self._pos[slot]) + ahead)
+                except OutOfBlocksError:
+                    if self._flight is None and self._first is None:
+                        raise
+                    self._drain(parent)
+                    rows &= self._active
+                    if rows[slot]:
+                        self._ensure_blocks(int(slot),
+                                            int(self._pos[slot]) + ahead)
             except OutOfBlocksError as e:
                 rows[slot] = False
                 self._preempt_row(int(slot),
@@ -603,8 +682,13 @@ class DecodeEngine:
             sess = self.session
             model = sess.model
 
-            def decode_step(params, state, carry, tokens, active, seeds,
-                            steps, gmask, temps, ks, ps, table=None):
+            def decode_step(params, state, carry, toks, last, fresh,
+                            active, seeds, steps, gmask, temps, ks, ps,
+                            table=None):
+                # a row steps from the token the step before sampled, or its
+                # prefill, which never left the device; a row whose last
+                # token was committed on the host (``fresh``) from that
+                tokens = jnp.where(fresh, last, toks)
                 # the fused step writes every row: an inactive row of a
                 # paged carry writes the trash block, not its own live
                 # blocks, and one of a static carry writes nothing
@@ -623,7 +707,7 @@ class DecodeEngine:
                 with jax.named_scope("freeze_rows"):
                     new_rnn = detach_block_table(
                         freeze_rows(new_rnn, fwd, active, sess.planes))
-                return new_rnn, jnp.where(active, toks, 0)
+                return new_rnn, jnp.where(active, toks, 0).astype(jnp.int32)
 
             self._fns["decode"] = jax.jit(decode_step, donate_argnums=2)
         return self._fns["decode"]
@@ -641,6 +725,17 @@ class DecodeEngine:
 
             self._fns["write"] = jax.jit(install_row, donate_argnums=0)
         return self._fns["write"]
+
+    def _set_token_fn(self):
+        """jit: a prefill's first token into the device's token vector, at
+        its row: dispatched beside the install, so the row's first step
+        needs nothing of the host."""
+        if "set_token" not in self._fns:
+            def set_token(toks, i, tok):
+                return toks.at[i].set(tok.astype(toks.dtype))
+
+            self._fns["set_token"] = jax.jit(set_token)
+        return self._fns["set_token"]
 
     def _paged_install_fn(self):
         """jit: install a 1-row STATIC prefill carry into the paged batch
@@ -934,6 +1029,11 @@ class DecodeEngine:
                              100.0 * (1.0 - len(req.prompt) / tb))
         if self._window:
             parent.set_attribute("windows", len(req.prompt) // self._window)
+        # one prefill in flight at a time: its row is as large as a slot
+        # (0.5 GB at EvaByte's widths), and a turn that admits sixteen
+        # must not hold sixteen. A turn's last prefill alone stays
+        # unfetched until the turn's step is dispatched
+        self._land_first(parent)
         with span("loop.prefill.dispatch", parent=parent):
             ids = np.zeros((1, tb), np.int32)
             ids[0, : len(req.prompt)] = req.prompt
@@ -943,7 +1043,7 @@ class DecodeEngine:
                 # disaggregated handoff: the prefill tier already ran the
                 # bucketed prefill and sampled the first token — install
                 # its shipped cache slice instead of recomputing
-                row, first = self._handoff_row(req.prefilled)
+                row, tok = self._handoff_row(req.prefilled)
             else:
                 row, tok = self._prefill_fn(tb)(
                     sess.model.params, sess.model.state, jnp.asarray(ids),
@@ -953,11 +1053,10 @@ class DecodeEngine:
                     jnp.asarray([req.temp], jnp.float32),
                     jnp.asarray([req.top_k], jnp.int32),
                     jnp.asarray([req.top_p], jnp.float32))
-        if req.prefilled is None:
-            with span("loop.prefill.sync", parent=parent):
-                first = int(tok)
         with span("loop.install", parent=parent):
             self._install_row(slot, row)
+            self._toks = self._set_token_fn()(
+                self._toks, jnp.asarray(slot, jnp.int32), tok)
         cap = -1 if req.spec_k is None else min(req.spec_k,
                                                 self.max_speculative_k)
         if self._spec is not None and cap != 0:
@@ -971,7 +1070,6 @@ class DecodeEngine:
                 jnp.asarray([len(req.prompt)], jnp.int32))
             self._draft_carry = self._write_row_fn()(
                 self._draft_carry, drow, jnp.asarray(slot, jnp.int32))
-        self._h_prefill.observe(time.perf_counter() - t0)
         self._breaker.record_success()
         if req.trace_ctx is not None:
             rec = self.tracer.make_record(
@@ -980,11 +1078,14 @@ class DecodeEngine:
                        "prompt_len": len(req.prompt), "bucket": tb})
             self.tracer.record_spans([rec])
             req.t_decode_start = trace_now()
-        # install the slot, emit the first token
+        # install the slot; the first token is fetched and emitted once
+        # the turn's step is dispatched (``_land_first``)
         self._requests[slot] = req
         self._active[slot] = True
-        self._last[slot] = first
+        self._fresh[slot] = False
         self._steps[slot] = 1  # next sample is decode step 1
+        self._limit[slot] = min(req.max_tokens,
+                                self.max_len - len(req.prompt))
         self._seeds[slot] = req.seed
         self._greedy[slot] = req.greedy
         self._temps[slot] = req.temp
@@ -993,9 +1094,7 @@ class DecodeEngine:
         self._pos[slot] = len(req.prompt)  # committed cache frontier
         self._spec_caps[slot] = cap
         self._g_active.set(int(self._active.sum()))
-        self._c_tokens.inc()
-        req.handle._emit(0, first)
-        self._retire_if_done(slot, first, emitted=1)
+        self._first = (slot, req, tok, t0)
 
     def _handoff_row(self, h: dict):
         """Rebuild a 1-row target carry from a serialized prefill handoff
@@ -1030,7 +1129,7 @@ class DecodeEngine:
                 full[:, :, :pos] = arr
                 new_st[key] = jnp.asarray(full, t.dtype)
             row[name] = new_st
-        return row, int(h["first_token"])
+        return row, jnp.asarray(int(h["first_token"]), jnp.int32)
 
     def _retire_if_done(self, slot: int, last_token: int, emitted: int) -> None:
         req = self._requests[slot]
@@ -1054,12 +1153,20 @@ class DecodeEngine:
             self._g_active.set(int(self._active.sum()))
             self._finish(req, reason)
 
-    def _fail_active(self, e: Exception) -> None:
+    def _fail_active(self, e: Exception, lost: bool = False) -> None:
         """Poisoned device step: fail every active request, open-circuit
         accounting, clear the batch — and leave the engine with a carry
-        the next admitted request can run on."""
+        the next admitted request can run on. A step in flight lands first
+        (its tokens are its requests'), unless the failure is its own or
+        that of the step before it (``lost``: the tokens never came, so
+        whatever was computed from that carry is lost with it)."""
+        if not lost and not self._drain():
+            return  # the landing failed, and has failed them all
+        self._flight = self._first = None
+        self._toks = jnp.zeros((self.slots,), jnp.int32)
         self._breaker.record_failure()
-        self._rebuild_lost_carry()  # before any caller hears of the failure
+        # before any caller hears of the failure
+        self._rebuild_lost_carry(force=lost)
         for slot in range(self.slots):
             req = self._requests[slot]
             if req is not None:
@@ -1075,11 +1182,14 @@ class DecodeEngine:
         return any(l.is_deleted() for l in jax.tree_util.tree_leaves(
             (self._carry, self._draft_carry)))
 
-    def _rebuild_lost_carry(self) -> None:
+    def _rebuild_lost_carry(self, force: bool = False) -> None:
         """Replace a carry that a failed donated call consumed by a zeroed
-        one (no row outlives it: the caller fails them all)."""
-        if not self._carry_lost():
+        one (no row outlives it: the caller fails them all). ``force``: the
+        step that returned it died after its dispatch, so what the arrays
+        hold is not a carry whatever they say."""
+        if not (force or self._carry_lost()):
             return
+        self._carry = None  # never two carries at once
         self._carry = self._fresh_carry()
         if self._spec is not None:
             self._draft_carry = self._spec.draft.decode_state(self.slots)
@@ -1087,53 +1197,133 @@ class DecodeEngine:
 
     def _step(self, rows: Optional[np.ndarray] = None,
               parent=NULL_SPAN) -> None:
-        """One plain [B, 1] decode step over ``rows`` (default: every
-        active slot — the non-speculative path, and the boundary fallback
-        for rows whose remaining cache room cannot hold a k+1 window).
-        ``parent`` is the turn's ``loop.step`` span."""
+        """Dispatch one plain [B, 1] decode step over ``rows`` (default:
+        every active slot — the non-speculative path, and the boundary
+        fallback for rows whose remaining cache room cannot hold a k+1
+        window), THEN fetch and emit the step before it and the first
+        token of this turn's last prefill: the host's work on step n-1
+        lies under the device's work on step n. A row whose request is
+        complete with the token in flight sits this step out; with no row
+        left to step, the turn only lands what is in flight. ``parent`` is
+        the turn's ``loop.step`` span."""
+        rows = (self._active if rows is None else rows) \
+            & (self._steps < self._limit)
+        if self._allocator is not None:
+            rows = self._reserve_rows(rows, 1, parent)
+        prev = self._flight
+        if rows.any():
+            try:
+                step = self._dispatch(rows, parent)
+            except Exception as e:  # noqa: BLE001 — poisoned step: fail active requests
+                self._fail_active(e)
+                return
+            if prev is not None:
+                self._c_ahead.inc()
+                parent.set_attribute("ahead", 1)
+            self._flight = step
+        else:
+            self._flight = None
+        if prev is None or self._land(prev, parent):
+            self._land_first(parent)
+
+    def _dispatch(self, rows: np.ndarray, parent=NULL_SPAN) -> _InFlight:
+        """Upload the rows' specs and enqueue their step, which takes its
+        tokens where the step before and the installs left them, on the
+        device. The rows' sampling position and cache frontier advance
+        here: the host's image of a row is one token ahead of what its
+        request has been handed."""
         sess = self.session
         span = self.tracer.span
-        rows = self._active if rows is None else rows
-        if self._allocator is not None:
-            rows = self._reserve_rows(rows, 1)
-            if not rows.any():
-                return
         t0 = time.perf_counter()
+        with span("loop.upload", parent=parent):
+            args = self._step_args(rows)
+        with span("loop.dispatch", parent=parent):
+            self._carry, self._toks = self._decode_step_fn()(
+                sess.model.params, sess.model.state, self._carry, *args,
+                self._table)
+        self._fresh[rows] = False
+        self._steps[rows] += 1
+        self._pos[rows] += 1
+        return _InFlight(self._toks, rows, list(self._requests), t0)
+
+    def _step_args(self, rows: np.ndarray) -> tuple:
+        """The decode step's operands after the carry, on the device: the
+        token vector that never left it, then the host's image of the rows.
+        Of copies: a transfer reads the host's array after the call that
+        asked for it returns, and the host writes these again (the next
+        dispatch, the next install) while the step is still in flight."""
+        return (self._toks,) + tuple(jnp.asarray(a.copy()) for a in (
+            self._last, self._fresh, rows, self._seeds, self._steps,
+            self._greedy, self._temps, self._ks, self._ps))
+
+    def _land(self, step: _InFlight, parent=NULL_SPAN) -> bool:
+        """Fetch a dispatched step's tokens and hand each to its request.
+        A row whose request ended after the dispatch (the slot is free, or
+        another request's) is dropped. False when the step died at run
+        time, which surfaces here: every active request has then failed."""
+        span = self.tracer.span
         try:
-            with span("loop.upload", parent=parent):
-                args = (
-                    jnp.asarray(self._last), jnp.asarray(rows),
-                    jnp.asarray(self._seeds), jnp.asarray(self._steps),
-                    jnp.asarray(self._greedy), jnp.asarray(self._temps),
-                    jnp.asarray(self._ks), jnp.asarray(self._ps))
-            with span("loop.dispatch", parent=parent):
-                self._carry, toks = self._decode_step_fn()(
-                    sess.model.params, sess.model.state, self._carry, *args,
-                    self._table)
             with span("loop.fetch", parent=parent):
-                toks_h = np.asarray(toks)
+                toks_h = np.asarray(step.toks)
         except Exception as e:  # noqa: BLE001 — poisoned step: fail active requests
-            self._fail_active(e)
-            return
-        dt = time.perf_counter() - t0
+            self._fail_active(e, lost=True)
+            return False
+        dt = time.perf_counter() - step.t0
         self._h_decode.observe(dt)
         self._breaker.record_success()
         with span("loop.emit", parent=parent):
-            for slot in np.nonzero(rows)[0]:
-                req = self._requests[slot]
+            for slot in np.nonzero(step.rows)[0]:
+                req = step.reqs[slot]
+                if req is not self._requests[slot]:
+                    self._c_dropped.inc()
+                    continue
                 tok = int(toks_h[slot])
                 emitted = len(req.handle.tokens)
                 req.handle._emit(emitted, tok)
                 self._last[slot] = tok
-                self._steps[slot] += 1
-                self._pos[slot] += 1
-                if self._window and self._pos[slot] % self._window == 0:
+                # the step wrote position len(prompt) + emitted - 1
+                if self._window and \
+                        (len(req.prompt) + emitted) % self._window == 0:
                     self._c_windows.inc()
                 self._c_tokens.inc()
                 self._h_token.observe(dt)
                 self._retire_if_done(slot, tok, emitted + 1)
         if self._step_hook is not None:
             self._step_hook()
+        return True
+
+    def _land_first(self, parent=NULL_SPAN) -> bool:
+        """Fetch and emit, as index 0, the first token of the prefill in
+        flight, if any. False when the prefill died at run time: it sat
+        between two steps in the device's queue, so every active request
+        has then failed."""
+        first, self._first = self._first, None
+        if first is None:
+            return True
+        slot, req, tok, t0 = first
+        span = self.tracer.span
+        try:
+            with span("loop.fetch", parent=parent):
+                tok = int(tok)
+        except Exception as e:  # noqa: BLE001 — poisoned prefill
+            self._fail_active(e, lost=True)
+            return False
+        self._h_prefill.observe(time.perf_counter() - t0)
+        with span("loop.emit", parent=parent):
+            if req is self._requests[slot]:
+                self._last[slot] = tok
+                self._c_tokens.inc()
+                req.handle._emit(0, tok)
+                self._retire_if_done(slot, tok, emitted=1)
+        return True
+
+    def _drain(self, parent=NULL_SPAN) -> bool:
+        """Land the step in flight and the first token in flight, if any:
+        afterwards the host's image of every row (``_last``, the handles)
+        is current."""
+        step, self._flight = self._flight, None
+        return (step is None or self._land(step, parent)) \
+            and self._land_first(parent)
 
     def _spec_step(self, parent=NULL_SPAN) -> None:
         """One speculative engine turn: propose/verify/accept for every
@@ -1143,6 +1333,10 @@ class DecodeEngine:
         frontier inside :meth:`SpeculativeGenerationSession.step`, so a
         cancelled or expired request never leaves speculative writes
         behind when its slot is reused."""
+        # the session takes every row's last token from the host
+        # (``_last``): this turn's prefills' first
+        if not self._drain(parent):
+            return
         k = max(1, self._spec_k)
         caps = np.where(self._spec_caps < 0, k,
                         np.minimum(self._spec_caps, k)).astype(np.int32)
@@ -1201,6 +1395,7 @@ class DecodeEngine:
                         emitted = len(req.handle.tokens)
                         req.handle._emit(emitted, tok)
                         self._last[slot] = tok
+                        self._fresh[slot] = True
                         self._steps[slot] += 1
                         self._pos[slot] += 1
                         self._c_tokens.inc()
@@ -1212,7 +1407,9 @@ class DecodeEngine:
             if self._step_hook is not None:
                 self._step_hook()
         if plain_rows.any():
+            # and their tokens next turn: no step stays in flight here
             self._step(plain_rows, parent)
+            self._drain(parent)
 
     def _sweep_pending(self) -> None:
         """Fail pending requests that died in the queue (cancel/expiry)
@@ -1228,8 +1425,9 @@ class DecodeEngine:
 
     def _loop(self) -> None:
         while True:
+            # a step in flight is work: its tokens are still to deliver
             if not self._active.any() and not self._pending \
-                    and not self._wait_for_work():
+                    and self._flight is None and not self._wait_for_work():
                 return
             self._turn()
 
@@ -1249,10 +1447,11 @@ class DecodeEngine:
                 self._wake.clear()
 
     def _turn(self) -> None:
-        """One pass of the loop that has work: admit, step, sweep. The
-        pass is a ``loop.turn`` trace of the engine's tracer (head-sampled
-        at its rate; every pass while a profiler session collects), whose
-        children say where the pass's time went on the host."""
+        """One pass of the loop that has work: admit, dispatch this turn's
+        step, land the step before, sweep. The pass is a ``loop.turn`` trace
+        of the engine's tracer (head-sampled at its rate; every pass while a
+        profiler session collects), whose children say where the pass's
+        time went on the host."""
         span = self.tracer.span
         self._n_turns += 1
         finished = self._n_finished
@@ -1262,10 +1461,10 @@ class DecodeEngine:
             with span("loop.admit", parent=turn) as admit:
                 turn.set_attribute("admitted", self._admit(admit))
             turn.set_attribute("rows", int(self._active.sum()))
-            if self._active.any():
+            if self._active.any() or self._flight is not None:
                 spec = self._spec is not None
                 with span("loop.step", parent=turn,
-                          attrs={"spec": spec}) as step:
+                          attrs={"spec": spec, "ahead": 0}) as step:
                     if spec:
                         self._spec_step(step)
                     else:
@@ -1370,6 +1569,11 @@ class DecodeEngine:
                                else self._allocator.free_blocks),
             "circuit_state": self._breaker.state.value,
             "carry_rebuilds": int(self._c_rebuilds.value),
+            # the loop runs one step ahead: steps fetched, those of them
+            # dispatched under the step before, row-steps thrown away
+            "decode_steps": int(self._h_decode.count),
+            "steps_ahead": int(self._c_ahead.value),
+            "dropped_row_steps": int(self._c_dropped.value),
             "draining": self._draining,
             # zero-guarded (PR-7 convention): derived ratios are None, not
             # 0.0, before any speculative traffic

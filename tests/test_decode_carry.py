@@ -60,10 +60,9 @@ def _host(x):
 
 
 def _step_args(e, active):
-    return (jnp.asarray(e._last), jnp.asarray(active),
-            jnp.asarray(e._seeds), jnp.asarray(e._steps),
-            jnp.asarray(e._greedy), jnp.asarray(e._temps),
-            jnp.asarray(e._ks), jnp.asarray(e._ps))
+    # the step's operands as the loop uploads them (ISSUE 30: the token
+    # vector that stays on the device first)
+    return e._step_args(active)
 
 
 def _planes(carry):

@@ -72,7 +72,9 @@ def _compile_step_and_install(model, params, slots, max_len, one_chip):
             lowered = {
                 "decode_step": eng._decode_step_fn().lower(
                     tm(spec, params), tm(spec, model.state), carry,
-                    vec(jnp.int32), vec(jnp.bool_), vec(jnp.uint32),
+                    # the step before's tokens, the host's, the fresh mask
+                    vec(jnp.int32), vec(jnp.int32), vec(jnp.bool_),
+                    vec(jnp.bool_), vec(jnp.uint32),
                     vec(jnp.int32), vec(jnp.bool_), vec(jnp.float32),
                     vec(jnp.int32), vec(jnp.float32)),
                 "install_row": eng._write_row_fn().lower(
