@@ -64,7 +64,8 @@ FULL = dict(
     # prefill buckets are powers of two: 16 | 17 and 32 | 33 straddle two
     prompt_lens=(12, 16, 17, 30, 32, 33),
     flash_shapes=((4, 12, 512, 64, False), (2, 12, 1024, 64, True)),
-    decode_shape=(8, 12, 1024, 64),
+    # the serve cell's step (128 slots) beside the eight-row one
+    decode_shapes=((8, 12, 1024, 64), (128, 12, 1024, 64)),
     # ROADMAP S2: 8,192 tokens x top-2 = 16,384 routed rows, d 768, 8
     # experts of width 1,536, capacity 1.25 x 16,384 / 8
     gmm_shape=(16384, 768, 8, 1536), gmm_capacity=2560,
@@ -82,7 +83,7 @@ DRY = dict(
     eva_prompt=12, eva_tokens=8,
     slots=4, block_size=16, max_tokens=4, prompt_lens=(4, 5),
     flash_shapes=((1, 2, 64, 16, True),),
-    decode_shape=(2, 2, 64, 16),
+    decode_shapes=((2, 2, 64, 16), (12, 2, 64, 16)),
     gmm_shape=(96, 16, 4, 32), gmm_capacity=32,
     four_batch=(4, 8),
 )
@@ -399,18 +400,27 @@ def phase_kernels(cfg, on_chip: bool, out: dict) -> None:
         for n, a, r in zip("qkv", got_g, ref_g):
             errs[f"{tag}_d{n}"] = (_rel_err(a, r), GRAD_TOL)
 
-    b, h, L, d = cfg["decode_shape"]
-    q, k, v = rand(5, b, h, 1, d), rand(6, b, h, L, d), rand(7, b, h, L, d)
-    # frontiers: first slot, a block edge on either side, the last slot,
-    # and an inactive row (-1: attends nothing, outputs 0)
-    pos = jnp.asarray(
-        ([0, L // 2 - 1, L // 2, L - 1, -1, 5, L // 3, L - 2] * b)[:b],
-        jnp.int32)
-    got = run_kernel(lambda q, k, v, p: flash_decode_attention(
-        q, k, v, p, interpret=interpret), q, k, v, pos,
-        holds=("flash_decode",))
-    ref = highest(decode_attention_reference, q, k, v, pos)
-    errs["flash_decode"] = (_rel_err(got, ref), FWD_TOL)
+    for b, h, L, d in cfg["decode_shapes"]:
+        q, k, v = rand(5, b, h, 1, d), rand(6, b, h, L, d), rand(7, b, h, L, d)
+        # frontiers: first slot, a block edge on either side, the last slot,
+        # and an inactive row (-1: attends nothing, outputs 0); the many-row
+        # shape has the serve cell's spread (positions 16-896 of 1,024)
+        if b <= 8:
+            pos = np.asarray(
+                [0, L // 2 - 1, L // 2, L - 1, -1, 5, L // 3, L - 2][:b])
+        else:
+            pos = np.random.RandomState(11).permutation(
+                np.linspace(L // 64, L * 7 // 8, b).astype(np.int32))
+            pos[4] = -1
+        idle = np.nonzero(pos < 0)[0]
+        pos = jnp.asarray(pos, jnp.int32)
+        got = run_kernel(lambda q, k, v, p: flash_decode_attention(
+            q, k, v, p, interpret=interpret), q, k, v, pos,
+            holds=("flash_decode",))
+        ref = highest(decode_attention_reference, q, k, v, pos)
+        errs[f"flash_decode_b{b}"] = (_rel_err(got, ref), FWD_TOL)
+        _check(not np.asarray(got, np.float32)[idle].any(),
+               "flash_decode: an inactive row's output is not 0")
 
     n, d, e, hdim = cfg["gmm_shape"]
     lhs, w = rand(8, n, d), rand(9, n, hdim)

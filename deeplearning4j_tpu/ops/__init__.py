@@ -20,6 +20,7 @@ from .flash_attention import (
     attention_impl,
     decode_attention,
     decode_attention_reference,
+    decode_fetched_entries,
     flash_attention,
     flash_decode_attention,
     flash_masked_cache_write,
@@ -66,6 +67,7 @@ __all__ = [
     "eva_prefill_attention",
     "decode_attention",
     "decode_attention_reference",
+    "decode_fetched_entries",
     "flash_decode_attention",
     "flash_masked_cache_write",
     "masked_cache_write",

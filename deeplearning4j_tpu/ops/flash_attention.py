@@ -660,26 +660,48 @@ def decode_attention_reference(
     return jnp.einsum("bhqk,bhkv->bhqv", weights, v)
 
 
+# entries a grid step of ``flash_decode`` takes of each head: timed on the chip
+# at the serve cell's shapes (128 rows at positions 16-896 of 1,024: PERF.md
+# section 6, PR 32) against 128 (more steps) and 512 (more invalid bytes)
+_DECODE_BLOCK_K = 256
+
+
+def decode_fetched_entries(lengths, max_len: int,
+                           block_k: int = _DECODE_BLOCK_K):
+    """Entries of its K plane (and as many of its V plane) that
+    ``flash_decode`` moves out of HBM for a row with ``lengths`` valid
+    entries in a cache of ``max_len``: whole blocks of ``block_k`` up to the
+    one that holds the row's last entry, and one block for a row with none.
+    Plain arithmetic, for Python ints, NumPy arrays and traced values alike:
+    the kernel's index map, the engine's counter and the tests share it."""
+    bk = min(block_k, max(max_len, 1))
+    blocks = (lengths + bk - 1) // bk
+    return (blocks + (blocks == 0)) * bk
+
+
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
-                   acc_scr, *, scale, block_k, heads):
-    """One (batch·head, k-block) grid step of single-query flash decode.
+                   acc_scr, *, scale, block_k, precision):
+    """One (row, head group, k-block) grid step of single-query flash
+    decode: every head of the group at once.
 
     The k axis is the innermost (sequential) grid dim so the VMEM online-
     softmax accumulators carry across k blocks, exactly like the training
     forward kernel — but the q block is a single row (the token being
     decoded) and the valid cache lengths arrive as a scalar-prefetch SMEM
-    vector, so k-blocks entirely past the decode frontier skip their
-    compute: the per-step work is O(position), not O(max_len).
+    vector, so a k-block entirely past the decode frontier is neither
+    fetched (the index map names no block of the row's there) nor
+    computed: the per-step work is O(position), not O(max_len).
 
-    K and V blocks arrive POSITION-MINOR, ``[d, block_k]``: that is how
-    the chip stores a ``[b, h, L, d]`` cache whose ``d`` is narrower than
-    its 128 lanes, so the kernel reads the cache where it lies and no
-    step transposes it. A one-row query gives the MXU nothing to do
-    (M=1), so scores and the weighted value sum are VPU broadcasts with
-    sublane / lane reductions: q is a ``[d, 1]`` column, scores a
-    ``[1, block_k]`` row with every lane in use."""
-    ki = pl.program_id(1)
-    length = len_ref[pl.program_id(0) // heads]  # valid entries = pos + 1
+    K and V blocks arrive POSITION-MINOR, ``[heads, d, block_k]``: that is
+    how the chip stores a ``[b, h, L, d]`` cache whose ``d`` is narrower
+    than its 128 lanes, so the kernel reads the cache where it lies and no
+    step transposes it. The one query row is broadcast to eight sublanes so
+    that both products are MXU matmuls over the head group. The softmax
+    weights stay float32 through the second one: row 0 of its left operand
+    is the weights rounded to V's precision, row 1 what the rounding lost,
+    and the two products are added."""
+    ki = pl.program_id(2)
+    length = len_ref[pl.program_id(0)]  # valid entries = pos + 1
 
     @pl.when(ki == 0)
     def _():
@@ -689,25 +711,37 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
 
     @pl.when(ki * block_k < length)
     def _():
-        q = q_ref[0].astype(jnp.float32) * scale    # [d, 1]
-        kt = k_ref[0].astype(jnp.float32)           # [d, block_k]
-        vt = v_ref[0].astype(jnp.float32)           # [dv, block_k]
-        s = jnp.sum(kt * q, axis=0, keepdims=True)  # [1, block_k]
+        q = q_ref[0]                                    # [hb, 1, d]
+        kt, vt = k_ref[0], v_ref[0]                     # [hb, d, block_k]
+        q8 = jnp.broadcast_to(q, (q.shape[0], 8, q.shape[2]))
+        s = jax.lax.dot_general(
+            q8, kt, (((2,), (1,)), ((0,), (0,))), precision=precision,
+            preferred_element_type=jnp.float32) * scale  # [hb, 8, block_k]
         k_ids = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), 1)
-        s = jnp.where(k_ids < length, s, _NEG)
+            jnp.int32, (1, 1, block_k), 2)
+        keep = k_ids < length
+        s = jnp.where(keep, s, _NEG)
         m, l, acc = m_scr[...], l_scr[...], acc_scr[...]
-        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))  # [1, 1]
-        p = jnp.where(s > _NEG * 0.5, jnp.exp(s - m_new), 0.0)
+        m_new = jnp.maximum(m, jnp.max(s, axis=2, keepdims=True))
+        p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
         alpha = jnp.exp(m - m_new)
         m_scr[...] = m_new
-        l_scr[...] = l * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[...] = acc * alpha + jnp.sum(vt * p, axis=1, keepdims=True)
+        l_scr[...] = l * alpha + jnp.sum(p, axis=2, keepdims=True)
+        hi = p.astype(vt.dtype).astype(jnp.float32)
+        row = jax.lax.broadcasted_iota(jnp.int32, p.shape, 1)
+        p2 = jnp.where(row == 0, hi, jnp.where(row == 1, p - hi, 0.0))
+        # what lies past the frontier is anyone's (0 x NaN is NaN)
+        vt = jnp.where(keep, vt, jnp.zeros_like(vt))
+        acc_scr[...] = acc * alpha + jax.lax.dot_general(
+            p2.astype(vt.dtype), vt, (((2,), (2,)), ((0,), (0,))),
+            precision=precision,
+            preferred_element_type=jnp.float32)          # [hb, 8, dv]
 
-    @pl.when(ki == pl.num_programs(1) - 1)
+    @pl.when(ki == pl.num_programs(2) - 1)
     def _():
-        o_ref[0] = (acc_scr[...] /
-                    jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
+        acc = acc_scr[...]
+        o_ref[0] = ((acc[:, 0:1, :] + acc[:, 1:2, :]) /
+                    jnp.maximum(l_scr[:, 0:1, :], 1e-30)).astype(o_ref.dtype)
 
 
 def flash_decode_attention(
@@ -716,13 +750,17 @@ def flash_decode_attention(
     v: jax.Array,           # [b, h, L, dv]
     start_pos: jax.Array,   # [b] int32
     scale: Optional[float] = None,
-    block_k: int = 512,
+    block_k: int = _DECODE_BLOCK_K,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Pallas single-query-block decode attention (same contract as
     :func:`decode_attention_reference` with ``tq == 1``). K and V go to
-    the kernel as ``[b*h, d, L]``: for a cache the chip keeps
-    position-minor that transpose is a relabelling, not a copy."""
+    the kernel as ``[b, h, d, L]``: for a cache the chip keeps
+    position-minor that transpose is a relabelling, not a copy. A grid
+    step takes ``block_k`` entries of every head of a row (of a divisor of
+    the heads where a block of all of them would crowd VMEM), and only the
+    blocks that a row's position makes valid are moved
+    (:func:`decode_fetched_entries`)."""
     if q.shape[2] != 1:
         raise ValueError("flash_decode_attention is the tq=1 kernel; use "
                          "decode_attention for multi-row queries")
@@ -733,43 +771,58 @@ def flash_decode_attention(
     b, h, _, d = q.shape
     L, dv = k.shape[2], v.shape[3]
     block_k = min(block_k, max(L, 1))
-    kp = _pad_to(k, 2, block_k)
-    vp = _pad_to(v, 2, block_k)
-    L_p = kp.shape[2]
-    qp = q.reshape(b * h, d, 1)
-    kp = jnp.swapaxes(kp, 2, 3).reshape(b * h, d, L_p)
-    vp = jnp.swapaxes(vp, 2, 3).reshape(b * h, dv, L_p)
-    lengths = start_pos.astype(jnp.int32) + 1  # [b], scalar-prefetched
+    kp = jnp.swapaxes(_pad_to(k, 2, block_k), 2, 3)
+    vp = jnp.swapaxes(_pad_to(v, 2, block_k), 2, 3)
+    # heads a step: all, unless a K and a V block of them would pass 4 MiB
+    # (each is held twice)
+    hb = max(g for g in range(1, h + 1) if h % g == 0 and (
+        g == 1 or g * (d + dv) * block_k * k.dtype.itemsize <= 4 << 20))
+    groups = h // hb
+    lengths = jnp.maximum(start_pos.astype(jnp.int32) + 1, 0)  # [b]
 
-    kern = functools.partial(_decode_kernel, scale=float(scale),
-                             block_k=block_k, heads=h)
+    def kv_block(r, g, ki, lens):
+        """A step the row's length leaves dead names the block that the
+        next row (or head group) starts with, which so comes in under this
+        row's arithmetic and dead steps and is there when its own step
+        names it again; the last row of all stays on its own last block.
+        Nothing is fetched twice, and no block past a row's length."""
+        live = ki * block_k < lens[r]
+        nxt = r * groups + g + 1
+        stay = live | (nxt == b * groups)
+        own_last = decode_fetched_entries(lens[r], L, block_k) // block_k - 1
+        return (jnp.where(stay, r, nxt // groups),
+                jnp.where(stay, g, nxt % groups), 0,
+                jnp.where(live, ki, jnp.where(stay, own_last, 0)))
+
+    def row_block(r, g, ki, lens):
+        return (r, g, 0, 0)
+
+    kern = functools.partial(
+        _decode_kernel, scale=float(scale), block_k=block_k,
+        precision=(jax.lax.Precision.HIGHEST if k.dtype == jnp.float32
+                   else None))
     kw = dict(memory_space=pltpu.VMEM)
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(b * h, L_p // block_k),
+            grid=(b, groups, kp.shape[3] // block_k),
             in_specs=[
-                pl.BlockSpec((1, d, 1), lambda bh, ki, lens: (bh, 0, 0),
-                             **kw),
-                pl.BlockSpec((1, d, block_k),
-                             lambda bh, ki, lens: (bh, 0, ki), **kw),
-                pl.BlockSpec((1, dv, block_k),
-                             lambda bh, ki, lens: (bh, 0, ki), **kw),
+                pl.BlockSpec((1, hb, 1, d), row_block, **kw),
+                pl.BlockSpec((1, hb, d, block_k), kv_block, **kw),
+                pl.BlockSpec((1, hb, dv, block_k), kv_block, **kw),
             ],
-            out_specs=pl.BlockSpec((1, dv, 1),
-                                   lambda bh, ki, lens: (bh, 0, 0), **kw),
+            out_specs=pl.BlockSpec((1, hb, 1, dv), row_block, **kw),
             scratch_shapes=[
-                pltpu.VMEM((1, 1), jnp.float32),
-                pltpu.VMEM((1, 1), jnp.float32),
-                pltpu.VMEM((dv, 1), jnp.float32),
+                pltpu.VMEM((hb, 8, 1), jnp.float32),
+                pltpu.VMEM((hb, 8, 1), jnp.float32),
+                pltpu.VMEM((hb, 8, dv), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b * h, dv, 1), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, 1, dv), q.dtype),
         interpret=interpret,
         name="flash_decode",
-    )(lengths, qp, kp, vp)
-    return out.reshape(b, h, 1, dv)
+    )(lengths, q, kp, vp)
 
 
 # ---------------------------------------------------------------------------

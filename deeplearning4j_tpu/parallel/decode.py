@@ -98,6 +98,7 @@ from ..generate.paged import (
 )
 from ..generate.sampling import sample_tokens
 from ..generate.session import GenerationSession, SpeculativeGenerationSession
+from ..ops.flash_attention import decode_fetched_entries
 from ..ops.paged_attention import pack_row_blocks
 from ..obs.metrics import MetricsRegistry, get_registry
 from ..obs.compiles import watch_compiles
@@ -331,6 +332,11 @@ class DecodeEngine:
         self._live_layers = Counter(
             l for l in self.session.model.layers
             if l.decode_live_bytes(0, 1))
+        # whether a plain step's attention is the single-query kernel over
+        # the static planes (what the two kv_entries counters describe)
+        self._static_kv = (self.block_size is None and cache_dtype != "int8"
+                           and any(l.pages_decode_planes
+                                   for l in self.session.model.layers))
         self._init_metrics(registry if registry is not None else get_registry())
 
         # device-side batch state: one preallocated carry, per-row specs.
@@ -496,6 +502,20 @@ class DecodeEngine:
             "Row-steps computed for a request that had ended (eos, cancel, "
             "deadline) by the time the step's tokens were fetched: the "
             "price of running one step ahead; the token is never emitted",
+            ("engine",)).labels(inst)
+        self._c_kv_attended = reg.counter(
+            "dl4j_tpu_decode_kv_entries_attended_total",
+            "Cache entries that the rows of the dispatched decode steps "
+            "attend, a layer and plane: the sum over a step's rows of "
+            "position + 1 (engines whose attention reads the static "
+            "[slots, heads, max_len, d] planes; 0 for the others)",
+            ("engine",)).labels(inst)
+        self._c_kv_fetched = reg.counter(
+            "dl4j_tpu_decode_kv_entries_fetched_total",
+            "Cache entries that the flash_decode kernel moves out of HBM "
+            "for the same rows: whole blocks up to each row's position "
+            "(ops.flash_attention.decode_fetched_entries); attended over "
+            "fetched is the share of the kernel's bytes that are valid",
             ("engine",)).labels(inst)
         self._c_windows = reg.counter(
             "dl4j_tpu_decode_windows_closed_total",
@@ -1241,6 +1261,11 @@ class DecodeEngine:
             self._carry, self._toks = self._decode_step_fn()(
                 sess.model.params, sess.model.state, self._carry, *args,
                 self._table)
+        if self._static_kv:
+            lengths = self._pos[rows] + 1
+            self._c_kv_attended.inc(int(lengths.sum()))
+            self._c_kv_fetched.inc(int(decode_fetched_entries(
+                lengths, self.max_len).sum()))
         self._fresh[rows] = False
         self._steps[rows] += 1
         self._pos[rows] += 1
@@ -1551,6 +1576,7 @@ class DecodeEngine:
         proposed = int(self._c_spec_proposed.value)
         accepted = int(self._c_spec_accepted.value)
         spec_steps = int(self._c_spec_steps.value)
+        kv_fetched = self._c_kv_fetched.value
         counts.update({
             "in_flight": self._admission.pending,
             # the engine-list aggregation key health()/pools sum over
@@ -1574,6 +1600,10 @@ class DecodeEngine:
             "decode_steps": int(self._h_decode.count),
             "steps_ahead": int(self._c_ahead.value),
             "dropped_row_steps": int(self._c_dropped.value),
+            # of the entries the decode kernel moved, the share it attended
+            "kv_fetch_valid_share": (
+                self._c_kv_attended.value / kv_fetched if kv_fetched
+                else None),
             "draining": self._draining,
             # zero-guarded (PR-7 convention): derived ratios are None, not
             # 0.0, before any speculative traffic

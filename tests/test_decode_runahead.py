@@ -345,3 +345,35 @@ def test_step_n_is_dispatched_before_step_n_minus_1_is_emitted(lm):
             landed_only += 1
             assert names == ["loop.fetch", "loop.emit"]
     assert ahead == steps_ahead >= 8 and landed_only >= 1 and both >= 1
+
+
+@pytest.mark.parametrize("carry", ["static", "paged", "eva", "int8"])
+def test_kv_entries_counters_follow_the_rows_positions(lm, eva, carry):
+    """ISSUE 32: a dispatched step's rows attend position + 1 entries of a
+    static plane and the decode kernel moves whole blocks up to there (a
+    cache of 32 is one block); engines whose step reads no static plane
+    through that kernel (paged, EVA's own state, an int8 cache) count
+    nothing and give no ratio."""
+    reg = MetricsRegistry()
+    layout = {"paged": {"block_size": 4}, "int8": {"cache_dtype": "int8"}}
+    model, max_len = (eva, EVA["max_len"]) if carry == "eva" else (lm, MAX_LEN)
+    e = _engine(model, max_len=max_len, registry=reg, name="kv", slots=1,
+                **layout.get(carry, {}))
+    try:
+        n, m = 5, 9
+        assert len(e.submit(list(range(1, n + 1)), max_tokens=m)
+                   .result(timeout=120)) == m
+        s = e.stats()
+    finally:
+        e.shutdown()
+    attended = reg.get("dl4j_tpu_decode_kv_entries_attended_total").labels(
+        "kv").value
+    fetched = reg.get("dl4j_tpu_decode_kv_entries_fetched_total").labels(
+        "kv").value
+    if carry != "static":
+        assert (attended, fetched, s["kv_fetch_valid_share"]) == (0, 0, None)
+        return
+    # the prefill hands out token 0; step i stands at position n + i
+    assert attended == sum(n + i + 1 for i in range(m - 1))
+    assert fetched == (m - 1) * MAX_LEN
+    assert s["kv_fetch_valid_share"] == pytest.approx(attended / fetched)

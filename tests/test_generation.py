@@ -24,6 +24,7 @@ from deeplearning4j_tpu.model.zoo import TextGenerationLSTM, TransformerLM
 from deeplearning4j_tpu.obs.metrics import MetricsRegistry
 from deeplearning4j_tpu.ops import (
     decode_attention_reference,
+    decode_fetched_entries,
     flash_decode_attention,
 )
 from deeplearning4j_tpu.parallel import DecodeEngine
@@ -135,19 +136,81 @@ class TestSampling:
 # ---------------------------------------------------------------------------
 
 
+# h, d, L, block_k, the rows' positions (-1: an inactive row)
+_DECODE_CASES = [
+    pytest.param(4, 16, 40, 8, [0, 5, 39], id="spread"),
+    pytest.param(4, 16, 40, 8, [1, 1, 1], id="all-equal"),
+    pytest.param(4, 16, 40, 8, [38, 0, 20], id="all-different"),
+    pytest.param(4, 16, 40, 8, [-1, 0, -1], id="lengths-0-and-1"),
+    pytest.param(4, 16, 40, 8, [6, 7, 8, 15, 16], id="round-a-block-edge"),
+    pytest.param(4, 16, 40, 8, [39, -1, 39, 31], id="the-whole-cache"),
+    pytest.param(3, 16, 40, 16, [20] * 5, id="five-equal-rows-padded-cache"),
+    pytest.param(2, 8, 600, 256, [599, 255, 256, 300, -1, 511, 512],
+                 id="600-that-no-block-divides"),
+    pytest.param(12, 64, 256, 128, [0, 127, 128, 255, 100, -1],
+                 id="12-heads-of-64"),
+    pytest.param(2, 8, 48, 256, [0, 47, -1, 13], id="one-block"),
+    # a K and a V block of all six heads pass 4 MiB: two head groups
+    pytest.param(6, 64, 4096, 2048, [5, 2047, 2048, -1, 4095],
+                 id="two-head-groups"),
+]
+
+
 class TestDecodeAttention:
-    def test_flash_matches_reference(self):
-        rng = np.random.RandomState(0)
-        b, h, L, d = 3, 4, 40, 16
+    @staticmethod
+    def _case(h, d, L, pos, seed=0):
+        rng = np.random.RandomState(seed)
+        b = len(pos)
         q = jnp.asarray(rng.randn(b, h, 1, d), jnp.float32)
-        k = jnp.asarray(rng.randn(b, h, L, d), jnp.float32)
-        v = jnp.asarray(rng.randn(b, h, L, d), jnp.float32)
-        for pos in ([0, 5, 39], [1, 1, 1], [38, 0, 20]):
-            sp = jnp.asarray(pos, jnp.int32)
-            ref = decode_attention_reference(q, k, v, sp)
-            fl = flash_decode_attention(q, k, v, sp, block_k=8)
-            np.testing.assert_allclose(np.asarray(fl), np.asarray(ref),
-                                       atol=1e-5, rtol=1e-5)
+        k = rng.randn(b, h, L, d).astype(np.float32)
+        v = rng.randn(b, h, L, d).astype(np.float32)
+        return q, k, v, jnp.asarray(pos, jnp.int32)
+
+    @pytest.mark.parametrize("h,d,L,block_k,pos", _DECODE_CASES)
+    def test_flash_matches_reference(self, h, d, L, block_k, pos):
+        q, k, v, sp = self._case(h, d, L, pos)
+        ref = decode_attention_reference(q, jnp.asarray(k), jnp.asarray(v), sp)
+        fl = flash_decode_attention(q, jnp.asarray(k), jnp.asarray(v), sp,
+                                    block_k=block_k)
+        np.testing.assert_allclose(np.asarray(fl), np.asarray(ref),
+                                   atol=1e-5, rtol=1e-5)
+        # an inactive row attends nothing and outputs exactly 0
+        idle = np.asarray(pos) < 0
+        assert not np.asarray(fl)[idle].any()
+
+    @pytest.mark.parametrize("h,d,L,block_k,pos", _DECODE_CASES[3:9])
+    def test_flash_reads_nothing_past_the_frontier(self, h, d, L, block_k,
+                                                   pos):
+        """The cache past each row's position holds NaN: the kernel's
+        result is finite and equal to the one over a clean cache."""
+        q, k, v, sp = self._case(h, d, L, pos, seed=3)
+        clean = flash_decode_attention(q, jnp.asarray(k), jnp.asarray(v), sp,
+                                       block_k=block_k)
+        for row, p in enumerate(pos):
+            k[row, :, p + 1:] = np.nan
+            v[row, :, p + 1:] = np.nan
+        got = np.asarray(flash_decode_attention(
+            q, jnp.asarray(k), jnp.asarray(v), sp, block_k=block_k))
+        assert np.isfinite(got).all()
+        np.testing.assert_array_equal(got, np.asarray(clean))
+
+    def test_fetched_entries_against_a_hand_count(self):
+        """Whole blocks up to the one that holds a row's last entry; one
+        block for a row with none; a block no longer than the cache."""
+        lengths = np.array([0, 1, 255, 256, 257, 512, 513, 1024])
+        got = decode_fetched_entries(lengths, 1024, 256)
+        assert isinstance(got, np.ndarray)  # host arithmetic stays on the host
+        assert got.tolist() == [256, 256, 256, 256, 512, 512, 768, 1024]
+        assert decode_fetched_entries(lengths, 1024, 128).tolist() == [
+            128, 128, 256, 256, 384, 512, 640, 1024]
+        assert decode_fetched_entries(221, 1024) == 256  # the default block
+        assert decode_fetched_entries(7, 40, 256) == 40
+        assert decode_fetched_entries(0, 40, 8) == 8
+        assert decode_fetched_entries(601, 1024, 512) == 1024
+        # traced, as the kernel's index map calls it
+        traced = jax.jit(lambda n: decode_fetched_entries(n, 1024, 256))(
+            jnp.asarray(lengths, jnp.int32))
+        assert np.asarray(traced).tolist() == got.tolist()
 
     def test_reference_masks_future(self):
         # entries past the frontier must not influence the output

@@ -55,9 +55,12 @@ def test_flash_forward_and_backward_lower(dtype, b, h, t, d, causal):
 
 
 @pytest.mark.parametrize("dtype", DTYPES)
-@pytest.mark.parametrize("L", [1024, 600])
-def test_flash_decode_lowers(dtype, L):
-    b, h, d = 8, 12, 64
+@pytest.mark.parametrize("b,L", [
+    (8, 1024), (8, 600),
+    (128, 1024),                # the serve cell's own step: 128 slots
+])
+def test_flash_decode_lowers(dtype, b, L):
+    h, d = 12, 64
     names = _kernels(
         lambda q, k, v, p: flash_decode_attention(q, k, v, p,
                                                   interpret=False),

@@ -43,18 +43,33 @@ def _rel(got, ref):
     return float(np.abs(got - ref).max() / (np.abs(ref).max() + 1e-30))
 
 
-@pytest.mark.parametrize("L,block_k", [(1024, 512), (600, 512), (64, 512)])
-def test_flash_decode_compiled_matches_reference(tpu_device, L, block_k):
-    b, h, d = 8, 12, 64
+@pytest.mark.parametrize("b,L,block_k", [
+    (8, 1024, 512), (8, 600, 512), (8, 64, 512),
+    (8, 1024, 256), (8, 1024, 128),
+    (128, 1024, 256),           # the serve cell's step: positions 16-896
+])
+def test_flash_decode_compiled_matches_reference(tpu_device, b, L, block_k):
+    h, d = 12, 64
     q, k, v = _rand(0, b, h, 1, d), _rand(1, b, h, L, d), _rand(2, b, h, L, d)
-    pos = jnp.asarray([0, L // 2 - 1, L // 2, L - 1, -1, 5, L // 3, L - 2],
-                      jnp.int32)
-    got = jax.jit(lambda *a: flash_decode_attention(
-        *a, block_k=block_k, interpret=False))(q, k, v, pos)
-    ref = _highest(decode_attention_reference, q, k, v, pos)
+    if b == 8:
+        pos = np.asarray([0, L // 2 - 1, L // 2, L - 1, -1, 5, L // 3, L - 2])
+    else:
+        pos = np.random.RandomState(11).permutation(
+            np.linspace(16, 896, b).astype(np.int32))
+        pos[4] = -1
+    run = jax.jit(lambda *a: flash_decode_attention(
+        *a, block_k=block_k, interpret=False))
+    start = jnp.asarray(pos, jnp.int32)
+    got = run(q, k, v, start)
+    ref = _highest(decode_attention_reference, q, k, v, start)
     assert _rel(got, ref) < FWD_TOL
     # the inactive row (pos -1) attends nothing and outputs exactly 0
     assert not np.asarray(got, np.float32)[4].any()
+    # what lies past a row's position may be anything: the result is the same
+    stale = np.arange(L)[None, :] > pos[:, None]              # [b, L]
+    k2, v2 = (jnp.where(stale[:, None, :, None], jnp.nan, a) for a in (k, v))
+    again = run(q, k2, v2, start)
+    assert (np.asarray(again, np.float32) == np.asarray(got, np.float32)).all()
 
 
 @pytest.mark.parametrize("t,dtype", [(1, jnp.bfloat16), (1, jnp.int8),
