@@ -103,6 +103,11 @@ class GenerationSession:
         self.planes: Dict[str, frozenset] = {
             name: frozenset(layer.decode_planes())
             for name, layer in named if layer.decode_planes()}
+        #: what each layer counts of a call in its decode state
+        #: (``Layer.decode_counts``): ``{layer name: {leaf: column names}}``
+        self.counts: Dict[str, dict] = {
+            name: layer.decode_counts()
+            for name, layer in named if layer.decode_counts()}
         #: the layers among them whose planes the paged layout can page
         self.paged_layers = frozenset(
             name for name, layer in named
@@ -143,6 +148,30 @@ class GenerationSession:
         by (and the ``dl4j_tpu_generate_kv_cache_bytes`` gauge)."""
         leaves = jax.tree_util.tree_leaves(self.decode_state(batch))
         return int(sum(l.size * l.dtype.itemsize for l in leaves))
+
+    def count_columns(self) -> Dict[str, tuple]:
+        """``{leaf: column names}`` of everything the model's layers count
+        (layers that declare a leaf of one name declare the same columns)."""
+        out: Dict[str, tuple] = {}
+        for leaves in self.counts.values():
+            out.update(leaves)
+        return out
+
+    def summed_counts(self, carry, rows=None) -> Dict[str, jax.Array]:
+        """The counting leaves of ``carry`` summed over its rows (those
+        ``rows [b]`` marks, if given) and over the layers that declare
+        them: ``{leaf: [columns] int32}``, ``{}`` for a model that counts
+        nothing. Traced inside the step or prefill that made the carry, so
+        the sums leave the device with that program's tokens."""
+        out: Dict[str, jax.Array] = {}
+        for name, leaves in self.counts.items():
+            for leaf in leaves:
+                c = carry[name][leaf]
+                if rows is not None:
+                    c = jnp.where(rows[:, None], c, 0)
+                c = jnp.sum(c, axis=0)
+                out[leaf] = out[leaf] + c if leaf in out else c
+        return out
 
     def bucket_sizes(self, limit: Optional[int] = None) -> List[int]:
         """Prompt-length buckets a warmup should compile (powers of two up

@@ -63,7 +63,9 @@ from .preprocessors import (
     RnnToFeedForwardPreProcessor,
 )
 from .eva import EvaDecoderBlockLayer, gated_silu_ffn, rotary_positions
-from .moe import MixtureOfExpertsLayer
+from .longcat import LongCatBlockLayer
+from .mla import LatentAttentionLayer
+from .moe import ExpertShareMoELayer, MixtureOfExpertsLayer
 from .samediff_layer import SameDiffLambdaLayer, SameDiffLayer
 from .recurrent import (
     BidirectionalLayer,

@@ -159,6 +159,16 @@ class Layer:
         keeps every entry (or none)."""
         return None
 
+    def decode_counts(self) -> Dict[str, Tuple[str, ...]]:
+        """What the layer COUNTS of each call in its :meth:`decode_state`:
+        ``{leaf: names of its columns}`` for every per-row leaf ``[batch,
+        columns]`` int32 that the call overwrites with counts of what it did
+        for the row's tokens (an expert layer: where the tokens' choices
+        went). The engine sums such a leaf over a step's active rows and the
+        layers that declare it and fetches the sums with the step's tokens;
+        ``{}`` for a layer that counts nothing."""
+        return {}
+
     def decode_live_bytes(self, position: int, itemsize: int) -> Dict[str, int]:
         """Bytes of the decode state that a row standing at ``position``
         has made valid, by kind of entry (host arithmetic, for the engine's

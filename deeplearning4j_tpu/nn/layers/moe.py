@@ -62,8 +62,10 @@ import jax.numpy as jnp
 from ...core.config import register_config
 from ...ops.grouped_matmul import grouped_matmul
 from ...ops.moe_dispatch import (
+    biased_top_k_routing,
     combine_rows,
     gather_dispatch,
+    held_expert_choices,
     make_dispatch_plan,
     scatter_combine,
     top_k_routing,
@@ -469,3 +471,208 @@ class MixtureOfExpertsLayer(Layer):
         if recurrent:
             y = jnp.transpose(y.reshape(b_, t_, self.n_out), (0, 2, 1))
         return y, new_state
+
+
+def _router_logits(x: jax.Array, wr: jax.Array) -> jax.Array:
+    """``x @ wr`` in float32. Router weights served in a 16-bit type are
+    exact in it, so a float32 activation goes through the MXU as its two
+    16-bit halves (what the second half leaves is 2^-17 of the value) and
+    the products accumulate in float32; a product of float32 by float32 at
+    the highest precision otherwise. (At 128 rows the chip's compiler ran
+    the latter on the vector unit: 0.54 ms a layer of a 23 ms step.)"""
+    f32 = jnp.float32
+    if wr.dtype == f32 or x.dtype != f32:
+        return jnp.dot(x.astype(f32), wr.astype(f32),
+                       precision=jax.lax.Precision.HIGHEST)
+    hi = x.astype(wr.dtype)
+    lo = (x - hi.astype(f32)).astype(wr.dtype)
+    return jnp.dot(hi, wr, preferred_element_type=f32) \
+        + jnp.dot(lo, wr, preferred_element_type=f32)
+
+
+@register_config
+@dataclasses.dataclass(frozen=True, kw_only=True)
+class ExpertShareMoELayer(Layer):
+    """ONE CHIP'S SHARE of an expert layer with modern routing, as expert
+    parallelism divides it: the router keeps its published width, the layer
+    is told which routed experts it holds (``n_held_experts`` from
+    ``first_held_expert`` on, of ``n_routed_experts``) and computes their
+    part of the result for EVERY token routed to them, whatever the load:
+    no capacity, no dropped token. What the absent experts would add is
+    another chip's; nothing here stands in for it or for the exchange.
+
+        s = softmax_float32(Wr u) over n_routed_experts + zero_expert_num
+        chosen: the top_k largest of s + br (the served selection bias
+        moves the choice, not the weight); weight routed_scaling_factor *
+        s_e, NOT renormalised
+        y = sum over the chosen of weight_e E_e(u): E_e the gated expert
+        ``Ed_e (silu(Eg_e u) * Eu_e u)`` (no bias) for a held e, nothing
+        for an absent one, and ``E_e(u) = u`` for e >= n_routed_experts
+        (a ZERO-COMPUTE expert: it holds no weights and lives on every
+        chip)
+
+    Params: ``Wr [n_in, E + Z]``, ``br [E + Z]``, ``Eg``/``Eu`` ``[held,
+    n_in, hidden]``, ``Ed [held, hidden, n_in]``. Shapes are static for the
+    worst load (every token to one held expert). Up to ``expert_rows``
+    tokens every held expert runs over every token and the weights (nought
+    where a token did not choose it) fold into the down-projection: at a
+    decode step's rows that product is under the time the expert weights
+    take to read. Beyond, tokens are sorted into ``[held, expert_rows]``
+    buffers with the dispatch plan ``MixtureOfExpertsLayer`` uses
+    (``ops/moe_dispatch.py``), and a call in which some held expert has
+    more than ``expert_rows`` tokens takes the first form instead
+    (``lax.cond``): slower, never lossy.
+
+    ``state["choice_counts"]`` (``[held + 2]``: a column a held expert, the
+    absent, the zero-compute) says where the call's choices went."""
+
+    n_in: int = 0
+    hidden: int = 0
+    n_routed_experts: int = 8
+    zero_expert_num: int = 0
+    n_held_experts: int = 0          # 0: every routed expert is held
+    first_held_expert: int = 0
+    top_k: int = 2
+    routed_scaling_factor: float = 1.0
+    expert_rows: int = 128
+
+    def __post_init__(self) -> None:
+        width = self.n_routed_experts + self.zero_expert_num
+        if not 1 <= self.top_k <= width:
+            raise ValueError(f"top_k={self.top_k} must be in [1, {width}]")
+        if self.first_held_expert + self.held > self.n_routed_experts:
+            raise ValueError(
+                f"experts {self.first_held_expert}..+{self.held} are not "
+                f"among the {self.n_routed_experts} routed")
+
+    @property
+    def held(self) -> int:
+        return self.n_held_experts or self.n_routed_experts
+
+    def output_type(self, input_type: InputType) -> InputType:
+        return input_type
+
+    def with_input(self, input_type: InputType) -> "ExpertShareMoELayer":
+        out = self
+        if not out.n_in:
+            out = dataclasses.replace(out, n_in=input_type.size)
+        if not out.hidden:
+            out = dataclasses.replace(out, hidden=4 * out.n_in)
+        return out
+
+    def has_params(self) -> bool:
+        return True
+
+    def trainable_param_names(self) -> Tuple[str, ...]:
+        return ("Wr", "br", "Eg", "Eu", "Ed")
+
+    def weight_param_names(self) -> Tuple[str, ...]:
+        return ("Wr", "Eg", "Eu", "Ed")
+
+    def init_state(self, dtype: Any) -> State:
+        return {"choice_counts": jnp.zeros((self.held + 2,), jnp.float32)}
+
+    def init(self, key: jax.Array, dtype: Any) -> Params:
+        e, d, f = self.held, self.n_in, self.hidden
+        width = self.n_routed_experts + self.zero_expert_num
+        wi = self.weight_init or WeightInit.XAVIER
+        kr, kg, ku, kd = jax.random.split(key, 4)
+
+        def mats(k, rows, cols):
+            return init_weights(k, (e, rows, cols), wi, fan_in=rows,
+                                fan_out=cols, dtype=dtype)
+
+        return {"Wr": init_weights(kr, (d, width), wi, fan_in=d,
+                                   fan_out=width, dtype=dtype),
+                "br": jnp.zeros((width,), dtype),
+                "Eg": mats(kg, d, f), "Eu": mats(ku, d, f),
+                "Ed": mats(kd, f, d)}
+
+    # ---- the share ----------------------------------------------------------
+    def _hidden_act(self, params: Params, eq: str, x: jax.Array) -> jax.Array:
+        f32 = jnp.float32
+        return jax.nn.silu(jnp.einsum(eq, x, params["Eg"],
+                                      preferred_element_type=f32)) \
+            * jnp.einsum(eq, x, params["Eu"], preferred_element_type=f32)
+
+    def _held_dense(self, params: Params, x2, vals, local) -> jax.Array:
+        """Every held expert over every token; a token's weight for an
+        expert it did not choose is nought."""
+        w = jnp.sum(jax.nn.one_hot(local, self.held, dtype=vals.dtype)
+                    * vals[..., None], axis=1)                   # [n, held]
+        h = self._hidden_act(params, "nd,edf->enf", x2) * w.T[:, :, None]
+        return jnp.einsum("enf,efd->nd", h.astype(x2.dtype), params["Ed"],
+                          preferred_element_type=jnp.float32)
+
+    def _held_sorted(self, params: Params, x2, vals, plan) -> jax.Array:
+        """The tokens of each held expert gathered into its
+        ``expert_rows`` slots (the plan granted every choice one)."""
+        xin = gather_dispatch(x2, plan, self.held, self.expert_rows)
+        h = self._hidden_act(params, "emd,edf->emf", xin)
+        out = jnp.einsum("emf,efd->emd", h.astype(x2.dtype), params["Ed"],
+                         preferred_element_type=jnp.float32)
+        return scatter_combine(out, vals, plan, renormalize=False)
+
+    def parts(self, params: Params, x2: jax.Array,
+              token_mask: Optional[jax.Array] = None):
+        """Tokens ``x2 [n, n_in]`` (``token_mask [n]`` marks the real ones)
+        -> ``(the held experts' part [n, n_in], the zero-compute experts'
+        part [n, n_in], counts [n, held + 2])``, the parts float32. The
+        share's result is their sum; over all the shares of a layer the
+        held parts and ONE zero-compute part add up to the uncut layer.
+        The ROUTER is float32 end to end: it takes ``x2`` as it comes (a
+        float32 activation stays one), because a score that moves in its
+        third digit flips a choice; the experts take ``x2`` in their
+        weights' type."""
+        f32 = jnp.float32
+        with jax.named_scope("moe_router"):
+            scores = jax.nn.softmax(_router_logits(x2, params["Wr"]), axis=-1)
+            vals, idx = biased_top_k_routing(
+                scores, params["br"].astype(f32), self.top_k,
+                self.routed_scaling_factor)
+            local, counts = held_expert_choices(
+                idx, self.first_held_expert, self.held,
+                self.n_routed_experts)
+            if token_mask is not None:
+                counts = counts * (token_mask > 0)[:, None].astype(
+                    counts.dtype)
+            zero_w = jnp.sum(jnp.where(idx >= self.n_routed_experts, vals,
+                                       0.0), axis=-1)
+        zero = zero_w[:, None] * x2.astype(f32)
+        x2 = x2.astype(params["Eg"].dtype)
+        with jax.named_scope("moe_experts"):
+            if x2.shape[0] <= self.expert_rows:
+                held = self._held_dense(params, x2, vals, local)
+            else:
+                plan = make_dispatch_plan(local, self.held, self.expert_rows,
+                                          token_mask=token_mask)
+                held = jax.lax.cond(
+                    plan.dropped_tokens == 0,
+                    lambda: self._held_sorted(params, x2, vals, plan),
+                    lambda: self._held_dense(params, x2, vals, local))
+        return held, zero, counts
+
+    def share(self, params: Params, x2: jax.Array,
+              token_mask: Optional[jax.Array] = None):
+        """``(y [n, n_in] float32, counts [n, held + 2])``."""
+        held, zero, counts = self.parts(params, x2, token_mask)
+        return held + zero, counts
+
+    def apply(self, params: Params, state: State, x: jax.Array,
+              ctx: LayerContext) -> Tuple[jax.Array, State]:
+        x = apply_input_dropout(self, x, ctx)
+        recurrent = x.ndim == 3
+        token_mask = None
+        if recurrent:  # [b, f, t] -> tokens [b*t, f]
+            b_, f_, t_ = x.shape
+            x2 = jnp.transpose(x, (0, 2, 1)).reshape(b_ * t_, f_)
+            if ctx.mask is not None:
+                token_mask = jnp.reshape(ctx.mask, (b_ * t_,))
+        else:
+            x2 = x
+        y, counts = self.share(params, x2, token_mask)
+        y = y.astype(x.dtype)
+        if recurrent:
+            y = jnp.transpose(y.reshape(b_, t_, f_), (0, 2, 1))
+        return y, {**state, "choice_counts": jnp.sum(
+            counts, axis=0).astype(jnp.float32)}

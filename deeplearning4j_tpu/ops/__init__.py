@@ -43,10 +43,17 @@ from .grouped_matmul import (
     grouped_matmul_reference,
     set_grouped_matmul_impl,
 )
+from .mla_attention import (
+    mla_decode_attention,
+    mla_decode_attention_pallas,
+    mla_decode_attention_reference,
+)
 from .moe_dispatch import (
     DispatchPlan,
+    biased_top_k_routing,
     combine_rows,
     gather_dispatch,
+    held_expert_choices,
     make_dispatch_plan,
     scatter_combine,
     top_k_routing,
@@ -90,6 +97,11 @@ __all__ = [
     "grouped_matmul_reference",
     "set_grouped_matmul_impl",
     "make_dispatch_plan",
+    "biased_top_k_routing",
+    "held_expert_choices",
+    "mla_decode_attention",
+    "mla_decode_attention_pallas",
+    "mla_decode_attention_reference",
     "pack_row_blocks",
     "paged_cache_write",
     "paged_decode_attention",

@@ -53,6 +53,36 @@ def top_k_routing(gates: jax.Array, top_k: int) -> Tuple[jax.Array, jax.Array]:
     return jax.lax.top_k(gates, top_k)
 
 
+def biased_top_k_routing(scores: jax.Array, bias: jax.Array, top_k: int,
+                          scale: float = 1.0) -> Tuple[jax.Array, jax.Array]:
+    """Route by ``scores + bias`` and weigh by the scores alone: the
+    selection bias of a served router (a load balancer's constant at
+    serving time) moves WHICH experts a token takes, never how much each
+    counts. Returns ``(scale * scores at the chosen [n, k], expert_idx
+    [n, k])``; the weights are not renormalised."""
+    _, idx = top_k_routing(scores + bias, top_k)
+    return scale * jnp.take_along_axis(scores, idx, axis=-1), idx
+
+
+def held_expert_choices(expert_idx: jax.Array, first: int, held: int,
+                        routed: int) -> Tuple[jax.Array, jax.Array]:
+    """Where a chip that holds the routed experts ``first .. first + held``
+    of ``routed`` (the router's outputs from ``routed`` on are zero-compute
+    experts, which every chip has) finds each choice ``[n, k]``: ``(local
+    [n, k], counts [n, held + 2])``. ``local`` is the held expert's index
+    here, or ``held`` (no expert: :func:`make_dispatch_plan` gives such a
+    choice no slot) for a choice that went elsewhere; ``counts`` are the
+    token's choices by where they went: one column a held expert, then the
+    ABSENT routed experts (another chip's), then the zero-compute ones."""
+    local = expert_idx - first
+    here = (local >= 0) & (local < held)
+    zero = expert_idx >= routed
+    local = jnp.where(here, local, held).astype(jnp.int32)
+    kinds = jnp.where(zero, held + 1, local)  # absent stays at ``held``
+    counts = jnp.sum(jax.nn.one_hot(kinds, held + 2, dtype=jnp.int32), axis=1)
+    return local, counts
+
+
 class DispatchPlan(NamedTuple):
     """Static-shape routing plan for one batch of ``n`` tokens.
 

@@ -220,16 +220,19 @@ class _Request:
 class _InFlight:
     """A decode step dispatched and not yet fetched: its tokens on the
     device, the rows it stepped, the request that held each row when it was
-    dispatched (a row whose request has ended since is dropped at the emit)
-    and the time its upload began."""
+    dispatched (a row whose request has ended since is dropped at the emit),
+    the time its upload began, and what the model's layers counted of the
+    step (``{leaf: sums}`` on the device, ``{}`` for a model that counts
+    nothing)."""
 
-    __slots__ = ("toks", "rows", "reqs", "t0")
+    __slots__ = ("toks", "rows", "reqs", "t0", "counts")
 
-    def __init__(self, toks, rows, reqs, t0) -> None:
+    def __init__(self, toks, rows, reqs, t0, counts) -> None:
         self.toks = toks
         self.rows = rows
         self.reqs = reqs
         self.t0 = t0
+        self.counts = counts
 
 
 class DecodeEngine:
@@ -329,6 +332,7 @@ class DecodeEngine:
         # the layers that count the bytes a row's position has made valid
         # (a layer is its configuration: equal layers are counted once)
         self._window = self.session.window
+        self._count_cols = self.session.count_columns()
         self._live_layers = Counter(
             l for l in self.session.model.layers
             if l.decode_live_bytes(0, 1))
@@ -391,7 +395,8 @@ class DecodeEngine:
         self._fresh = np.zeros((self.slots,), bool)
         # the step dispatched and not yet fetched, and the first token of
         # the prefill dispatched and not yet fetched: (slot, request, token
-        # on the device, when its prefill's dispatch began)
+        # on the device, what the layers counted of the prompt, when its
+        # prefill's dispatch began)
         self._flight: Optional[_InFlight] = None
         self._first: Optional[tuple] = None
         self._seeds = np.zeros((self.slots,), np.uint32)
@@ -524,6 +529,18 @@ class DecodeEngine:
             "summaries that later positions attend); prompts' windows are "
             "not counted (the loop.prefill span has them)",
             ("engine",)).labels(inst)
+        self._c_moe_choices = reg.counter(
+            "dl4j_tpu_moe_choices_total",
+            "Expert choices of the tokens a served model's expert layers "
+            "routed (decode steps and prefills, every layer), by where "
+            "they went: held (a routed expert this engine holds: the "
+            "token-expert pairs computed here), absent (a routed expert "
+            "of another chip's share: nothing is computed for it here), "
+            "zero (a zero-compute expert)", ("engine", "kind"))
+        self._c_moe_expert = reg.counter(
+            "dl4j_tpu_moe_expert_tokens_total",
+            "Token-expert pairs each held expert computed, summed over "
+            "the layers (the shape of the load)", ("engine", "expert"))
         self._g_state_bytes = reg.gauge(
             "dl4j_tpu_decode_state_bytes",
             "Bytes of the decode state that the active rows' positions have "
@@ -669,7 +686,9 @@ class DecodeEngine:
                     params, state, sess.decode_state(1), ids, lengths)
                 tok = sample_tokens(last, seed, jnp.zeros((1,), jnp.int32),
                                     gflag, temp, k, p)
-                return new_rnn, tok[0]
+                # what the layers counted of the prompt comes home with
+                # its first token ({} for a model that counts nothing)
+                return new_rnn, tok[0], sess.summed_counts(new_rnn)
 
             # the profiler's "XLA Modules" line shows jit_<name>
             fn.__name__ = f"prefill_{tb}"
@@ -725,9 +744,13 @@ class DecodeEngine:
                 # idle/finished slots must not advance their pos or (h, c);
                 # their cache planes the masked write left as they were
                 with jax.named_scope("freeze_rows"):
+                    counts = sess.summed_counts(new_rnn, active)
                     new_rnn = detach_block_table(
                         freeze_rows(new_rnn, fwd, active, sess.planes))
-                return new_rnn, jnp.where(active, toks, 0).astype(jnp.int32)
+                # the counts come home with the step's tokens ({} for a
+                # model that counts nothing)
+                return (new_rnn, jnp.where(active, toks, 0).astype(jnp.int32),
+                        counts)
 
             self._fns["decode"] = jax.jit(decode_step, donate_argnums=2)
         return self._fns["decode"]
@@ -1063,9 +1086,9 @@ class DecodeEngine:
                 # disaggregated handoff: the prefill tier already ran the
                 # bucketed prefill and sampled the first token — install
                 # its shipped cache slice instead of recomputing
-                row, tok = self._handoff_row(req.prefilled)
+                (row, tok), counts = self._handoff_row(req.prefilled), {}
             else:
-                row, tok = self._prefill_fn(tb)(
+                row, tok, counts = self._prefill_fn(tb)(
                     sess.model.params, sess.model.state, jnp.asarray(ids),
                     jnp.asarray([len(req.prompt)], jnp.int32),
                     jnp.asarray([req.seed], jnp.uint32),
@@ -1114,7 +1137,7 @@ class DecodeEngine:
         self._pos[slot] = len(req.prompt)  # committed cache frontier
         self._spec_caps[slot] = cap
         self._g_active.set(int(self._active.sum()))
-        self._first = (slot, req, tok, t0)
+        self._first = (slot, req, tok, counts, t0)
 
     def _handoff_row(self, h: dict):
         """Rebuild a 1-row target carry from a serialized prefill handoff
@@ -1258,7 +1281,7 @@ class DecodeEngine:
         with span("loop.upload", parent=parent):
             args = self._step_args(rows)
         with span("loop.dispatch", parent=parent):
-            self._carry, self._toks = self._decode_step_fn()(
+            self._carry, self._toks, counts = self._decode_step_fn()(
                 sess.model.params, sess.model.state, self._carry, *args,
                 self._table)
         if self._static_kv:
@@ -1269,7 +1292,7 @@ class DecodeEngine:
         self._fresh[rows] = False
         self._steps[rows] += 1
         self._pos[rows] += 1
-        return _InFlight(self._toks, rows, list(self._requests), t0)
+        return _InFlight(self._toks, rows, list(self._requests), t0, counts)
 
     def _step_args(self, rows: np.ndarray) -> tuple:
         """The decode step's operands after the carry, on the device: the
@@ -1290,6 +1313,8 @@ class DecodeEngine:
         try:
             with span("loop.fetch", parent=parent):
                 toks_h = np.asarray(step.toks)
+                if step.counts:  # the same program's: they are there
+                    self._count(jax.device_get(step.counts))
         except Exception as e:  # noqa: BLE001 — poisoned step: fail active requests
             self._fail_active(e, lost=True)
             return False
@@ -1317,6 +1342,27 @@ class DecodeEngine:
             self._step_hook()
         return True
 
+    def _count(self, counts: dict) -> dict:
+        """What the model's layers counted of one step or one prefill, on
+        the host (``{leaf: sums}`` by the columns the layers declare,
+        ``GenerationSession.count_columns``), into the registry. An expert
+        layer's ``moe_choices`` are its tokens' choices by where they went:
+        ``expert:<id>`` a held expert, ``absent``, ``zero``. Returns what a
+        span says of them."""
+        cols = self._count_cols.get("moe_choices")
+        if cols is None:
+            return {}
+        held = 0
+        for col, n in zip(cols, np.asarray(counts["moe_choices"]).tolist()):
+            kind, _, expert = col.partition(":")
+            if kind == "expert":
+                held += n
+                self._c_moe_expert.labels(self.name, expert).inc(n)
+            else:
+                self._c_moe_choices.labels(self.name, kind).inc(n)
+        self._c_moe_choices.labels(self.name, "held").inc(held)
+        return {"moe_held_pairs": held}
+
     def _land_first(self, parent=NULL_SPAN) -> bool:
         """Fetch and emit, as index 0, the first token of the prefill in
         flight, if any. False when the prefill died at run time: it sat
@@ -1325,11 +1371,18 @@ class DecodeEngine:
         first, self._first = self._first, None
         if first is None:
             return True
-        slot, req, tok, t0 = first
+        slot, req, tok, counts, t0 = first
         span = self.tracer.span
         try:
-            with span("loop.fetch", parent=parent):
+            with span("loop.fetch", parent=parent) as fetch:
                 tok = int(tok)
+                if counts:
+                    # the prompt's counts: the prefill's own span closed at
+                    # its dispatch, so the span that lands them says them
+                    fetch.set_attribute("req", req.seq)
+                    for name, n in self._count(
+                            jax.device_get(counts)).items():
+                        fetch.set_attribute(name, n)
         except Exception as e:  # noqa: BLE001 — poisoned prefill
             self._fail_active(e, lost=True)
             return False

@@ -151,7 +151,7 @@ def _check_inactive_rows(e):
     active = np.asarray([True, False, False, True])
     carry, host = _fill(e, rs, pos)
     sess = e.session
-    new, toks = e._decode_step_fn()(
+    new, toks, _ = e._decode_step_fn()(
         sess.model.params, sess.model.state, carry, *_step_args(e, active),
         e._table)
     assert all(p.is_deleted() for p in _planes(carry))
@@ -275,7 +275,7 @@ def test_lstm_engine_freezes_idle_rows():
         host = jax.tree_util.tree_map(_host, carry)
         active = np.asarray([True, False])
         sess = e.session
-        new, _ = e._decode_step_fn()(
+        new, _, _ = e._decode_step_fn()(
             sess.model.params, sess.model.state, carry,
             *_step_args(e, active), e._table)
         for a, b in zip(jax.tree_util.tree_leaves(new),

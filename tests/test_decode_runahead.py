@@ -236,12 +236,12 @@ def test_a_step_that_dies_at_run_time_fails_at_the_fetch(lm, layout):
 
     def step(params, state, carry, prev, *rest):
         dead = isinstance(prev, _Poisoned)
-        new, toks = real(params, state, carry, prev.toks if dead else prev,
-                         *rest)
+        new, toks, counts = real(params, state, carry,
+                                 prev.toks if dead else prev, *rest)
         if dead or mode["poison"]:
             mode["poison"] = False
-            return new, _Poisoned(toks)
-        return new, toks
+            return new, _Poisoned(toks), counts
+        return new, toks, counts
 
     try:
         want = e.submit([1, 2, 3], max_tokens=5).result(timeout=120)

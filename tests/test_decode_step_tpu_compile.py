@@ -173,3 +173,86 @@ def test_evabyte_step_holds_its_kernels_and_no_loop(eva_programs):
     assert text.count("tpu_custom_call") >= 10
     assert "eva_decode" in text and "kv_cache_write" in text
     assert not re.search(r" while\(", text)
+
+
+# ------------------------------------------------------------------ LongCat
+LC_SLOTS, LC_MAX_LEN, LC_HEADS, LC_WIDE = 128, 2560, 64, 576
+LC_PLANE = rf"bf16\[{LC_SLOTS},1,{LC_MAX_LEN},{LC_WIDE}\]"
+# what the cell's memory reckoning leaves beside 10.35 GB of weights and
+# 3.02 GB of latent planes on a chip of 16.9 GB (ISSUE 34)
+LC_ROOM = 3_500_000_000
+
+
+@pytest.fixture(scope="module")
+def longcat_programs(one_chip):
+    """The latent-attention cell's step, install and largest prefill (the
+    1,024 bucket) at LongCat-Flash's published widths (ISSUE 34: 128 slots x
+    2,560 positions, 64 heads over one 576-wide latent plane an attention
+    block, 16 held experts of 512 beside 256 zero-compute ones; two double
+    layers instead of four), shapes only."""
+    from deeplearning4j_tpu.model.zoo import LongCatFlashLM
+    from deeplearning4j_tpu.nn.sequential import MultiLayerNetwork
+    from deeplearning4j_tpu.obs.metrics import MetricsRegistry
+    from deeplearning4j_tpu.parallel.decode import DecodeEngine
+
+    tm = jax.tree_util.tree_map
+    with jax.enable_x64(False):
+        model = MultiLayerNetwork(LongCatFlashLM(
+            vocab_size=16384, hidden=6144, n_layers=2, n_heads=LC_HEADS,
+            qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+            q_lora_rank=1536, kv_lora_rank=512, ffn_size=12288,
+            expert_ffn_size=2048, n_routed_experts=512, zero_expert_num=256,
+            n_held_experts=16, moe_topk=12, routed_scaling_factor=6.0,
+            dtype="bfloat16").conf())
+        params = jax.eval_shape(lambda: model.init().params)
+        model.params = tm(lambda a: jnp.zeros((), a.dtype), params)
+        model._initialized = True
+        model.state = jax.eval_shape(lambda: model.init().state)
+        model._persistent_keys = {n: () for n in model.layer_names()}
+        out = _compile_step_and_install(model, params, LC_SLOTS, LC_MAX_LEN,
+                                        one_chip)
+
+        def spec(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        eng = DecodeEngine(model, max_len=LC_MAX_LEN, slots=1,
+                           registry=MetricsRegistry())
+        try:
+            with pytest.MonkeyPatch.context() as mp:
+                mp.setattr(jax, "default_backend", lambda: "tpu")
+                c = eng._prefill_fn(1024).lower(
+                    tm(lambda a: spec(a.shape, a.dtype), params),
+                    tm(lambda a: spec(a.shape, a.dtype), model.state),
+                    spec((1, 1024), jnp.int32), spec((1,), jnp.int32),
+                    spec((1,), jnp.uint32), spec((1,), jnp.bool_),
+                    spec((1,), jnp.float32), spec((1,), jnp.int32),
+                    spec((1,), jnp.float32)).compile()
+            out["prefill_1024"] = (c.as_text(), c.memory_analysis(), 0)
+        finally:
+            eng.shutdown(drain=False)
+        return out
+
+
+@pytest.mark.parametrize("name", ["decode_step", "install_row"])
+def test_longcat_latent_planes_are_aliased_and_none_is_copied(
+        longcat_programs, name):
+    text, ma, planes = longcat_programs[name]
+    # two layers of two planes: 4 x 128 x 2,560 x 576 x 2 B = 1.51 GB
+    assert planes == 4 * LC_SLOTS * LC_MAX_LEN * LC_WIDE * 2
+    assert ma.alias_size_in_bytes >= planes, (ma.alias_size_in_bytes, planes)
+    lines = text.splitlines()
+    for op in ("copy", "select", "transpose"):
+        hits = [l[:160] for l in lines
+                if re.search(rf"= {LC_PLANE}\S* {op}\(", l)]
+        assert not hits, hits[:3]
+    # the step attends the latent itself: no key or value a head and position
+    wide = [l[:160] for l in lines if re.search(
+        rf"\[{LC_SLOTS},{LC_HEADS},{LC_MAX_LEN},\d+\]", l)]
+    assert not wide, wide[:3]
+
+
+@pytest.mark.parametrize("name", ["decode_step", "prefill_1024"])
+def test_longcat_temporaries_fit_beside_weights_and_planes(longcat_programs,
+                                                           name):
+    ma = longcat_programs[name][1]
+    assert ma.temp_size_in_bytes < LC_ROOM, ma.temp_size_in_bytes

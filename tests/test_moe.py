@@ -397,3 +397,128 @@ def test_pre_pr3_state_migrates_in_make_servable():
         assert "expert_tokens" in net.state[name]
     finally:
         pi.shutdown(drain=False)
+
+
+# ---------------------------------------------------------------------------
+# ExpertShareMoELayer: one chip's share of an expert layer (ISSUE 34)
+# ---------------------------------------------------------------------------
+def _share_layer(first=0, held=0, rows=128, zero=8, k=4):
+    from deeplearning4j_tpu.nn.layers import ExpertShareMoELayer
+
+    return ExpertShareMoELayer(
+        n_in=16, hidden=8, n_routed_experts=16, zero_expert_num=zero,
+        n_held_experts=held, first_held_expert=first, top_k=k,
+        routed_scaling_factor=6.0, expert_rows=rows)
+
+
+def _share_params(seed=0):
+    """The uncut layer's parameters (16 experts), the router spread so that
+    scores differ, the selection bias of the order of a score."""
+    p = _share_layer().init(jax.random.PRNGKey(seed), jnp.float32)
+    k1, k2 = jax.random.split(jax.random.PRNGKey(seed + 1))
+    p["Wr"] = jax.random.normal(k1, p["Wr"].shape, jnp.float32) * 0.4
+    p["br"] = jax.random.normal(k2, p["br"].shape, jnp.float32) * 0.03
+    return p
+
+
+def _cut(p, first, held):
+    return {**p, **{n: p[n][first:first + held] for n in ("Eg", "Eu", "Ed")}}
+
+
+def _by_hand(p, x, k=4, scale=6.0, routed=16):
+    """Token by token, expert by expert, in NumPy float64: softmax, the
+    choice by s + b, the weight scale * s unnormalised, an identity expert
+    from ``routed`` on."""
+    p = {n: np.asarray(v, np.float64) for n, v in p.items()}
+    out = np.zeros(x.shape)
+    for t, u in enumerate(np.asarray(x, np.float64)):
+        z = u @ p["Wr"]
+        s = np.exp(z - z.max())
+        s /= s.sum()
+        for e in np.argsort(-(s + p["br"]), kind="stable")[:k]:
+            if e >= routed:
+                y = u
+            else:
+                g = u @ p["Eg"][e]
+                y = (g / (1 + np.exp(-g)) * (u @ p["Eu"][e])) @ p["Ed"][e]
+            out[t] += scale * s[e] * y
+    return out
+
+
+@pytest.mark.parametrize("rows", [128, 4], ids=["dense", "sorted"])
+def test_share_uses_the_bias_for_the_choice_and_not_for_the_weight(rows):
+    """The uncut layer against a hand computation: the choice by ``s + b``,
+    the weight ``6 s`` unnormalised, zero-compute experts return their
+    input. float32 against float64: 1e-5 of values of the order of 1."""
+    p = _share_params()
+    x = jax.random.normal(jax.random.PRNGKey(9), (24, 16), jnp.float32)
+    y, counts = _share_layer(rows=rows).share(p, x)
+    want = _by_hand(p, x)
+    assert np.abs(want).max() > 0.3
+    np.testing.assert_allclose(np.asarray(y), want, atol=1e-5, rtol=0)
+    assert np.asarray(counts).sum(axis=1).tolist() == [4] * 24
+    # the bias moved some choice: without it the layer gives another result
+    y0, _ = _share_layer(rows=rows).share({**p, "br": 0 * p["br"]}, x)
+    assert np.abs(np.asarray(y0) - want).max() > 1e-3
+    # and a renormalised weight would be another result too
+    assert np.abs(_by_hand(p, x, scale=1.0) * 6 - want).max() < 1e-12
+
+
+@pytest.mark.parametrize("rows", [128, 4], ids=["dense", "sorted"])
+def test_the_shares_of_a_layer_add_up_to_the_uncut_layer(rows):
+    """Four chips hold four of the 16 routed experts each. The parts their
+    held experts give, and the zero-compute experts' part (which every chip
+    computes alike) counted ONCE, add up to the uncut layer; each share's
+    counts say where the same choices went."""
+    p = _share_params(3)
+    x = jax.random.normal(jax.random.PRNGKey(5), (24, 16), jnp.float32)
+    whole, whole_counts = _share_layer(rows=rows).share(p, x)
+    total, zero = 0.0, None
+    for first in (0, 4, 8, 12):
+        layer = _share_layer(first=first, held=4, rows=rows)
+        held, z, counts = layer.parts(_cut(p, first, 4), x)
+        total = total + held
+        if zero is None:
+            zero = z
+        np.testing.assert_array_equal(np.asarray(z), np.asarray(zero))
+        counts, wc = np.asarray(counts), np.asarray(whole_counts)
+        np.testing.assert_array_equal(counts[:, :4],
+                                      wc[:, first:first + 4])
+        np.testing.assert_array_equal(counts[:, 5], wc[:, 17])  # zero
+        np.testing.assert_array_equal(
+            counts[:, 4], wc[:, :16].sum(1) - counts[:, :4].sum(1))
+    np.testing.assert_allclose(np.asarray(total + zero), np.asarray(whole),
+                               atol=2e-6, rtol=0)
+    assert np.abs(np.asarray(zero)).max() > 0.05  # some token chose one
+
+
+def test_no_token_is_dropped_when_every_row_chooses_one_held_expert():
+    """The worst load: a selection bias sends every one of 40 tokens to
+    held expert 5 (and to three zero-compute experts), ten times the
+    ``expert_rows`` the sorted form grants: the call takes the other form,
+    and every token gets the expert's part."""
+    p = _share_params(7)
+    br = np.full((24,), -1.0, np.float32)
+    br[[5, 16, 17, 18]] = 1.0
+    p["br"] = jnp.asarray(br)
+    x = jax.random.normal(jax.random.PRNGKey(2), (40, 16), jnp.float32)
+    layer = _share_layer(first=4, held=4, rows=4)
+    held, zero, counts = layer.parts(_cut(p, 4, 4), x)
+    assert np.asarray(counts).sum(0).tolist() == [0, 40, 0, 0, 0, 120]
+    want = _by_hand(p, x)
+    np.testing.assert_allclose(np.asarray(held + zero), want, atol=1e-5,
+                               rtol=0)
+    assert (np.abs(np.asarray(held)).max(axis=1) > 0).all()
+
+
+def test_share_layer_in_a_network_and_its_state():
+    """As a sequential layer over ``[b, f, t]`` with a mask: padded tokens
+    are counted nowhere."""
+    layer = _share_layer(first=0, held=4)
+    p = _cut(_share_params(), 0, 4)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, 6), jnp.float32)
+    mask = jnp.asarray([[1, 1, 1, 1, 0, 0], [1, 1, 1, 1, 1, 1]], jnp.float32)
+    y, st = layer.apply(p, layer.init_state(jnp.float32), x,
+                        LayerContext(train=False, rng=None, mask=mask))
+    assert y.shape == x.shape
+    assert float(st["choice_counts"].sum()) == 10 * 4

@@ -402,3 +402,35 @@ def test_bench_tool_smoke(capsys):
     assert row["einsum_grad_step_ms"] > 0
     assert row["grouped_grad_step_ms"] > 0
     assert row["grouped_max_abs_output_diff"] == 0.0
+
+
+# ---------------------------------------------------------------------------
+# routing of a served share (ISSUE 34)
+# ---------------------------------------------------------------------------
+def test_biased_routing_chooses_by_the_sum_and_weighs_by_the_score():
+    from deeplearning4j_tpu.ops import biased_top_k_routing
+
+    scores = jnp.asarray([[0.5, 0.3, 0.15, 0.05]])
+    bias = jnp.asarray([0.0, -0.2, 0.0, 0.2])
+    vals, idx = biased_top_k_routing(scores, bias, 2, scale=6.0)
+    assert idx.tolist() == [[0, 3]]  # 0.5, then 0.05 + 0.2 over 0.15
+    np.testing.assert_allclose(np.asarray(vals), [[3.0, 0.3]], rtol=1e-6)
+    plain, pidx = biased_top_k_routing(scores, 0 * bias, 2)
+    assert pidx.tolist() == [[0, 1]] and plain.tolist() == [[0.5, 0.3]]
+
+
+def test_held_expert_choices_sort_choices_by_where_they_went():
+    """Experts 4..7 of 16 routed are held; outputs 16.. are zero-compute."""
+    from deeplearning4j_tpu.ops import held_expert_choices
+
+    idx = jnp.asarray([[4, 7, 0, 16], [5, 5, 23, 15], [3, 8, 9, 10]])
+    local, counts = held_expert_choices(idx, first=4, held=4, routed=16)
+    assert local.tolist() == [[0, 3, 4, 4], [1, 1, 4, 4], [4, 4, 4, 4]]
+    #                     held 4  5  6  7  absent zero
+    assert counts.tolist() == [[1, 0, 0, 1, 1, 1],
+                               [0, 2, 0, 0, 1, 1],
+                               [0, 0, 0, 0, 4, 0]]
+    # a choice that went elsewhere claims no slot in the dispatch plan
+    plan = make_dispatch_plan(local, 4, 2)
+    assert plan.expert_tokens.tolist() == [1, 2, 0, 1]
+    assert int(plan.dropped_tokens) == 0

@@ -17,6 +17,7 @@ from deeplearning4j_tpu.ops.eva_attention import eva_decode_attention_pallas
 from deeplearning4j_tpu.ops.flash_attention import (
     flash_attention, flash_decode_attention, flash_masked_cache_write)
 from deeplearning4j_tpu.ops.grouped_matmul import _gmm, _tiling
+from deeplearning4j_tpu.ops.mla_attention import mla_decode_attention_pallas
 
 DTYPES = [jnp.bfloat16, jnp.float32]
 
@@ -83,8 +84,22 @@ def test_eva_decode_lowers(dtype):
     assert names == ["eva_decode"]
 
 
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_mla_decode_lowers(dtype):
+    """LongCat-Flash's step at its published widths: 128 rows, 64 heads
+    over one plane of 2,560 entries of 512 + 64 numbers."""
+    b, h, L, rank, rope = 128, 64, 2560, 512, 64
+    names = _kernels(
+        lambda q, plane, n: mla_decode_attention_pallas(
+            q, plane, n, rank, 192 ** -0.5, interpret=False),
+        _spec(b, h, rank + rope, dtype=dtype),
+        _spec(b, 1, L, rank + rope, dtype=dtype), _spec(b, dtype=jnp.int32))
+    assert names == ["mla_decode"]
+
+
 @pytest.mark.parametrize("shape", [(8, 12, 1024, 64), (8, 12, 1024),
-                                   (8, 12, 600, 64), (16, 32, 4096, 128)])
+                                   (8, 12, 600, 64), (16, 32, 4096, 128),
+                                   (128, 1, 2560, 576)])
 @pytest.mark.parametrize("dtype", DTYPES + [jnp.int8])
 def test_kv_cache_write_lowers(dtype, shape):
     new = shape[:2] + (1,) + shape[3:]

@@ -155,3 +155,34 @@ def test_flash_unaligned_length_compiled(tpu_device, causal):
     assert _rel(out, rout) < FWD_TOL
     for name, a, r in zip("qkv", grads, rgrads):
         assert _rel(a, r) < GRAD_TOL, f"d{name}"
+
+
+@pytest.mark.parametrize("b,block_k", [(8, 512), (8, 256), (128, 512)])
+def test_mla_decode_compiled_matches_reference(tpu_device, b, block_k):
+    """LongCat-Flash's latent plane at its published widths: 64 heads over
+    one plane of 2,560 entries of 512 + 64 numbers (128 rows: the cell's
+    step). A row of length 0 attends nothing; what lies past a row's length
+    may be anything, NaN included."""
+    from deeplearning4j_tpu.ops.mla_attention import (
+        mla_decode_attention_pallas, mla_decode_attention_reference)
+
+    h, L, rank, w = 64, 2560, 512, 576
+    q, plane = _rand(0, b, h, w), _rand(1, b, 1, L, w)
+    if b == 8:
+        n = np.asarray([1, 512, 513, L, 0, 6, L // 3, L - 1])
+    else:
+        n = np.random.RandomState(11).permutation(
+            np.linspace(65, 2400, b).astype(np.int32))
+        n[4] = 0
+    lengths = jnp.asarray(n, jnp.int32)
+    run = jax.jit(lambda q, p, n: mla_decode_attention_pallas(
+        q, p, n, rank, 192 ** -0.5, block_k=block_k, interpret=False))
+    got = run(q, plane, lengths)
+    ref = _highest(lambda q, p, n: mla_decode_attention_reference(
+        q, p, n, rank, 192 ** -0.5), q, plane, lengths)
+    assert _rel(got, ref) < FWD_TOL
+    assert not np.asarray(got, np.float32)[4].any()
+    stale = np.arange(L)[None, :] >= n[:, None]               # [b, L]
+    again = run(q, jnp.where(stale[:, None, :, None], jnp.nan, plane),
+                lengths)
+    assert (np.asarray(again, np.float32) == np.asarray(got, np.float32)).all()
