@@ -19,10 +19,32 @@ token tied with the k-th largest logit (the support may exceed k on
 ties); ``top_p`` keeps the smallest prefix of the sorted distribution
 whose cumulative mass reaches ``p``, including the token that crosses
 the threshold, plus any tokens tied with the last kept probability.
+
+Cost contract: :func:`sample_tokens` decides once per BATCH, on the device
+and inside the one compiled program, which work its rows' specs ask for
+(:func:`sampler_path`), and runs only that:
+
+* ``argmax`` -- every row greedy: one argmax of the logits. No temperature
+  divide, no sort, no softmax or cumulative sum, no key and no noise.
+* ``sample`` -- some row samples and no sampling row truncates (``k > 0`` or
+  ``p < 1``): the temperature scale and one Gumbel draw a row, no sort.
+* ``sort`` -- some sampling row truncates: ONE descending sort of the scaled
+  logits serves top-k (its k-th value is the threshold) and top-p (masking
+  below that threshold keeps the order, so the sorted probabilities and
+  their cumulative mass come from the same array).
+
+A greedy row's ``k`` and ``p`` hold nobody on the sort path, and a mixed
+batch takes the costliest path any of its sampling rows asks for. The
+choice is one ``lax.switch`` outside the ``vmap`` over rows (a ``where`` or
+a ``cond`` on a per-row flag under ``vmap`` evaluates both sides), and each
+arm hands back the tokens alone, so the arms share their temporaries.
+:func:`speculative_accept` reads its distributions through the same single
+sort, for every batch.
 """
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Optional
 
 import jax
@@ -46,25 +68,34 @@ def temperature(logits: jax.Array, key: jax.Array, temp: float = 1.0) -> jax.Arr
                                   axis=-1).astype(jnp.int32)
 
 
-def _top_k_logits(z: jax.Array, k) -> jax.Array:
-    v = z.shape[-1]
-    kk = jnp.clip(jnp.asarray(k, jnp.int32), 1, v)
-    sorted_z = jnp.sort(z, axis=-1)[..., ::-1]
+def _sort_desc(z: jax.Array) -> jax.Array:
+    # values alone, so not stable: a stable sort carries an index beside
+    # every value, to no end (equal values are equal wherever they land)
+    axis = z.ndim - 1
+    return jax.lax.rev(jax.lax.sort(z, dimension=axis, is_stable=False),
+                       (axis,))
+
+
+def _keep_top_k(z: jax.Array, sz: jax.Array, k):
+    """``z`` and its descending sort ``sz``, both masked below the k-th
+    largest value: the masked ``sz`` is still the descending sort of the
+    masked ``z``."""
+    kk = jnp.clip(jnp.asarray(k, jnp.int32), 1, z.shape[-1])
     thr = jnp.take_along_axis(
-        sorted_z, jnp.broadcast_to(kk - 1, z.shape[:-1])[..., None], axis=-1)
-    return jnp.where(z >= thr, z, _NEG)
+        sz, jnp.broadcast_to(kk - 1, z.shape[:-1])[..., None], axis=-1)
+    return jnp.where(z >= thr, z, _NEG), jnp.where(sz >= thr, sz, _NEG)
 
 
-def top_k(logits: jax.Array, key: jax.Array, k: int,
-          temp: float = 1.0) -> jax.Array:
-    """Sample among the k highest-logit tokens (ties at the k-th kept)."""
-    return jax.random.categorical(
-        key, _top_k_logits(_scaled(logits, temp), k), axis=-1).astype(jnp.int32)
-
-
-def _top_p_logits(z: jax.Array, p) -> jax.Array:
-    probs = jax.nn.softmax(z, axis=-1)
-    sp = jnp.sort(probs, axis=-1)[..., ::-1]
+def _keep_top_p(z: jax.Array, sz: jax.Array, p) -> jax.Array:
+    """``z`` masked outside its nucleus, given its descending sort ``sz``.
+    One shift and one normaliser make ``z``'s probabilities and ``sz``'s by
+    the same elementwise steps, so the latter ARE the former sorted, bit
+    for bit."""
+    shift = sz[..., :1]
+    unnorm = jnp.exp(z - shift)
+    total = jnp.sum(unnorm, axis=-1, keepdims=True)
+    probs = unnorm / total
+    sp = jnp.exp(sz - shift) / total
     cs = jnp.cumsum(sp, axis=-1)
     # keep while the mass BEFORE this token is < p (always keeps the top-1,
     # includes the token that crosses the threshold)
@@ -73,34 +104,66 @@ def _top_p_logits(z: jax.Array, p) -> jax.Array:
     return jnp.where(probs >= thr, z, _NEG)
 
 
+def top_k(logits: jax.Array, key: jax.Array, k: int,
+          temp: float = 1.0) -> jax.Array:
+    """Sample among the k highest-logit tokens (ties at the k-th kept)."""
+    z = _scaled(logits, temp)
+    return jax.random.categorical(
+        key, _keep_top_k(z, _sort_desc(z), k)[0], axis=-1).astype(jnp.int32)
+
+
 def top_p(logits: jax.Array, key: jax.Array, p: float,
           temp: float = 1.0) -> jax.Array:
     """Nucleus sampling: smallest prefix of the sorted distribution with
     cumulative probability >= p."""
+    z = _scaled(logits, temp)
     return jax.random.categorical(
-        key, _top_p_logits(_scaled(logits, temp), p), axis=-1).astype(jnp.int32)
+        key, _keep_top_p(z, _sort_desc(z), p), axis=-1).astype(jnp.int32)
+
+
+# what a batch's specs ask of the sampler, cheapest first (module docstring)
+PATHS = ("argmax", "sample", "sort")
+
+
+def sampler_path(greedy_mask, k, p):
+    """Index into :data:`PATHS` of the work a batch's specs ask for, from
+    its sampling rows alone. Takes numpy or jax arrays (bool, int, float,
+    one entry a row): the device's branch and the engine's host-side count
+    of it are this one expression."""
+    sampling = ~greedy_mask
+    truncating = sampling & ((k > 0) | (p < 1.0))
+    return (sampling.any().astype("int32")
+            + truncating.any().astype("int32"))
+
+
+def _scale_only(logits, temp, k, p):
+    return _scaled(logits.astype(jnp.float32), temp)
 
 
 def _warp(logits, temp, k, p):
     """Shared logits warping: temperature scale, then top-k, then top-p
-    over the surviving support (``k == 0`` and ``p >= 1`` disable)."""
-    z = _scaled(logits.astype(jnp.float32), temp)
-    z = jnp.where(k > 0, _top_k_logits(z, jnp.maximum(k, 1)), z)
-    z = jnp.where(p < 1.0, _top_p_logits(z, jnp.clip(p, 1e-6, 1.0)), z)
-    return z
+    over the surviving support (``k == 0`` and ``p >= 1`` disable), both
+    read from ONE descending sort of the scaled logits."""
+    z = _scale_only(logits, temp, k, p)
+    sz = _sort_desc(z)
+    zk, szk = _keep_top_k(z, sz, jnp.maximum(k, 1))
+    z, sz = jnp.where(k > 0, zk, z), jnp.where(k > 0, szk, sz)
+    return jnp.where(p < 1.0, _keep_top_p(z, sz, jnp.clip(p, 1e-6, 1.0)), z)
 
 
-def _sample_one(logits, seed, step, greedy_flag, temp, k, p):
-    """One row of the batched engine sampler. ``k == 0`` disables top-k,
-    ``p >= 1`` disables top-p; both compose (top-k first, then top-p over
-    the surviving support). Keyed by fold_in(PRNGKey(seed), step) so the
-    stream depends only on (seed, position), never on batch composition."""
+def _sample_one(warp, logits, seed, step, greedy_flag, temp, k, p):
+    """One row of the batched engine sampler, its logits warped by ``warp``
+    (:func:`_warp`, or :func:`_scale_only` for a batch in which no sampling
+    row truncates). ``k == 0`` disables top-k, ``p >= 1`` disables top-p;
+    both compose (top-k first, then top-p over the surviving support).
+    Keyed by fold_in(PRNGKey(seed), step) so the stream depends only on
+    (seed, position), never on batch composition."""
     key = jax.random.fold_in(jax.random.PRNGKey(seed.astype(jnp.uint32)), step)
-    z = _warp(logits, temp, k, p)
-    sampled = jax.random.categorical(key, z)
+    sampled = jax.random.categorical(key, warp(logits, temp, k, p))
     return jnp.where(greedy_flag, jnp.argmax(logits), sampled).astype(jnp.int32)
 
 
+@jax.jit  # an eager switch would trace, and compile, its arms at every call
 def sample_tokens(
     logits: jax.Array,       # [B, V]
     seeds: jax.Array,        # [B] uint32 per-request seed
@@ -113,11 +176,24 @@ def sample_tokens(
     """Batched per-row sampler for the continuous-batching decode engine:
     every row carries its own sampling spec, so requests with different
     (greedy/temperature/top-k/top-p, seed) settings share one compiled
-    decode step."""
-    return jax.vmap(_sample_one)(logits, seeds.astype(jnp.uint32),
-                                 steps.astype(jnp.int32), greedy_mask,
-                                 temp.astype(jnp.float32),
-                                 k.astype(jnp.int32), p.astype(jnp.float32))
+    decode step, whose cost follows what the batch's specs ask for (the
+    module's cost contract)."""
+    # float32 BEFORE the branch: what an arm is handed is materialised as
+    # written, and bfloat16 log-probabilities tie at the top (on the chip
+    # XLA's excess precision keeps the producer's float32 up to here)
+    logits = logits.astype(jnp.float32)
+    greedy_mask = greedy_mask.astype(bool)
+    k, p = k.astype(jnp.int32), p.astype(jnp.float32)
+
+    def rows(warp):
+        return lambda: jax.vmap(partial(_sample_one, warp))(
+            logits, seeds.astype(jnp.uint32), steps.astype(jnp.int32),
+            greedy_mask, temp.astype(jnp.float32), k, p)
+
+    # one arm a path, in PATHS' order; each hands back [B] tokens only
+    return jax.lax.switch(
+        sampler_path(greedy_mask, k, p),
+        (lambda: greedy(logits), rows(_scale_only), rows(_warp)))
 
 
 # ---------------------------------------------------------------------------

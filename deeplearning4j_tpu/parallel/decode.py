@@ -96,7 +96,7 @@ from ..generate.paged import (
     mask_inactive_writes,
     paged_decode_state,
 )
-from ..generate.sampling import sample_tokens
+from ..generate.sampling import PATHS, sample_tokens, sampler_path
 from ..generate.session import GenerationSession, SpeculativeGenerationSession
 from ..ops.flash_attention import decode_fetched_entries
 from ..ops.paged_attention import pack_row_blocks
@@ -522,6 +522,16 @@ class DecodeEngine:
             "(ops.flash_attention.decode_fetched_entries); attended over "
             "fetched is the share of the kernel's bytes that are valid",
             ("engine",)).labels(inst)
+        sampler = reg.counter(
+            "dl4j_tpu_decode_sampler_steps_total",
+            "Decode steps dispatched, by the work their rows' sampling "
+            "specs asked of the sampler (generate.sampling.sampler_path, "
+            "the expression the step's program branches on, over the "
+            "arrays the step uploads): argmax (every active row greedy: "
+            "no sort, no noise), sample (a temperature draw, no sort), "
+            "sort (a sampling row has top-k or top-p: one sort over the "
+            "vocabulary)", ("engine", "path"))
+        self._c_sampler = [sampler.labels(inst, path) for path in PATHS]
         self._c_windows = reg.counter(
             "dl4j_tpu_decode_windows_closed_total",
             "Windows that decoding rows closed (a row's position reached a "
@@ -738,9 +748,13 @@ class DecodeEngine:
                         params, state, sess._prep(tokens[:, None]), None, fwd)
                 with jax.named_scope("logits"):
                     logits = sess._logits(out, params)[:, :, 0]
+                # a slot keeps its last request's spec after it ends: an
+                # inactive row is greedy to the sampler (its token is
+                # zeroed below), so a sampled request that finished holds
+                # no later batch on the sort path
                 with jax.named_scope("sample"):
-                    toks = sample_tokens(logits, seeds, steps, gmask, temps,
-                                         ks, ps)
+                    toks = sample_tokens(logits, seeds, steps,
+                                         gmask | ~active, temps, ks, ps)
                 # idle/finished slots must not advance their pos or (h, c);
                 # their cache planes the masked write left as they were
                 with jax.named_scope("freeze_rows"):
@@ -1284,6 +1298,8 @@ class DecodeEngine:
             self._carry, self._toks, counts = self._decode_step_fn()(
                 sess.model.params, sess.model.state, self._carry, *args,
                 self._table)
+        self._c_sampler[int(sampler_path(
+            self._greedy | ~rows, self._ks, self._ps))].inc()
         if self._static_kv:
             lengths = self._pos[rows] + 1
             self._c_kv_attended.inc(int(lengths.sum()))
@@ -1630,6 +1646,7 @@ class DecodeEngine:
         accepted = int(self._c_spec_accepted.value)
         spec_steps = int(self._c_spec_steps.value)
         kv_fetched = self._c_kv_fetched.value
+        sampler_steps = [c.value for c in self._c_sampler]
         counts.update({
             "in_flight": self._admission.pending,
             # the engine-list aggregation key health()/pools sum over
@@ -1657,6 +1674,10 @@ class DecodeEngine:
             "kv_fetch_valid_share": (
                 self._c_kv_attended.value / kv_fetched if kv_fetched
                 else None),
+            # of the plain steps dispatched, the share whose sampler sorted
+            "sampler_sort_share": (
+                sampler_steps[PATHS.index("sort")] / sum(sampler_steps)
+                if any(sampler_steps) else None),
             "draining": self._draining,
             # zero-guarded (PR-7 convention): derived ratios are None, not
             # 0.0, before any speculative traffic

@@ -125,6 +125,64 @@ def test_the_step_holds_its_kernels_and_no_loop(programs):
     assert not re.search(r" while\(", text)
 
 
+# what PERF.md section 4 gives the step for temporaries (GB 0.049). At this
+# file's two blocks the parent of ISSUE 35 read 30,271,488 (two stable sorts
+# of f32[128, 50257], each beside an index plane) and the branching sampler
+# reads 38,231,552 (its arms take the logits as float32, 25.7 MB, so that no
+# bfloat16 rounding becomes real at the branch); at the cell's twelve blocks
+# 57,746,432 and 57,972,224
+STEP_TEMP_ROOM = 49_000_000
+
+
+def _computations(text):
+    """name -> lines of each computation of a compiled module's text."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        m = re.match(r"(?:ENTRY )?%([\w.\-]+) \(", line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    return comps
+
+
+def test_the_step_sorts_once_and_only_inside_a_branch(programs):
+    """The sampler branches on its batch's specs INSIDE the step: the one
+    sort over the vocabulary is in a computation that only a
+    ``conditional``'s branch reaches, so an all-greedy step never runs it;
+    the arms hand back tokens alone and share their temporaries; and what
+    they are handed is float32 (ISSUE 35)."""
+    text, ma, _ = programs["decode_step"]
+    comps = _computations(text)
+    called = {name: {c for l in lines for c in re.findall(r"%([\w.\-]+)", l)
+                     if c in comps}
+              for name, lines in comps.items()}
+    branches = {c.strip().lstrip("%")
+                for l in text.splitlines() if " conditional(" in l
+                for group in re.findall(r"branch_computations=\{([^}]*)\}", l)
+                for c in group.split(",")}
+    assert len(branches) == 3, branches  # argmax, sample, sort
+    reach, todo = set(), list(branches)
+    while todo:
+        c = todo.pop()
+        if c not in reach:
+            reach.add(c)
+            todo.extend(called[c])
+    sorts = [name for name, lines in comps.items()
+             for l in lines if re.search(r" sort\(", l)]
+    assert len(sorts) == 1 and sorts[0] in reach, sorts
+    (cond,) = [l for l in text.splitlines() if " conditional(" in l]
+    assert re.search(r"= \(s32\[128\]\S*\) conditional\(", cond), cond[:200]
+    operands = re.findall(r"%(tuple[\w.\-]*)", cond.split("conditional(")[1])
+    handed = [l for l in text.splitlines()
+              if re.match(rf"\s*%({'|'.join(map(re.escape, operands))}) = ", l)]
+    assert handed and not [l[:200] for l in handed
+                           if "bf16[128,50257]" in l], handed
+    assert ma.temp_size_in_bytes <= STEP_TEMP_ROOM, ma.temp_size_in_bytes
+
+
 # ------------------------------------------------------------------ EvaByte
 EVA_SLOTS, EVA_MAX_LEN = 16, 32768
 EVA_PLANE = rf"bf16\[{EVA_SLOTS},32,4096,128\]"

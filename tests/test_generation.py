@@ -58,6 +58,112 @@ def _full_greedy(model, prompt, n, vocab, max_len, one_hot=False):
 # sampling
 # ---------------------------------------------------------------------------
 
+_NEG = -1e30
+
+
+def _ref_top_k(z, k):
+    kk = jnp.clip(jnp.asarray(k, jnp.int32), 1, z.shape[-1])
+    thr = jnp.sort(z)[::-1][kk - 1]
+    return jnp.where(z >= thr, z, _NEG)
+
+
+def _ref_top_p(z, p):
+    probs = jax.nn.softmax(z)
+    sp = jnp.sort(probs)[::-1]
+    cs = jnp.cumsum(sp)
+    thr = jnp.min(jnp.where((cs - sp) < jnp.asarray(p, probs.dtype), sp,
+                            jnp.inf))
+    return jnp.where(probs >= thr, z, _NEG)
+
+
+def _ref_sample_one(logits, seed, step, greedy_flag, temp, k, p):
+    """The plain per-row sampler ``sample_tokens`` is held to: every row
+    pays for everything (two sorts, a softmax, a cumulative sum, a draw)
+    and a ``where`` picks what its spec asked for."""
+    key = jax.random.fold_in(jax.random.PRNGKey(seed.astype(jnp.uint32)), step)
+    z = logits.astype(jnp.float32) / jnp.maximum(temp, 1e-6)
+    z = jnp.where(k > 0, _ref_top_k(z, jnp.maximum(k, 1)), z)
+    z = jnp.where(p < 1.0, _ref_top_p(z, jnp.clip(p, 1e-6, 1.0)), z)
+    sampled = jax.random.categorical(key, z)
+    return jnp.where(greedy_flag, jnp.argmax(logits), sampled).astype(jnp.int32)
+
+
+def _spec_vectors(greedy, temp, k, p):
+    return (jnp.asarray(greedy, bool), jnp.asarray(temp, jnp.float32),
+            jnp.asarray(k, jnp.int32), jnp.asarray(p, jnp.float32))
+
+
+_V = 48
+# logits with ties: row 0's k-th largest value (k = 4) stands three times
+_TIED = np.random.RandomState(11).randn(4, _V).astype(np.float32)
+_TIED[0, :6] = [5.0, 4.0, 3.0, 2.0, 2.0, 2.0]
+_TIED[0, 6:] = np.minimum(_TIED[0, 6:], 1.0)
+# one token carries 0.6 of the mass and three 0.1 each: p = 0.7 is crossed
+# by the second, whose probability the two after it tie
+_CROSS = np.full((4, _V), -30.0, np.float32)
+_CROSS[:, :4] = np.log([0.6, 0.1, 0.1, 0.1])
+_CROSS[:, 4:] += np.random.RandomState(12).randn(4, _V - 4)
+
+# id -> (logits or None for random, greedy, temp, k, p), a row an entry
+_SPEC_GRID = {
+    "all-greedy": (None, [True] * 4, [1.0] * 4, [0] * 4, [1.0] * 4),
+    "greedy-and-sampling-mixed": (
+        None, [True, False, True, False], [1.0, 0.8, 1.0, 1.2],
+        [0, 5, 0, 0], [1.0, 1.0, 1.0, 0.9]),
+    "temperature-only": (None, [False] * 4, [0.5, 0.8, 1.0, 1.7],
+                         [0] * 4, [1.0] * 4),
+    "top-k-only": (None, [False] * 4, [1.0] * 4, [1, 3, 5, 40], [1.0] * 4),
+    "top-p-only": (None, [False] * 4, [1.0] * 4, [0] * 4,
+                   [0.1, 0.5, 0.9, 0.99]),
+    "top-k-and-top-p": (None, [False] * 4, [0.7, 1.0, 1.3, 1.0],
+                        [3, 10, 5, 40], [0.9, 0.5, 0.99, 0.3]),
+    "ties-at-the-kth-logit": (_TIED, [False] * 4, [1.0] * 4, [4, 4, 2, 6],
+                              [1.0, 0.9, 1.0, 1.0]),
+    "the-token-that-crosses-p": (_CROSS, [False] * 4, [1.0] * 4,
+                                 [0, 0, 3, 0], [0.7, 0.6, 0.7, 0.65]),
+    "k-at-least-vocab": (None, [False] * 4, [1.0] * 4,
+                         [_V, _V + 1, 10 * _V, _V], [1.0, 1.0, 0.8, 1.0]),
+    "p-equal-one": (None, [False] * 4, [0.9] * 4, [0, 0, 5, 0], [1.0] * 4),
+    "greedy-row-carrying-k-and-p": (
+        None, [True, True, True, True], [0.8] * 4, [5, 0, 3, 0],
+        [0.9, 0.5, 1.0, 1.0]),
+}
+
+
+def _eqn_subjaxprs(eqn):
+    for v in eqn.params.values():
+        for j in (v if isinstance(v, (tuple, list)) else (v,)):
+            j = getattr(j, "jaxpr", j)
+            if hasattr(j, "eqns"):
+                yield j
+
+
+def _primitives(jaxpr, enter_cond=True):
+    """Names of a jaxpr's primitives, sub-jaxprs included; with
+    ``enter_cond=False`` a ``cond``'s branches are left out."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        if eqn.primitive.name == "cond" and not enter_cond:
+            continue
+        for sub in _eqn_subjaxprs(eqn):
+            yield from _primitives(sub, enter_cond)
+
+
+def _is_costly(name):
+    return (name in ("sort", "cumsum", "exp", "div")
+            or "random" in name or "threefry" in name)
+
+
+@pytest.fixture(scope="module")
+def sampler_jaxpr():
+    """The jaxpr that ``sample_tokens`` jits (its one equation's own)."""
+    b = 4
+    (call,) = jax.make_jaxpr(sample_tokens)(
+        jnp.zeros((b, _V), jnp.float32), jnp.zeros((b,), jnp.uint32),
+        jnp.zeros((b,), jnp.int32),
+        *_spec_vectors([True] * b, [1.0] * b, [0] * b, [1.0] * b)).eqns
+    return call.params["jaxpr"].jaxpr
+
 
 class TestSampling:
     def test_greedy_is_argmax(self):
@@ -129,6 +235,134 @@ class TestSampling:
             jnp.asarray([False, False]), jnp.asarray([0.8, 1.0], jnp.float32),
             jnp.asarray([0, 0], jnp.int32), jnp.asarray([1.0, 1.0], jnp.float32))
         assert int(solo[0]) == int(both[0])
+
+    @pytest.mark.parametrize("neighbours", [
+        "alone", "greedy", "sampling", "truncating"])
+    @pytest.mark.parametrize("spec", ["temperature", "top-k-and-top-p"])
+    def test_seeded_row_draws_alike_on_every_branch(self, spec, neighbours):
+        """The batch's branch follows the neighbours' specs; a seeded row's
+        token follows its own (seed, step) and spec alone."""
+        rng = np.random.RandomState(5)
+        logits = jnp.asarray(rng.randn(4, _V), jnp.float32)
+        mine = {"temperature": (False, 0.8, 0, 1.0),
+                "top-k-and-top-p": (False, 0.8, 6, 0.9)}[spec]
+        theirs = {"greedy": (True, 1.0, 0, 1.0),
+                  "sampling": (False, 1.1, 0, 1.0),
+                  "truncating": (False, 1.1, 3, 0.8)}
+        n = 1 if neighbours == "alone" else 4
+        rows = [mine] + [theirs.get(neighbours)] * (n - 1)
+        want = _ref_sample_one(logits[0], jnp.uint32(9), jnp.int32(2),
+                               *_spec_vectors(*mine))
+        got = sample_tokens(
+            logits[:n], jnp.asarray([9, 1, 2, 3][:n], jnp.uint32),
+            jnp.asarray([2, 0, 7, 1][:n], jnp.int32),
+            *_spec_vectors(*zip(*rows)))
+        assert int(got[0]) == int(want)
+
+    @pytest.mark.parametrize("case", list(_SPEC_GRID))
+    def test_batched_sampler_equals_the_plain_per_row_reference(self, case):
+        """Token for token, over seeds and steps: a greedy row's argmax, a
+        sampling row's key, warped support and draw are the reference's."""
+        logits, greedy, temp, k, p = _SPEC_GRID[case]
+        spec = _spec_vectors(greedy, temp, k, p)
+        ref = jax.jit(jax.vmap(_ref_sample_one))
+        run = jax.jit(sample_tokens)
+        rng = np.random.RandomState(6)
+        for trial in range(12):
+            z = jnp.asarray(rng.randn(4, _V) * 2.0 if logits is None
+                            else logits, jnp.float32)
+            seeds = jnp.asarray(rng.randint(0, 2**31, 4), jnp.uint32)
+            steps = jnp.asarray(rng.randint(0, 500, 4), jnp.int32)
+            assert (run(z, seeds, steps, *spec).tolist()
+                    == ref(z, seeds, steps, *spec).tolist()), trial
+
+    @pytest.mark.parametrize("case", ["top-k-and-top-p",
+                                      "ties-at-the-kth-logit",
+                                      "the-token-that-crosses-p"])
+    def test_single_sort_support_is_the_two_sort_support(self, case):
+        """The warped logits themselves, not only a draw from them: what
+        one sort keeps is what top-k's sort and then top-p's kept."""
+        logits, _, temp, k, p = _SPEC_GRID[case]
+        z = jnp.asarray(np.random.RandomState(7).randn(4, _V) * 2.0
+                        if logits is None else logits, jnp.float32)
+        for i in range(4):
+            t, kk, pp = (jnp.float32(temp[i]), jnp.int32(k[i]),
+                         jnp.float32(p[i]))
+            want = z[i] / t
+            want = jnp.where(kk > 0, _ref_top_k(want, jnp.maximum(kk, 1)),
+                             want)
+            want = jnp.where(pp < 1.0, _ref_top_p(want, pp), want)
+            got = S._warp(z[i], t, kk, pp)
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        if case == "ties-at-the-kth-logit":  # k = 4 keeps all three ties
+            kept = np.asarray(S._warp(z[0], 1.0, jnp.int32(4),
+                                      jnp.float32(1.0))) > _NEG
+            assert kept.sum() == 6
+        if case == "the-token-that-crosses-p":  # 0.6, the crosser, its ties
+            kept = np.asarray(S._warp(z[0], 1.0, jnp.int32(0),
+                                      jnp.float32(0.7))) > _NEG
+            assert np.nonzero(kept)[0].tolist() == [0, 1, 2, 3]
+
+    def test_nothing_costly_stands_outside_a_branch(self, sampler_jaxpr):
+        outside = list(_primitives(sampler_jaxpr, enter_cond=False))
+        assert not [n for n in outside if _is_costly(n)], outside
+        assert outside.count("cond") == 1
+
+    def test_the_whole_sampler_holds_one_sort(self, sampler_jaxpr):
+        names = list(_primitives(sampler_jaxpr))
+        assert names.count("sort") == 1 and names.count("cumsum") == 1
+
+    @pytest.mark.parametrize("path", S.PATHS)
+    def test_each_arm_runs_what_its_path_asks_for(self, sampler_jaxpr, path):
+        (switch,) = [e for e in sampler_jaxpr.eqns
+                     if e.primitive.name == "cond"]
+        assert len(switch.params["branches"]) == len(S.PATHS)
+        arm = switch.params["branches"][S.PATHS.index(path)].jaxpr
+        names = set(_primitives(arm))
+        draws = any("random" in n for n in names)
+        if path == "argmax":
+            assert names <= {"argmax", "convert_element_type"}, names
+        elif path == "sample":
+            assert draws and not names & {"sort", "cumsum", "exp"}, names
+        else:
+            assert draws and {"sort", "cumsum"} <= names, names
+
+    @pytest.mark.parametrize("case", ["all-greedy",
+                                      "greedy-and-sampling-mixed"])
+    def test_bfloat16_logits_reach_the_arms_as_float32(self, case):
+        """What a branch is handed is materialised as written: bfloat16
+        log-probabilities tie at the top, and on the chip the step before
+        ISSUE 35 never rounded them (XLA kept the producer's float32 up to
+        its fused argmax). The cast stands before the branch, and changes
+        no token where the rounding is real."""
+        _, greedy, temp, k, p = _SPEC_GRID[case]
+        z = jnp.asarray(np.random.RandomState(8).randn(4, _V) * 2.0,
+                        jnp.bfloat16)
+        args = (z, jnp.arange(4, dtype=jnp.uint32),
+                jnp.arange(4, dtype=jnp.int32),
+                *_spec_vectors(greedy, temp, k, p))
+        (call,) = jax.make_jaxpr(sample_tokens)(*args).eqns
+        (switch,) = [e for e in call.params["jaxpr"].jaxpr.eqns
+                     if e.primitive.name == "cond"]
+        wide = [v.aval.dtype for v in switch.invars if v.aval.shape == (4, _V)]
+        assert wide and all(d == jnp.float32 for d in wide), wide
+        assert (sample_tokens(*args).tolist()
+                == jax.vmap(_ref_sample_one)(*args).tolist())
+
+    @pytest.mark.parametrize("greedy,k,p,path", [
+        ([True, True], [0, 0], [1.0, 1.0], "argmax"),
+        ([True, True], [5, 0], [0.5, 1.0], "argmax"),
+        ([True, False], [5, 0], [0.5, 1.0], "sample"),
+        ([False, False], [0, 0], [1.0, 1.5], "sample"),
+        ([True, False], [0, 2], [1.0, 1.0], "sort"),
+        ([False, True], [0, 0], [0.99, 1.0], "sort"),
+    ])
+    def test_sampler_path_on_host_and_device_arrays(self, greedy, k, p, path):
+        host = (np.asarray(greedy), np.asarray(k, np.int32),
+                np.asarray(p, np.float32))
+        assert S.PATHS[int(S.sampler_path(*host))] == path
+        assert S.PATHS[int(jax.jit(S.sampler_path)(
+            *map(jnp.asarray, host)))] == path
 
 
 # ---------------------------------------------------------------------------
@@ -477,6 +711,61 @@ class TestDecodeEngine:
         assert eng._g_inflight.value == 0
         assert eng._h_prefill.count >= 1
         assert eng._h_decode.count >= 1
+        # the prefill hands out token 0, three greedy steps the rest
+        sampler = reg.get("dl4j_tpu_decode_sampler_steps_total")
+        assert [sampler.labels("eng-obs", path).value
+                for path in S.PATHS] == [3, 0, 0]
+        assert eng.stats()["sampler_sort_share"] == 0.0
+
+    def test_sampler_counter_follows_the_active_rows_specs(self, lm,
+                                                           monkeypatch):
+        """A mixed batch counts ``sort``; once the sampled request has ended
+        its slot's stale spec holds no later step there; and what the host
+        counts is what the step's program branched on."""
+        from collections import Counter
+
+        from deeplearning4j_tpu.parallel import decode as D
+
+        on_device = []
+
+        def spy(logits, seeds, steps, gmask, temps, ks, ps):
+            if logits.shape[0] == 2:  # the step's call, not a prefill's
+                jax.debug.callback(lambda i: on_device.append(int(i)),
+                                   S.sampler_path(gmask, ks, ps))
+            return sample_tokens(logits, seeds, steps, gmask, temps, ks, ps)
+
+        monkeypatch.setattr(D, "sample_tokens", spy)
+        eng, reg = self._engine(lm, slots=2, name="eng-path")
+        assert eng.stats()["sampler_sort_share"] is None
+        sampler = reg.get("dl4j_tpu_decode_sampler_steps_total")
+
+        def counts():
+            return {path: int(sampler.labels("eng-path", path).value)
+                    for path in S.PATHS}
+
+        try:
+            long = eng.submit([1, 2, 3], max_tokens=16)  # slot 0, greedy
+            next(iter(long.events(timeout=60)))
+            # slot 1: three tokens, two of them from steps beside slot 0's
+            eng.submit([4, 5], max_tokens=3, greedy=False, top_k=5,
+                       seed=3).result(timeout=120)
+            long.result(timeout=120)
+            first = counts()
+            share = eng.stats()["sampler_sort_share"]
+            # slot 1 still holds top_k = 5; slot 0 serves again
+            eng.submit([6, 7], max_tokens=8).result(timeout=120)
+            second = counts()
+            jax.effects_barrier()
+            assert not eng._greedy[1] and eng._ks[1] == 5
+            assert eng.stats()["sampler_sort_share"] < share
+        finally:
+            eng.shutdown()
+        assert 1 <= first["sort"] <= 3 and first["sample"] == 0
+        assert first["argmax"] + first["sort"] == 15
+        assert second == {"argmax": first["argmax"] + 7, "sample": 0,
+                          "sort": first["sort"]}
+        assert Counter(S.PATHS[i] for i in on_device) == Counter(
+            {k: v for k, v in second.items() if v})
 
     def test_prompt_too_long_rejected(self, lm):
         eng, _ = self._engine(lm, slots=1, name="eng-long")
