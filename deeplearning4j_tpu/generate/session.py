@@ -217,7 +217,8 @@ class GenerationSession:
                     "an output layer with decode_logits reads its own "
                     "parameters: pass the model's params")
             params, _ = self.model._to_compute(params, out)
-            return self._head(params[self._layer_names[-1]], out)
+            return self._head(self.model.layer_params(
+                params, len(self._layer_names) - 1), out)
         if self._out_is_probs:
             return jnp.log(jnp.maximum(out, 1e-30))
         return out

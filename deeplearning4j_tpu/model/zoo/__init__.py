@@ -10,6 +10,7 @@ from .darknet import Darknet19, TinyYOLO
 from .evabyte import EvaByteLM
 from .inception_resnet import InceptionResNetV1
 from .lenet import LeNet
+from .lfm2_moe import Lfm2MoeLM
 from .longcat_flash import LongCatFlashLM
 from .misc import FaceNetNN4Small2, SimpleCNN, YOLO2
 from .resnet50 import ResNet50
@@ -29,6 +30,7 @@ __all__ = [
     "FaceNetNN4Small2",
     "InceptionResNetV1",
     "LeNet",
+    "Lfm2MoeLM",
     "LongCatFlashLM",
     "ResNet50",
     "SimpleCNN",

@@ -39,6 +39,7 @@ from .output import (
     OutputLayer,
     RnnLossLayer,
     RnnOutputLayer,
+    TiedRnnOutputLayer,
 )
 from .pooling import (
     Cropping2DLayer,
@@ -62,11 +63,14 @@ from .preprocessors import (
     RnnToCnnPreProcessor,
     RnnToFeedForwardPreProcessor,
 )
+from .decoder_block import DecoderBlockLayer, GatedFFNLayer
 from .eva import EvaDecoderBlockLayer, gated_silu_ffn, rotary_positions
+from .gqa import GroupedQueryAttentionLayer
 from .longcat import LongCatBlockLayer
 from .mla import LatentAttentionLayer
 from .moe import ExpertShareMoELayer, MixtureOfExpertsLayer
 from .samediff_layer import SameDiffLambdaLayer, SameDiffLayer
+from .short_conv import ShortConvLayer
 from .recurrent import (
     BidirectionalLayer,
     BidirectionalMode,

@@ -179,6 +179,15 @@ class Layer:
     def has_params(self) -> bool:
         return False
 
+    def tied_params(self) -> Dict[str, Tuple[int, str]]:
+        """Parameters the layer READS from another layer of its network
+        and does not own: ``{the name it reads them under: (index of the
+        layer that owns them, their name there)}`` (a head that shares the
+        embedding's matrix). The network hands them over beside the
+        layer's own (``MultiLayerNetwork.layer_params``); they are
+        initialised, trained and saved once, where they are owned."""
+        return {}
+
     # ---- forward -----------------------------------------------------------
     def apply(self, params: Params, state: State, x: jax.Array, ctx: LayerContext) -> Tuple[jax.Array, State]:
         raise NotImplementedError
@@ -194,6 +203,13 @@ class Layer:
     def weight_param_names(self) -> Tuple[str, ...]:
         """Params that l1/l2/weight-decay apply to (biases excluded)."""
         return tuple(n for n in self.trainable_param_names() if n not in ("b", "gb", "bb"))
+
+
+def sub_params(params: Params, prefix: str) -> Params:
+    """The parameters a layer holds flat under ``prefix`` for one of its
+    parts, under the part's own names."""
+    return {k[len(prefix):]: v for k, v in params.items()
+            if k.startswith(prefix)}
 
 
 def resolve(value, default):

@@ -1,0 +1,194 @@
+"""A decoder block made of parts: ``norm | mixer | norm | feed-forward``.
+
+    h = x + Op(N(x; op_gn));   y = h + FF(N(h; ff_gn))
+
+``N`` is an RMSNorm with a gain of its own each time, ``Op`` and ``FF`` are
+the block's two PARTS, layers of their own that the block is configured
+with, not code of the block's:
+
+* a MIXER (``Op``) gives ``mix(params, state, u [b, t, n_in], mask) -> (o,
+  new state)`` and owns its decode state (``decode_state``, ``decode_planes``,
+  ``pages_decode_planes``, ``decode_live_bytes``) and the scope its
+  operations carry in a device trace:
+  :class:`~.short_conv.ShortConvLayer` (a rolling state a row),
+  :class:`~.gqa.GroupedQueryAttentionLayer` (K/V planes),
+  :class:`~.mla.LatentAttentionLayer` (a latent plane);
+* a FEED-FORWARD (``FF``) gives ``feed(params, u [n, n_in], token_mask) ->
+  (y [n, n_in], counts or None)`` over the block's tokens:
+  :class:`GatedFFNLayer` (dense), :class:`~.moe.ExpertShareMoELayer` (an
+  expert layer, whose ``counts`` the block keeps in its decode state for
+  the engine to bring home).
+
+A model whose layers differ in their parts (LFM2: convolution or attention,
+dense or experts) is the same block configured four ways, not four classes.
+The block holds its parts' parameters flat under ``op_`` and ``ff_``, each
+with the gain of the norm before it (``op_gn``, ``ff_gn``). The residual
+stream is float32 whatever the parameters' type.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from ...core.config import register_config
+from ..input_type import InputType, RecurrentType
+from ..weights import WeightInit, init_weights
+from .base import (Layer, LayerContext, Params, State, apply_input_dropout,
+                   sub_params)
+from .eva import gated_silu_ffn
+from .norm import rms_norm
+
+_F32 = jnp.float32
+
+
+@register_config
+@dataclasses.dataclass(frozen=True, kw_only=True)
+class GatedFFNLayer(Layer):
+    """``Wd (silu(Wg x) * Wu x)`` without biases, as a sequential layer
+    (input/output ``[b, n_in, t]`` or ``[n, n_in]``) and as a block's
+    feed-forward part."""
+
+    n_in: int = 0
+    hidden: int = 0
+
+    def with_input(self, input_type: InputType) -> "GatedFFNLayer":
+        out = self
+        if not out.n_in:
+            out = dataclasses.replace(out, n_in=input_type.size)
+        if not out.hidden:
+            out = dataclasses.replace(out, hidden=4 * out.n_in)
+        return out
+
+    def has_params(self) -> bool:
+        return True
+
+    def trainable_param_names(self) -> Tuple[str, ...]:
+        return ("Wg", "Wu", "Wd")
+
+    def init(self, key: jax.Array, dtype: Any) -> Params:
+        wi = self.weight_init or WeightInit.XAVIER
+        h, f = self.n_in, self.hidden
+        kg, ku, kd = jax.random.split(key, 3)
+        return {"Wg": init_weights(kg, (h, f), wi, h, f, None, dtype),
+                "Wu": init_weights(ku, (h, f), wi, h, f, None, dtype),
+                "Wd": init_weights(kd, (f, h), wi, f, h, None, dtype)}
+
+    def feed(self, params: Params, x2: jax.Array, token_mask=None):
+        """Tokens ``x2 [n, n_in]`` -> ``(y [n, n_in], None)``: the operands
+        in the parameters' type; it counts nothing."""
+        with jax.named_scope("ffn"):
+            return gated_silu_ffn(x2.astype(params["Wg"].dtype), params["Wg"],
+                                  params["Wu"], params["Wd"]), None
+
+    def apply(self, params: Params, state: State, x: jax.Array, ctx: LayerContext) -> Tuple[jax.Array, State]:
+        x = apply_input_dropout(self, x, ctx)
+        xt = x.transpose(0, 2, 1) if x.ndim == 3 else x
+        y = self.feed(params, xt)[0].astype(x.dtype)
+        return (y.transpose(0, 2, 1) if x.ndim == 3 else y), state
+
+
+@register_config
+@dataclasses.dataclass(frozen=True, kw_only=True)
+class DecoderBlockLayer(Layer):
+    """One block as ONE sequential layer (input/output ``[b, n_in, t]``).
+
+    Decode state: the mixer's own leaves under their own names (its planes
+    are the block's planes), and, with a feed-forward that counts,
+    ``moe_choices`` ``[b, held + 2]``: where the choices of the row's tokens
+    OF THE LAST CALL went (:meth:`decode_counts`)."""
+
+    n_in: int = 0
+    mixer: Optional[Layer] = None
+    ffn: Optional[Layer] = None
+    eps: float = 1e-5
+
+    def output_type(self, input_type: InputType) -> InputType:
+        return RecurrentType(size=self.n_in, timesteps=input_type.timesteps)
+
+    def with_input(self, input_type: InputType) -> "DecoderBlockLayer":
+        n_in = self.n_in or input_type.size
+        here = RecurrentType(size=n_in, timesteps=input_type.timesteps)
+        return dataclasses.replace(
+            self, n_in=n_in, mixer=self.mixer.with_input(here),
+            ffn=self.ffn.with_input(here))
+
+    def has_params(self) -> bool:
+        return True
+
+    def trainable_param_names(self) -> Tuple[str, ...]:
+        return ("op_gn",) + tuple(
+            f"op_{n}" for n in self.mixer.trainable_param_names()) \
+            + ("ff_gn",) + tuple(
+            f"ff_{n}" for n in self.ffn.trainable_param_names())
+
+    def weight_param_names(self) -> Tuple[str, ...]:
+        return tuple(f"op_{n}" for n in self.mixer.weight_param_names()) \
+            + tuple(f"ff_{n}" for n in self.ffn.weight_param_names())
+
+    def init(self, key: jax.Array, dtype: Any) -> Params:
+        k_op, k_ff = jax.random.split(key)
+        out: Dict[str, jax.Array] = {"op_gn": jnp.ones((self.n_in,), dtype),
+                                     "ff_gn": jnp.ones((self.n_in,), dtype)}
+        out |= {f"op_{n}": v for n, v in
+                self.mixer.init(k_op, dtype).items()}
+        return out | {f"ff_{n}": v for n, v in
+                      self.ffn.init(k_ff, dtype).items()}
+
+    # ---- the decode state and what the layer declares of it ---------------
+    @property
+    def _counts(self) -> Tuple[str, ...]:
+        columns = getattr(self.ffn, "choice_columns", None)
+        return columns() if columns else ()
+
+    def decode_state(self, batch: int, max_len: int, dtype: Any) -> State:
+        out = dict(self.mixer.decode_state(batch, max_len, dtype))
+        if self._counts:
+            out["moe_choices"] = jnp.zeros((batch, len(self._counts)),
+                                           jnp.int32)
+        return out
+
+    def decode_planes(self) -> Tuple[str, ...]:
+        return self.mixer.decode_planes()
+
+    @property
+    def pages_decode_planes(self) -> bool:
+        return self.mixer.pages_decode_planes
+
+    def decode_window(self) -> Optional[int]:
+        return self.mixer.decode_window()
+
+    def decode_counts(self) -> Dict[str, Tuple[str, ...]]:
+        return {"moe_choices": self._counts} if self._counts else {}
+
+    def decode_live_bytes(self, position: int, itemsize: int) -> Dict[str, int]:
+        return self.mixer.decode_live_bytes(position, itemsize)
+
+    # ---- forward ------------------------------------------------------------
+    def apply(self, params: Params, state: State, x: jax.Array, ctx: LayerContext) -> Tuple[jax.Array, State]:
+        x = apply_input_dropout(self, x, ctx)
+        xt = x.transpose(0, 2, 1)                            # [b, t, h]
+        xt = xt.astype(jnp.promote_types(xt.dtype, _F32))    # the residual
+        b, t, h = xt.shape
+        cd = params["op_gn"].dtype
+        # the block keeps no state between calls but its decode state
+        sub = {k: v for k, v in state.items() if k != "moe_choices"}
+        u = rms_norm(xt, params["op_gn"], self.eps).astype(cd)
+        o, new = self.mixer.mix(sub_params(params, "op_"), sub, u, ctx.mask)
+        h1 = xt + o.astype(xt.dtype)
+        # float32 into the part: an expert layer's router reads it as it is
+        u = rms_norm(h1, params["ff_gn"], self.eps)
+        token_mask = None if ctx.mask is None else ctx.mask.reshape(b * t)
+        m, counts = self.ffn.feed(sub_params(params, "ff_"),
+                                  u.reshape(b * t, h), token_mask)
+        y = h1 + m.reshape(b, t, h).astype(xt.dtype)
+        new_state = state
+        if sub:
+            new_state = dict(new)
+            if counts is not None:
+                new_state["moe_choices"] = jnp.sum(
+                    counts.reshape(b, t, -1), axis=1)
+        return y.transpose(0, 2, 1), new_state

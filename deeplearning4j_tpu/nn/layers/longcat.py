@@ -30,18 +30,14 @@ import jax.numpy as jnp
 from ...core.config import register_config
 from ..input_type import InputType, RecurrentType
 from ..weights import WeightInit, init_weights
-from .base import Layer, LayerContext, Params, State, apply_input_dropout
+from .base import (Layer, LayerContext, Params, State, apply_input_dropout,
+                   sub_params)
 from .eva import gated_silu_ffn
 from .mla import LatentAttentionLayer
 from .moe import ExpertShareMoELayer
 from .norm import rms_norm
 
 _F32 = jnp.float32
-
-
-def _sub(params: Params, prefix: str) -> Params:
-    return {k[len(prefix):]: v for k, v in params.items()
-            if k.startswith(prefix)}
 
 
 @register_config
@@ -157,10 +153,7 @@ class LongCatBlockLayer(Layer):
         return ("latent0", "latent1")
 
     def decode_counts(self) -> Dict[str, Tuple[str, ...]]:
-        first = self.first_held_expert
-        return {"moe_choices": tuple(
-            f"expert:{first + e}" for e in range(self.moe.held))
-            + ("absent", "zero")}
+        return {"moe_choices": self.moe.choice_columns()}
 
     def decode_live_bytes(self, position: int, itemsize: int) -> Dict[str, int]:
         return {k: 2 * n for k, n in
@@ -177,7 +170,7 @@ class LongCatBlockLayer(Layer):
             **({"write_mask": state["write_mask"]}
                if "write_mask" in state else {})}
         with jax.named_scope(f"mla_{j}"):
-            o, new = self.mixer.mix(_sub(params, f"a{j}_"), sub, u, mask)
+            o, new = self.mixer.mix(sub_params(params, f"a{j}_"), sub, u, mask)
         return o.astype(h.dtype), new
 
     def apply(self, params: Params, state: State, x: jax.Array, ctx: LayerContext) -> Tuple[jax.Array, State]:
@@ -190,7 +183,8 @@ class LongCatBlockLayer(Layer):
         h1 = xt + o
         u = rms_norm(h1, params["f0_gn"], self.eps)  # float32: the router's
         token_mask = None if ctx.mask is None else ctx.mask.reshape(b * t)
-        m, counts = self.moe.share(_sub(params, "m_"), u.reshape(b * t, h),
+        m, counts = self.moe.share(sub_params(params, "m_"),
+                                   u.reshape(b * t, h),
                                    token_mask)
         u = u.astype(cd)
         with jax.named_scope("ffn_0"):

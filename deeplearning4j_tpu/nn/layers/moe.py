@@ -490,6 +490,13 @@ def _router_logits(x: jax.Array, wr: jax.Array) -> jax.Array:
         + jnp.dot(lo, wr, preferred_element_type=f32)
 
 
+#: slots of the sorted form over an expert's mean load: a served selection
+#: bias makes the loads uneven, and the hottest expert of a layer took up to
+#: 2.7 times the mean over 200 simulated seeds at 32 experts top-4
+#: (``benchmarks/families/lfm2_moe.py`` ``init_scale``)
+SORTED_LOAD_FACTOR = 3.0
+
+
 @register_config
 @dataclasses.dataclass(frozen=True, kw_only=True)
 class ExpertShareMoELayer(Layer):
@@ -502,9 +509,12 @@ class ExpertShareMoELayer(Layer):
     another chip's; nothing here stands in for it or for the exchange.
 
         s = softmax_float32(Wr u) over n_routed_experts + zero_expert_num
+        (``scoring="sigmoid"``: sigmoid_float32, each output on its own)
         chosen: the top_k largest of s + br (the served selection bias
         moves the choice, not the weight); weight routed_scaling_factor *
-        s_e, NOT renormalised
+        s_e, NOT renormalised (``norm_topk_prob``: routed_scaling_factor *
+        s_e / (the sum of s over ALL the chosen + 1e-6), whichever chip
+        holds them, so that the shares of a layer still add up)
         y = sum over the chosen of weight_e E_e(u): E_e the gated expert
         ``Ed_e (silu(Eg_e u) * Eu_e u)`` (no bias) for a held e, nothing
         for an absent one, and ``E_e(u) = u`` for e >= n_routed_experts
@@ -517,11 +527,16 @@ class ExpertShareMoELayer(Layer):
     tokens every held expert runs over every token and the weights (nought
     where a token did not choose it) fold into the down-projection: at a
     decode step's rows that product is under the time the expert weights
-    take to read. Beyond, tokens are sorted into ``[held, expert_rows]``
-    buffers with the dispatch plan ``MixtureOfExpertsLayer`` uses
+    take to read. Beyond, tokens are sorted into ``[held, rows]`` buffers
+    with the dispatch plan ``MixtureOfExpertsLayer`` uses
     (``ops/moe_dispatch.py``), and a call in which some held expert has
-    more than ``expert_rows`` tokens takes the first form instead
-    (``lax.cond``): slower, never lossy.
+    more than ``rows`` tokens runs the held experts one at a time over
+    every token instead (``lax.cond``; what is alive is one expert's, not
+    ``held`` times the call's tokens): slower, never lossy. ``rows`` follows
+    the call's tokens
+    (:meth:`sorted_rows`: three times an expert's mean load, in whole
+    ``expert_rows``), so a long prompt's buffers hold its load and a
+    short one's are not sized for it.
 
     ``state["choice_counts"]`` (``[held + 2]``: a column a held expert, the
     absent, the zero-compute) says where the call's choices went."""
@@ -535,11 +550,15 @@ class ExpertShareMoELayer(Layer):
     top_k: int = 2
     routed_scaling_factor: float = 1.0
     expert_rows: int = 128
+    scoring: str = "softmax"         # or "sigmoid"
+    norm_topk_prob: bool = False
 
     def __post_init__(self) -> None:
         width = self.n_routed_experts + self.zero_expert_num
         if not 1 <= self.top_k <= width:
             raise ValueError(f"top_k={self.top_k} must be in [1, {width}]")
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring={self.scoring!r}: softmax or sigmoid")
         if self.first_held_expert + self.held > self.n_routed_experts:
             raise ValueError(
                 f"experts {self.first_held_expert}..+{self.held} are not "
@@ -548,6 +567,21 @@ class ExpertShareMoELayer(Layer):
     @property
     def held(self) -> int:
         return self.n_held_experts or self.n_routed_experts
+
+    def choice_columns(self) -> Tuple[str, ...]:
+        """The names of the ``held + 2`` columns of the counts."""
+        first = self.first_held_expert
+        return tuple(f"expert:{first + e}" for e in range(self.held)) \
+            + ("absent", "zero")
+
+    def sorted_rows(self, n_tokens: int) -> int:
+        """Slots an expert's buffer of the sorted form has in a call of
+        ``n_tokens``: :data:`SORTED_LOAD_FACTOR` times the mean load of one
+        of the router's outputs, rounded up to whole ``expert_rows``."""
+        width = self.n_routed_experts + self.zero_expert_num
+        mean = n_tokens * self.top_k / width
+        return self.expert_rows * max(
+            1, math.ceil(SORTED_LOAD_FACTOR * mean / self.expert_rows))
 
     def output_type(self, input_type: InputType) -> InputType:
         return input_type
@@ -595,19 +629,44 @@ class ExpertShareMoELayer(Layer):
                                       preferred_element_type=f32)) \
             * jnp.einsum(eq, x, params["Eu"], preferred_element_type=f32)
 
+    def _held_weights(self, vals, local) -> jax.Array:
+        """``[n, held]``: a token's weight for every held expert, nought
+        for one it did not choose."""
+        return jnp.sum(jax.nn.one_hot(local, self.held, dtype=vals.dtype)
+                       * vals[..., None], axis=1)
+
     def _held_dense(self, params: Params, x2, vals, local) -> jax.Array:
-        """Every held expert over every token; a token's weight for an
-        expert it did not choose is nought."""
-        w = jnp.sum(jax.nn.one_hot(local, self.held, dtype=vals.dtype)
-                    * vals[..., None], axis=1)                   # [n, held]
+        """Every held expert over every token under the mask of
+        :meth:`_held_weights`."""
+        w = self._held_weights(vals, local)
         h = self._hidden_act(params, "nd,edf->enf", x2) * w.T[:, :, None]
         return jnp.einsum("enf,efd->nd", h.astype(x2.dtype), params["Ed"],
                           preferred_element_type=jnp.float32)
 
-    def _held_sorted(self, params: Params, x2, vals, plan) -> jax.Array:
-        """The tokens of each held expert gathered into its
-        ``expert_rows`` slots (the plan granted every choice one)."""
-        xin = gather_dispatch(x2, plan, self.held, self.expert_rows)
+    def _held_each(self, params: Params, x2, vals, local) -> jax.Array:
+        """:meth:`_held_dense` one held expert at a time (a scan): the
+        sorted form's way out at a prompt's tokens, where every expert over
+        every token at once would be ``held`` hidden activations alive."""
+        f32 = jnp.float32
+
+        def one(acc, e):
+            eg, eu, ed, we = e
+            h = jax.nn.silu(jnp.dot(x2, eg, preferred_element_type=f32)) \
+                * jnp.dot(x2, eu, preferred_element_type=f32) * we[:, None]
+            return acc + jnp.dot(h.astype(x2.dtype), ed,
+                                 preferred_element_type=f32), None
+
+        acc, _ = jax.lax.scan(
+            one, jnp.zeros((x2.shape[0], self.n_in), f32),
+            (params["Eg"], params["Eu"], params["Ed"],
+             self._held_weights(vals, local).T))
+        return acc
+
+    def _held_sorted(self, params: Params, x2, vals, plan,
+                     rows: int) -> jax.Array:
+        """The tokens of each held expert gathered into its ``rows`` slots
+        (the plan granted every choice one)."""
+        xin = gather_dispatch(x2, plan, self.held, rows)
         h = self._hidden_act(params, "emd,edf->emf", xin)
         out = jnp.einsum("emf,efd->emd", h.astype(x2.dtype), params["Ed"],
                          preferred_element_type=jnp.float32)
@@ -626,10 +685,20 @@ class ExpertShareMoELayer(Layer):
         weights' type."""
         f32 = jnp.float32
         with jax.named_scope("moe_router"):
-            scores = jax.nn.softmax(_router_logits(x2, params["Wr"]), axis=-1)
-            vals, idx = biased_top_k_routing(
-                scores, params["br"].astype(f32), self.top_k,
-                self.routed_scaling_factor)
+            logits = _router_logits(x2, params["Wr"])
+            scores = jax.nn.softmax(logits, axis=-1) \
+                if self.scoring == "softmax" else jax.nn.sigmoid(logits)
+            # (two spellings of one call: the unnormalised one is the
+            # layer as PR 34 served it, operation for operation)
+            if self.norm_topk_prob:
+                vals, idx = biased_top_k_routing(
+                    scores, params["br"].astype(f32), self.top_k)
+                vals = self.routed_scaling_factor * vals / (
+                    jnp.sum(vals, axis=-1, keepdims=True) + 1e-6)
+            else:
+                vals, idx = biased_top_k_routing(
+                    scores, params["br"].astype(f32), self.top_k,
+                    self.routed_scaling_factor)
             local, counts = held_expert_choices(
                 idx, self.first_held_expert, self.held,
                 self.n_routed_experts)
@@ -644,12 +713,13 @@ class ExpertShareMoELayer(Layer):
             if x2.shape[0] <= self.expert_rows:
                 held = self._held_dense(params, x2, vals, local)
             else:
-                plan = make_dispatch_plan(local, self.held, self.expert_rows,
+                rows = self.sorted_rows(x2.shape[0])
+                plan = make_dispatch_plan(local, self.held, rows,
                                           token_mask=token_mask)
                 held = jax.lax.cond(
                     plan.dropped_tokens == 0,
-                    lambda: self._held_sorted(params, x2, vals, plan),
-                    lambda: self._held_dense(params, x2, vals, local))
+                    lambda: self._held_sorted(params, x2, vals, plan, rows),
+                    lambda: self._held_each(params, x2, vals, local))
         return held, zero, counts
 
     def share(self, params: Params, x2: jax.Array,
@@ -657,6 +727,12 @@ class ExpertShareMoELayer(Layer):
         """``(y [n, n_in] float32, counts [n, held + 2])``."""
         held, zero, counts = self.parts(params, x2, token_mask)
         return held + zero, counts
+
+    def feed(self, params: Params, x2: jax.Array,
+             token_mask: Optional[jax.Array] = None):
+        """:meth:`share` under the name a block of parts calls its
+        feed-forward by (``decoder_block.py``)."""
+        return self.share(params, x2, token_mask)
 
     def apply(self, params: Params, state: State, x: jax.Array,
               ctx: LayerContext) -> Tuple[jax.Array, State]:

@@ -253,6 +253,56 @@ class MultiTokenRnnOutputLayer(BaseOutputLayer):
 
 @register_config
 @dataclasses.dataclass(frozen=True, kw_only=True)
+class TiedRnnOutputLayer(BaseOutputLayer):
+    """Per-timestep head that SHARES the embedding's matrix: ``logits = x
+    E^T`` with ``E [n_out, n_in]`` the ``W`` of layer ``tied_layer`` of the
+    network (``Layer.tied_params``). It owns no parameter, so the matrix is
+    held, trained and saved once. ``apply`` gives float32 LOGITS ``[b,
+    n_out, t]`` (operands in the matrix's type, float32 accumulation);
+    labels are sparse next-token ids ``[b, t]``."""
+
+    n_in: int = 0
+    n_out: int = 0
+    tied_layer: int = 0
+
+    def output_type(self, input_type: InputType) -> InputType:
+        ts = input_type.timesteps if isinstance(input_type, RecurrentType) else None
+        return RecurrentType(size=self.n_out, timesteps=ts)
+
+    def with_input(self, input_type: InputType) -> "TiedRnnOutputLayer":
+        if self.n_in or not isinstance(input_type, RecurrentType):
+            return self
+        return dataclasses.replace(self, n_in=input_type.size)
+
+    def tied_params(self):
+        return {"W": (self.tied_layer, "W")}
+
+    def preoutput(self, params: Params, x: jax.Array, ctx: LayerContext) -> jax.Array:
+        x = apply_input_dropout(self, x, ctx)
+        w = params["W"]
+        return jnp.einsum("bft,of->bto", x.astype(w.dtype), w,
+                          preferred_element_type=jnp.float32)
+
+    def apply(self, params: Params, state: State, x: jax.Array, ctx: LayerContext) -> Tuple[jax.Array, State]:
+        return self.preoutput(params, x, ctx).transpose(0, 2, 1), state
+
+    def decode_logits(self, params: Params, x: jax.Array) -> jax.Array:
+        """The logits [b, n_out, t] from the layer's input."""
+        return self.apply(params, {}, x, LayerContext())[0]
+
+    def compute_loss(self, params, x, labels, ctx, label_mask=None):
+        b, _, t = x.shape
+        logp = jax.nn.log_softmax(self.preoutput(params, x, ctx), axis=-1)
+        labels = labels.reshape(b, t).astype(jnp.int32)
+        mask = label_mask if label_mask is not None else ctx.mask
+        mask = (jnp.ones((b, t), logp.dtype) if mask is None
+                else mask.reshape(b, t).astype(logp.dtype))
+        picked = jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
+        return -jnp.sum(picked * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+
+
+@register_config
+@dataclasses.dataclass(frozen=True, kw_only=True)
 class RnnLossLayer(BaseOutputLayer):
     """Per-timestep loss without params (reference: RnnLossLayer)."""
 

@@ -98,6 +98,17 @@ class MultiLayerNetwork:
     def layer_names(self) -> List[str]:
         return [self.conf.layer_name(i) for i in range(len(self.layers))]
 
+    def layer_params(self, params, i: int) -> Dict[str, jax.Array]:
+        """Layer ``i``'s parameters out of the network's ``params``: its
+        own, and beside them those it reads from another layer
+        (``Layer.tied_params``)."""
+        own = params.get(self.conf.layer_name(i), {})
+        tied = self.layers[i].tied_params()
+        if not tied:
+            return own
+        return {**own, **{mine: params[self.conf.layer_name(j)][theirs]
+                          for mine, (j, theirs) in tied.items()}}
+
     def named_param_layers(self):
         """(name, layer) pairs for layers holding trainable params — the
         updater-block boundaries (used by the Solver's LayerOptimizers)."""
@@ -199,7 +210,7 @@ class MultiLayerNetwork:
             key = jax.random.fold_in(rng, i) if rng is not None else None
             ctx = LayerContext(train=train, rng=key, mask=cur_mask, dist=dist)
             y, lstate_out = _apply_layer(
-                layer, params.get(name, {}), lstate, x, ctx, name=name,
+                layer, self.layer_params(params, i), lstate, x, ctx, name=name,
                 remat=self.conf.gradient_checkpointing and train)
             persistent = self._persistent_keys.get(name, ())
             new_state[name] = {k: v for k, v in lstate_out.items() if k in persistent}
@@ -254,8 +265,9 @@ class MultiLayerNetwork:
         key = jax.random.fold_in(rng, len(self.layers) - 1) if rng is not None else None
         ctx = LayerContext(train=train, rng=key, mask=cur_mask)
         with jax.named_scope(name):  # as apply_layer names the others
-            loss = out_layer.compute_loss(params.get(name, {}), feat, labels,
-                                          ctx, label_mask=label_mask)
+            loss = out_layer.compute_loss(
+                self.layer_params(params, len(self.layers) - 1), feat, labels,
+                ctx, label_mask=label_mask)
         # output layer state passes through unchanged (loss layers are stateless)
         new_state[name] = dict(state.get(name, {}))
         # score in >= float32 precision; float64 models keep float64 (gradcheck)

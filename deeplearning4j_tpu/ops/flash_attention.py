@@ -632,19 +632,24 @@ def flash_attention(
 
 def decode_attention_reference(
     q: jax.Array,           # [b, h, tq, d] — queries at positions start+i
-    k: jax.Array,           # [b, h, L, d]  — static-shape KV cache
-    v: jax.Array,           # [b, h, L, dv]
+    k: jax.Array,           # [b, h_kv, L, d]  — static-shape KV cache
+    v: jax.Array,           # [b, h_kv, L, dv]
     start_pos: jax.Array,   # [b] int32 — absolute position of q's first row
     scale: Optional[float] = None,
 ) -> jax.Array:
     """Builtin XLA decode attention against a cached K/V: query ``i`` of row
     ``b`` sits at absolute position ``start_pos[b] + i`` and attends cache
-    entries ``[0, start_pos[b] + i]`` inclusive. Cache slots past the
+    entries ``[0, start_pos[b] + i]`` inclusive. With fewer K/V heads than
+    query heads (grouped queries) query head ``h`` reads K/V head ``h //
+    (h / h_kv)``; this spelling repeats the cache's heads for the call. Cache slots past the
     frontier (pad garbage, not-yet-written zeros) are masked out, so the
     cache can stay a fixed ``[b, h, max_len, d]`` allocation for the whole
     generation — no shape ever depends on how far decoding has advanced."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
+    if k.shape[1] != q.shape[1]:  # grouped queries: head h reads K/V h // g
+        k, v = (jnp.repeat(a, q.shape[1] // a.shape[1], axis=1)
+                for a in (k, v))
     tq, L = q.shape[2], k.shape[2]
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
     q_ids = jax.lax.broadcasted_iota(jnp.int32, (tq, L), 0)
@@ -680,7 +685,7 @@ def decode_fetched_entries(lengths, max_len: int,
 
 
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
-                   acc_scr, *, scale, block_k, precision):
+                   acc_scr, *, scale, block_k, precision, group=1):
     """One (row, head group, k-block) grid step of single-query flash
     decode: every head of the group at once.
 
@@ -697,9 +702,14 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
     than its 128 lanes, so the kernel reads the cache where it lies and no
     step transposes it. The one query row is broadcast to eight sublanes so
     that both products are MXU matmuls over the head group. The softmax
-    weights stay float32 through the second one: row 0 of its left operand
-    is the weights rounded to V's precision, row 1 what the rounding lost,
-    and the two products are added."""
+    weights stay float32 through the second one: rows ``0 .. group`` of its
+    left operand are the weights rounded to V's precision, rows ``group ..
+    2 group`` what the rounding lost, and the two products are added.
+
+    GROUPED queries (``group`` query heads share a K/V head; 2 or 4): the
+    group's queries arrive as the eight sublanes (the group, repeated), so
+    a K/V block is read ONCE for all of them and the products keep their
+    shapes."""
     ki = pl.program_id(2)
     length = len_ref[pl.program_id(0)]  # valid entries = pos + 1
 
@@ -711,9 +721,10 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
 
     @pl.when(ki * block_k < length)
     def _():
-        q = q_ref[0]                                    # [hb, 1, d]
+        q = q_ref[0]                     # [hb, 1, d]; grouped: [hb, 8, d]
         kt, vt = k_ref[0], v_ref[0]                     # [hb, d, block_k]
-        q8 = jnp.broadcast_to(q, (q.shape[0], 8, q.shape[2]))
+        q8 = q if group > 1 else jnp.broadcast_to(
+            q, (q.shape[0], 8, q.shape[2]))
         s = jax.lax.dot_general(
             q8, kt, (((2,), (1,)), ((0,), (0,))), precision=precision,
             preferred_element_type=jnp.float32) * scale  # [hb, 8, block_k]
@@ -729,7 +740,8 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
         l_scr[...] = l * alpha + jnp.sum(p, axis=2, keepdims=True)
         hi = p.astype(vt.dtype).astype(jnp.float32)
         row = jax.lax.broadcasted_iota(jnp.int32, p.shape, 1)
-        p2 = jnp.where(row == 0, hi, jnp.where(row == 1, p - hi, 0.0))
+        p2 = jnp.where(row < group, hi,
+                       jnp.where(row < 2 * group, p - hi, 0.0))
         # what lies past the frontier is anyone's (0 x NaN is NaN)
         vt = jnp.where(keep, vt, jnp.zeros_like(vt))
         acc_scr[...] = acc * alpha + jax.lax.dot_general(
@@ -739,15 +751,15 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
 
     @pl.when(ki == pl.num_programs(2) - 1)
     def _():
-        acc = acc_scr[...]
-        o_ref[0] = ((acc[:, 0:1, :] + acc[:, 1:2, :]) /
-                    jnp.maximum(l_scr[:, 0:1, :], 1e-30)).astype(o_ref.dtype)
+        acc, g = acc_scr[...], group
+        o_ref[0] = ((acc[:, 0:g, :] + acc[:, g:2 * g, :]) /
+                    jnp.maximum(l_scr[:, 0:g, :], 1e-30)).astype(o_ref.dtype)
 
 
 def flash_decode_attention(
     q: jax.Array,           # [b, h, 1, d]
-    k: jax.Array,           # [b, h, L, d]
-    v: jax.Array,           # [b, h, L, dv]
+    k: jax.Array,           # [b, h_kv, L, d]
+    v: jax.Array,           # [b, h_kv, L, dv]
     start_pos: jax.Array,   # [b] int32
     scale: Optional[float] = None,
     block_k: int = _DECODE_BLOCK_K,
@@ -755,12 +767,14 @@ def flash_decode_attention(
 ) -> jax.Array:
     """Pallas single-query-block decode attention (same contract as
     :func:`decode_attention_reference` with ``tq == 1``). K and V go to
-    the kernel as ``[b, h, d, L]``: for a cache the chip keeps
+    the kernel as ``[b, h_kv, d, L]``: for a cache the chip keeps
     position-minor that transpose is a relabelling, not a copy. A grid
-    step takes ``block_k`` entries of every head of a row (of a divisor of
-    the heads where a block of all of them would crowd VMEM), and only the
-    blocks that a row's position makes valid are moved
-    (:func:`decode_fetched_entries`)."""
+    step takes ``block_k`` entries of every K/V head of a row (of a divisor
+    of the heads where a block of all of them would crowd VMEM), and only
+    the blocks that a row's position makes valid are moved
+    (:func:`decode_fetched_entries`). With ``h = group x h_kv`` (grouped
+    queries, ``group`` 2 or 4) the ``group`` query heads of a K/V head are
+    rows of one product: the cache is read once for them, never repeated."""
     if q.shape[2] != 1:
         raise ValueError("flash_decode_attention is the tq=1 kernel; use "
                          "decode_attention for multi-row queries")
@@ -768,8 +782,16 @@ def flash_decode_attention(
         scale = 1.0 / math.sqrt(q.shape[-1])
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    b, h, _, d = q.shape
-    L, dv = k.shape[2], v.shape[3]
+    b, hq, _, d = q.shape
+    h, L, dv = k.shape[1], k.shape[2], v.shape[3]
+    group = hq // h
+    if hq != group * h or group not in (1, 2, 4):
+        raise ValueError(
+            f"flash_decode_attention: {hq} query heads over {h} K/V heads "
+            "(a group of 1, 2 or 4 fits the kernel's eight sublanes)")
+    if group > 1:  # the group's queries, twice over, as the eight sublanes
+        q = jnp.tile(q.reshape(b, h, group, d), (1, 1, 8 // group, 1))
+    rows = 8 if group > 1 else 1
     block_k = min(block_k, max(L, 1))
     kp = jnp.swapaxes(_pad_to(k, 2, block_k), 2, 3)
     vp = jnp.swapaxes(_pad_to(v, 2, block_k), 2, 3)
@@ -800,29 +822,30 @@ def flash_decode_attention(
     kern = functools.partial(
         _decode_kernel, scale=float(scale), block_k=block_k,
         precision=(jax.lax.Precision.HIGHEST if k.dtype == jnp.float32
-                   else None))
+                   else None), group=group)
     kw = dict(memory_space=pltpu.VMEM)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, groups, kp.shape[3] // block_k),
             in_specs=[
-                pl.BlockSpec((1, hb, 1, d), row_block, **kw),
+                pl.BlockSpec((1, hb, rows, d), row_block, **kw),
                 pl.BlockSpec((1, hb, d, block_k), kv_block, **kw),
                 pl.BlockSpec((1, hb, dv, block_k), kv_block, **kw),
             ],
-            out_specs=pl.BlockSpec((1, hb, 1, dv), row_block, **kw),
+            out_specs=pl.BlockSpec((1, hb, group, dv), row_block, **kw),
             scratch_shapes=[
                 pltpu.VMEM((hb, 8, 1), jnp.float32),
                 pltpu.VMEM((hb, 8, 1), jnp.float32),
                 pltpu.VMEM((hb, 8, dv), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, h, 1, dv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, group, dv), q.dtype),
         interpret=interpret,
         name="flash_decode",
     )(lengths, q, kp, vp)
+    return out.reshape(b, hq, 1, dv)
 
 
 # ---------------------------------------------------------------------------

@@ -555,8 +555,10 @@ class DecodeEngine:
             "dl4j_tpu_decode_state_bytes",
             "Bytes of the decode state that the active rows' positions have "
             "made valid, by kind of entry as the layers name them (a "
-            "windowed mixer: window, summary); host arithmetic, once a loop "
-            "turn", ("engine", "kind"))
+            "windowed mixer: window, summary; latent attention: latent; "
+            "grouped-query attention: kv; a short convolution's rolling "
+            "columns: conv, the same at every position); host arithmetic, "
+            "once a loop turn", ("engine", "kind"))
         self._g_kv_bytes = reg.gauge(
             "dl4j_tpu_generate_kv_cache_bytes",
             "Live resident bytes of the decode KV cache: the full "

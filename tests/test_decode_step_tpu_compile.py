@@ -314,3 +314,98 @@ def test_longcat_temporaries_fit_beside_weights_and_planes(longcat_programs,
                                                            name):
     ma = longcat_programs[name][1]
     assert ma.temp_size_in_bytes < LC_ROOM, ma.temp_size_in_bytes
+
+
+# --------------------------------------------------------------------- LFM2
+LF_SLOTS, LF_MAX_LEN, LF_HEADS, LF_KV = 128, 6144, 32, 8
+LF_PLANE = rf"bf16\[{LF_SLOTS},{LF_KV},{LF_MAX_LEN},64\]"
+LF_STATE = rf"bf16\[{LF_SLOTS},2,2048\]"
+# what the cell's memory reckoning leaves beside 9.33 GB of weights and
+# 4.83 GB of K/V planes on a chip of 16.9 GB (ISSUE 36)
+LF_ROOM = 2_700_000_000
+
+
+@pytest.fixture(scope="module")
+def lfm2_programs(one_chip):
+    """The LFM2 cell's step, install and largest prefill (the 4,096 bucket)
+    at LFM2-8B-A1B's published widths (ISSUE 36: 128 slots x 6,144
+    positions, 32 query heads over a K/V pair of 8 heads of 64, rolling
+    states of two columns of 2,048, 32 experts of 1,792 all held; 2 dense +
+    4 expert layers instead of 2 + 12), shapes only."""
+    from deeplearning4j_tpu.model.zoo import Lfm2MoeLM
+    from deeplearning4j_tpu.nn.sequential import MultiLayerNetwork
+    from deeplearning4j_tpu.obs.metrics import MetricsRegistry
+    from deeplearning4j_tpu.parallel.decode import DecodeEngine
+
+    tm = jax.tree_util.tree_map
+    with jax.enable_x64(False):
+        model = MultiLayerNetwork(Lfm2MoeLM(
+            vocab_size=65536, hidden=2048,
+            layer_types=("conv", "conv", "full_attention", "conv", "conv",
+                         "conv"),
+            n_dense_layers=2, n_heads=LF_HEADS, n_kv_heads=LF_KV,
+            ffn_size=7168, expert_ffn_size=1792, n_experts=32, top_k=4,
+            dtype="bfloat16").conf())
+        params = jax.eval_shape(lambda: model.init().params)
+        model.params = tm(lambda a: jnp.zeros((), a.dtype), params)
+        model._initialized = True
+        model.state = {n: {} for n in model.layer_names()}
+        model._persistent_keys = {n: () for n in model.layer_names()}
+        out = _compile_step_and_install(model, params, LF_SLOTS, LF_MAX_LEN,
+                                        one_chip)
+
+        def spec(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        eng = DecodeEngine(model, max_len=LF_MAX_LEN, slots=1,
+                           registry=MetricsRegistry())
+        try:
+            with pytest.MonkeyPatch.context() as mp:
+                mp.setattr(jax, "default_backend", lambda: "tpu")
+                c = eng._prefill_fn(4096).lower(
+                    tm(lambda a: spec(a.shape, a.dtype), params),
+                    tm(lambda a: spec(a.shape, a.dtype), model.state),
+                    spec((1, 4096), jnp.int32), spec((1,), jnp.int32),
+                    spec((1,), jnp.uint32), spec((1,), jnp.bool_),
+                    spec((1,), jnp.float32), spec((1,), jnp.int32),
+                    spec((1,), jnp.float32)).compile()
+            out["prefill_4096"] = (c.as_text(), c.memory_analysis(), 0)
+        finally:
+            eng.shutdown(drain=False)
+        return out
+
+
+@pytest.mark.parametrize("name", ["decode_step", "install_row"])
+def test_lfm2_planes_and_rolling_states_are_aliased_and_no_plane_is_copied(
+        lfm2_programs, name):
+    text, ma, planes = lfm2_programs[name]
+    # one attention layer of the six: a K and a V plane of 8 heads, 1.61 GB
+    assert planes == 2 * LF_SLOTS * LF_KV * LF_MAX_LEN * 64 * 2
+    states = 5 * LF_SLOTS * 2 * 2048 * 2       # five convolutions' columns
+    assert ma.alias_size_in_bytes >= planes + states, ma.alias_size_in_bytes
+    lines = text.splitlines()
+    for op in ("copy", "select", "transpose"):
+        hits = [l[:160] for l in lines
+                if re.search(rf"= {LF_PLANE}\S* {op}\(", l)]
+        assert not hits, hits[:3]
+    # the cache stays at 8 heads: nothing repeats it to the queries' 32, and
+    # no float32 scores [128, 32, 6144] stand outside the kernel
+    wide = [l[:160] for l in lines if re.search(
+        rf"\[{LF_SLOTS},{LF_HEADS},(\d+,)?{LF_MAX_LEN}(,\d+)?\]", l)]
+    assert not wide, wide[:3]
+
+
+def test_lfm2_step_holds_its_kernels_and_no_loop(lfm2_programs):
+    """One attention layer: one call of the grouped decode kernel and two
+    in-place writes; the rolling states are plain slices and selects."""
+    text = lfm2_programs["decode_step"][0]
+    assert text.count("tpu_custom_call") >= 3
+    assert "flash_decode" in text and "kv_cache_write" in text
+    assert not re.search(r" while\(", text)
+    assert re.search(LF_STATE, text)
+
+
+@pytest.mark.parametrize("name", ["decode_step", "prefill_4096"])
+def test_lfm2_temporaries_fit_beside_weights_and_planes(lfm2_programs, name):
+    ma = lfm2_programs[name][1]
+    assert ma.temp_size_in_bytes < LF_ROOM, ma.temp_size_in_bytes

@@ -162,3 +162,78 @@ def test_flash_backward_ragged_blocks():
     for g, r, name in zip(got, ref, "qkv"):
         np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=5e-4,
                                    rtol=1e-4, err_msg=f"d{name}")
+
+
+# ---------------------------------------------------------------------------
+# single-query decode over GROUPED heads (ISSUE 36)
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("dtype, tol", [(jnp.float32, 2e-6),
+                                        (jnp.bfloat16, 2e-2)])
+@pytest.mark.parametrize("group, h_kv", [(4, 8), (2, 3), (4, 1)])
+def test_grouped_flash_decode_equals_the_reference_over_repeated_heads(
+        group, h_kv, dtype, tol):
+    """``flash_decode`` (interpreted) with K and V of ``h_kv`` heads beside
+    ``group x h_kv`` query heads against ``decode_attention_reference`` with
+    the K/V heads REPEATED by hand, query head ``h`` reading pair ``h //
+    group``: rows at position 0, a block's edge on both sides, the whole
+    cache, and -1 (attends nothing: exactly 0); what lies past a row's
+    position may be anything, NaN included. float32 agrees to rounding;
+    bfloat16 to the rounding of the result (values of the order of 1)."""
+    from deeplearning4j_tpu.ops.flash_attention import (
+        decode_attention_reference, flash_decode_attention)
+
+    b, d, L = 6, 16, 600
+    ks = jax.random.split(jax.random.PRNGKey(group * 10 + h_kv), 3)
+    q = jax.random.normal(ks[0], (b, group * h_kv, 1, d), dtype)
+    k = jax.random.normal(ks[1], (b, h_kv, L, d), dtype)
+    v = jax.random.normal(ks[2], (b, h_kv, L, d), dtype)
+    pos = np.asarray([0, 255, 256, 599, 77, -1])
+    stale = np.arange(L)[None, :] > pos[:, None]
+    k, v = (jnp.where(stale[:, None, :, None], jnp.nan, a) for a in (k, v))
+    got = np.asarray(flash_decode_attention(
+        q, k, v, jnp.asarray(pos), interpret=True), np.float32)
+    rep = [jnp.repeat(jnp.nan_to_num(a), group, axis=1) for a in (k, v)]
+    assert rep[0].shape[1] == q.shape[1]
+    want = np.asarray(decode_attention_reference(
+        q, rep[0], rep[1], jnp.asarray(pos)), np.float32)
+    assert got.shape == (b, group * h_kv, 1, d)
+    assert np.isfinite(got).all() and not got[5].any()
+    np.testing.assert_allclose(got[:5], want[:5], atol=tol, rtol=0)
+    # the reference takes the grouped call itself, too (the XLA spelling)
+    np.testing.assert_allclose(
+        np.asarray(decode_attention_reference(
+            q, jnp.nan_to_num(k), jnp.nan_to_num(v), jnp.asarray(pos)),
+            np.float32)[:5], want[:5], atol=tol, rtol=0)
+
+
+def test_ungrouped_flash_decode_is_the_program_it_was():
+    """With as many K/V heads as query heads the call traces to what it
+    traced to before grouped heads: one query row a head into the kernel,
+    broadcast inside it, the same operations in the kernel's body; a group
+    of 3 does not fit the kernel's eight sublanes and is refused."""
+    from deeplearning4j_tpu.ops.flash_attention import flash_decode_attention
+
+    def trace(hq, hkv):
+        f32 = jnp.float32
+        return str(jax.make_jaxpr(lambda q, k, v, p: flash_decode_attention(
+            q, k, v, p, interpret=True))(
+            jnp.zeros((2, hq, 1, 16), f32), jnp.zeros((2, hkv, 300, 16), f32),
+            jnp.zeros((2, hkv, 300, 16), f32), jnp.zeros((2,), jnp.int32)))
+
+    def block(rows):
+        return ("Blocked(block_size=1), Blocked(block_size=4), "
+                f"Blocked(block_size={rows}), Blocked(block_size=16)")
+
+    plain, grouped = trace(4, 4), trace(8, 4)
+    assert plain.count("pallas_call") == grouped.count("pallas_call") == 1
+    assert "name=flash_decode" in plain and "name=flash_decode" in grouped
+    # the ungrouped call hands the kernel one query row a head, as it came,
+    # and takes one row a head back ...
+    assert "float32[2,4,1,16]" in plain and plain.count(block(1)) == 2
+    assert block(8) not in plain
+    # ... the grouped one eight rows a K/V head (the group's, twice over)
+    # and the group's two back
+    assert block(8) in grouped and block(2) in grouped
+    assert "float32[2,4,2,16]" in grouped
+    with pytest.raises(ValueError, match="group of 1, 2 or 4"):
+        trace(12, 4)

@@ -2,6 +2,8 @@
 absent upstream, implemented TPU-native here via dense one-hot dispatch
 and expert-dim sharding)."""
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -522,3 +524,162 @@ def test_share_layer_in_a_network_and_its_state():
                         LayerContext(train=False, rng=None, mask=mask))
     assert y.shape == x.shape
     assert float(st["choice_counts"].sum()) == 10 * 4
+
+
+# ---------------------------------------------------------------------------
+# sigmoid scoring with renormalised weights, every expert held (ISSUE 36)
+# ---------------------------------------------------------------------------
+def _sigmoid_layer(first=0, held=0, rows=128, k=4, renorm=True):
+    from deeplearning4j_tpu.nn.layers import ExpertShareMoELayer
+
+    return ExpertShareMoELayer(
+        n_in=16, hidden=8, n_routed_experts=32, n_held_experts=held,
+        first_held_expert=first, top_k=k, scoring="sigmoid",
+        norm_topk_prob=renorm, expert_rows=rows)
+
+
+def _sigmoid_params(seed=0):
+    """32 experts, the router spread so that the scores lie over (0.1, 0.9),
+    the selection bias of the order of the gaps between the top scores."""
+    p = _sigmoid_layer().init(jax.random.PRNGKey(seed), jnp.float32)
+    k1, k2 = jax.random.split(jax.random.PRNGKey(seed + 1))
+    p["Wr"] = jax.random.normal(k1, p["Wr"].shape, jnp.float32) * 0.4
+    p["br"] = jax.random.normal(k2, p["br"].shape, jnp.float32) * 0.05
+    return p
+
+
+def _sigmoid_by_hand(p, x, k=4, renorm=True, bias_in_weight=False):
+    """Token by token in NumPy float64: sigmoid scores, the choice by s + b,
+    the weight s_e / (the chosen's sum + 1e-6). Returns ``(the layer's
+    result, the weights' sums, the choices)``."""
+    p = {n: np.asarray(v, np.float64) for n, v in p.items()}
+    out, sums, chose = np.zeros(x.shape), [], []
+    for t, u in enumerate(np.asarray(x, np.float64)):
+        s = 1 / (1 + np.exp(-(u @ p["Wr"])))
+        chosen = np.argsort(-(s + p["br"]), kind="stable")[:k]
+        w = (s + p["br"] if bias_in_weight else s)[chosen]
+        if renorm:
+            w = w / (w.sum() + 1e-6)
+        for e, we in zip(chosen, w):
+            g = u @ p["Eg"][e]
+            out[t] += we * ((g / (1 + np.exp(-g)) * (u @ p["Eu"][e]))
+                            @ p["Ed"][e])
+        sums.append(w.sum())
+        chose.append(sorted(chosen.tolist()))
+    return out, np.asarray(sums), chose
+
+
+@pytest.mark.parametrize("rows", [128, 4], ids=["dense", "sorted"])
+def test_sigmoid_scoring_chooses_by_s_plus_b_and_renormalises(rows):
+    """Against a hand computation (float32 against float64: 1e-5 of values
+    of the order of 1). The bias moves the choice and never the weight; the
+    four weights sum to 1 less the 1e-6 term."""
+    p = _sigmoid_params()
+    x = jax.random.normal(jax.random.PRNGKey(9), (24, 16), jnp.float32)
+    y, counts = _sigmoid_layer(rows=rows).share(p, x)
+    want, sums, chose = _sigmoid_by_hand(p, x)
+    assert np.abs(want).max() > 0.1
+    np.testing.assert_allclose(np.asarray(y), want, atol=1e-5, rtol=0)
+    counts = np.asarray(counts)
+    assert counts[:, 32:].sum() == 0          # nothing absent, nothing zero
+    assert [np.nonzero(c[:32])[0].tolist() for c in counts] == chose
+    # the weights: 1 less the 1e-6 term's share, s_sum / (s_sum + 1e-6)
+    assert (sums < 1).all() and (sums > 1 - 1e-6).all()
+    # the bias moved some choice, and leaked into no weight
+    _, _, unbiased = _sigmoid_by_hand({**p, "br": 0 * p["br"]}, x)
+    assert unbiased != chose
+    leaked, _, _ = _sigmoid_by_hand(p, x, bias_in_weight=True)
+    assert np.abs(leaked - want).max() > 1e-3
+    # and unnormalised weights (about 3.4 in sum) would be another result
+    raw, raw_sums, _ = _sigmoid_by_hand(p, x, renorm=False)
+    assert raw_sums.min() > 2 and np.abs(raw - want).max() > 0.1
+    y_raw, _ = _sigmoid_layer(rows=rows, renorm=False).share(p, x)
+    np.testing.assert_allclose(np.asarray(y_raw), raw, atol=3e-5, rtol=0)
+
+
+@pytest.mark.parametrize("rows", [128, 4], ids=["dense", "sorted"])
+def test_four_shares_of_8_sigmoid_experts_add_up_to_the_uncut_layer(rows):
+    """The renormalisation is over ALL the chosen, wherever they are held:
+    four chips hold 8 of the 32 experts each, and their parts add up to the
+    layer that holds them all."""
+    p = _sigmoid_params(3)
+    x = jax.random.normal(jax.random.PRNGKey(5), (24, 16), jnp.float32)
+    whole, whole_counts = _sigmoid_layer(rows=rows).share(p, x)
+    total = 0.0
+    for first in (0, 8, 16, 24):
+        layer = _sigmoid_layer(first=first, held=8, rows=rows)
+        held, zero, counts = layer.parts(_cut(p, first, 8), x)
+        assert not np.asarray(zero).any()
+        total = total + held
+        counts, wc = np.asarray(counts), np.asarray(whole_counts)
+        np.testing.assert_array_equal(counts[:, :8], wc[:, first:first + 8])
+        np.testing.assert_array_equal(counts[:, 8], 4 - counts[:, :8].sum(1))
+    np.testing.assert_allclose(np.asarray(total), np.asarray(whole),
+                               atol=2e-6, rtol=0)
+
+
+def test_no_token_is_dropped_when_every_row_chooses_the_same_four():
+    """The worst load with every expert held: a bias sends all 300 tokens
+    to experts 3, 9, 20 and 31: 300 pairs each where the sorted form
+    grants ``sorted_rows(300)`` = 128 slots an expert. The call runs the
+    experts one at a time over every token instead, and every token gets
+    all four parts."""
+    p = _sigmoid_params(7)
+    br = np.full((32,), -2.0, np.float32)
+    br[[3, 9, 20, 31]] = 2.0
+    p["br"] = jnp.asarray(br)
+    x = jax.random.normal(jax.random.PRNGKey(2), (300, 16), jnp.float32)
+    layer = _sigmoid_layer(rows=128)
+    assert layer.sorted_rows(300) == 128 < 300
+    y, counts = layer.share(p, x)
+    loads = np.asarray(counts).sum(0)
+    assert loads[[3, 9, 20, 31]].tolist() == [300] * 4 and loads.sum() == 1200
+    want, _, _ = _sigmoid_by_hand(p, x)
+    np.testing.assert_allclose(np.asarray(y), want, atol=1e-5, rtol=0)
+
+
+def test_the_sorted_forms_rows_follow_the_calls_tokens():
+    """``SORTED_LOAD_FACTOR`` (3) times an expert's mean load, in whole
+    ``expert_rows``: LFM2's 32 experts top-4 at a prompt's buckets, and
+    LongCat's 768 outputs top-12, which stay at 128 up to its 1,024."""
+    from deeplearning4j_tpu.nn.layers import ExpertShareMoELayer
+
+    lfm2 = ExpertShareMoELayer(n_in=16, hidden=8, n_routed_experts=32,
+                               top_k=4)
+    assert [lfm2.sorted_rows(n) for n in (256, 512, 1024, 2048, 4096)] == [
+        128, 256, 384, 768, 1536]
+    longcat = ExpertShareMoELayer(n_in=16, hidden=8, n_routed_experts=512,
+                                  zero_expert_num=256, n_held_experts=16,
+                                  top_k=12)
+    assert {longcat.sorted_rows(n) for n in (256, 512, 1024)} == {128}
+
+
+@pytest.mark.parametrize("rows", [128, 4], ids=["dense", "sorted"])
+def test_scoring_and_norm_topk_prob_default_to_longcats_layer_bit_for_bit(
+        rows):
+    """The two fields at their defaults are the layer ISSUE 34 served:
+    softmax scores, ``6 s_e`` unnormalised, written out here from the
+    routing helpers as that layer had them."""
+    from deeplearning4j_tpu.nn.layers.moe import _router_logits
+    from deeplearning4j_tpu.ops.moe_dispatch import (biased_top_k_routing,
+                                                     held_expert_choices)
+
+    layer = _share_layer(first=4, held=4, rows=rows)
+    assert (layer.scoring, layer.norm_topk_prob) == ("softmax", False)
+    p = _cut(_share_params(11), 4, 4)
+    x = jax.random.normal(jax.random.PRNGKey(12), (24, 16), jnp.float32)
+    held, zero, counts = layer.parts(p, x)
+    scores = jax.nn.softmax(_router_logits(x, p["Wr"]), axis=-1)
+    vals, idx = biased_top_k_routing(scores, p["br"], 4, 6.0)
+    local, want_counts = held_expert_choices(idx, 4, 4, 16)
+    zero_w = jnp.sum(jnp.where(idx >= 16, vals, 0.0), axis=-1)
+    np.testing.assert_array_equal(np.asarray(zero),
+                                  np.asarray(zero_w[:, None] * x))
+    np.testing.assert_array_equal(np.asarray(counts), np.asarray(want_counts))
+    if rows == 128:
+        want = layer._held_dense(p, x, vals, local)
+        np.testing.assert_array_equal(np.asarray(held), np.asarray(want))
+    explicit = dataclasses.replace(layer, scoring="softmax",
+                                   norm_topk_prob=False)
+    np.testing.assert_array_equal(np.asarray(explicit.parts(p, x)[0]),
+                                  np.asarray(held))

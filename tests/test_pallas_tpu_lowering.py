@@ -71,6 +71,21 @@ def test_flash_decode_lowers(dtype, b, L):
 
 
 @pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("h, h_kv", [(32, 8), (4, 2)])
+def test_grouped_flash_decode_lowers(dtype, h, h_kv):
+    """K and V of fewer heads than the queries (LFM2's 32 over 8 at the
+    cell's 128 rows x 6,144 positions): the group's queries are rows of
+    one product."""
+    b, L, d = 128, 6144, 64
+    names = _kernels(
+        lambda q, k, v, p: flash_decode_attention(q, k, v, p,
+                                                  interpret=False),
+        _spec(b, h, 1, d, dtype=dtype), _spec(b, h_kv, L, d, dtype=dtype),
+        _spec(b, h_kv, L, d, dtype=dtype), _spec(b, dtype=jnp.int32))
+    assert names == ["flash_decode"]
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
 def test_eva_decode_lowers(dtype):
     """EvaByte's step at its published widths: 16 rows, 32 heads of 128,
     2,048 summaries and 2,048 singletons a plane."""

@@ -72,6 +72,40 @@ def test_flash_decode_compiled_matches_reference(tpu_device, b, L, block_k):
     assert (np.asarray(again, np.float32) == np.asarray(got, np.float32)).all()
 
 
+@pytest.mark.parametrize("h,h_kv,b,L", [
+    (32, 8, 8, 1024), (4, 2, 8, 600),
+    (32, 8, 128, 6144),         # the LFM2 cell's step: positions 200-6,100
+])
+def test_grouped_flash_decode_compiled_matches_reference(tpu_device, h, h_kv,
+                                                         b, L):
+    """K and V of fewer heads than the queries: the compiled kernel against
+    the reference over the K/V heads repeated by hand."""
+    d, g = 64, h // h_kv
+    q, k, v = (_rand(0, b, h, 1, d), _rand(1, b, h_kv, L, d),
+               _rand(2, b, h_kv, L, d))
+    if b == 8:
+        pos = np.asarray([0, L // 2 - 1, L // 2, L - 1, -1, 5, L // 3, L - 2])
+    else:
+        pos = np.random.RandomState(11).permutation(
+            np.linspace(200, 6100, b).astype(np.int32))
+        pos[4] = -1
+    run = jax.jit(lambda *a: flash_decode_attention(*a, interpret=False))
+    start = jnp.asarray(pos, jnp.int32)
+    got = run(q, k, v, start)
+    # sixteen rows at a time: the repeated planes of all 128 in float32
+    # would be 12.9 GB
+    ref = np.concatenate([np.asarray(_highest(
+        decode_attention_reference, q[r:r + 16],
+        jnp.repeat(k[r:r + 16], g, axis=1), jnp.repeat(v[r:r + 16], g, axis=1),
+        start[r:r + 16])) for r in range(0, b, 16)])
+    assert _rel(got, ref) < FWD_TOL
+    assert not np.asarray(got, np.float32)[4].any()
+    stale = np.arange(L)[None, :] > pos[:, None]              # [b, L]
+    k2, v2 = (jnp.where(stale[:, None, :, None], jnp.nan, a) for a in (k, v))
+    again = run(q, k2, v2, start)
+    assert (np.asarray(again, np.float32) == np.asarray(got, np.float32)).all()
+
+
 @pytest.mark.parametrize("t,dtype", [(1, jnp.bfloat16), (1, jnp.int8),
                                      (4, jnp.bfloat16)])
 def test_masked_cache_write_compiled_drops_idle_rows(tpu_device, t, dtype):
