@@ -28,6 +28,7 @@ re-forward at every position) is enforced in tier-1
 
 from __future__ import annotations
 
+import struct
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
@@ -52,6 +53,20 @@ def bucket_length(n: int, limit: int) -> int:
 
 
 CACHE_DTYPES = (None, "int8")
+
+
+# one prompt's admission as one int32 vector: its length, the slot it is for,
+# then the sampling law; the seed and the two floats ride as their bits
+_ROW_SPEC = struct.Struct("<iiIifif")
+ROW_SPEC_WORDS = _ROW_SPEC.size // 4
+
+
+def pack_row_spec(length: int, slot: int, seed: int, greedy: bool,
+                  temperature: float, top_k: int, top_p: float) -> np.ndarray:
+    """The operand of :meth:`GenerationSession.prefill_row` beside the ids:
+    ``int32[ROW_SPEC_WORDS]``, bit-exact for the seed and the floats."""
+    return np.frombuffer(_ROW_SPEC.pack(
+        length, slot, seed, greedy, temperature, top_k, top_p), np.int32)
 
 
 def quantize_decode_state(st):
@@ -259,6 +274,23 @@ class GenerationSession:
         first, last = piece(carry, ids[:, :w], 0)  # fixes the loop's types
         return jax.lax.fori_loop(
             1, (jnp.max(lengths) + w - 1) // w, window, (first, last))
+
+    def prefill_row(self, params, state, ids, spec):
+        """One prompt ``ids`` [1, t] (right-padded) under ``spec``
+        (:func:`pack_row_spec`) -> ``(row, first token, counts)``: the
+        prompt through the model on a fresh one-row carry, made here, its
+        first token sampled at decode step 0, and what the layers counted
+        of the prompt (``{}`` for a model that counts nothing). The one
+        body of every tier that prefills: a serving engine installs the
+        row inside the same program, a prefill tier ships it."""
+        bits = jax.lax.bitcast_convert_type
+        row, last = self.prefill_logits(
+            params, state, self.decode_state(1), ids, spec[0:1])
+        tok = sample_tokens(
+            last, bits(spec[2:3], jnp.uint32), jnp.zeros((1,), jnp.int32),
+            spec[3:4] != 0, bits(spec[4:5], jnp.float32), spec[5:6],
+            bits(spec[6:7], jnp.float32))
+        return row, tok[0], self.summed_counts(row)
 
     # ----- jitted steps -----------------------------------------------
     def _prefill_fn(self, t_bucket: int):

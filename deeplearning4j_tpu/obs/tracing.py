@@ -381,6 +381,11 @@ class TraceStore:
     ``max_spans_per_trace`` spans are kept per trace (later spans are
     counted, not stored — a runaway fan-out cannot grow a trace without
     bound). ``tools/check_trace_contract.py`` enforces both bounds.
+    A trace that a profiler session took (a span marked ``profiled``) goes
+    only when no other is left to go: the slice a session traced stays
+    whole in the store however many head-sampled traces come after it (a
+    decode loop of a hundred turns a second fills the store in half a
+    minute at the default rate).
     """
 
     def __init__(self, max_traces: int = 256,
@@ -397,17 +402,24 @@ class TraceStore:
         with self._lock:
             entry = self._traces.get(tid)
             if entry is None:
-                entry = {"spans": [], "dropped": 0}
+                entry = {"spans": [], "dropped": 0, "profiled": False}
                 self._traces[tid] = entry
             else:
                 self._traces.move_to_end(tid)
+            if span["attrs"].get("profiled"):
+                entry["profiled"] = True
             if len(entry["spans"]) >= self.max_spans_per_trace:
                 entry["dropped"] += 1
                 self.dropped_spans += 1
             else:
                 entry["spans"].append(span)
             while len(self._traces) > self.max_traces:
-                self._traces.popitem(last=False)
+                oldest = next((t for t, e in self._traces.items()
+                               if not e["profiled"]), None)
+                if oldest is None:
+                    self._traces.popitem(last=False)
+                else:
+                    del self._traces[oldest]
                 self.evicted_traces += 1
 
     def __len__(self) -> int:

@@ -56,12 +56,15 @@ the registry; traced requests get ``engine.queue_wait``,
 ``engine.prefill`` and ``engine.decode`` child spans in ``/v1/traces``.
 Every pass of the loop that has work is itself a ``loop.turn`` trace of
 the engine's tracer (children ``loop.admit`` > ``loop.prefill`` >
-``loop.prefill.dispatch``/``loop.install``; ``loop.step`` >
-``loop.upload``/``loop.dispatch`` of this turn's step, then
-``loop.fetch``/``loop.emit`` of the step before and again of this turn's
-prefills' first tokens; ``loop.sweep``),
+``loop.prefill.dispatch``, and ``loop.install`` for a handed-over row;
+``loop.step`` > ``loop.upload``/``loop.dispatch`` of this turn's step, then
+``loop.fetch``/``loop.emit`` of the step before and again of each of this
+turn's prefills' first tokens; ``loop.sweep``),
 head-sampled like a request and taken whole while a ``jax.profiler``
-session collects (README "Tracing").
+session collects (README "Tracing"). What a turn costs the host in device
+calls it says itself: ``uploads``, ``programs`` and ``fetches`` on the
+turn (ISSUE 37: a step is one upload and one program, an admitted prompt
+one of each more).
 """
 
 from __future__ import annotations
@@ -71,6 +74,7 @@ import queue
 import threading
 import time
 from collections import Counter, deque
+from functools import partial
 from typing import Callable, List, Optional, Sequence
 
 import numpy as np
@@ -97,7 +101,8 @@ from ..generate.paged import (
     paged_decode_state,
 )
 from ..generate.sampling import PATHS, sample_tokens, sampler_path
-from ..generate.session import GenerationSession, SpeculativeGenerationSession
+from ..generate.session import (ROW_SPEC_WORDS, GenerationSession,
+                                SpeculativeGenerationSession, pack_row_spec)
 from ..ops.flash_attention import decode_fetched_entries
 from ..ops.paged_attention import pack_row_blocks
 from ..obs.metrics import MetricsRegistry, get_registry
@@ -109,6 +114,11 @@ _engine_seq = itertools.count()
 
 _OUTCOMES = ("completed", "deadline", "cancelled", "shed", "failed",
              "circuit_rejected")
+# what the loop asks of the device, by kind of call (the counter's label),
+# and what a turn's span calls its count of each
+_CALLS = ("upload", "program", "fetch")
+_CALL_ATTRS = ("uploads", "programs", "fetches")
+_UPLOAD, _PROGRAM, _FETCH = range(3)
 
 
 class GenerationHandle:
@@ -235,6 +245,37 @@ class _InFlight:
         self.counts = counts
 
 
+def install_row(carry, row, i):
+    """A one-row carry into row ``i`` of the batch carry: one static
+    ``dynamic_update_slice`` a leaf, in place where the carry is donated."""
+    def put(c, r):
+        z = jnp.zeros((), i.dtype)
+        return jax.lax.dynamic_update_slice(
+            c, r.astype(c.dtype), (i,) + (z,) * (c.ndim - 1))
+
+    return jax.tree_util.tree_map(put, carry, row)
+
+
+def paged_install(carry, row, dest, slot, block_size: int):
+    """A one-row STATIC carry into the paged batch carry: each cache plane
+    packed into block units and scattered at the slot's block ids (``dest``,
+    static length max_len/bs: the unallocated tail is id 0, so pad blocks
+    land in trash); the position counters at ``slot``."""
+    out = {}
+    for name, st in carry.items():
+        r = row[name]
+        new_st = dict(st)
+        for key, pool in st.items():
+            if key == "pos":
+                new_st[key] = jax.lax.dynamic_update_slice(
+                    pool, r["pos"].astype(pool.dtype), (slot,))
+            else:
+                packed = pack_row_blocks(r[key][0], block_size)
+                new_st[key] = pool.at[dest].set(packed.astype(pool.dtype))
+        out[name] = new_st
+    return out
+
+
 class DecodeEngine:
     def __init__(
         self,
@@ -349,7 +390,10 @@ class DecodeEngine:
         # paged layout's block table is held beside it (``_table``), never
         # inside: one buffer under twelve layers cannot be donated twelve
         # times
-        self._table = None
+        self._table, self._table_stale = None, False
+        # this turn's device calls by kind (``_CALLS``), counted where they
+        # are made
+        self._calls = [0, 0, 0]
         if self.block_size is not None:
             self._allocator = BlockAllocator(self.num_kv_blocks)
             # host image of every row's block list (pushed to the device
@@ -393,12 +437,12 @@ class DecodeEngine:
         # set instead (``_last``: a speculative turn commits on the host)
         self._toks = jnp.zeros((self.slots,), jnp.int32)
         self._fresh = np.zeros((self.slots,), bool)
-        # the step dispatched and not yet fetched, and the first token of
-        # the prefill dispatched and not yet fetched: (slot, request, token
-        # on the device, what the layers counted of the prompt, when its
-        # prefill's dispatch began)
+        # the step dispatched and not yet fetched, and the first tokens of
+        # the turn's admissions, in their order, dispatched and not yet
+        # fetched: (slot, request, token on the device, what the layers
+        # counted of the prompt, when its prefill's dispatch began)
         self._flight: Optional[_InFlight] = None
-        self._first: Optional[tuple] = None
+        self._first: List[tuple] = []
         self._seeds = np.zeros((self.slots,), np.uint32)
         self._greedy = np.ones((self.slots,), bool)
         self._temps = np.ones((self.slots,), np.float32)
@@ -522,6 +566,15 @@ class DecodeEngine:
             "(ops.flash_attention.decode_fetched_entries); attended over "
             "fetched is the share of the kernel's bytes that are valid",
             ("engine",)).labels(inst)
+        calls = reg.counter(
+            "dl4j_tpu_decode_device_calls_total",
+            "What the engine's loop asked of the device, by kind of call: "
+            "upload (a host array sent), program (a jitted program "
+            "dispatched), fetch (an array brought to the host). A decode "
+            "step is one upload and one program, an admitted prompt one of "
+            "each more, a landed step or first token one fetch (two where "
+            "the model's layers count)", ("engine", "kind"))
+        self._c_calls = [calls.labels(inst, kind) for kind in _CALLS]
         sampler = reg.counter(
             "dl4j_tpu_decode_sampler_steps_total",
             "Decode steps dispatched, by the work their rows' sampling "
@@ -597,6 +650,21 @@ class DecodeEngine:
         copy: the host image is written again while a step that was given
         the table may still be in flight."""
         self._table = jnp.asarray(self._block_tables.copy())
+        self._table_stale = False
+        self._calls[_UPLOAD] += 1
+
+    @property
+    def _table_width(self) -> int:
+        """Block ids a row's list holds (0 for the static layout)."""
+        return 0 if self._allocator is None else self._block_tables.shape[1]
+
+    def _device_table(self):
+        """The block table for a program that takes it: pushed here if a
+        row's block list changed since the last push, so once a turn at
+        most, however many rows grew, were admitted or retired."""
+        if self._table_stale:
+            self._push_tables()
+        return self._table
 
     def _fresh_carry(self):
         """A zeroed batch carry: the static per-layer caches, or the
@@ -619,7 +687,7 @@ class DecodeEngine:
         ids = self._allocator.alloc(need - held)
         self._block_tables[slot, held:need] = ids
         self._nblocks[slot] = need
-        self._push_tables()
+        self._table_stale = True
         self._update_kv_bytes()
 
     def _release_blocks(self, slot: int) -> None:
@@ -630,7 +698,7 @@ class DecodeEngine:
             self._allocator.free(self._block_tables[slot, :held].tolist())
             self._block_tables[slot, :held] = 0
             self._nblocks[slot] = 0
-            self._push_tables()
+            self._table_stale = True
             self._update_kv_bytes()
 
     def _preempt_row(self, slot: int, why: str) -> None:
@@ -662,7 +730,7 @@ class DecodeEngine:
                     self._ensure_blocks(int(slot),
                                         int(self._pos[slot]) + ahead)
                 except OutOfBlocksError:
-                    if self._flight is None and self._first is None:
+                    if self._flight is None and not self._first:
                         raise
                     self._drain(parent)
                     rows &= self._active
@@ -687,25 +755,56 @@ class DecodeEngine:
 
     # ----- jitted steps -----------------------------------------------
     def _prefill_fn(self, tb: int):
+        """jit: an admitted prompt, whole (ISSUE 37). ``adm`` is the
+        admission's one upload (``_prefill_args``). The row and its first
+        token are ``GenerationSession.prefill_row``'s, installed HERE: the
+        row into the donated batch carry at its slot, the token into the
+        token vector (not donated: the step in flight still holds it for
+        its fetch). No buffer the size of a slot outlives the program, so
+        a turn may dispatch every admission it has before it fetches
+        anything."""
         key = ("prefill", tb)
         if key not in self._fns:
             sess = self.session
-            model = sess.model
+            bs = self.block_size
+            ids_at = ROW_SPEC_WORDS + self._table_width
 
-            def fn(params, state, ids, lengths, seed, gflag, temp, k, p):
-                # a fresh row's carry is zeros: made here, not handed in
-                new_rnn, last = sess.prefill_logits(
-                    params, state, sess.decode_state(1), ids, lengths)
-                tok = sample_tokens(last, seed, jnp.zeros((1,), jnp.int32),
-                                    gflag, temp, k, p)
+            def fn(params, state, carry, toks, adm):
+                spec = adm[:ROW_SPEC_WORDS]
+                row, tok, counts = sess.prefill_row(
+                    params, state, adm[None, ids_at:], spec)
+                slot = spec[1]
+                if bs is None:
+                    carry = install_row(carry, row, slot)
+                else:
+                    carry = paged_install(carry, row,
+                                          adm[ROW_SPEC_WORDS:ids_at], slot, bs)
                 # what the layers counted of the prompt comes home with
                 # its first token ({} for a model that counts nothing)
-                return new_rnn, tok[0], sess.summed_counts(new_rnn)
+                return (carry, toks.at[slot].set(tok.astype(toks.dtype)),
+                        tok, counts)
 
             # the profiler's "XLA Modules" line shows jit_<name>
             fn.__name__ = f"prefill_{tb}"
-            self._fns[key] = jax.jit(fn)
+            self._fns[key] = jax.jit(fn, donate_argnums=2)
         return self._fns[key]
+
+    def _prefill_args(self, tb: int, slot: int, req: "_Request") -> tuple:
+        """``_prefill_fn(tb)``'s operands after the carry, on the device:
+        the token vector, then the admission as ONE array: the row's spec
+        (its length, its slot, its sampling law), the slot's block ids for
+        a paged carry (the unallocated tail 0), the prompt right-padded to
+        its bucket."""
+        nb = self._table_width
+        adm = np.zeros((ROW_SPEC_WORDS + nb + tb,), np.int32)
+        adm[:ROW_SPEC_WORDS] = pack_row_spec(
+            len(req.prompt), slot, req.seed, req.greedy, req.temp,
+            req.top_k, req.top_p)
+        if nb:
+            held = int(self._nblocks[slot])
+            adm[ROW_SPEC_WORDS:][:held] = self._block_tables[slot, :held]
+        adm[ROW_SPEC_WORDS + nb:][:len(req.prompt)] = req.prompt
+        return self._toks, jnp.asarray(adm)
 
     def _draft_prefill_fn(self, tb: int):
         """jit: 1-row draft prefill (cache build only — the draft's
@@ -733,9 +832,13 @@ class DecodeEngine:
             sess = self.session
             model = sess.model
 
-            def decode_step(params, state, carry, toks, last, fresh,
-                            active, seeds, steps, gmask, temps, ks, ps,
-                            table=None):
+            def decode_step(params, state, carry, toks, image, table=None):
+                # the host's image of the rows, one array (``_step_args``)
+                bits = jax.lax.bitcast_convert_type
+                last, steps, ks = image[0], image[4], image[7]
+                fresh, active, gmask = (image[i] != 0 for i in (1, 2, 5))
+                seeds = bits(image[3], jnp.uint32)
+                temps, ps = (bits(image[i], jnp.float32) for i in (6, 8))
                 # a row steps from the token the step before sampled, or its
                 # prefill, which never left the device; a row whose last
                 # token was committed on the host (``fresh``) from that
@@ -772,23 +875,16 @@ class DecodeEngine:
         return self._fns["decode"]
 
     def _write_row_fn(self):
+        """jit: a row that no prefill of this engine made (a handed-over
+        row, the draft model's) into a batch carry."""
         if "write" not in self._fns:
-            def install_row(carry, row, i):
-                def put(c, r):
-                    z = jnp.zeros((), i.dtype)
-                    idx = (i,) + (z,) * (c.ndim - 1)
-                    return jax.lax.dynamic_update_slice(
-                        c, r.astype(c.dtype), idx)
-
-                return jax.tree_util.tree_map(put, carry, row)
-
             self._fns["write"] = jax.jit(install_row, donate_argnums=0)
         return self._fns["write"]
 
     def _set_token_fn(self):
-        """jit: a prefill's first token into the device's token vector, at
-        its row: dispatched beside the install, so the row's first step
-        needs nothing of the host."""
+        """jit: a handed-over row's first token into the device's token
+        vector, at its row: dispatched beside the install, so the row's
+        first step needs nothing of the host."""
         if "set_token" not in self._fns:
             def set_token(toks, i, tok):
                 return toks.at[i].set(tok.astype(toks.dtype))
@@ -797,47 +893,33 @@ class DecodeEngine:
         return self._fns["set_token"]
 
     def _paged_install_fn(self):
-        """jit: install a 1-row STATIC prefill carry into the paged batch
-        carry — pack each cache plane into block units and scatter them
-        at the slot's block ids (``dest``, static length max_len/bs: the
-        unallocated tail is id 0, so pad blocks land in trash). One
-        compiled program total, regardless of prompt length."""
+        """jit: a handed-over 1-row STATIC carry into the paged batch carry
+        (``paged_install``). One compiled program, whatever the prompt's
+        length."""
         if "paged_install" not in self._fns:
-            bs = self.block_size
-
-            def paged_install(carry, row, dest, slot):
-                out = {}
-                for name, st in carry.items():
-                    r = row[name]
-                    new_st = dict(st)
-                    for key, pool in st.items():
-                        if key == "pos":
-                            new_st[key] = jax.lax.dynamic_update_slice(
-                                pool, r["pos"].astype(pool.dtype), (slot,))
-                        else:
-                            packed = pack_row_blocks(r[key][0], bs)
-                            new_st[key] = pool.at[dest].set(
-                                packed.astype(pool.dtype))
-                    out[name] = new_st
-                return out
-
-            self._fns["paged_install"] = jax.jit(paged_install,
-                                                 donate_argnums=0)
+            self._fns["paged_install"] = jax.jit(
+                partial(paged_install, block_size=self.block_size),
+                donate_argnums=0)
         return self._fns["paged_install"]
 
-    def _install_row(self, slot: int, row) -> None:
-        """Scatter a fresh 1-row target carry into the batch carry (the
-        static dynamic-update-slice, or the paged block scatter)."""
+    def _install_row(self, slot: int, row, tok) -> None:
+        """A handed-over row into the batch carry (the static
+        dynamic-update-slice, or the paged block scatter) and its first
+        token into the token vector: the one admission that has no prefill
+        to ride in."""
+        at = jnp.asarray(slot, jnp.int32)
         if self._allocator is None:
-            self._carry = self._write_row_fn()(
-                self._carry, row, jnp.asarray(slot, jnp.int32))
-            return
-        dest = np.zeros((self._block_tables.shape[1],), np.int32)
-        held = int(self._nblocks[slot])
-        dest[:held] = self._block_tables[slot, :held]
-        self._carry = self._paged_install_fn()(
-            self._carry, row, jnp.asarray(dest),
-            jnp.asarray(slot, jnp.int32))
+            self._carry = self._write_row_fn()(self._carry, row, at)
+            self._calls[_UPLOAD] += 1
+        else:
+            dest = np.zeros((self._table_width,), np.int32)
+            held = int(self._nblocks[slot])
+            dest[:held] = self._block_tables[slot, :held]
+            self._carry = self._paged_install_fn()(
+                self._carry, row, jnp.asarray(dest), at)
+            self._calls[_UPLOAD] += 2
+        self._toks = self._set_token_fn()(self._toks, at, tok)
+        self._calls[_PROGRAM] += 2
 
     # ----- client side ------------------------------------------------
     def submit(
@@ -1088,14 +1170,7 @@ class DecodeEngine:
                              100.0 * (1.0 - len(req.prompt) / tb))
         if self._window:
             parent.set_attribute("windows", len(req.prompt) // self._window)
-        # one prefill in flight at a time: its row is as large as a slot
-        # (0.5 GB at EvaByte's widths), and a turn that admits sixteen
-        # must not hold sixteen. A turn's last prefill alone stays
-        # unfetched until the turn's step is dispatched
-        self._land_first(parent)
         with span("loop.prefill.dispatch", parent=parent):
-            ids = np.zeros((1, tb), np.int32)
-            ids[0, : len(req.prompt)] = req.prompt
             t0 = time.perf_counter()
             tt0 = trace_now() if req.trace_ctx is not None else 0.0
             if req.prefilled is not None:
@@ -1104,18 +1179,17 @@ class DecodeEngine:
                 # its shipped cache slice instead of recomputing
                 (row, tok), counts = self._handoff_row(req.prefilled), {}
             else:
-                row, tok, counts = self._prefill_fn(tb)(
-                    sess.model.params, sess.model.state, jnp.asarray(ids),
-                    jnp.asarray([len(req.prompt)], jnp.int32),
-                    jnp.asarray([req.seed], jnp.uint32),
-                    jnp.asarray([req.greedy], bool),
-                    jnp.asarray([req.temp], jnp.float32),
-                    jnp.asarray([req.top_k], jnp.int32),
-                    jnp.asarray([req.top_p], jnp.float32))
-        with span("loop.install", parent=parent):
-            self._install_row(slot, row)
-            self._toks = self._set_token_fn()(
-                self._toks, jnp.asarray(slot, jnp.int32), tok)
+                # one upload, one program: the row and its first token are
+                # installed where they are computed, and nothing is fetched
+                # before the turn's step is dispatched
+                self._carry, self._toks, tok, counts = self._prefill_fn(tb)(
+                    sess.model.params, sess.model.state, self._carry,
+                    *self._prefill_args(tb, slot, req))
+                self._calls[_UPLOAD] += 1
+                self._calls[_PROGRAM] += 1
+        if req.prefilled is not None:
+            with span("loop.install", parent=parent):
+                self._install_row(slot, row, tok)
         cap = -1 if req.spec_k is None else min(req.spec_k,
                                                 self.max_speculative_k)
         if self._spec is not None and cap != 0:
@@ -1123,12 +1197,16 @@ class DecodeEngine:
             # condition on the same committed prefix the target verifies.
             # For handoffs this re-runs the (cheap) draft prefill locally:
             # the draft cache never crosses the wire.
+            ids = np.zeros((1, tb), np.int32)
+            ids[0, : len(req.prompt)] = req.prompt
             drow = self._draft_prefill_fn(tb)(
                 self._spec.draft.model.params, self._spec.draft.model.state,
                 self._draft_row, jnp.asarray(ids),
                 jnp.asarray([len(req.prompt)], jnp.int32))
             self._draft_carry = self._write_row_fn()(
                 self._draft_carry, drow, jnp.asarray(slot, jnp.int32))
+            self._calls[_UPLOAD] += 3
+            self._calls[_PROGRAM] += 2
         self._breaker.record_success()
         if req.trace_ctx is not None:
             rec = self.tracer.make_record(
@@ -1138,7 +1216,7 @@ class DecodeEngine:
             self.tracer.record_spans([rec])
             req.t_decode_start = trace_now()
         # install the slot; the first token is fetched and emitted once
-        # the turn's step is dispatched (``_land_first``)
+        # the turn's step is dispatched (``_land_firsts``)
         self._requests[slot] = req
         self._active[slot] = True
         self._fresh[slot] = False
@@ -1153,7 +1231,7 @@ class DecodeEngine:
         self._pos[slot] = len(req.prompt)  # committed cache frontier
         self._spec_caps[slot] = cap
         self._g_active.set(int(self._active.sum()))
-        self._first = (slot, req, tok, counts, t0)
+        self._first.append((slot, req, tok, counts, t0))
 
     def _handoff_row(self, h: dict):
         """Rebuild a 1-row target carry from a serialized prefill handoff
@@ -1188,6 +1266,7 @@ class DecodeEngine:
                 full[:, :, :pos] = arr
                 new_st[key] = jnp.asarray(full, t.dtype)
             row[name] = new_st
+        self._calls[_UPLOAD] += 1 + len(jax.tree_util.tree_leaves(row))
         return row, jnp.asarray(int(h["first_token"]), jnp.int32)
 
     def _retire_if_done(self, slot: int, last_token: int, emitted: int) -> None:
@@ -1221,7 +1300,7 @@ class DecodeEngine:
         whatever was computed from that carry is lost with it)."""
         if not lost and not self._drain():
             return  # the landing failed, and has failed them all
-        self._flight = self._first = None
+        self._flight, self._first = None, []
         self._toks = jnp.zeros((self.slots,), jnp.int32)
         self._breaker.record_failure()
         # before any caller hears of the failure
@@ -1260,8 +1339,8 @@ class DecodeEngine:
         every active slot — the non-speculative path, and the boundary
         fallback for rows whose remaining cache room cannot hold a k+1
         window), THEN fetch and emit the step before it and the first
-        token of this turn's last prefill: the host's work on step n-1
-        lies under the device's work on step n. A row whose request is
+        tokens of this turn's prefills, in their order: the host's work on
+        step n-1 lies under the device's work on step n. A row whose request is
         complete with the token in flight sits this step out; with no row
         left to step, the turn only lands what is in flight. ``parent`` is
         the turn's ``loop.step`` span."""
@@ -1283,11 +1362,11 @@ class DecodeEngine:
         else:
             self._flight = None
         if prev is None or self._land(prev, parent):
-            self._land_first(parent)
+            self._land_firsts(parent)
 
     def _dispatch(self, rows: np.ndarray, parent=NULL_SPAN) -> _InFlight:
-        """Upload the rows' specs and enqueue their step, which takes its
-        tokens where the step before and the installs left them, on the
+        """Upload the rows' image and enqueue their step, which takes its
+        tokens where the step before and the prefills left them, on the
         device. The rows' sampling position and cache frontier advance
         here: the host's image of a row is one token ahead of what its
         request has been handed."""
@@ -1299,7 +1378,9 @@ class DecodeEngine:
         with span("loop.dispatch", parent=parent):
             self._carry, self._toks, counts = self._decode_step_fn()(
                 sess.model.params, sess.model.state, self._carry, *args,
-                self._table)
+                self._device_table())
+        self._calls[_UPLOAD] += 1
+        self._calls[_PROGRAM] += 1
         self._c_sampler[int(sampler_path(
             self._greedy | ~rows, self._ks, self._ps))].inc()
         if self._static_kv:
@@ -1314,13 +1395,20 @@ class DecodeEngine:
 
     def _step_args(self, rows: np.ndarray) -> tuple:
         """The decode step's operands after the carry, on the device: the
-        token vector that never left it, then the host's image of the rows.
-        Of copies: a transfer reads the host's array after the call that
-        asked for it returns, and the host writes these again (the next
-        dispatch, the next install) while the step is still in flight."""
-        return (self._toks,) + tuple(jnp.asarray(a.copy()) for a in (
-            self._last, self._fresh, rows, self._seeds, self._steps,
-            self._greedy, self._temps, self._ks, self._ps))
+        token vector that never left it, then the host's image of the
+        rows, ONE array (ISSUE 37). A transfer reads the host's array
+        after the call that asked for it returns, and the host writes its
+        arrays again (the next dispatch, the next admission) while the
+        step is still in flight: the image is built anew every time and
+        never written again, so it is the copy that makes that safe."""
+        arrays = (self._last, self._fresh, rows, self._seeds, self._steps,
+                  self._greedy, self._temps, self._ks, self._ps)
+        image = np.empty((len(arrays), self.slots), np.int32)
+        # every entry is 4 bytes or fewer: the seeds and the two float
+        # rows ride as their bits, and ``decode_step`` takes them apart
+        for i, a in enumerate(arrays):
+            image[i] = a.view(np.int32) if a.itemsize == 4 else a
+        return self._toks, jnp.asarray(image)
 
     def _land(self, step: _InFlight, parent=NULL_SPAN) -> bool:
         """Fetch a dispatched step's tokens and hand each to its request.
@@ -1331,8 +1419,10 @@ class DecodeEngine:
         try:
             with span("loop.fetch", parent=parent):
                 toks_h = np.asarray(step.toks)
+                self._calls[_FETCH] += 1
                 if step.counts:  # the same program's: they are there
                     self._count(jax.device_get(step.counts))
+                    self._calls[_FETCH] += 1
         except Exception as e:  # noqa: BLE001 — poisoned step: fail active requests
             self._fail_active(e, lost=True)
             return False
@@ -1381,20 +1471,24 @@ class DecodeEngine:
         self._c_moe_choices.labels(self.name, "held").inc(held)
         return {"moe_held_pairs": held}
 
-    def _land_first(self, parent=NULL_SPAN) -> bool:
-        """Fetch and emit, as index 0, the first token of the prefill in
-        flight, if any. False when the prefill died at run time: it sat
-        between two steps in the device's queue, so every active request
-        has then failed."""
-        first, self._first = self._first, None
-        if first is None:
-            return True
+    def _land_firsts(self, parent=NULL_SPAN) -> bool:
+        """Fetch and emit, each as index 0 and in the order of their
+        admission, the first tokens of the prefills in flight. False when
+        one died at run time: it sat between two steps in the device's
+        queue and took the carry with it, so every active request has then
+        failed."""
+        firsts, self._first = self._first, []
+        return all(self._land_first(first, parent) for first in firsts)
+
+    def _land_first(self, first: tuple, parent=NULL_SPAN) -> bool:
         slot, req, tok, counts, t0 = first
         span = self.tracer.span
         try:
             with span("loop.fetch", parent=parent) as fetch:
                 tok = int(tok)
+                self._calls[_FETCH] += 1
                 if counts:
+                    self._calls[_FETCH] += 1
                     # the prompt's counts: the prefill's own span closed at
                     # its dispatch, so the span that lands them says them
                     fetch.set_attribute("req", req.seq)
@@ -1414,12 +1508,12 @@ class DecodeEngine:
         return True
 
     def _drain(self, parent=NULL_SPAN) -> bool:
-        """Land the step in flight and the first token in flight, if any:
+        """Land the step in flight and the first tokens in flight, if any:
         afterwards the host's image of every row (``_last``, the handles)
         is current."""
         step, self._flight = self._flight, None
         return (step is None or self._land(step, parent)) \
-            and self._land_first(parent)
+            and self._land_firsts(parent)
 
     def _spec_step(self, parent=NULL_SPAN) -> None:
         """One speculative engine turn: propose/verify/accept for every
@@ -1461,7 +1555,8 @@ class DecodeEngine:
                     # inside the carry, and donates nothing
                     (carry, self._draft_carry, toks, n_acc,
                      n_emit) = self._spec.step(
-                        attach_block_table(self._carry, self._table),
+                        attach_block_table(self._carry,
+                                           self._device_table()),
                         self._draft_carry, self._last,
                         self._steps, spec_rows, jnp.asarray(self._seeds),
                         jnp.asarray(self._greedy), jnp.asarray(self._temps),
@@ -1471,6 +1566,10 @@ class DecodeEngine:
                     toks_h = np.asarray(toks)
                     acc_h = np.asarray(n_acc)
                     ne_h = np.asarray(n_emit)
+                    # the session uploads its nine operands a turn
+                    self._calls[_UPLOAD] += 9
+                    self._calls[_PROGRAM] += 1
+                    self._calls[_FETCH] += 3
             except Exception as e:  # noqa: BLE001
                 self._fail_active(e)
                 return
@@ -1551,6 +1650,7 @@ class DecodeEngine:
         span = self.tracer.span
         self._n_turns += 1
         finished = self._n_finished
+        self._calls = [0, 0, 0]
         with span("loop.turn", parent=None, attrs={
                 "engine": self.name, "turn": self._n_turns,
                 "pending": len(self._pending)}) as turn:
@@ -1582,6 +1682,10 @@ class DecodeEngine:
                     self.adjust()
                     self._next_adjust = self._clock() + self._adjust_interval
             turn.set_attribute("retired", self._n_finished - finished)
+            for attr, child, n in zip(_CALL_ATTRS, self._c_calls,
+                                      self._calls):
+                turn.set_attribute(attr, n)
+                child.inc(n)
 
     # ----- decode-side AIMD control -----------------------------------
     @property
@@ -1672,6 +1776,9 @@ class DecodeEngine:
             "decode_steps": int(self._h_decode.count),
             "steps_ahead": int(self._c_ahead.value),
             "dropped_row_steps": int(self._c_dropped.value),
+            # what the loop's turns asked of the device, by kind of call
+            "device_calls": {kind: int(c.value) for kind, c in
+                             zip(_CALLS, self._c_calls)},
             # of the entries the decode kernel moved, the share it attended
             "kv_fetch_valid_share": (
                 self._c_kv_attended.value / kv_fetched if kv_fetched

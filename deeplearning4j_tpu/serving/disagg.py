@@ -61,8 +61,7 @@ from ..core.resilience import (
     CircuitState,
     Deadline,
 )
-from ..generate.sampling import sample_tokens
-from ..generate.session import GenerationSession
+from ..generate.session import GenerationSession, pack_row_spec
 from ..obs.metrics import MetricsRegistry, get_registry
 
 _engine_seq = itertools.count()
@@ -146,10 +145,10 @@ def deserialize_handoff(data: bytes) -> dict:
 class PrefillEngine:
     """Prefill-tier engine: the bucketed-prefill half of a
     :class:`~deeplearning4j_tpu.parallel.decode.DecodeEngine`, producing
-    handoffs instead of decoding. The jitted prefill function and the
-    seeded first-token sample are bit-for-bit the computation the decode
-    engine runs locally, which is what makes the restored decode stream
-    token-identical to an unbroken one."""
+    handoffs instead of decoding. The row and its seeded first token are
+    :meth:`GenerationSession.prefill_row`'s, the one body the decode
+    engine's own prefill installs from, which is what makes the restored
+    decode stream token-identical to an unbroken one."""
 
     role = "prefill"
 
@@ -165,8 +164,8 @@ class PrefillEngine:
         self.max_len = int(max_len)
         self.name = name or f"prefill-{next(_engine_seq)}"
         self._breaker = circuit_breaker or CircuitBreaker(clock=clock)
-        self._row_template = self.session.decode_state(1)
-        self._fns: dict = {}
+        # the decode engine's own row body; one compile a prompt bucket
+        self._prefill = jax.jit(self.session.prefill_row)
         self._lock = threading.Lock()
         self._inflight = 0
         reg = registry if registry is not None else get_registry()
@@ -180,32 +179,6 @@ class PrefillEngine:
             "dl4j_tpu_disagg_prefill_latency_seconds",
             "Prefill-tier bucketed prefill latency (admit to handoff)",
             ("instance",)).labels(self.name)
-
-    def _prefill_fn(self, tb: int):
-        # IDENTICAL computation to DecodeEngine._prefill_fn — any drift
-        # here breaks cross-tier token identity
-        key = ("prefill", tb)
-        if key not in self._fns:
-            sess = self.session
-            model = sess.model
-
-            def fn(params, state, row_carry, ids, lengths, seed, gflag,
-                   temp, k, p):
-                mask = (jnp.arange(tb, dtype=jnp.int32)[None, :]
-                        < lengths[:, None]).astype(model.dtype)
-                out, _, new_rnn = model.forward_pure(
-                    params, state, sess._prep(ids), train=False, rng=None,
-                    mask=mask, rnn_state=row_carry)
-                logits = sess._logits(out)
-                last = jnp.take_along_axis(
-                    logits, (lengths - 1)[:, None, None].astype(jnp.int32),
-                    axis=2)[:, :, 0]
-                tok = sample_tokens(last, seed, jnp.zeros((1,), jnp.int32),
-                                    gflag, temp, k, p)
-                return new_rnn, tok[0]
-
-            self._fns[key] = jax.jit(fn)
-        return self._fns[key]
 
     def prefill(self, prompt: Sequence[int], *,
                 max_tokens: Optional[int] = None, greedy: bool = True,
@@ -233,14 +206,11 @@ class PrefillEngine:
                           if s >= len(prompt)), self.max_len)
             ids = np.zeros((1, tb), np.int32)
             ids[0, : len(prompt)] = prompt
-            row, tok = self._prefill_fn(tb)(
-                sess.model.params, sess.model.state, self._row_template,
-                jnp.asarray(ids), jnp.asarray([len(prompt)], jnp.int32),
-                jnp.asarray([int(seed) & 0xFFFFFFFF], jnp.uint32),
-                jnp.asarray([bool(greedy)], bool),
-                jnp.asarray([float(temperature)], jnp.float32),
-                jnp.asarray([int(top_k)], jnp.int32),
-                jnp.asarray([float(top_p)], jnp.float32))
+            row, tok, _ = self._prefill(
+                sess.model.params, sess.model.state, jnp.asarray(ids),
+                jnp.asarray(pack_row_spec(
+                    len(prompt), 0, int(seed) & 0xFFFFFFFF, bool(greedy),
+                    float(temperature), int(top_k), float(top_p))))
             pos = len(prompt)
             layers: Dict[str, dict] = {}
             for lname, st in row.items():

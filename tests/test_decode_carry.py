@@ -1,5 +1,6 @@
-"""The decode engine's KV carry is updated in place (ISSUE 27): the step
-and both installs donate it, an idle row's write is dropped by the cache
+"""The decode engine's KV carry is updated in place (ISSUE 27): the step,
+the prefill that installs its own row (ISSUE 37) and both installs of a
+handed-over row donate it, an idle row's write is dropped by the cache
 write itself (no select over the cache), and an engine whose donated
 program died at run time comes back with a fresh carry.
 
@@ -22,7 +23,7 @@ from deeplearning4j_tpu.obs.metrics import MetricsRegistry
 from deeplearning4j_tpu.ops import (flash_masked_cache_write,
                                     masked_cache_write_reference,
                                     set_attention_impl)
-from deeplearning4j_tpu.parallel.decode import DecodeEngine
+from deeplearning4j_tpu.parallel.decode import DecodeEngine, _Request
 
 MAX_LEN = 16
 VOCAB = 23
@@ -78,6 +79,12 @@ def _programs(e):
     out = [("decode_step", e._decode_step_fn().lower(
         sess.model.params, sess.model.state, e._carry,
         *_step_args(e, active), e._table))]
+    # an admitted prompt: the prefill installs its row and its first token
+    req = _Request([1, 2, 3], 4, None, None, 7, True, 1.0, 0, 1.0, None, None)
+    out.append(("prefill_4", e._prefill_fn(4).lower(
+        sess.model.params, sess.model.state, e._carry,
+        *e._prefill_args(4, 1, req))))
+    # a handed-over row: the install alone
     slot = jnp.asarray(1, jnp.int32)
     if e._allocator is None:
         out.append(("install_row", e._write_row_fn().lower(
@@ -90,8 +97,8 @@ def _programs(e):
 
 
 def test_programs_alias_every_cache_plane(eng):
-    """(a) the compiled step and install write their carry where it lies:
-    every cache plane of the input is aliased to its output."""
+    """(a) the compiled step, prefill and install write their carry where
+    it lies: every cache plane of the input is aliased to its output."""
     want = sum(p.size * p.dtype.itemsize for p in _planes(eng._carry))
     for name, lowered in _programs(eng):
         got = lowered.compile().memory_analysis().alias_size_in_bytes
@@ -99,7 +106,7 @@ def test_programs_alias_every_cache_plane(eng):
 
 
 def test_a_step_and_an_install_consume_the_previous_carry(eng):
-    """(a) after one `_step` (and one install before it) the planes the
+    """(a) after one `_step` (and one prefill before it) the planes the
     engine held are deleted: nothing keeps a second carry alive. (e) the
     paged engine's ONE shared table is no part of what is donated."""
     before = _planes(eng._carry)
@@ -365,37 +372,82 @@ def test_a_poisoned_step_leaves_a_carry_to_go_on_with(lm, layout):
         e.shutdown(drain=False)
 
 
-def test_a_poisoned_install_fails_every_row_and_rebuilds(lm):
-    """An install that dies at run time takes every row's cache with it:
-    the request being admitted and the rows mid-decode all fail, and the
-    engine serves again."""
+class _DeadToken:
+    """A first token whose program died on the device: the dispatch
+    returned, the fetch raises."""
+
+    def __int__(self):
+        raise RuntimeError("prefill halted")
+
+
+@pytest.mark.parametrize("how", ["install", "prefill", "prefill-at-fetch"])
+def test_a_poisoned_admission_fails_every_row_and_rebuilds(lm, how):
+    """An admission's program that dies at run time takes every row's cache
+    with it, whether it is the install of a handed-over row or a prefill
+    that installs its own (ISSUE 37), and whether the failure shows at the
+    call or only where the first token is fetched: the request being
+    admitted and the rows mid-decode all fail, and the engine serves
+    again."""
+    from deeplearning4j_tpu.serving.disagg import PrefillEngine
+
     reg = MetricsRegistry()
     gate = {"delay": 0.05}
     e = _engine(lm, slots=2, registry=reg,
                 step_hook=lambda: time.sleep(gate["delay"]))
-    real = e._write_row_fn()
+    key = "write" if how == "install" else ("prefill", 2)
+    real = e._write_row_fn() if how == "install" else e._prefill_fn(2)
     mode = {"poison": False}
 
-    def install(*args):
+    def program(*args):
         out = real(*args)
         if mode["poison"]:
             mode["poison"] = False
+            if how == "prefill-at-fetch":
+                carry, toks, _, counts = out
+                return carry, toks, _DeadToken(), counts
             raise RuntimeError("install halted")
         return out
 
     try:
         want = e.submit([1, 2, 3], max_tokens=4).result(timeout=120)
-        e._fns["write"] = install
+        handoff = PrefillEngine(lm, max_len=MAX_LEN, registry=reg).prefill(
+            [4, 5], max_tokens=4)
+        e._fns[key] = program
         first = e.submit([1, 2, 3], max_tokens=MAX_LEN - 4)
         _wait(lambda: len(first.tokens) >= 2)
         mode["poison"] = True
-        second = e.submit([4, 5], max_tokens=4)
+        second = (e.submit_prefilled(handoff) if how == "install"
+                  else e.submit([4, 5], max_tokens=4))
         _wait(lambda: first.done and second.done)
         assert first.reason == second.reason == "failed"
-        assert e.stats()["carry_rebuilds"] == 1
+        assert e.stats()["carry_rebuilds"] == 1 and not e._carry_lost()
         gate["delay"] = 0.0
         e._breaker.record_success()
         assert e.submit([1, 2, 3], max_tokens=4).result(timeout=120) == want
+    finally:
+        e.shutdown(drain=False)
+
+
+def test_a_prefill_that_fails_while_tracing_consumes_nothing(lm):
+    """ISSUE 37: the carry is donated when the program runs, not when it is
+    traced or compiled: a prefill that fails before that fails its own
+    request, and the row mid-decode goes on to the tokens it owes."""
+    e = _engine(lm, slots=2, step_hook=lambda: time.sleep(0.02))
+    try:
+        want = e.submit([1, 2, 3], max_tokens=MAX_LEN - 4).result(timeout=120)
+
+        def program(*args):
+            raise RuntimeError("failed while tracing")
+
+        e._fns[("prefill", 2)] = program
+        first = e.submit([1, 2, 3], max_tokens=MAX_LEN - 4)
+        _wait(lambda: len(first.tokens) >= 2)
+        second = e.submit([4, 5], max_tokens=4)
+        last = list(second.events(timeout=60))[-1]
+        assert last["reason"] == "failed" and "tracing" in last["error"]
+        e._breaker.record_success()
+        assert first.result(timeout=120) == want
+        assert e.stats()["carry_rebuilds"] == 0 and not e._carry_lost()
     finally:
         e.shutdown(drain=False)
 

@@ -101,6 +101,100 @@ def test_streams_under_churn_equal_the_session(lm, eva, carry, sampling):
         assert stats["kv_blocks_free"] == stats["kv_blocks_total"]
 
 
+# every row its own law: seeds past 2**31 and floats with no short binary
+# form, which the step's packed image has to carry bit for bit (ISSUE 37)
+OWN_SPECS = [
+    {"seed": 0xFFFFFFFF, "temperature": 0.7, "top_k": 0, "top_p": 0.9},
+    {"seed": 0x80000001, "temperature": 1.3, "top_k": 5, "top_p": 1.0},
+    {"seed": 3, "temperature": 1 / 3, "top_k": 0, "top_p": 1.0},
+    {"seed": 0xDEADBEEF, "temperature": 0.9, "top_k": 11, "top_p": 0.61},
+    {"seed": 17, "greedy": True},
+    {"seed": 0x7FFFFFFF, "temperature": 2.5, "top_k": 3, "top_p": 0.97},
+    {"seed": 2 ** 31, "temperature": 0.11, "top_k": 0, "top_p": 0.3},
+]
+
+
+@pytest.mark.parametrize("carry", ["static", "paged", "eva"])
+def test_rows_with_their_own_specs_are_sampled_as_the_session_samples(
+        lm, eva, carry):
+    """Three slots refilled as they free, so that turns admit up to three
+    prompts at once beside rows mid-stream: each sampled stream is the
+    single-sequence session's under the same seed and law."""
+    if carry == "eva":
+        model, max_len, prompts, lengths = (eva, EVA["max_len"], EVA_PROMPTS,
+                                            EVA_LENGTHS)
+    else:
+        model, max_len, prompts, lengths = lm, MAX_LEN, PROMPTS, LENGTHS
+    specs = [dict({"greedy": False}, **kw)
+             for kw in OWN_SPECS[:len(prompts)]]
+    e = _engine(model, max_len=max_len, slots=3,
+                **({"block_size": 4} if carry == "paged" else {}))
+    try:
+        hs = [e.submit(p, max_tokens=n, **kw)
+              for p, n, kw in zip(prompts, lengths, specs)]
+        got = [h.result(timeout=300) for h in hs]
+        stats = e.stats()
+    finally:
+        e.shutdown()
+    sess = GenerationSession(model, max_len=max_len)
+    for i, (p, n, kw) in enumerate(zip(prompts, lengths, specs)):
+        assert got[i] == sess.generate([p], n, **kw)[0], i
+    assert stats["failed"] == 0 and stats["dropped_row_steps"] == 0
+
+
+@pytest.mark.parametrize("n", [2, 3])
+def test_a_turns_admissions_are_all_dispatched_before_anything_is_fetched(
+        lm, session, n):
+    """ISSUE 37: no prefill waits for the one before it. A turn that admits
+    ``n`` prompts dispatches all their programs, then its step, and only
+    then fetches: the step before, then the ``n`` first tokens, which land
+    in the order of admission, each as its request's index 0."""
+    tracer = Tracer(TraceStore(max_traces=4096), sample_rate=1.0)
+    gate = {"delay": 0.0}
+    e = _engine(lm, slots=n + 1, tracer=tracer,
+                step_hook=lambda: time.sleep(gate["delay"]))
+    order = []
+    try:
+        e.generate([4, 5, 6], max_tokens=2)  # the bucket and the step compiled
+        running = e.submit([7, 5, 4], max_tokens=MAX_LEN - 3)
+        _wait(lambda: len(running.tokens) >= 2)
+        gate["delay"] = 0.3     # the loop sleeps in its hook: all n queue up
+        _wait(lambda: len(running.tokens) >= 4)
+        prompts = [[1, 2, 3], [3, 2, 1], [2, 3, 1]][:n]
+        hs = [e.submit(p, max_tokens=4) for p in prompts]
+        for i, h in enumerate(hs):
+            def emit(index, token, i=i, real=h._emit):
+                order.append((i, index))
+                real(index, token)
+            h._emit = emit
+        gate["delay"] = 0.0
+        got = [h.result(timeout=120) for h in hs]
+        running.result(timeout=120)
+    finally:
+        e.shutdown()
+    assert got == [session.generate([p], 4)[0] for p in prompts]
+    assert [i for i, index in order if index == 0] == list(range(n))
+    assert all(order.index((i, 0)) < order.index((i, 1)) for i in range(n))
+    assert tracer.flush()
+    (turn,) = [t for t in tracer.store.traces(limit=10_000)
+               if t["root"] == "loop.turn" and any(
+                   s["attrs"].get("admitted") == n for s in t["spans"])]
+    spans = sorted(turn["spans"], key=lambda s: s["start"])
+    sent = [s for s in spans if s["name"] in ("loop.prefill.dispatch",
+                                              "loop.dispatch")]
+    fetched = [s for s in spans if s["name"] == "loop.fetch"]
+    assert [s["name"] for s in sent] == \
+        ["loop.prefill.dispatch"] * n + ["loop.dispatch"]
+    # the step before, then a first token an admission
+    assert len(fetched) == 1 + n
+    assert max(s["end"] for s in sent) <= min(s["start"] for s in fetched)
+    step = next(s for s in spans if s["name"] == "loop.step")
+    assert all(s["parent_id"] == step["span_id"] for s in fetched)
+    root = next(s for s in spans if s["parent_id"] is None)
+    assert (root["attrs"]["uploads"], root["attrs"]["programs"],
+            root["attrs"]["fetches"]) == (1 + n, 1 + n, 1 + n)
+
+
 def _events_then_nothing(handle, timeout=60):
     """The handle's events; after the terminal one its queue is empty and
     stays so."""
@@ -330,12 +424,12 @@ def test_step_n_is_dispatched_before_step_n_minus_1_is_emitted(lm):
                         if s["parent_id"] == step["span_id"]),
                        key=lambda s: s["start"])
         names = [s["name"] for s in parts]
-        admitted = any(s["name"] == "loop.prefill" for s in t["spans"])
-        # after the step before, this turn's prefills' first tokens
+        admitted = sum(s["name"] == "loop.prefill" for s in t["spans"])
+        # after the step before, this turn's prefills' first tokens, each
         firsts = ["loop.fetch", "loop.emit"] * admitted
         if step["attrs"]["ahead"]:
             ahead += 1
-            both += admitted
+            both += bool(admitted)
             assert names == ["loop.upload", "loop.dispatch", "loop.fetch",
                              "loop.emit"] + firsts
             assert parts[1]["end"] <= parts[3]["start"]

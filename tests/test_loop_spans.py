@@ -83,15 +83,16 @@ def test_turn_children_and_self_time_add_up_to_the_turn(served):
         parts = [s for s in t["spans"] if s["parent_id"] == step["span_id"]]
         # this turn's step is dispatched, then what is in flight is landed
         # (ISSUE 30): the step before (a batch's first turn has none, its
-        # last nothing to dispatch), then this turn's prefills' first tokens
+        # last nothing to dispatch), then this turn's prefills' first
+        # tokens, one an admission (ISSUE 37)
         names = tuple(s["name"] for s in parts)
         landed = names[2:] if names[:2] == STEP_PARTS[:2] else names
-        assert landed in ((), STEP_PARTS[2:], 2 * STEP_PARTS[2:]), names
+        lands = len(landed) // 2
+        assert landed == lands * STEP_PARTS[2:] and lands <= 3, names
         assert step["attrs"]["spec"] is False
         prefills = sum(s["name"] == "loop.prefill" for s in t["spans"])
-        lands = len(landed) // 2
         assert step["attrs"]["ahead"] == \
-            (names[:2] == STEP_PARTS[:2] and lands - bool(prefills) == 1)
+            (names[:2] == STEP_PARTS[:2] and lands - prefills == 1)
         kinds.add((names[:2] == STEP_PARTS[:2], lands))
     assert {(True, 1), (False, 1)} <= kinds
 
@@ -125,11 +126,86 @@ def test_every_prefill_has_its_request_number_and_queue_wait(served):
     for s in prefills:
         kids = [n for n in by_parent[s["span_id"]] if n != "xla.compile"]
         # no sync: the first token stays on the device (ISSUE 30) and is
-        # fetched under `loop.step`, once the turn's step is dispatched; a
-        # turn's second prefill first lands the token of the one before it
-        # (one prefill's row in flight at a time)
-        assert kids[-2:] == ["loop.prefill.dispatch", "loop.install"]
-        assert kids[:-2] in ([], ["loop.fetch", "loop.emit"])
+        # fetched under `loop.step`, once the turn's step is dispatched. No
+        # install either (ISSUE 37): the prefill's own program writes the
+        # row and the token, so a turn's second prefill waits for nothing
+        assert kids == ["loop.prefill.dispatch"]
+
+
+def test_a_handed_over_row_is_the_one_admission_with_an_install(lm):
+    from deeplearning4j_tpu.serving.disagg import PrefillEngine
+
+    tracer = Tracer(TraceStore(max_traces=4096), sample_rate=1.0)
+    handoff = PrefillEngine(lm, max_len=32, registry=MetricsRegistry()) \
+        .prefill([1, 2, 3], max_tokens=4)
+    eng = DecodeEngine(lm, max_len=32, slots=2, tracer=tracer,
+                       registry=MetricsRegistry())
+    try:
+        want = eng.generate([1, 2, 3], max_tokens=4)
+        assert eng.submit_prefilled(handoff).result(timeout=120) == want
+    finally:
+        eng.shutdown()
+    assert tracer.flush()
+    traces = tracer.store.traces(limit=10_000)
+    kids = [[c["name"] for c in sorted(t["spans"], key=lambda c: c["start"])
+             if c["parent_id"] == s["span_id"] and c["name"] != "xla.compile"]
+            for t in traces for s in t["spans"] if s["name"] == "loop.prefill"]
+    assert sorted(kids) == [["loop.prefill.dispatch"],
+                            ["loop.prefill.dispatch", "loop.install"]]
+    # a handed-over row: its leaves, its token and the slot uploaded, the
+    # install and the token's write dispatched, beside the turn's step
+    roots = [_root(t)["attrs"] for t in _turns(traces)]
+    handed = next(r for r in roots if r["admitted"] and r["programs"] == 3)
+    assert handed["uploads"] > 4
+
+
+@pytest.mark.parametrize("layout", ["static", "paged"])
+def test_a_turn_says_what_it_asked_of_the_device(lm, layout):
+    """ISSUE 37: a step is one upload (the rows' packed image) and one
+    program, an admitted prompt one of each more, a landed step or first
+    token one fetch; a paged engine's step takes the block table up again
+    when a row's block list changed. The registry's counter and `stats()` hold the
+    turns' sums."""
+    reg = MetricsRegistry()
+    tracer = Tracer(TraceStore(max_traces=4096), sample_rate=1.0)
+    eng = DecodeEngine(lm, max_len=32, slots=2, tracer=tracer, registry=reg,
+                       name="calls",
+                       **({"block_size": 4} if layout == "paged" else {}))
+    try:
+        handles = [eng.submit([1 + i, 2, 3], max_tokens=9) for i in range(5)]
+        for h in handles:
+            h.result(timeout=120)
+        stats = eng.stats()
+    finally:
+        eng.shutdown()
+    assert tracer.flush()
+    sums = dict.fromkeys(("uploads", "programs", "fetches"), 0)
+    kinds = set()
+    for t in _turns(tracer.store.traces(limit=10_000)):
+        attrs = _root(t)["attrs"]
+        names = [s["name"] for s in t["spans"]]
+        stepped, admitted = names.count("loop.dispatch"), attrs["admitted"]
+        assert attrs["programs"] == stepped + admitted
+        assert attrs["fetches"] == names.count("loop.fetch")
+        # the block table again, once a turn at most and with the step that
+        # takes it, however many rows grew, came or went since the last
+        tables = attrs["uploads"] - attrs["programs"]
+        assert 0 <= tables <= (stepped if layout == "paged" else 0)
+        if stepped and admitted <= 1:
+            assert attrs["programs"] == 1 + admitted
+            assert attrs["fetches"] <= 1 + admitted
+        kinds.add((stepped, admitted))
+        for k in sums:
+            sums[k] += attrs[k]
+    assert {(1, 0), (1, 1)} <= kinds
+    counter = reg.get("dl4j_tpu_decode_device_calls_total")
+    assert {k + ("es" if k == "fetch" else "s"):
+            counter.labels("calls", k).value
+            for k in ("upload", "program", "fetch")} == sums
+    assert stats["device_calls"] == {
+        "upload": sums["uploads"], "program": sums["programs"],
+        "fetch": sums["fetches"]}
+    assert sums["programs"] == stats["decode_steps"] + 5
 
 
 def test_decode_histogram_counts_the_step_spans(served):
@@ -228,6 +304,32 @@ def test_a_root_that_outlives_the_session_is_not_profiled(tmp_path):
     assert tracer.flush()
     attrs = {t["root"]: _root(t)["attrs"] for t in tracer.store.traces()}
     assert attrs == {"inside": {"profiled": True}, "straddles": {}}
+
+
+def test_a_sessions_slice_outlives_the_sampled_traces_that_follow_it():
+    """The store is bounded, and the traces a profiler session took go last:
+    a fast loop's head-sampled turns after the slice must not push the
+    slice out before its reader comes (ISSUE 37: a hundred turns a second
+    fill the default store in half a minute)."""
+    def root(i, **attrs):
+        return {"trace_id": f"t{i}", "span_id": f"s{i}", "parent_id": None,
+                "name": "loop.turn", "start": float(i), "end": i + 0.5,
+                "duration_ms": 500.0, "error": False,
+                "attrs": dict(attrs, turn=i)}
+
+    store = TraceStore(max_traces=4)
+    store.add(root(0))
+    for i in (1, 2, 3):
+        store.add(root(i, profiled=True))
+    for i in range(4, 10):
+        store.add(root(i))
+    kept = sorted(_root(t)["attrs"]["turn"] for t in store.traces())
+    assert kept == [1, 2, 3, 9] and store.evicted_traces == 6
+    # nothing else left to go: the oldest of the slice goes
+    for i in (10, 11):
+        store.add(root(i, profiled=True))
+    kept = sorted(_root(t)["attrs"]["turn"] for t in store.traces())
+    assert kept == [2, 3, 10, 11] and len(store) == 4
 
 
 def test_a_traced_request_gets_its_queue_wait_record(lm):
