@@ -173,7 +173,9 @@ class Layer:
         """Bytes of the decode state that a row standing at ``position``
         has made valid, by kind of entry (host arithmetic, for the engine's
         ``dl4j_tpu_decode_state_bytes`` gauge); ``{}`` where the layer does
-        not say."""
+        not say. The engine hands over all its rows' positions at once, an
+        integer array: keep to arithmetic that works on either (a kind that
+        does not depend on the position stays one number)."""
         return {}
 
     def has_params(self) -> bool:

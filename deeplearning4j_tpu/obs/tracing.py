@@ -34,6 +34,18 @@ profiler's trace, on the clock the device's operations are on
 (``tools/host_gaps.py`` reads them there). Each span of an assembled
 trace carries ``self_ms``, its duration less what its children cover.
 
+A span entered as a context manager also reads its thread's CPU clock
+(``time.thread_time``: 0.3 us on a plain Linux host, a system call of 6-29 us
+that ticks at 10 ms on a sandboxed one, PERF.md PR 38: read means over many
+spans there) where it is entered and where it is left, when both happen on
+one thread: its record carries ``cpu_ms`` (what the thread
+RAN inside the span; ``duration_ms - cpu_ms`` is what it waited: for the
+device, for a lock, for the interpreter lock) and ``thread``, and an
+assembled trace gives ``self_cpu_ms`` beside ``self_ms``: ``cpu_ms`` less
+that of the span's children on the same thread. A span that was only
+``finish()``-ed, one left on another thread, and the records of
+:meth:`Tracer.make_record` carry none of the three.
+
 Timestamps: every span timestamp is ``perf_counter`` anchored to one
 process-wide wall-clock epoch, so timestamps are strictly monotonic
 across threads (wall-clock steps can never reorder a parent after its
@@ -215,7 +227,8 @@ class TraceSpan:
 
     __slots__ = ("tracer", "name", "trace_id", "span_id", "parent_id",
                  "sampled", "attributes", "start_time", "end_time", "error",
-                 "_token", "_finished", "_annotation")
+                 "_token", "_finished", "_annotation", "_thread", "_cpu0",
+                 "_cpu_s")
 
     def __init__(self, tracer: "Tracer", name: str, trace_id: str,
                  span_id: str, parent_id: Optional[str], sampled: bool,
@@ -233,6 +246,11 @@ class TraceSpan:
         self._token = None
         self._finished = False
         self._annotation = None
+        # the thread that entered the span and its CPU clock there, and,
+        # once the span is left on that thread, the seconds it ran inside
+        self._thread: Optional[int] = None
+        self._cpu0 = 0.0
+        self._cpu_s: Optional[float] = None
 
     @property
     def context(self) -> TraceContext:
@@ -260,9 +278,14 @@ class TraceSpan:
                 k: v for k, v in self.attributes.items()
                 if isinstance(v, (bool, int, float, str))})
             self._annotation.__enter__()
+        self._thread = threading.get_ident()
+        self._cpu0 = time.thread_time()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        # another thread's clock says nothing of the one that entered
+        if self._thread == threading.get_ident():
+            self._cpu_s = time.thread_time() - self._cpu0
         # restore-first: even if export misbehaves, the previous current
         # span must come back (contextvar token reset is exact — nested
         # and concurrent-thread spans cannot cross-restore)
@@ -294,7 +317,7 @@ class TraceSpan:
             self.tracer._export(self._record())
 
     def _record(self) -> dict:
-        return {
+        rec = {
             "trace_id": self.trace_id,
             "span_id": self.span_id,
             "parent_id": self.parent_id,
@@ -305,6 +328,10 @@ class TraceSpan:
             "error": self.error,
             "attrs": self.attributes,
         }
+        if self._cpu_s is not None:
+            rec["cpu_ms"] = round(self._cpu_s * 1e3, 6)
+            rec["thread"] = self._thread
+        return rec
 
 
 class _NullSpan:
@@ -371,6 +398,20 @@ def _self_ms(spans: List[dict]) -> List[float]:
                 upto = hi
         out.append(round((s["end"] - s["start"] - covered) * 1e3, 6))
     return out
+
+
+def _self_cpu_ms(spans: List[dict]) -> List[Optional[float]]:
+    """Each span's own CPU time in ms: its ``cpu_ms`` less that of its
+    children on the same thread (whose intervals, on one thread, cannot
+    overlap); ``None`` for a span that carries no ``cpu_ms``."""
+    nested: Dict[tuple, float] = {}
+    for s in spans:
+        if "cpu_ms" in s:
+            key = (s["parent_id"], s["thread"])
+            nested[key] = nested.get(key, 0.0) + s["cpu_ms"]
+    return [round(max(s["cpu_ms"] - nested.get(
+        (s["span_id"], s["thread"]), 0.0), 0.0), 6) if "cpu_ms" in s else None
+        for s in spans]
 
 
 class TraceStore:
@@ -456,8 +497,10 @@ class TraceStore:
         end = max((s["end"] for s in spans), default=0.0)
         routes = sorted({s["attrs"]["route"] for s in spans
                          if "route" in s["attrs"]})
-        spans = [dict(s, self_ms=ms)
-                 for s, ms in zip(spans, _self_ms(spans))]
+        spans = [dict(s, self_ms=ms) if cpu is None
+                 else dict(s, self_ms=ms, self_cpu_ms=cpu)
+                 for s, ms, cpu in zip(spans, _self_ms(spans),
+                                       _self_cpu_ms(spans))]
         return {
             "trace_id": trace_id,
             "root": root["name"] if root else None,

@@ -64,7 +64,13 @@ head-sampled like a request and taken whole while a ``jax.profiler``
 session collects (README "Tracing"). What a turn costs the host in device
 calls it says itself: ``uploads``, ``programs`` and ``fetches`` on the
 turn (ISSUE 37: a step is one upload and one program, an admitted prompt
-one of each more).
+one of each more). And whether the device ran dry under it (ISSUE 38,
+:class:`_DryAccount`): some ten times a turn the loop asks the newest
+program it enqueued whether it has finished, which brackets each
+interval in which the device's queue was empty between two host
+timestamps and names the phase the host was in; ``dry_ms``,
+``dry_slack_ms`` and ``dry_<phase>_ms`` on the turn, three counters and
+``stats()["loop"]`` carry it, traced or not.
 """
 
 from __future__ import annotations
@@ -111,6 +117,8 @@ from ..obs.tracing import (NULL_SPAN, Tracer, current_context, get_tracer,
                            trace_now)
 
 _engine_seq = itertools.count()
+# the dry account's clock: a name of the module, so that a test can script it
+_now = time.perf_counter
 
 _OUTCOMES = ("completed", "deadline", "cancelled", "shed", "failed",
              "circuit_rejected")
@@ -119,6 +127,14 @@ _OUTCOMES = ("completed", "deadline", "cancelled", "shed", "failed",
 _CALLS = ("upload", "program", "fetch")
 _CALL_ATTRS = ("uploads", "programs", "fetches")
 _UPLOAD, _PROGRAM, _FETCH = range(3)
+# the phases of a turn that the dry account tells apart: the ``loop.<phase>``
+# spans (a prefill's and an install's lie in ``admit``), and ``step`` for
+# ``loop.step``'s own time; what a turn's span calls each phase's share
+_PHASES = ("admit", "select", "upload", "dispatch", "account", "fetch",
+           "emit", "sweep", "step")
+_DRY_ATTRS = {phase: f"dry_{phase}_ms" for phase in _PHASES}
+# rows of the emit loop between two looks at the device's queue
+_EMIT_LOOK_ROWS = 32
 
 
 class GenerationHandle:
@@ -243,6 +259,113 @@ class _InFlight:
         self.reqs = reqs
         self.t0 = t0
         self.counts = counts
+
+
+class _DryAccount:
+    """The loop thread's account of the device's queue, one turn at a time.
+
+    The device's queue is empty when the newest program the loop enqueued
+    has finished (``DecodeEngine._device_dry``: ``is_ready()`` of an output
+    of it; no wait, 0.3 us alone and 3-11 us inside the running loop on the
+    chip's host, PERF.md PR 38). A *look* asks that where it can tell
+    something: where the turn starts and ends, before each program the loop
+    enqueues, when a fetch has returned, every 32 rows of the emit loop and
+    at its end; the other boundaries only *mark* the phase.
+    The look that first finds the queue empty, at host time ``t1``, says
+    that the device ran dry between the look before it (``t0``, not dry)
+    and ``t1``; nothing runs there again before the loop next enqueues a
+    program, at ``t2``. ``t2 - t1`` is the interval's **lower bound**,
+    split over the phases it spans (``by_phase``, seconds), and ``t1 -
+    t0`` its **slack**: the device's true idle time lies between the lower
+    bound and the lower bound plus the slack (and a program starts some
+    tenths of a millisecond after its dispatch returns, which neither
+    holds). Only a turn counts: an engine parked in ``loop.wait`` is idle,
+    not starved."""
+
+    __slots__ = ("engine", "newest", "phase", "t_turn", "t_look", "t_dry",
+                 "slack", "by_phase")
+
+    def __init__(self, engine: "DecodeEngine", newest) -> None:
+        self.engine = engine
+        # an output of the newest program enqueued that no later program
+        # donates (the token vector, nearly always)
+        self.newest = newest
+        self.phase = _PHASES[0]
+        self.t_turn = self.t_look = 0.0
+        # while an interval is open: the time up to which it is counted
+        self.t_dry: Optional[float] = None
+        self.slack = 0.0
+        self.by_phase: dict = {}
+
+    def begin(self) -> None:
+        """A turn starts, with its first look: what came before it (a
+        ``loop.wait``, the loop's own check) is no part of any interval."""
+        self.t_turn = self.t_look = _now()
+        self.t_dry, self.slack, self.by_phase = None, 0.0, {}
+        self.look(_PHASES[0])
+
+    def _count(self, now: float) -> None:
+        if now > self.t_dry:
+            self.by_phase[self.phase] = \
+                self.by_phase.get(self.phase, 0.0) + now - self.t_dry
+
+    def look(self, phase: Optional[str] = None) -> None:
+        """Look at the queue; what follows is ``phase`` (or the phase it
+        was)."""
+        now = _now()
+        if self.t_dry is not None:  # still dry: nothing was enqueued since
+            self._count(now)
+            self.t_dry = now
+        elif self.engine._device_dry():
+            self.slack += now - self.t_look
+            self.t_dry = now
+        self.t_look = now
+        if phase is not None:
+            self.phase = phase
+
+    def mark(self, phase: str) -> None:
+        """What follows is ``phase``. An open interval is counted up to
+        here under the phase it was; the device is asked nothing."""
+        if self.t_dry is not None:
+            now = _now()
+            self._count(now)
+            self.t_dry = now
+        self.phase = phase
+
+    def enqueued(self, out=None) -> None:
+        """The loop has enqueued a program, of which ``out`` is an output:
+        an open interval ends here. Without ``out``: the next call enqueues
+        and fetches by itself (a speculative step), so the interval ends
+        before it and the call's end is told with its output."""
+        now = _now()
+        if self.t_dry is not None:
+            self._count(now)
+            self.t_dry = None
+        self.t_look = now
+        if out is not None:
+            self.newest = out
+
+    def end(self) -> float:
+        """The turn ends, with its last look: an interval still open is
+        counted up to here, and the next turn's first look finds it again.
+        Returns the turn's wall seconds."""
+        self.look()
+        return self.t_look - self.t_turn
+
+
+def live_state_bytes(layers: "Counter", positions: np.ndarray,
+                     itemsize: int) -> "Counter":
+    """Bytes of the decode state that rows standing at ``positions`` have
+    made valid, by kind of entry, summed over ``layers`` (``{layer: how many
+    of it}``). Every row at once: a layer's bytes are arithmetic on the
+    position, so an array of positions gives an array (or one number that
+    is every row's)."""
+    live: Counter = Counter()
+    for layer, count in layers.items():
+        for kind, n in layer.decode_live_bytes(positions, itemsize).items():
+            live[kind] += count * int(
+                np.broadcast_to(n, positions.shape).sum())
+    return live
 
 
 def install_row(carry, row, i):
@@ -437,6 +560,10 @@ class DecodeEngine:
         # set instead (``_last``: a speculative turn commits on the host)
         self._toks = jnp.zeros((self.slots,), jnp.int32)
         self._fresh = np.zeros((self.slots,), bool)
+        # whether, for how long and under which phase of a turn the device
+        # ran dry (``_toks`` is the newest program's output wherever the
+        # loop does not say otherwise)
+        self._dry = _DryAccount(self, self._toks)
         # the step dispatched and not yet fetched, and the first tokens of
         # the turn's admissions, in their order, dispatched and not yet
         # fetched: (slot, request, token on the device, what the layers
@@ -575,6 +702,26 @@ class DecodeEngine:
             "each more, a landed step or first token one fetch (two where "
             "the model's layers count)", ("engine", "kind"))
         self._c_calls = [calls.labels(inst, kind) for kind in _CALLS]
+        self._c_loop_s = reg.counter(
+            "dl4j_tpu_decode_loop_seconds_total",
+            "Wall seconds the engine's loop spent inside turns (passes "
+            "with work; a parked engine counts nothing): what the two "
+            "device_dry counters are shares of", ("engine",)).labels(inst)
+        dry = reg.counter(
+            "dl4j_tpu_decode_device_dry_seconds_total",
+            "Seconds inside the loop's turns in which the device's queue "
+            "was empty, as a lower bound: from the look that first found "
+            "the newest program finished to the loop's next enqueue, by "
+            "the phase of the turn the host was in (admit, select, upload, "
+            "dispatch, account, fetch, emit, sweep; step: loop.step's own "
+            "time)", ("engine", "phase"))
+        self._c_dry = {phase: dry.labels(inst, phase) for phase in _PHASES}
+        self._c_dry_slack = reg.counter(
+            "dl4j_tpu_decode_device_dry_slack_seconds_total",
+            "Seconds between the look that first found the device's queue "
+            "empty and the look before it: the device ran dry somewhere "
+            "in there, so its idle time is the lower bound plus at most "
+            "this", ("engine",)).labels(inst)
         sampler = reg.counter(
             "dl4j_tpu_decode_sampler_steps_total",
             "Decode steps dispatched, by the work their rows' sampling "
@@ -633,14 +780,8 @@ class DecodeEngine:
     def _update_state_bytes(self) -> None:
         """The live share of the decode state from the rows' positions."""
         size = jnp.dtype(self.session.model.dtype).itemsize
-        live: Counter = Counter()
-        for layer, count in self._live_layers.items():
-            live.update(dict.fromkeys(layer.decode_live_bytes(0, size), 0))
-            for slot in np.nonzero(self._active)[0]:
-                for kind, n in layer.decode_live_bytes(
-                        int(self._pos[slot]), size).items():
-                    live[kind] += count * n
-        for kind, n in live.items():
+        for kind, n in live_state_bytes(
+                self._live_layers, self._pos[self._active], size).items():
             self._g_state_bytes.labels(self.name, kind).set(n)
 
     def _push_tables(self) -> None:
@@ -908,6 +1049,7 @@ class DecodeEngine:
         token into the token vector: the one admission that has no prefill
         to ride in."""
         at = jnp.asarray(slot, jnp.int32)
+        self._dry.look()
         if self._allocator is None:
             self._carry = self._write_row_fn()(self._carry, row, at)
             self._calls[_UPLOAD] += 1
@@ -919,6 +1061,7 @@ class DecodeEngine:
                 self._carry, row, jnp.asarray(dest), at)
             self._calls[_UPLOAD] += 2
         self._toks = self._set_token_fn()(self._toks, at, tok)
+        self._dry.enqueued(self._toks)
         self._calls[_PROGRAM] += 2
 
     # ----- client side ------------------------------------------------
@@ -1066,6 +1209,16 @@ class DecodeEngine:
         return handle
 
     # ----- engine loop ------------------------------------------------
+    def _device_dry(self) -> bool:
+        """Whether the device's queue is empty: the newest program the loop
+        enqueued has finished. Asked without a wait; whatever cannot say
+        (a step that died, tokens that are no array) answers no and raises
+        nothing: a dead step surfaces where it is fetched."""
+        try:
+            return self._dry.newest.is_ready()
+        except Exception:  # noqa: BLE001 — the fetch reports it
+            return False
+
     def _finish(self, req: _Request, reason: str,
                 error: Optional[str] = None) -> None:
         req.handle._finish(reason, error)
@@ -1182,9 +1335,11 @@ class DecodeEngine:
                 # one upload, one program: the row and its first token are
                 # installed where they are computed, and nothing is fetched
                 # before the turn's step is dispatched
+                self._dry.look()
                 self._carry, self._toks, tok, counts = self._prefill_fn(tb)(
                     sess.model.params, sess.model.state, self._carry,
                     *self._prefill_args(tb, slot, req))
+                self._dry.enqueued(self._toks)
                 self._calls[_UPLOAD] += 1
                 self._calls[_PROGRAM] += 1
         if req.prefilled is not None:
@@ -1205,6 +1360,9 @@ class DecodeEngine:
                 jnp.asarray([len(req.prompt)], jnp.int32))
             self._draft_carry = self._write_row_fn()(
                 self._draft_carry, drow, jnp.asarray(slot, jnp.int32))
+            # the newest program is the draft's now, not the token vector's
+            self._dry.enqueued(
+                jax.tree_util.tree_leaves(self._draft_carry)[0])
             self._calls[_UPLOAD] += 3
             self._calls[_PROGRAM] += 2
         self._breaker.record_success()
@@ -1302,6 +1460,7 @@ class DecodeEngine:
             return  # the landing failed, and has failed them all
         self._flight, self._first = None, []
         self._toks = jnp.zeros((self.slots,), jnp.int32)
+        self._dry.enqueued(self._toks)
         self._breaker.record_failure()
         # before any caller hears of the failure
         self._rebuild_lost_carry(force=lost)
@@ -1344,10 +1503,12 @@ class DecodeEngine:
         complete with the token in flight sits this step out; with no row
         left to step, the turn only lands what is in flight. ``parent`` is
         the turn's ``loop.step`` span."""
-        rows = (self._active if rows is None else rows) \
-            & (self._steps < self._limit)
-        if self._allocator is not None:
-            rows = self._reserve_rows(rows, 1, parent)
+        with self.tracer.span("loop.select", parent=parent):
+            self._dry.mark("select")
+            rows = (self._active if rows is None else rows) \
+                & (self._steps < self._limit)
+            if self._allocator is not None:
+                rows = self._reserve_rows(rows, 1, parent)
         prev = self._flight
         if rows.any():
             try:
@@ -1372,26 +1533,33 @@ class DecodeEngine:
         request has been handed."""
         sess = self.session
         span = self.tracer.span
+        dry = self._dry
         t0 = time.perf_counter()
         with span("loop.upload", parent=parent):
+            dry.mark("upload")
             args = self._step_args(rows)
         with span("loop.dispatch", parent=parent):
+            dry.look("dispatch")
             self._carry, self._toks, counts = self._decode_step_fn()(
                 sess.model.params, sess.model.state, self._carry, *args,
                 self._device_table())
-        self._calls[_UPLOAD] += 1
-        self._calls[_PROGRAM] += 1
-        self._c_sampler[int(sampler_path(
-            self._greedy | ~rows, self._ks, self._ps))].inc()
-        if self._static_kv:
-            lengths = self._pos[rows] + 1
-            self._c_kv_attended.inc(int(lengths.sum()))
-            self._c_kv_fetched.inc(int(decode_fetched_entries(
-                lengths, self.max_len).sum()))
-        self._fresh[rows] = False
-        self._steps[rows] += 1
-        self._pos[rows] += 1
-        return _InFlight(self._toks, rows, list(self._requests), t0, counts)
+            dry.enqueued(self._toks)
+        with span("loop.account", parent=parent):
+            dry.phase = "account"
+            self._calls[_UPLOAD] += 1
+            self._calls[_PROGRAM] += 1
+            self._c_sampler[int(sampler_path(
+                self._greedy | ~rows, self._ks, self._ps))].inc()
+            if self._static_kv:
+                lengths = self._pos[rows] + 1
+                self._c_kv_attended.inc(int(lengths.sum()))
+                self._c_kv_fetched.inc(int(decode_fetched_entries(
+                    lengths, self.max_len).sum()))
+            self._fresh[rows] = False
+            self._steps[rows] += 1
+            self._pos[rows] += 1
+            return _InFlight(self._toks, rows, list(self._requests), t0,
+                             counts)
 
     def _step_args(self, rows: np.ndarray) -> tuple:
         """The decode step's operands after the carry, on the device: the
@@ -1416,8 +1584,10 @@ class DecodeEngine:
         another request's) is dropped. False when the step died at run
         time, which surfaces here: every active request has then failed."""
         span = self.tracer.span
+        dry = self._dry
         try:
             with span("loop.fetch", parent=parent):
+                dry.mark("fetch")
                 toks_h = np.asarray(step.toks)
                 self._calls[_FETCH] += 1
                 if step.counts:  # the same program's: they are there
@@ -1429,15 +1599,28 @@ class DecodeEngine:
         dt = time.perf_counter() - step.t0
         self._h_decode.observe(dt)
         self._breaker.record_success()
+        # a sampled turn also says what a row costs, in its three parts: no
+        # clock is read for a turn that is not
+        timed = parent is not NULL_SPAN
+        clock = time.perf_counter_ns
+        put = count = retire = t0 = t1 = t2 = 0
         with span("loop.emit", parent=parent):
-            for slot in np.nonzero(step.rows)[0]:
+            dry.look("emit")
+            slots = np.nonzero(step.rows)[0]
+            for n, slot in enumerate(slots):
+                if n % _EMIT_LOOK_ROWS == 0 and n:
+                    dry.look()
                 req = step.reqs[slot]
                 if req is not self._requests[slot]:
                     self._c_dropped.inc()
                     continue
                 tok = int(toks_h[slot])
                 emitted = len(req.handle.tokens)
+                if timed:
+                    t0 = clock()
                 req.handle._emit(emitted, tok)
+                if timed:
+                    t1 = clock()
                 self._last[slot] = tok
                 # the step wrote position len(prompt) + emitted - 1
                 if self._window and \
@@ -1445,7 +1628,21 @@ class DecodeEngine:
                     self._c_windows.inc()
                 self._c_tokens.inc()
                 self._h_token.observe(dt)
+                if timed:
+                    t2 = clock()
                 self._retire_if_done(slot, tok, emitted + 1)
+                if timed:
+                    put += t1 - t0
+                    count += t2 - t1
+                    retire += clock() - t2
+            if timed:
+                attrs = parent.attributes
+                for key, n in (("emit_rows", len(slots)),
+                               ("emit_put_ms", put * 1e-6),
+                               ("emit_count_ms", count * 1e-6),
+                               ("emit_retire_ms", retire * 1e-6)):
+                    parent.set_attribute(key, attrs.get(key, 0) + n)
+            dry.look("step")
         if self._step_hook is not None:
             self._step_hook()
         return True
@@ -1483,8 +1680,10 @@ class DecodeEngine:
     def _land_first(self, first: tuple, parent=NULL_SPAN) -> bool:
         slot, req, tok, counts, t0 = first
         span = self.tracer.span
+        dry = self._dry
         try:
             with span("loop.fetch", parent=parent) as fetch:
+                dry.mark("fetch")
                 tok = int(tok)
                 self._calls[_FETCH] += 1
                 if counts:
@@ -1500,11 +1699,13 @@ class DecodeEngine:
             return False
         self._h_prefill.observe(time.perf_counter() - t0)
         with span("loop.emit", parent=parent):
+            dry.mark("emit")
             if req is self._requests[slot]:
                 self._last[slot] = tok
                 self._c_tokens.inc()
                 req.handle._emit(0, tok)
                 self._retire_if_done(slot, tok, emitted=1)
+            dry.look("step")
         return True
 
     def _drain(self, parent=NULL_SPAN) -> bool:
@@ -1545,12 +1746,18 @@ class DecodeEngine:
                     spec_rows[slot] = False
         plain_rows = self._active & ~spec_rows
         span = self.tracer.span
+        dry = self._dry
         if spec_rows.any():
             t0 = time.perf_counter()
             try:
                 # propose, verify and accept are dispatched and fetched
-                # inside the session's step: one span round all of it
+                # inside the session's step: one span round all of it. An
+                # open dry interval ends before it, and once its tokens
+                # are home the device is dry again until the loop's next
+                # dispatch
                 with span("loop.fetch", parent=parent):
+                    dry.look("fetch")
+                    dry.enqueued()
                     # the session's step takes (and returns) the tables
                     # inside the carry, and donates nothing
                     (carry, self._draft_carry, toks, n_acc,
@@ -1570,6 +1777,7 @@ class DecodeEngine:
                     self._calls[_UPLOAD] += 9
                     self._calls[_PROGRAM] += 1
                     self._calls[_FETCH] += 3
+                    dry.enqueued(toks)
             except Exception as e:  # noqa: BLE001
                 self._fail_active(e)
                 return
@@ -1578,6 +1786,7 @@ class DecodeEngine:
             self._breaker.record_success()
             self._c_spec_steps.inc()
             with span("loop.emit", parent=parent):
+                dry.look("emit")
                 for slot in np.nonzero(spec_rows)[0]:
                     req = self._requests[slot]
                     if req is None:
@@ -1599,6 +1808,7 @@ class DecodeEngine:
                         if self._requests[slot] is None:
                             break  # retired mid-window: drop the tail
                     self._h_token.observe(dt / max(1, committed))
+                dry.look("step")
             if self._step_hook is not None:
                 self._step_hook()
         if plain_rows.any():
@@ -1648,17 +1858,20 @@ class DecodeEngine:
         profiler session collects), whose children say where the pass's
         time went on the host."""
         span = self.tracer.span
+        dry = self._dry
         self._n_turns += 1
         finished = self._n_finished
         self._calls = [0, 0, 0]
         with span("loop.turn", parent=None, attrs={
                 "engine": self.name, "turn": self._n_turns,
                 "pending": len(self._pending)}) as turn:
+            dry.begin()
             with span("loop.admit", parent=turn) as admit:
                 turn.set_attribute("admitted", self._admit(admit))
             turn.set_attribute("rows", int(self._active.sum()))
             if self._active.any() or self._flight is not None:
                 spec = self._spec is not None
+                dry.mark("step")
                 with span("loop.step", parent=turn,
                           attrs={"spec": spec, "ahead": 0}) as step:
                     if spec:
@@ -1666,6 +1879,7 @@ class DecodeEngine:
                     else:
                         self._step(parent=step)
             with span("loop.sweep", parent=turn):
+                dry.mark("sweep")
                 # also sweep cancelled requests on slots that produced
                 # nothing
                 for slot in range(self.slots):
@@ -1686,6 +1900,15 @@ class DecodeEngine:
                                       self._calls):
                 turn.set_attribute(attr, n)
                 child.inc(n)
+            # the turn's account of the device's queue, in seconds to the
+            # counters and in ms on the span
+            self._c_loop_s.inc(dry.end())
+            self._c_dry_slack.inc(dry.slack)
+            for phase, s in dry.by_phase.items():
+                self._c_dry[phase].inc(s)
+                turn.set_attribute(_DRY_ATTRS[phase], s * 1e3)
+            turn.set_attribute("dry_ms", sum(dry.by_phase.values()) * 1e3)
+            turn.set_attribute("dry_slack_ms", dry.slack * 1e3)
 
     # ----- decode-side AIMD control -----------------------------------
     @property
@@ -1779,6 +2002,11 @@ class DecodeEngine:
             # what the loop's turns asked of the device, by kind of call
             "device_calls": {kind: int(c.value) for kind, c in
                              zip(_CALLS, self._c_calls)},
+            # the loop's account of the device's queue over all its turns:
+            # the share of their wall time in which the device was dry (a
+            # lower bound), what the looks' spacing leaves open above it,
+            # and the lower bound by the phase of the turn
+            "loop": self._loop_stats(),
             # of the entries the decode kernel moved, the share it attended
             "kv_fetch_valid_share": (
                 self._c_kv_attended.value / kv_fetched if kv_fetched
@@ -1806,6 +2034,19 @@ class DecodeEngine:
             },
         })
         return counts
+
+    def _loop_stats(self) -> dict:
+        wall = self._c_loop_s.value
+        by_phase = {phase: c.value for phase, c in self._c_dry.items()
+                    if c.value}
+        return {
+            "turn_seconds": wall,
+            "dry_share": sum(by_phase.values()) / wall if wall else None,
+            "dry_slack_share": (self._c_dry_slack.value / wall if wall
+                                else None),
+            "dry_by_phase": {phase: s / wall for phase, s in
+                             by_phase.items()},
+        }
 
     def drain(self, timeout: Optional[float] = None) -> bool:
         """Stop admitting, wait for in-flight generations to finish."""

@@ -347,7 +347,8 @@ def test_a_step_that_dies_at_run_time_fails_at_the_fetch(lm, layout):
                 (e._carry_lost(), e._flight is None, e._active.any())))
         _wait(lambda: all(len(h.tokens) >= 3 for h in hs))
         mode["poison"] = True
-        _wait(lambda: all(h.done for h in hs))
+        # a handle reads done before its callbacks have run: wait for them
+        _wait(lambda: all(h.done for h in hs) and len(heard) == 2)
         assert [h.reason for h in hs] == ["failed", "failed"]
         assert all("device halted" in list(h.events())[-1]["error"]
                    for h in hs)
@@ -427,17 +428,18 @@ def test_step_n_is_dispatched_before_step_n_minus_1_is_emitted(lm):
         admitted = sum(s["name"] == "loop.prefill" for s in t["spans"])
         # after the step before, this turn's prefills' first tokens, each
         firsts = ["loop.fetch", "loop.emit"] * admitted
+        sent = ["loop.select", "loop.upload", "loop.dispatch",
+                "loop.account"]
         if step["attrs"]["ahead"]:
             ahead += 1
             both += bool(admitted)
-            assert names == ["loop.upload", "loop.dispatch", "loop.fetch",
-                             "loop.emit"] + firsts
-            assert parts[1]["end"] <= parts[3]["start"]
+            assert names == sent + ["loop.fetch", "loop.emit"] + firsts
+            assert parts[2]["end"] <= parts[5]["start"]
         elif "loop.dispatch" in names:  # a batch's first step: nothing to land
-            assert names == ["loop.upload", "loop.dispatch"] + firsts
+            assert names == sent + firsts
         else:  # nothing left to step: the turn only lands the last step
             landed_only += 1
-            assert names == ["loop.fetch", "loop.emit"]
+            assert names == ["loop.select", "loop.fetch", "loop.emit"]
     assert ahead == steps_ahead >= 8 and landed_only >= 1 and both >= 1
 
 

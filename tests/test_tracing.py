@@ -5,6 +5,7 @@ span instrumentation. The cross-process propagation contract lives in
 tools/check_trace_contract.py (tier-1 via test_trace_contract.py)."""
 
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -172,6 +173,108 @@ def test_record_span_cross_thread_parenting():
     assert rec["trace_id"] == ctx.trace_id
     assert rec["error"] is True
     assert abs(rec["duration_ms"] - 250.0) < 1e-6
+
+
+# ---------------------------------------------------------------------------
+# CPU time beside wall time (ISSUE 38)
+# ---------------------------------------------------------------------------
+def _only_span(tracer, name):
+    assert tracer.flush()
+    (span,) = [s for t in tracer.store.traces() for s in t["spans"]
+               if s["name"] == name]
+    return span
+
+
+@pytest.mark.parametrize("body", ["busy", "sleeping"])
+def test_a_span_carries_what_its_thread_ran_beside_what_it_took(body):
+    """`cpu_ms` is the entering thread's CPU clock over the span: all of a
+    busy span (never more than its wall time), next to none of one that
+    waits. `duration_ms - cpu_ms` is what the thread did not run."""
+    t = Tracer(TraceStore())
+    with t.span(body):
+        if body == "busy":
+            end = time.perf_counter() + 0.05
+            while time.perf_counter() < end:
+                pass
+        else:
+            time.sleep(0.05)
+    rec = _only_span(t, body)
+    assert rec["thread"] == threading.get_ident()
+    assert 0.0 <= rec["cpu_ms"] <= rec["duration_ms"]
+    assert rec["duration_ms"] >= 50.0
+    if body == "busy":  # a preempted thread ran less than it took: half
+        assert rec["cpu_ms"] > 25.0
+    else:
+        assert rec["cpu_ms"] < 10.0
+    assert rec["self_cpu_ms"] == rec["cpu_ms"]  # no child took any of it
+
+
+def test_self_cpu_is_cpu_less_the_children_on_the_same_thread():
+    t = Tracer(TraceStore())
+    with t.span("parent") as parent:
+        with t.span("child"):
+            end = time.perf_counter() + 0.03
+            while time.perf_counter() < end:
+                pass
+        # another thread's child, timed by that thread's clock: none of it
+        # is the parent's thread's
+        th = threading.Thread(target=lambda: t.span(
+            "elsewhere", parent=parent).__enter__().__exit__(None, None, None))
+        th.start()
+        th.join(timeout=10)
+        t.record_span("measured", parent=parent, start_time=trace_now(),
+                      end_time=trace_now() + 0.01)
+    assert t.flush()
+    spans = {s["name"]: s for s in t.store.get(parent.trace_id)["spans"]}
+    assert spans["parent"]["self_cpu_ms"] == pytest.approx(
+        spans["parent"]["cpu_ms"] - spans["child"]["cpu_ms"], abs=1e-5)
+    assert spans["elsewhere"]["thread"] != spans["parent"]["thread"]
+    assert 0.0 <= spans["parent"]["self_cpu_ms"] < spans["parent"]["cpu_ms"]
+    # the stored records are not rewritten by reading them
+    assert all("self_cpu_ms" not in s
+               for s in t.store._traces[parent.trace_id]["spans"])
+
+
+@pytest.mark.parametrize("how", ["record_span", "finished_only",
+                                 "finished_on_another_thread"])
+def test_spans_that_no_one_thread_timed_carry_no_cpu_time(how):
+    t = Tracer(TraceStore())
+    with t.span("root") as root:
+        if how == "record_span":
+            t.record_span("x", parent=root, start_time=trace_now(),
+                          end_time=trace_now() + 0.01)
+        elif how == "finished_only":
+            t.span("x").finish()
+        else:
+            with t.span("x") as span:
+                th = threading.Thread(target=span.finish)
+                th.start()
+                th.join(timeout=10)
+                assert not th.is_alive()
+    rec = _only_span(t, "x")
+    assert rec["duration_ms"] >= 0.0 and rec["self_ms"] >= 0.0
+    assert not {"cpu_ms", "thread", "self_cpu_ms"} & set(rec)
+    assert "cpu_ms" in _only_span(t, "root")
+
+
+def test_a_span_that_is_not_taken_reads_no_clock(monkeypatch):
+    """Disabled and unsampled spans stay `NULL_SPAN`: no CPU clock, no wall
+    clock, nothing stored."""
+    def clock():
+        raise AssertionError("an untaken span read a clock")
+
+    for tracer in (Tracer(TraceStore(), enabled=False),
+                   Tracer(TraceStore(), sample_rate=0.0)):
+        monkeypatch.setattr(time, "thread_time", clock)
+        monkeypatch.setattr(time, "perf_counter", clock)
+        try:
+            with tracer.span("root") as root:
+                with tracer.span("child") as child:
+                    pass
+        finally:
+            monkeypatch.undo()
+        assert root is NULL_SPAN and child is NULL_SPAN
+        assert len(tracer.store) == 0
 
 
 # ---------------------------------------------------------------------------

@@ -18,12 +18,25 @@ stdout: ms a call, median (p10, p90) of ``N`` calls, and the device's kind
 itself runs beside 128 client threads that take turns at the interpreter
 lock, so its calls cost more than these: the ratios carry over, not the
 milliseconds.
+
+Beside them, under ``account_us`` (microseconds, ISSUE 38): what the loop's
+own account of the device's queue costs a turn, which is always on: one look
+at the queue (``is_ready()`` of a finished array, the two clocks, a counter's
+increment, each alone), and ``turn`` whole: the ten looks, seven marks, two
+enqueues and the turn's end with its counters that a steady turn of 128 rows
+with one admission makes, with nothing found dry (the dearer branch: every
+look asks the device). Inside the running loop the same calls cost several
+times more (PERF.md, PR 38). And under ``state_bytes_us`` what ``dl4j_tpu_decode_state_bytes``
+costs a turn at 128 active rows of the LFM2 cell's layers, row by row as the
+loop made it until ISSUE 38 and in one array expression a layer as it makes
+it now (the two give the same numbers).
 """
 
 import json
 import os
 import sys
 import time
+from collections import Counter
 
 sys.path.insert(0, os.getcwd())
 
@@ -34,7 +47,8 @@ import numpy as np
 from deeplearning4j_tpu.generate.sampling import sample_tokens
 from deeplearning4j_tpu.model.zoo import TransformerLM
 from deeplearning4j_tpu.obs.metrics import MetricsRegistry
-from deeplearning4j_tpu.parallel.decode import DecodeEngine, _Request
+from deeplearning4j_tpu.parallel.decode import (DecodeEngine, _Request,
+                                                live_state_bytes)
 
 N = 200
 
@@ -64,6 +78,100 @@ def timed_fetch(make, fetch, n=50):
     out.sort()
     return [round(out[n // 2], 4), round(out[n // 10], 4),
             round(out[(9 * n) // 10], 4)]
+
+
+def timed_us(fn, batch=1000, n=30):
+    """us a call of ``fn``: the median of ``n`` batches of ``batch`` calls
+    (one call is under the clock's own cost)."""
+    out = []
+    for _ in range(n):
+        t0 = time.perf_counter()
+        for _ in range(batch):
+            fn()
+        out.append(1e6 * (time.perf_counter() - t0) / batch)
+    out.sort()
+    return round(out[n // 2], 4)
+
+
+def account_costs(e) -> dict:
+    """The dry account's pieces and one steady turn of it, us (the engine's
+    loop is parked: nothing else touches the account)."""
+    dry, real = e._dry, e._device_dry
+    jax.block_until_ready(dry.newest)
+    res = {"is_ready": timed_us(dry.newest.is_ready),
+           "device_dry": timed_us(real),
+           "perf_counter": timed_us(time.perf_counter),
+           "thread_time": timed_us(time.thread_time),
+           "counter_inc": timed_us(lambda: e._c_loop_s.inc(1e-9))}
+    dry.begin()
+    res["look_dry"] = timed_us(dry.look)  # already dry: no question asked
+    # every look asks the device and hears "busy"
+    e._device_dry = lambda: real() and False
+    dry.begin()
+    res["look_busy"] = timed_us(dry.look)
+
+    def turn():
+        dry.begin()
+        dry.look()
+        dry.enqueued(dry.newest)          # an admission
+        for phase in ("step", "select", "upload"):
+            dry.mark(phase)
+        dry.look("dispatch")
+        dry.enqueued(dry.newest)          # the step
+        dry.mark("fetch")
+        for _ in range(5):                # the emit loop's start, rows, end
+            dry.look()
+        for phase in ("fetch", "emit"):   # a first token
+            dry.mark(phase)
+        dry.look("step")
+        dry.mark("sweep")
+        e._c_loop_s.inc(dry.end())
+        e._c_dry_slack.inc(dry.slack)
+        for phase in ("emit", "sweep", "admit"):
+            e._c_dry[phase].inc(1e-9)
+    res["turn"] = timed_us(turn, batch=100)
+    del e._device_dry
+    return res
+
+
+def state_bytes_costs(tiny: bool) -> dict:
+    """``_update_state_bytes`` at 128 active rows of the LFM2 cell's layers
+    (no weights: a layer is its configuration): row by row, and now."""
+    from deeplearning4j_tpu.model import zoo
+    from deeplearning4j_tpu.nn.sequential import MultiLayerNetwork
+
+    cfg = json.load(open(os.path.join(
+        "benchmarks", "configs", "lfm2-8b-a1b-pp2.json")))
+    model = dict(cfg["model"])
+    if tiny:
+        model.update(vocab_size=64, hidden=64, n_heads=4, n_kv_heads=2,
+                     ffn_size=128, expert_ffn_size=32, n_experts=4, top_k=2,
+                     max_len=64)
+    layers = Counter(
+        l for l in MultiLayerNetwork(getattr(zoo, cfg["model_class"])(
+            **model, dtype=cfg["dtype"]).conf()).layers
+        if l.decode_live_bytes(0, 1))
+    rng = np.random.default_rng(0)
+    active = np.ones((128,), bool)
+    pos = rng.integers(200, 6100, 128).astype(np.int64)
+
+    def by_row():
+        live = Counter()
+        for layer, count in layers.items():
+            live.update(dict.fromkeys(layer.decode_live_bytes(0, 2), 0))
+            for slot in np.nonzero(active)[0]:
+                for kind, n in layer.decode_live_bytes(
+                        int(pos[slot]), 2).items():
+                    live[kind] += count * n
+        return live
+
+    def by_array():
+        return live_state_bytes(layers, pos[active], 2)
+
+    assert by_row() == by_array(), (by_row(), by_array())
+    return {"layer_kinds": len(layers), "rows": 128,
+            "row_by_row": timed_us(by_row, batch=20),
+            "one_array_a_layer": timed_us(by_array, batch=20)}
 
 
 def main():
@@ -155,6 +263,8 @@ def main():
     res["dispatch_prefill_now"] = timed(dispatch_now, n=N // 2)
     res["admission_now"] = timed(admission_now, n=N // 2)
     res["fetch_first_token"] = timed_fetch(dispatch_now, int)
+    res["account_us"] = account_costs(e)
+    res["state_bytes_us"] = state_bytes_costs("--tiny" in sys.argv)
     e.shutdown(drain=False)
     print(json.dumps(res))
 
