@@ -150,7 +150,11 @@ class GenerationHandle:
         self.deadline = deadline
         self.tokens: List[int] = []
         self.reason: Optional[str] = None
-        self._events: "queue.Queue[dict]" = queue.Queue()
+        # a C-level queue: the engine's put and the consumer's blocking get
+        # run no bytecode and take no Python-level lock, so handing a token
+        # to each of a step's rows does not hold the engine's thread behind
+        # the interpreter lock while the woken consumers run
+        self._events: "queue.SimpleQueue[dict]" = queue.SimpleQueue()
         self._done = threading.Event()
         self._cancelled = threading.Event()
         self._cb_lock = threading.Lock()
@@ -158,8 +162,10 @@ class GenerationHandle:
 
     # ----- engine side -----
     def _emit(self, index: int, token: int) -> None:
-        self.tokens.append(int(token))
-        self._events.put({"token": int(token), "index": int(index)})
+        """Called with Python ints: the engine converts once, where it
+        fetches."""
+        self.tokens.append(token)
+        self._events.put({"token": token, "index": index})
 
     def _finish(self, reason: str, error: Optional[str] = None) -> None:
         self.reason = reason
@@ -205,7 +211,8 @@ class GenerationHandle:
         return self._done.is_set()
 
     def events(self, timeout: Optional[float] = None):
-        """Yield events in order until (and including) the terminal one."""
+        """Yield events in order until (and including) the terminal one;
+        ``queue.Empty`` when none arrives within ``timeout`` seconds."""
         while True:
             ev = self._events.get(timeout=timeout)
             yield ev
@@ -2007,6 +2014,12 @@ class DecodeEngine:
             # lower bound), what the looks' spacing leaves open above it,
             # and the lower bound by the phase of the turn
             "loop": self._loop_stats(),
+            # events put into the live requests' handles and not yet taken:
+            # a consumer that falls behind the loop backs up here, not in
+            # the engine (summed here, never in the loop)
+            "undelivered_events": sum(
+                req.handle._events.qsize() for req in self._requests
+                if req is not None),
             # of the entries the decode kernel moved, the share it attended
             "kv_fetch_valid_share": (
                 self._c_kv_attended.value / kv_fetched if kv_fetched

@@ -30,11 +30,24 @@ times more (PERF.md, PR 38). And under ``state_bytes_us`` what ``dl4j_tpu_decode
 costs a turn at 128 active rows of the LFM2 cell's layers, row by row as the
 loop made it until ISSUE 38 and in one array expression a layer as it makes
 it now (the two give the same numbers).
+
+Under ``handoff``: what handing a token to its consumer costs. A ``put`` and
+the matching ``get`` of one event, in one quiet thread, us, for
+``queue.Queue`` (a ``GenerationHandle``'s queue before) and
+``queue.SimpleQueue`` (its queue now); and the put pass of one step in
+place, ms: 128 consumer threads each blocked in a ``get`` on its own queue
+and running the benchmark client's per-token body, a producer that puts one
+event into each queue and then waits 2 ms with the lock released, as the
+loop waits for the device. The pass's time is what the row loop's puts cost
+with 128 woken threads queued at the interpreter lock, the number beside the
+quiet one.
 """
 
 import json
 import os
+import queue
 import sys
+import threading
 import time
 from collections import Counter
 
@@ -174,6 +187,59 @@ def state_bytes_costs(tiny: bool) -> dict:
             "one_array_a_layer": timed_us(by_array, batch=20)}
 
 
+def put_pass_ms(make_queue, rows=128, passes=200) -> list:
+    """ms of one put pass into ``rows`` queues whose consumer threads wait
+    in ``get``: median, p10, p90 of ``passes`` passes."""
+    queues = [make_queue() for _ in range(rows)]
+    seen = [[] for _ in range(rows)]
+
+    def consume(q, tokens):  # the benchmark client's per-token body
+        times = []
+        while True:
+            ev = q.get(timeout=60)
+            now = time.perf_counter()
+            if ev.get("done"):
+                return
+            tokens.append(ev["token"])
+            times.append(now)
+
+    threads = [threading.Thread(target=consume, args=(q, s), daemon=True)
+               for q, s in zip(queues, seen)]
+    for t in threads:
+        t.start()
+    out = []
+    for i in range(passes):
+        t0 = time.perf_counter()
+        for q in queues:
+            q.put({"token": i, "index": i})
+        out.append(1e3 * (time.perf_counter() - t0))
+        time.sleep(0.002)
+    for q in queues:
+        q.put({"done": True})
+    for t in threads:
+        t.join(timeout=60)
+    assert all(s == list(range(passes)) for s in seen)
+    out.sort()
+    return [round(out[passes // 2], 4), round(out[passes // 10], 4),
+            round(out[(9 * passes) // 10], 4)]
+
+
+def handoff_costs() -> dict:
+    """A put with its get in one quiet thread (us), and a step's put pass to
+    128 waiting consumers (ms), for each kind of queue."""
+    res = {}
+    for name, make in (("Queue", queue.Queue),
+                       ("SimpleQueue", queue.SimpleQueue)):
+        q, ev = make(), {"token": 1, "index": 0}
+        res[name] = {
+            "put_get_us": timed_us(lambda: (q.put(ev), q.get(timeout=1))),
+            "put_pass_128_ms": put_pass_ms(make)}
+    res["put_pass_128_ms_is"] = "median, p10, p90"
+    res["switch_interval_s"] = sys.getswitchinterval()
+    res["cpus"] = os.cpu_count()
+    return res
+
+
 def main():
     if "--tiny" in sys.argv:
         model = TransformerLM(vocab_size=64, hidden=32, n_layers=2, n_heads=4,
@@ -266,6 +332,7 @@ def main():
     res["account_us"] = account_costs(e)
     res["state_bytes_us"] = state_bytes_costs("--tiny" in sys.argv)
     e.shutdown(drain=False)
+    res["handoff"] = handoff_costs()
     print(json.dumps(res))
 
 
