@@ -255,10 +255,12 @@ class GenerationSession:
             mask = (at < lengths[:, None]).astype(self.model.dtype)
             out, new = self._forward(params, state, self._prep(ids), mask,
                                      carry)
+            idx = jnp.clip(lengths - 1 - start, 0, n - 1)[:, None, None]
+            if self._head is not None:  # the head at the one position read
+                return new, self._logits(jnp.take_along_axis(
+                    out, idx, axis=2), params)[:, :, 0]  # [b, V]
             logits = self._logits(out, params)  # [b, V, n]
-            idx = jnp.clip(lengths - 1 - start, 0, n - 1)
-            return new, jnp.take_along_axis(
-                logits, idx[:, None, None], axis=2)[:, :, 0]  # [b, V]
+            return new, jnp.take_along_axis(logits, idx, axis=2)[:, :, 0]
 
         if w is None or t <= w:
             return piece(carry, ids, 0)

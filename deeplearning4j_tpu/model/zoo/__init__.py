@@ -21,6 +21,7 @@ from .unet import UNet
 from .vgg16 import AlexNet, VGG16, VGG19
 from .xception import Xception
 from .nasnet import NASNet
+from .phi4_flash import Phi4FlashLM
 
 __all__ = [
     "AlexNet",
@@ -48,4 +49,5 @@ __all__ = [
     "YOLO2",
     "Xception",
     "NASNet",
+    "Phi4FlashLM",
 ]

@@ -29,6 +29,7 @@ from .norm import (
     LayerNormLayer,
     LocalResponseNormalizationLayer,
     RMSNormLayer,
+    layer_norm,
     rms_norm,
 )
 from .output import (
@@ -63,10 +64,14 @@ from .preprocessors import (
     RnnToCnnPreProcessor,
     RnnToFeedForwardPreProcessor,
 )
+from .cross_decoder import CrossDecoderLayer
 from .decoder_block import DecoderBlockLayer, GatedFFNLayer
+from .diff_attention import DifferentialAttentionLayer
 from .eva import EvaDecoderBlockLayer, gated_silu_ffn, rotary_positions
+from .gmu import GatedMemoryLayer
 from .gqa import GroupedQueryAttentionLayer
 from .longcat import LongCatBlockLayer
+from .mamba import MambaMixerLayer
 from .mla import LatentAttentionLayer
 from .moe import ExpertShareMoELayer, MixtureOfExpertsLayer
 from .samediff_layer import SameDiffLambdaLayer, SameDiffLayer

@@ -159,6 +159,15 @@ class Layer:
         keeps every entry (or none)."""
         return None
 
+    def decode_ring(self) -> Optional[int]:
+        """The entries of a RING that the layer's decode state keeps a row
+        (a sliding window's last keys and values, position ``p`` at slot
+        ``p mod ring``), which a row at position ``p`` attends ``min(p + 1,
+        ring)`` of; ``None`` for a layer that keeps none. Not a
+        :meth:`decode_window`: nothing folds, and a prompt is prefilled
+        whole."""
+        return None
+
     def decode_counts(self) -> Dict[str, Tuple[str, ...]]:
         """What the layer COUNTS of each call in its :meth:`decode_state`:
         ``{leaf: names of its columns}`` for every per-row leaf ``[batch,

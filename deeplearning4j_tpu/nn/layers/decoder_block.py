@@ -2,7 +2,8 @@
 
     h = x + Op(N(x; op_gn));   y = h + FF(N(h; ff_gn))
 
-``N`` is an RMSNorm with a gain of its own each time, ``Op`` and ``FF`` are
+``N`` is an RMSNorm with a gain of its own each time (``norm="layer"``: a
+LayerNorm with a gain and a bias, ``op_gb`` / ``ff_gb``), ``Op`` and ``FF`` are
 the block's two PARTS, layers of their own that the block is configured
 with, not code of the block's:
 
@@ -12,7 +13,10 @@ with, not code of the block's:
   operations carry in a device trace:
   :class:`~.short_conv.ShortConvLayer` (a rolling state a row),
   :class:`~.gqa.GroupedQueryAttentionLayer` (K/V planes),
-  :class:`~.mla.LatentAttentionLayer` (a latent plane);
+  :class:`~.mla.LatentAttentionLayer` (a latent plane),
+  :class:`~.mamba.MambaMixerLayer` (a selective scan's state),
+  :class:`~.diff_attention.DifferentialAttentionLayer` (a window's ring, or
+  a K/V cache that other blocks read), :class:`~.gmu.GatedMemoryLayer`;
 * a FEED-FORWARD (``FF``) gives ``feed(params, u [n, n_in], token_mask) ->
   (y [n, n_in], counts or None)`` over the block's tokens:
   :class:`GatedFFNLayer` (dense), :class:`~.moe.ExpertShareMoELayer` (an
@@ -40,7 +44,7 @@ from ..weights import WeightInit, init_weights
 from .base import (Layer, LayerContext, Params, State, apply_input_dropout,
                    sub_params)
 from .eva import gated_silu_ffn
-from .norm import rms_norm
+from .norm import layer_norm, rms_norm
 
 _F32 = jnp.float32
 
@@ -105,6 +109,11 @@ class DecoderBlockLayer(Layer):
     mixer: Optional[Layer] = None
     ffn: Optional[Layer] = None
     eps: float = 1e-5
+    norm: str = "rms"      # "rms" | "layer" (a LayerNorm with a bias)
+
+    @property
+    def _norm_params(self) -> Tuple[str, ...]:
+        return ("gn", "gb") if self.norm == "layer" else ("gn",)
 
     def output_type(self, input_type: InputType) -> InputType:
         return RecurrentType(size=self.n_in, timesteps=input_type.timesteps)
@@ -120,9 +129,9 @@ class DecoderBlockLayer(Layer):
         return True
 
     def trainable_param_names(self) -> Tuple[str, ...]:
-        return ("op_gn",) + tuple(
+        return tuple(f"op_{n}" for n in self._norm_params) + tuple(
             f"op_{n}" for n in self.mixer.trainable_param_names()) \
-            + ("ff_gn",) + tuple(
+            + tuple(f"ff_{n}" for n in self._norm_params) + tuple(
             f"ff_{n}" for n in self.ffn.trainable_param_names())
 
     def weight_param_names(self) -> Tuple[str, ...]:
@@ -133,6 +142,9 @@ class DecoderBlockLayer(Layer):
         k_op, k_ff = jax.random.split(key)
         out: Dict[str, jax.Array] = {"op_gn": jnp.ones((self.n_in,), dtype),
                                      "ff_gn": jnp.ones((self.n_in,), dtype)}
+        if self.norm == "layer":
+            out |= {"op_gb": jnp.zeros((self.n_in,), dtype),
+                    "ff_gb": jnp.zeros((self.n_in,), dtype)}
         out |= {f"op_{n}": v for n, v in
                 self.mixer.init(k_op, dtype).items()}
         return out | {f"ff_{n}": v for n, v in
@@ -161,6 +173,9 @@ class DecoderBlockLayer(Layer):
     def decode_window(self) -> Optional[int]:
         return self.mixer.decode_window()
 
+    def decode_ring(self) -> Optional[int]:
+        return self.mixer.decode_ring()
+
     def decode_counts(self) -> Dict[str, Tuple[str, ...]]:
         return {"moe_choices": self._counts} if self._counts else {}
 
@@ -168,23 +183,41 @@ class DecoderBlockLayer(Layer):
         return self.mixer.decode_live_bytes(position, itemsize)
 
     # ---- forward ------------------------------------------------------------
+    def _normed(self, params: Params, x: jax.Array, part: str) -> jax.Array:
+        if self.norm == "layer":
+            return layer_norm(x, params[f"{part}_gn"], params[f"{part}_gb"],
+                              self.eps)
+        return rms_norm(x, params[f"{part}_gn"], self.eps)
+
+    def block(self, params: Params, state: State, xt: jax.Array, mask,
+              mix=None):
+        """The block over the residual stream ``xt [b, t, n_in]`` (float32)
+        -> ``(y, the mixer's new state, the feed-forward's counts or None,
+        what else the mixer gave)``. ``mix`` stands in for ``self.mixer.mix``
+        where a caller hands the mixer more than its own state (a memory, a
+        cache another block wrote: ``cross_decoder.py``); it returns ``(o,
+        new state, *more)``."""
+        b, t, h = xt.shape
+        cd = params["op_gn"].dtype
+        u = self._normed(params, xt, "op").astype(cd)
+        o, new, *more = (mix or self.mixer.mix)(
+            sub_params(params, "op_"), state, u, mask)
+        h1 = xt + o.astype(xt.dtype)
+        # float32 into the part: an expert layer's router reads it as it is
+        u = self._normed(params, h1, "ff")
+        token_mask = None if mask is None else mask.reshape(b * t)
+        m, counts = self.ffn.feed(sub_params(params, "ff_"),
+                                  u.reshape(b * t, h), token_mask)
+        return h1 + m.reshape(b, t, h).astype(xt.dtype), new, counts, more
+
     def apply(self, params: Params, state: State, x: jax.Array, ctx: LayerContext) -> Tuple[jax.Array, State]:
         x = apply_input_dropout(self, x, ctx)
         xt = x.transpose(0, 2, 1)                            # [b, t, h]
         xt = xt.astype(jnp.promote_types(xt.dtype, _F32))    # the residual
         b, t, h = xt.shape
-        cd = params["op_gn"].dtype
         # the block keeps no state between calls but its decode state
         sub = {k: v for k, v in state.items() if k != "moe_choices"}
-        u = rms_norm(xt, params["op_gn"], self.eps).astype(cd)
-        o, new = self.mixer.mix(sub_params(params, "op_"), sub, u, ctx.mask)
-        h1 = xt + o.astype(xt.dtype)
-        # float32 into the part: an expert layer's router reads it as it is
-        u = rms_norm(h1, params["ff_gn"], self.eps)
-        token_mask = None if ctx.mask is None else ctx.mask.reshape(b * t)
-        m, counts = self.ffn.feed(sub_params(params, "ff_"),
-                                  u.reshape(b * t, h), token_mask)
-        y = h1 + m.reshape(b, t, h).astype(xt.dtype)
+        y, new, counts, _ = self.block(params, sub, xt, ctx.mask)
         new_state = state
         if sub:
             new_state = dict(new)

@@ -294,6 +294,20 @@ def rms_norm(x: jax.Array, gain: jax.Array, eps: float = 1e-5,
     return x32 * inv * g.reshape(shape)
 
 
+def layer_norm(x: jax.Array, gain: jax.Array, bias: jax.Array,
+               eps: float = 1e-5, axis: int = -1) -> jax.Array:
+    """``(x - mean(x)) / sqrt(var(x) + eps) * g + b`` over ``axis``; the
+    statistics and the result float32 at least, as :func:`rms_norm`."""
+    x32 = x.astype(jnp.promote_types(x.dtype, jnp.float32))
+    mean = jnp.mean(x32, axis=axis, keepdims=True)
+    var = jnp.mean(jnp.square(x32 - mean), axis=axis, keepdims=True)
+    shape = [1] * x.ndim
+    shape[axis] = -1
+    return (x32 - mean) * jax.lax.rsqrt(var + eps) \
+        * gain.astype(x32.dtype).reshape(shape) \
+        + bias.astype(x32.dtype).reshape(shape)
+
+
 @register_config
 @dataclasses.dataclass(frozen=True, kw_only=True)
 class RMSNormLayer(Layer):

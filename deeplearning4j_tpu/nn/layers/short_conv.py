@@ -14,7 +14,8 @@ select of ``freeze_rows`` and the install's ``write_row`` as a recurrent
 layer's ``(h, c)`` do. A call reads the state's columns to the left of its
 first token, so a decode step is one column in and one out, and a
 right-padded prefill hands over the columns at each row's TRUE length (the
-mask's count), not at the bucket's end.
+mask's count), not at the bucket's end: :func:`rolling_conv`, which the
+Mamba mixer's convolution calls too.
 """
 
 from __future__ import annotations
@@ -31,6 +32,32 @@ from ..weights import WeightInit, init_weights
 from .base import Layer, LayerContext, Params, State, apply_input_dropout
 
 _F32 = jnp.float32
+
+
+def rolling_conv(x: jax.Array, state, w: jax.Array, mask):
+    """The causal depthwise convolution of ``x [b, t, c]`` by ``w [c, L]``
+    (tap ``L - 1`` on the current position) -> ``(z [b, t, c] float32, the
+    new state or None)``. ``state`` is the last ``L - 1`` columns of the
+    row before the call, ``[b, L - 1, c]``, or None (a whole sequence from
+    position 0: zeros on the left, and no state handed on). The new state
+    is the ``L - 1`` columns left of each row's first pad (``mask [b, t]``;
+    None: every token is real) in ``x``'s type. The taps multiply in
+    float32."""
+    b, t, c = x.shape
+    taps = w.shape[1]
+    left = taps - 1
+    before = state.astype(x.dtype) if state is not None \
+        else jnp.zeros((b, left, c), x.dtype)
+    ext = jnp.concatenate([before, x], axis=1)           # [b, left + t, c]
+    wc = w.astype(_F32)
+    z = sum(wc[:, j] * ext[:, j:j + t].astype(_F32) for j in range(taps))
+    if state is None:
+        return z, None
+    if mask is None:  # every token is real: a step, an unpadded prompt
+        return z, ext[:, t:]
+    at = jnp.sum(mask > 0, axis=1).astype(jnp.int32)[:, None] \
+        + jnp.arange(left, dtype=jnp.int32)[None, :]
+    return z, jnp.take_along_axis(ext, at[:, :, None], axis=1)
 
 
 @register_config
@@ -84,25 +111,13 @@ class ShortConvLayer(Layer):
         """x ``[b, t, n_in]`` in the parameters' type -> ``(Conv(x) [b, t,
         n_in], the new state)``; ``state`` may be empty (a whole sequence
         from position 0)."""
-        b, t, h = x.shape
-        left = self.kernel - 1
+        h = x.shape[2]
         bcx = jnp.dot(x, params["Win"], preferred_element_type=_F32)
         bx = (bcx[..., :h] * bcx[..., 2 * h:]).astype(x.dtype)
-        before = state["conv"].astype(x.dtype) if "conv" in state \
-            else jnp.zeros((b, left, h), x.dtype)
-        ext = jnp.concatenate([before, bx], axis=1)      # [b, left + t, h]
-        wc = params["Wc"].astype(_F32)
-        z = sum(wc[:, j] * ext[:, j:j + t].astype(_F32)
-                for j in range(self.kernel))
+        z, new = rolling_conv(bx, state.get("conv"), params["Wc"], mask)
         y = jnp.dot((bcx[..., h:2 * h] * z).astype(x.dtype), params["Wout"])
-        if "conv" not in state:
+        if new is None:
             return y, state
-        if mask is None:  # every token is real: a step, an unpadded prompt
-            new = ext[:, t:]
-        else:  # the columns left of each row's first pad
-            at = jnp.sum(mask > 0, axis=1).astype(jnp.int32)[:, None] \
-                + jnp.arange(left, dtype=jnp.int32)[None, :]
-            new = jnp.take_along_axis(ext, at[:, :, None], axis=1)
         return y, {**state, "conv": new.astype(state["conv"].dtype)}
 
     def apply(self, params: Params, state: State, x: jax.Array, ctx: LayerContext) -> Tuple[jax.Array, State]:

@@ -503,6 +503,10 @@ class DecodeEngine:
         # the layers that count the bytes a row's position has made valid
         # (a layer is its configuration: equal layers are counted once)
         self._window = self.session.window
+        # the ring a sliding window's layers keep a row (the shortest), or
+        # None: what the window_entries counter counts against
+        self._ring = min((l.decode_ring() for l in self.session.model.layers
+                          if l.decode_ring()), default=None)
         self._count_cols = self.session.count_columns()
         self._live_layers = Counter(
             l for l in self.session.model.layers
@@ -693,6 +697,13 @@ class DecodeEngine:
             "position + 1 (engines whose attention reads the static "
             "[slots, heads, max_len, d] planes; 0 for the others)",
             ("engine",)).labels(inst)
+        self._c_window_attended = reg.counter(
+            "dl4j_tpu_decode_window_entries_attended_total",
+            "Ring entries that the rows of the dispatched decode steps "
+            "attend in a sliding window's layer, a layer and plane: the sum "
+            "over a step's rows of min(position + 1, ring) (engines whose "
+            "model keeps a ring, Layer.decode_ring; 0 for the others)",
+            ("engine",)).labels(inst)
         self._c_kv_fetched = reg.counter(
             "dl4j_tpu_decode_kv_entries_fetched_total",
             "Cache entries that the flash_decode kernel moves out of HBM "
@@ -763,8 +774,11 @@ class DecodeEngine:
             "Bytes of the decode state that the active rows' positions have "
             "made valid, by kind of entry as the layers name them (a "
             "windowed mixer: window, summary; latent attention: latent; "
-            "grouped-query attention: kv; a short convolution's rolling "
-            "columns: conv, the same at every position); host arithmetic, "
+            "grouped-query attention and a full differential layer's cache: "
+            "kv; a sliding window's ring: window; a short convolution's "
+            "rolling columns: conv; a Mamba layer's scan state and columns: "
+            "ssm; the last three the same from some position on); host "
+            "arithmetic, "
             "once a loop turn", ("engine", "kind"))
         self._g_kv_bytes = reg.gauge(
             "dl4j_tpu_generate_kv_cache_bytes",
@@ -1562,6 +1576,9 @@ class DecodeEngine:
                 self._c_kv_attended.inc(int(lengths.sum()))
                 self._c_kv_fetched.inc(int(decode_fetched_entries(
                     lengths, self.max_len).sum()))
+            if self._ring:
+                self._c_window_attended.inc(int(np.minimum(
+                    self._pos[rows] + 1, self._ring).sum()))
             self._fresh[rows] = False
             self._steps[rows] += 1
             self._pos[rows] += 1

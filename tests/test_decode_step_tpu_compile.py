@@ -394,3 +394,84 @@ def test_lfm2_step_holds_its_kernels_and_no_loop(lfm2_programs):
 def test_lfm2_temporaries_fit_beside_weights_and_planes(lfm2_programs, name):
     ma = lfm2_programs[name][1]
     assert ma.temp_size_in_bytes < LF_ROOM, ma.temp_size_in_bytes
+
+
+# ------------------------------------------------------- Phi-4-mini-flash
+PF_SLOTS, PF_MAX_LEN, PF_HEADS, PF_KV, PF_WINDOW = 64, 10240, 40, 20, 512
+PF_PLANE = rf"bf16\[{PF_SLOTS},{PF_KV},{PF_MAX_LEN},64\]"
+PF_RING = rf"bf16\[{PF_SLOTS},{PF_KV},{PF_WINDOW},64\]"
+PF_SCAN = rf"f32\[{PF_SLOTS},16,5120\]"
+# what the cell's memory reckoning leaves beside 7.705 GB of weights and
+# 4.904 GB of carry on a chip of 16.9 GB (ISSUE 41)
+PF_ROOM = 3_500_000_000
+
+
+@pytest.fixture(scope="module")
+def phi4f_programs(one_chip):
+    """The Phi-4-mini-flash cell's step, install and largest prefill (the
+    8,192 bucket) at the published widths (ISSUE 41: 64 slots x 10,240
+    positions, 40 query heads over 20 K/V heads of 64, rings of 512, Mamba
+    states of 16 x 5,120; 8 layers instead of 32, by the same rule: Mamba,
+    window, Mamba, window | the memory Mamba, the full layer, a GMU, a
+    cross layer), shapes only."""
+    from deeplearning4j_tpu.model.zoo import Phi4FlashLM
+    from deeplearning4j_tpu.nn.sequential import MultiLayerNetwork
+
+    tm = jax.tree_util.tree_map
+    with jax.enable_x64(False):
+        model = MultiLayerNetwork(Phi4FlashLM(
+            vocab_size=200064, hidden=2560, n_layers=8, mb_per_layer=2,
+            n_heads=PF_HEADS, n_kv_heads=PF_KV, ffn_size=10240,
+            sliding_window=PF_WINDOW, d_inner=5120, d_state=16, d_conv=4,
+            dt_rank=160, dtype="bfloat16").conf())
+        params = jax.eval_shape(lambda: model.init().params)
+        model.params = tm(lambda a: jnp.zeros((), a.dtype), params)
+        model._initialized = True
+        model.state = {n: {} for n in model.layer_names()}
+        model._persistent_keys = {n: () for n in model.layer_names()}
+        return _compile_step_and_install(model, params, PF_SLOTS, PF_MAX_LEN,
+                                         one_chip, 8192)
+
+
+@pytest.mark.parametrize("name", ["decode_step", "install_row",
+                                  "prefill_8192"])
+def test_phi4f_cache_rings_and_scans_are_aliased_and_no_plane_is_copied(
+        phi4f_programs, name):
+    text, ma, planes = phi4f_programs[name]
+    # the one full-length cache and two rings: a K and a V plane each
+    assert planes == 2 * PF_SLOTS * PF_KV * 64 * 2 * (PF_MAX_LEN
+                                                     + 2 * PF_WINDOW)
+    scans = 3 * PF_SLOTS * 16 * 5120 * 4          # three Mamba layers' states
+    assert ma.alias_size_in_bytes >= planes + scans, ma.alias_size_in_bytes
+    lines = text.splitlines()
+    for plane in (PF_PLANE, PF_RING):
+        for op in ("copy", "select", "transpose"):
+            hits = [l[:160] for l in lines
+                    if re.search(rf"= {plane}\S* {op}\(", l)]
+            assert not hits, hits[:3]
+    # the cache stays at 20 heads: nothing repeats it to the queries' 40,
+    # and no float32 scores over 10,240 entries stand outside the kernel
+    wide = [l[:160] for l in lines if re.search(
+        rf"= (f32\[{PF_SLOTS},({PF_HEADS}|{PF_KV}|10),(\d+,)?{PF_MAX_LEN}\b|"
+        rf"bf16\[{PF_SLOTS},{PF_HEADS},{PF_MAX_LEN},)", l)]
+    assert not wide, wide[:3]
+
+
+def test_phi4f_step_holds_both_kernels_and_no_loop(phi4f_programs):
+    """Two window layers and two readers of the one cache: two calls of
+    ``diff_decode_window`` and two of ``diff_decode``, an in-place write of
+    a K and a V entry for each of the three layers that own planes; the
+    scans are one position, no loop."""
+    text = phi4f_programs["decode_step"][0]
+    assert text.count("tpu_custom_call") >= 10
+    for kernel in ("diff_decode_window", "diff_decode", "kv_cache_write"):
+        assert kernel in text, kernel
+    assert not re.search(r" while\(", text)
+    assert re.search(PF_SCAN, text)
+
+
+@pytest.mark.parametrize("name", ["decode_step", "prefill_8192"])
+def test_phi4f_temporaries_fit_beside_weights_and_carry(phi4f_programs,
+                                                        name):
+    ma = phi4f_programs[name][1]
+    assert ma.temp_size_in_bytes < PF_ROOM, ma.temp_size_in_bytes

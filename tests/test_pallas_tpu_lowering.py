@@ -13,11 +13,13 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from deeplearning4j_tpu.ops.diff_attention import diff_decode_attention_pallas
 from deeplearning4j_tpu.ops.eva_attention import eva_decode_attention_pallas
 from deeplearning4j_tpu.ops.flash_attention import (
     flash_attention, flash_decode_attention, flash_masked_cache_write)
 from deeplearning4j_tpu.ops.grouped_matmul import _gmm, _tiling
 from deeplearning4j_tpu.ops.mla_attention import mla_decode_attention_pallas
+from deeplearning4j_tpu.ops.selective_scan import selective_scan_pallas
 
 DTYPES = [jnp.bfloat16, jnp.float32]
 
@@ -110,6 +112,39 @@ def test_mla_decode_lowers(dtype):
         _spec(b, h, rank + rope, dtype=dtype),
         _spec(b, 1, L, rank + rope, dtype=dtype), _spec(b, dtype=jnp.int32))
     assert names == ["mla_decode"]
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("L,name", [(10240, "diff_decode"),
+                                    (512, "diff_decode_window")])
+def test_diff_decode_lowers(dtype, L, name):
+    """Phi-4-mini-flash's step at its published widths: 64 rows, 40 query
+    heads over 20 K/V heads of 64 in pairs, the shared cache of 10,240
+    entries and a window's ring of 512."""
+    b, hq, hk, d = 64, 40, 20, 64
+    plane = _spec(b, hk, L, d, dtype=dtype)
+    names = _kernels(
+        lambda q, k, v, n, lam: diff_decode_attention_pallas(
+            q, k, v, n, lam, name=name, interpret=False),
+        _spec(b, hq, d, dtype=dtype), plane, plane,
+        _spec(b, dtype=jnp.int32), _spec(dtype=jnp.float32))
+    assert names == [name]
+
+
+@pytest.mark.parametrize("t", [8192, 300])
+def test_selective_scan_lowers(t):
+    """Phi-4-mini-flash's Mamba prefill at its published widths: one row,
+    d_inner 5,120, d_state 16; 8,192 positions, and a length that no
+    block divides."""
+    di, n = 5120, 16
+    f32 = jnp.float32
+    names = _kernels(
+        lambda x, d, a, b, c, s: selective_scan_pallas(x, d, a, b, c, s,
+                                                       interpret=False),
+        _spec(1, t, di, dtype=f32), _spec(1, t, di, dtype=f32),
+        _spec(di, n, dtype=f32), _spec(1, t, n, dtype=f32),
+        _spec(1, t, n, dtype=f32), _spec(1, n, di, dtype=f32))
+    assert names == ["selective_scan"]
 
 
 @pytest.mark.parametrize("shape", [(8, 12, 1024, 64), (8, 12, 1024),
