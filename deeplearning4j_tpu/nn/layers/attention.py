@@ -97,7 +97,8 @@ def _cached_attention(q, k_new, v_new, state, mask):
     :func:`~deeplearning4j_tpu.ops.decode_attention`'s reference path —
     the resident cache holds ~1/2 the bytes of an fp16 cache (1/4 of
     f32), so the same HBM budget fits ~2× the concurrent sequences."""
-    from ...ops import decode_attention, masked_cache_write
+    from ...ops import (decode_attention, decode_write_fuses,
+                        flash_decode_attention, masked_cache_write)
 
     t = q.shape[2]
     pos = state["pos"].astype(jnp.int32)
@@ -149,6 +150,13 @@ def _cached_attention(q, k_new, v_new, state, mask):
                      "cache_k_scale": k_scale, "cache_v_scale": v_scale,
                      "pos": pos + valid}
         return o, new_state
+    if decode_write_fuses(q, state["cache_k"]):
+        # the decode kernel writes the step's entries into the planes it
+        # reads (aliased): no separate write of either plane
+        o, cache_k, cache_v = flash_decode_attention(
+            q, state["cache_k"], state["cache_v"], pos, new=(k_new, v_new),
+            write_mask=keep)
+        return o, {"cache_k": cache_k, "cache_v": cache_v, "pos": pos + valid}
     cache_k = write(state["cache_k"], k_new, pos)
     cache_v = write(state["cache_v"], v_new, pos)
     # query i at absolute position pos+i attends cache [0, pos+i]; the

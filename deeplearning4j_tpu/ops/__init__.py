@@ -21,6 +21,7 @@ from .flash_attention import (
     decode_attention,
     decode_attention_reference,
     decode_fetched_entries,
+    decode_write_fuses,
     flash_attention,
     flash_decode_attention,
     flash_masked_cache_write,
@@ -75,6 +76,7 @@ __all__ = [
     "decode_attention",
     "decode_attention_reference",
     "decode_fetched_entries",
+    "decode_write_fuses",
     "flash_decode_attention",
     "flash_masked_cache_write",
     "masked_cache_write",

@@ -20,9 +20,11 @@ Inputs [batch, heads, time, head_dim].
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
-from typing import Optional
+import threading
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -684,8 +686,8 @@ def decode_fetched_entries(lengths, max_len: int,
     return (blocks + (blocks == 0)) * bk
 
 
-def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
-                   acc_scr, *, scale, block_k, precision, group=1):
+def _decode_kernel(len_ref, *refs, scale, block_k, precision, group=1,
+                   write=False):
     """One (row, head group, k-block) grid step of single-query flash
     decode: every head of the group at once.
 
@@ -709,9 +711,25 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
     GROUPED queries (``group`` query heads share a K/V head; 2 or 4): the
     group's queries arrive as the eight sublanes (the group, repeated), so
     a K/V block is read ONCE for all of them and the products keep their
-    shapes."""
+    shapes.
+
+    WRITING (``write``: :func:`flash_decode_attention` given the step's new
+    entries): on the one step whose block holds a kept row's position, the
+    128-lane tile round that position takes the new K and V entries in
+    VMEM before the scores are formed, and the tile alone goes back to the
+    cache (aliased, left in HBM) by one DMA a plane from a scratch of its
+    own, waited on before the scratch is used again and at the end."""
+    if write:
+        (wpos_ref, q_ref, k_ref, v_ref, kn_ref, vn_ref, o_ref, ko_ref, vo_ref,
+         m_scr, l_scr, acc_scr, k_tile, v_tile, sems, pending) = refs
+    else:
+        q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = refs
     ki = pl.program_id(2)
     length = len_ref[pl.program_id(0)]  # valid entries = pos + 1
+    if write:
+        _decode_write_tile(wpos_ref, k_ref, v_ref, kn_ref, vn_ref, ko_ref,
+                           vo_ref, k_tile, v_tile, sems, pending,
+                           block_k=block_k)
 
     @pl.when(ki == 0)
     def _():
@@ -756,6 +774,64 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
                     jnp.maximum(l_scr[:, 0:g, :], 1e-30)).astype(o_ref.dtype)
 
 
+def _tile_copies(ko_ref, vo_ref, k_tile, v_tile, sems, r=0, head=0, lane=0):
+    """The two DMAs that send a row's K and V tiles from their scratches
+    to the caches at ``[r, head:, :, lane:]`` (a wait needs only a copy's
+    size: the defaults do)."""
+    hb, tile = k_tile.shape[0], k_tile.shape[2]
+    return [pltpu.make_async_copy(
+        src, dst.at[r, pl.ds(head, hb), :, pl.ds(lane, tile)], sems.at[i])
+        for i, (src, dst) in enumerate(((k_tile, ko_ref), (v_tile, vo_ref)))]
+
+
+def _decode_write_tile(wpos_ref, k_ref, v_ref, kn_ref, vn_ref, ko_ref, vo_ref,
+                       k_tile, v_tile, sems, pending, *, block_k):
+    """The write of :func:`_decode_kernel`, on the step whose block holds
+    row ``r``'s write position ``wpos[r]`` (-1: the row writes nothing):
+    the lane of the position takes the new entry in the K and V blocks as
+    they lie in VMEM, so the scores see it, and the 128-lane tile round it
+    is copied to a scratch and sent to the cache. A copy is waited for
+    before the scratches are written again, and at the last step of all."""
+    r, g, ki = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    w = wpos_ref[r]
+    hb, tile = k_tile.shape[0], k_tile.shape[2]
+
+    def wait():
+        for c in _tile_copies(ko_ref, vo_ref, k_tile, v_tile, sems):
+            c.wait()
+
+    @pl.when((r == 0) & (g == 0) & (ki == 0))
+    def _():
+        pending[0] = 0
+
+    @pl.when((w >= 0) & (ki == w // block_k))
+    def _():
+        pl.when(pending[0] != 0)(wait)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, tile), 2)
+        put = lane == w % tile
+        for t in range(block_k // tile):  # the tile that holds the lane
+            @pl.when((w % block_k) // tile == t)
+            def _():
+                at = slice(t * tile, (t + 1) * tile)
+                for blk, new, scr in ((k_ref, kn_ref, k_tile),
+                                      (v_ref, vn_ref, v_tile)):
+                    # the entry arrives as a row [hb, 1, d] and goes in
+                    # as a column (d on sublanes, position on lanes)
+                    col = jnp.swapaxes(new[0].astype(jnp.float32), 1, 2)
+                    val = jnp.where(put, col.astype(blk.dtype),
+                                    blk[0, :, :, at])
+                    scr[...] = val
+                    blk[0, :, :, at] = val
+
+        for c in _tile_copies(ko_ref, vo_ref, k_tile, v_tile, sems, r,
+                              g * hb, pl.multiple_of(w // tile * tile, tile)):
+            c.start()
+        pending[0] = 1
+
+    pl.when((r == pl.num_programs(0) - 1) & (g == pl.num_programs(1) - 1)
+            & (ki == pl.num_programs(2) - 1) & (pending[0] != 0))(wait)
+
+
 def flash_decode_attention(
     q: jax.Array,           # [b, h, 1, d]
     k: jax.Array,           # [b, h_kv, L, d]
@@ -764,7 +840,9 @@ def flash_decode_attention(
     scale: Optional[float] = None,
     block_k: int = _DECODE_BLOCK_K,
     interpret: Optional[bool] = None,
-) -> jax.Array:
+    new: Optional[Tuple[jax.Array, jax.Array]] = None,
+    write_mask: Optional[jax.Array] = None,
+):
     """Pallas single-query-block decode attention (same contract as
     :func:`decode_attention_reference` with ``tq == 1``). K and V go to
     the kernel as ``[b, h_kv, d, L]``: for a cache the chip keeps
@@ -774,7 +852,18 @@ def flash_decode_attention(
     the blocks that a row's position makes valid are moved
     (:func:`decode_fetched_entries`). With ``h = group x h_kv`` (grouped
     queries, ``group`` 2 or 4) the ``group`` query heads of a K/V head are
-    rows of one product: the cache is read once for them, never repeated."""
+    rows of one product: the cache is read once for them, never repeated.
+
+    ``new = (k_new, v_new)`` (``[b, h_kv, 1, d]``) makes the call the
+    step's cache write too, and it returns ``(out, k, v)``: each row that
+    ``write_mask`` (``[b]`` bool; None keeps every row) keeps writes its
+    entries at its position (clamped to the cache, as
+    :func:`masked_cache_write_reference`) and attends them; every other
+    entry stays as it was. The caches are aliased to the two outputs and
+    only the 128-position tile that holds a kept row's position goes back
+    (:func:`decode_write_fuses` says where that fits: ``L`` a multiple of
+    128 and of the block). A row at a negative position attends nothing
+    and writes nothing."""
     if q.shape[2] != 1:
         raise ValueError("flash_decode_attention is the tq=1 kernel; use "
                          "decode_attention for multi-row queries")
@@ -802,7 +891,7 @@ def flash_decode_attention(
     groups = h // hb
     lengths = jnp.maximum(start_pos.astype(jnp.int32) + 1, 0)  # [b]
 
-    def kv_block(r, g, ki, lens):
+    def kv_block(r, g, ki, lens, *_):
         """A step the row's length leaves dead names the block that the
         next row (or head group) starts with, which so comes in under this
         row's arithmetic and dead steps and is there when its own step
@@ -816,7 +905,7 @@ def flash_decode_attention(
                 jnp.where(stay, g, nxt % groups), 0,
                 jnp.where(live, ki, jnp.where(stay, own_last, 0)))
 
-    def row_block(r, g, ki, lens):
+    def row_block(r, g, ki, lens, *_):
         return (r, g, 0, 0)
 
     kern = functools.partial(
@@ -824,6 +913,10 @@ def flash_decode_attention(
         precision=(jax.lax.Precision.HIGHEST if k.dtype == jnp.float32
                    else None), group=group)
     kw = dict(memory_space=pltpu.VMEM)
+    if new is not None:
+        return _flash_decode_write(
+            kern, q, kp, vp, new, write_mask, lengths, start_pos, block_k,
+            hb, rows, group, interpret, (row_block, kv_block))
     out = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -846,6 +939,76 @@ def flash_decode_attention(
         name="flash_decode",
     )(lengths, q, kp, vp)
     return out.reshape(b, hq, 1, dv)
+
+
+#: positions of a row that the fused write sends back: one lane tile
+_WRITE_TILE = 128
+
+
+def _write_fits(max_len: int, block_k: int = _DECODE_BLOCK_K) -> bool:
+    """Whether :func:`flash_decode_attention` can take a cache of
+    ``max_len`` positions as it lies and write into it: whole blocks and
+    whole 128-lane tiles, no pad (a padded copy could not be aliased)."""
+    bk = min(block_k, max(max_len, 1))
+    return max_len % bk == 0 and bk % _WRITE_TILE == 0
+
+
+def _flash_decode_write(kern, q, kp, vp, new, write_mask, lengths, start_pos,
+                        block_k, hb, rows, group, interpret, maps):
+    """The ``new=`` form of :func:`flash_decode_attention`: the same grid
+    and index maps, a second scalar vector (each row's write position, -1
+    for a row that writes nothing), the new entries a row's block, and the
+    caches aliased to two outputs left in HBM for the tile's DMA."""
+    row_block, kv_block = maps
+    b, h, d, L = kp.shape
+    dv = vp.shape[2]
+    if not _write_fits(L, block_k):
+        raise ValueError(f"flash_decode_attention: a write into a cache of "
+                         f"{L} positions needs no pad (decode_write_fuses)")
+    count_kv_writes("fused", 2)
+    keep = (jnp.ones((b,), bool) if write_mask is None
+            else write_mask.astype(bool))
+    pos = start_pos.astype(jnp.int32)
+    wpos = jnp.where(keep & (pos >= 0), jnp.minimum(pos, L - 1), -1)
+    # the new entries as they come, [b, h, 1, d]: a [.., d, 1] plane
+    # would lie padded to 128 lanes, a copy of 12.6 MB a plane in GPT-2
+    kn, vn = (a.astype(c.dtype) for a, c in zip(new, (kp, vp)))
+    kw = dict(memory_space=pltpu.VMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    out, ko, vo = pl.pallas_call(
+        functools.partial(kern, write=True),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, h // hb, L // block_k),
+            in_specs=[
+                pl.BlockSpec((1, hb, rows, d), row_block, **kw),
+                pl.BlockSpec((1, hb, d, block_k), kv_block, **kw),
+                pl.BlockSpec((1, hb, dv, block_k), kv_block, **kw),
+                pl.BlockSpec((1, hb, 1, d), row_block, **kw),
+                pl.BlockSpec((1, hb, 1, dv), row_block, **kw),
+            ],
+            out_specs=[pl.BlockSpec((1, hb, group, dv), row_block, **kw),
+                       hbm, hbm],
+            scratch_shapes=[
+                pltpu.VMEM((hb, 8, 1), jnp.float32),
+                pltpu.VMEM((hb, 8, 1), jnp.float32),
+                pltpu.VMEM((hb, 8, dv), jnp.float32),
+                pltpu.VMEM((hb, d, _WRITE_TILE), kp.dtype),
+                pltpu.VMEM((hb, dv, _WRITE_TILE), vp.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),
+            ],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((b, h, group, dv), q.dtype),
+                   jax.ShapeDtypeStruct(kp.shape, kp.dtype),
+                   jax.ShapeDtypeStruct(vp.shape, vp.dtype)],
+        # operands: lengths, wpos, q, k, v, k_new, v_new
+        input_output_aliases={3: 1, 4: 2},
+        interpret=interpret,
+        name="flash_decode",
+    )(lengths, wpos, q, kp, vp, kn, vn)
+    return (out.reshape(b, h * group, 1, dv), jnp.swapaxes(ko, 2, 3),
+            jnp.swapaxes(vo, 2, 3))
 
 
 # ---------------------------------------------------------------------------
@@ -995,16 +1158,46 @@ def flash_masked_cache_write(
     return jnp.swapaxes(out, 2, 3) if planes else out
 
 
+_KV_TALLIES = threading.local()
+
+
+@contextlib.contextmanager
+def kv_write_tally():
+    """Count the cache planes that the program traced inside the block
+    writes, by how: ``{"fused": n, "separate": m}`` (the decode kernel's
+    own write of K and V, or a write of a plane of its own). Tracing is
+    where the count is made: a compiled program is not counted again."""
+    tally = {"fused": 0, "separate": 0}
+    stack = _KV_TALLIES.__dict__.setdefault("open", [])
+    stack.append(tally)
+    try:
+        yield tally
+    finally:
+        stack.pop()
+
+
+def count_kv_writes(path: str, planes: int = 1) -> None:
+    """Add ``planes`` cache planes written ``path`` ("fused" or
+    "separate") to every :func:`kv_write_tally` open on this thread."""
+    for tally in getattr(_KV_TALLIES, "open", ()):
+        tally[path] += planes
+
+
+def _decode_impl() -> str:
+    """The selected implementation, "auto" resolved: flash on TPU."""
+    if _IMPL == "auto":
+        return "flash" if jax.default_backend() == "tpu" else "xla"
+    return _IMPL
+
+
 def masked_cache_write(cache: jax.Array, new: jax.Array, pos: jax.Array,
                        write_mask: jax.Array) -> jax.Array:
     """Helper-seam dispatch for the cache write of a fused batch decode
     step (mirrors :func:`decode_attention`): the Pallas in-place kernel
     when "flash" is selected (or automatically on TPU) and one token is
     written, the builtin scatter otherwise."""
-    impl = _IMPL
-    if impl == "auto":
-        impl = "flash" if jax.default_backend() == "tpu" else "xla"
-    if impl == "flash" and new.shape[2] == 1:
+    count_kv_writes("separate")
+    if _decode_impl() == "flash" and new.shape[2] == 1:
         return flash_masked_cache_write(cache, new, pos, write_mask)
     return masked_cache_write_reference(cache, new, pos, write_mask)
 
@@ -1037,12 +1230,21 @@ def decode_attention(
         if v_scale is not None:
             v = v.astype(q.dtype) * v_scale[..., None].astype(q.dtype)
         return decode_attention_reference(q, k, v, start_pos, scale=scale)
-    impl = _IMPL
-    if impl == "auto":
-        impl = "flash" if jax.default_backend() == "tpu" else "xla"
-    if impl == "flash" and q.shape[2] == 1:
+    if _decode_impl() == "flash" and q.shape[2] == 1:
         return flash_decode_attention(q, k, v, start_pos, scale=scale)
     return decode_attention_reference(q, k, v, start_pos, scale=scale)
+
+
+def decode_write_fuses(q: jax.Array, cache: jax.Array) -> bool:
+    """Whether a decode step's write into the static cache ``cache``
+    (``[b, h_kv, L, d]``) and its attention over it are one call of
+    :func:`flash_decode_attention` (``new=``): the Pallas kernel selected
+    (or automatically on TPU), one query row, a floating-point plane, and
+    ``L`` that needs no pad. Everything else writes with
+    :func:`masked_cache_write` and attends with :func:`decode_attention`."""
+    return (_decode_impl() == "flash" and q.shape[2] == 1
+            and jnp.issubdtype(cache.dtype, jnp.floating)
+            and _write_fits(cache.shape[2]))
 
 
 def mha_attention(

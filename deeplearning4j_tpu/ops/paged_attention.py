@@ -31,6 +31,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from .flash_attention import count_kv_writes, decode_attention
+
 
 def pack_row_blocks(x: jax.Array, block_size: int) -> jax.Array:
     """Reshape one row's contiguous cache plane ``[h, L, ...]`` into its
@@ -63,6 +65,7 @@ def paged_cache_write(pool: jax.Array, new: jax.Array,
     ``dynamic_update_slice`` write. Positions past the table's capacity
     clamp to the last slot (the engine retires rows before that happens;
     the clamp only keeps indices in range for frozen/done rows)."""
+    count_kv_writes("separate")
     b, t = new.shape[0], new.shape[2]
     bs = pool.shape[2]
     cap = block_table.shape[1] * bs
@@ -94,8 +97,6 @@ def paged_decode_attention(
     masked out exactly as the static cache's pad garbage is."""
     k = paged_gather(pool_k, block_table)
     v = paged_gather(pool_v, block_table)
-    from .flash_attention import decode_attention
-
     return decode_attention(
         q, k, v, start_pos, scale=scale,
         k_scale=None if k_scale is None else paged_gather(k_scale,

@@ -109,7 +109,7 @@ from ..generate.paged import (
 from ..generate.sampling import PATHS, sample_tokens, sampler_path
 from ..generate.session import (ROW_SPEC_WORDS, GenerationSession,
                                 SpeculativeGenerationSession, pack_row_spec)
-from ..ops.flash_attention import decode_fetched_entries
+from ..ops.flash_attention import decode_fetched_entries, kv_write_tally
 from ..ops.paged_attention import pack_row_blocks
 from ..obs.metrics import MetricsRegistry, get_registry
 from ..obs.compiles import watch_compiles
@@ -516,6 +516,9 @@ class DecodeEngine:
         self._static_kv = (self.block_size is None and cache_dtype != "int8"
                            and any(l.pages_decode_planes
                                    for l in self.session.model.layers))
+        # cache planes a decode step writes (fused, separate): set when its
+        # program is traced
+        self._kv_writes = (0, 0)
         self._init_metrics(registry if registry is not None else get_registry())
 
         # device-side batch state: one preallocated carry, per-row specs.
@@ -711,6 +714,16 @@ class DecodeEngine:
             "(ops.flash_attention.decode_fetched_entries); attended over "
             "fetched is the share of the kernel's bytes that are valid",
             ("engine",)).labels(inst)
+        writes = reg.counter(
+            "dl4j_tpu_decode_kv_writes_total",
+            "Cache planes that the dispatched decode steps wrote, by how: "
+            "fused (the decode kernel wrote the step's K and V entries into "
+            "the planes it read) or separate (each plane by a call of its "
+            "own: kv_cache_write or a scatter); tallied where the step's "
+            "program is traced, added once a dispatched step",
+            ("engine", "path"))
+        self._c_kv_writes = [writes.labels(inst, path)
+                             for path in ("fused", "separate")]
         calls = reg.counter(
             "dl4j_tpu_decode_device_calls_total",
             "What the engine's loop asked of the device, by kind of call: "
@@ -1010,9 +1023,12 @@ class DecodeEngine:
                 # blocks, and one of a static carry writes nothing
                 fwd = mask_inactive_writes(
                     attach_block_table(carry, table), active, sess.planes)
-                with jax.named_scope("forward"):
+                with jax.named_scope("forward"), kv_write_tally() as tally:
                     out, new_rnn = sess._forward(
                         params, state, sess._prep(tokens[:, None]), None, fwd)
+                # the cache planes the step writes, by how: counted as the
+                # program is traced, added once a dispatched step
+                self._kv_writes = (tally["fused"], tally["separate"])
                 with jax.named_scope("logits"):
                     logits = sess._logits(out, params)[:, :, 0]
                 # a slot keeps its last request's spec after it ends: an
@@ -1579,6 +1595,9 @@ class DecodeEngine:
             if self._ring:
                 self._c_window_attended.inc(int(np.minimum(
                     self._pos[rows] + 1, self._ring).sum()))
+            for c, planes in zip(self._c_kv_writes, self._kv_writes):
+                if planes:
+                    c.inc(planes)
             self._fresh[rows] = False
             self._steps[rows] += 1
             self._pos[rows] += 1
@@ -1999,6 +2018,7 @@ class DecodeEngine:
         accepted = int(self._c_spec_accepted.value)
         spec_steps = int(self._c_spec_steps.value)
         kv_fetched = self._c_kv_fetched.value
+        fused, separate = (c.value for c in self._c_kv_writes)
         sampler_steps = [c.value for c in self._c_sampler]
         counts.update({
             "in_flight": self._admission.pending,
@@ -2041,6 +2061,10 @@ class DecodeEngine:
             "kv_fetch_valid_share": (
                 self._c_kv_attended.value / kv_fetched if kv_fetched
                 else None),
+            # of the cache planes the steps wrote, the share that the
+            # decode kernel wrote itself
+            "kv_write_fused_share": (
+                fused / (fused + separate) if fused + separate else None),
             # of the plain steps dispatched, the share whose sampler sorted
             "sampler_sort_share": (
                 sampler_steps[PATHS.index("sort")] / sum(sampler_steps)

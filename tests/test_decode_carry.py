@@ -154,7 +154,8 @@ def test_inactive_rows_are_bit_identical_across_a_step(eng):
 
 def _check_inactive_rows(e):
     rs = np.random.RandomState(7)
-    pos = np.asarray([3, MAX_LEN - 1, MAX_LEN, 5], np.int32)
+    L = e.max_len
+    pos = np.asarray([3, L - 1, L, 5], np.int32)
     active = np.asarray([True, False, False, True])
     carry, host = _fill(e, rs, pos)
     sess = e.session
@@ -175,7 +176,7 @@ def _check_inactive_rows(e):
                                               host[name][k][idle])
                 # the active rows wrote their own position, only that
                 for r in np.nonzero(active)[0]:
-                    same = np.ones((MAX_LEN,), bool)
+                    same = np.ones((L,), bool)
                     same[pos[r]] = False
                     np.testing.assert_array_equal(
                         st[k][r][:, same], host[name][k][r][:, same])
@@ -213,6 +214,41 @@ def test_inactive_rows_are_bit_identical_under_the_kernels(lm, pallas_kernels):
         e.shutdown(drain=False)
     assert got == GenerationSession(lm, max_len=MAX_LEN).generate(
         [[1, 2, 3]], 5)[0]
+
+
+@pytest.mark.parametrize("layout", ["static", "int8"])
+def test_the_decode_kernel_writes_the_steps_planes(layout, pallas_kernels):
+    """A cache of 128 positions (one block, one tile) under the step's TPU
+    spelling: the float planes are written by the decode kernel itself
+    (2 planes a layer a step, ``path="fused"``), an int8 cache's four by
+    writes of their own. Inactive rows stay bit-identical either way, and
+    the tokens are the XLA spelling's."""
+    L = 128
+    lm = TransformerLM(vocab_size=VOCAB, hidden=32, n_layers=2, n_heads=4,
+                       max_len=L).init()
+    reg = MetricsRegistry()
+    e = DecodeEngine(lm, max_len=L, slots=SLOTS, registry=reg, name="kvw",
+                     **LAYOUTS[layout])
+    try:
+        assert e.stats()["kv_write_fused_share"] is None
+        _check_inactive_rows(e)
+        n, m = 3, 6
+        got = e.submit(list(range(1, n + 1)), max_tokens=m).result(
+            timeout=120)
+        share = e.stats()["kv_write_fused_share"]
+    finally:
+        e.shutdown(drain=False)
+    c = reg.get("dl4j_tpu_decode_kv_writes_total")
+    fused, separate = (c.labels("kvw", p).value for p in ("fused", "separate"))
+    steps = m - 1  # the prefill hands out the first token
+    if layout == "static":
+        assert (fused, separate, share) == (2 * 2 * steps, 0, 1.0)
+    else:
+        assert (fused, separate, share) == (0, 4 * 2 * steps, 0.0)
+    set_attention_impl("xla")
+    want = GenerationSession(lm, max_len=L).generate([list(range(1, n + 1))],
+                                                     m)[0]
+    assert got == want
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32, jnp.int8])

@@ -134,12 +134,17 @@ def test_every_plane_is_updated_where_it_lies(programs, name):
 
 
 def test_the_step_holds_its_kernels_and_no_loop(programs):
-    """Two blocks: two calls of the decode kernel, four of the in-place
-    cache write, and no `%while` of one small update a row in its place."""
+    """Two blocks: two calls of the decode kernel, which writes the step's
+    K and V entries itself (no call of the separate cache write), and no
+    `%while` of one small update a row in its place."""
     text = programs["decode_step"][0]
-    assert text.count("tpu_custom_call") >= 6
-    assert "flash_decode" in text and "kv_cache_write" in text
+    assert text.count("tpu_custom_call") >= 2
+    assert "flash_decode" in text and "kv_cache_write" not in text
     assert not re.search(r" while\(", text)
+    # the new entries reach the kernel as rows: an entry laid out as a
+    # column [.., 64, 1] lies padded to 128 lanes, 12.6 MB a plane a step
+    assert not re.search(rf"bf16\[{SLOTS},{HEADS},{HIDDEN // HEADS},1\]",
+                         text)
 
 
 # what PERF.md section 4 gives the step for temporaries (GB 0.049). At this
@@ -381,11 +386,12 @@ def test_lfm2_planes_and_rolling_states_are_aliased_and_no_plane_is_copied(
 
 
 def test_lfm2_step_holds_its_kernels_and_no_loop(lfm2_programs):
-    """One attention layer: one call of the grouped decode kernel and two
-    in-place writes; the rolling states are plain slices and selects."""
+    """One attention layer: one call of the grouped decode kernel, which
+    writes the step's K and V entries itself; the rolling states are plain
+    slices and selects."""
     text = lfm2_programs["decode_step"][0]
-    assert text.count("tpu_custom_call") >= 3
-    assert "flash_decode" in text and "kv_cache_write" in text
+    assert text.count("tpu_custom_call") >= 1
+    assert "flash_decode" in text and "kv_cache_write" not in text
     assert not re.search(r" while\(", text)
     assert re.search(LF_STATE, text)
 

@@ -136,6 +136,8 @@ def test_engine_rows_at_different_positions_with_idle_rows(lm, impl):
         got = [h.result(timeout=300) for h in handles]
         assert got == [_greedy(w, p, n) for p, n in zip(prompts, lens)]
         assert eng.stats()["failed"] == 0 and eng.stats()["carry_rebuilds"] == 0
+        # EVA's planes are written by writes of their own, either spelling
+        assert eng.stats()["kv_write_fused_share"] == 0.0
     finally:
         eng.shutdown(drain=False)
         set_attention_impl("auto")

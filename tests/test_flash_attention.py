@@ -237,3 +237,79 @@ def test_ungrouped_flash_decode_is_the_program_it_was():
     assert "float32[2,4,2,16]" in grouped
     with pytest.raises(ValueError, match="group of 1, 2 or 4"):
         trace(12, 4)
+
+
+# ---------------------------------------------------------------------------
+# the decode kernel writes the step's K and V itself
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("dtype, tol", [(jnp.float32, 2e-6),
+                                        (jnp.bfloat16, 2e-2)])
+@pytest.mark.parametrize("group", [1, 4])
+def test_flash_decode_writes_what_the_separate_writes_write(group, dtype, tol):
+    """``flash_decode_attention(..., new=, write_mask=)`` (interpreted)
+    against ``masked_cache_write_reference`` on each plane followed by
+    ``decode_attention_reference``: rows at positions 0, 127, 128, 255,
+    256 and L - 1 (a block's and a tile's edges on both sides), a row the
+    mask keeps off and a row at -1. The outputs agree to the tolerances
+    above; both planes agree BIT FOR BIT at every entry, what lies past a
+    row's position (NaN here) included. A row at -1 attends nothing and
+    writes nothing, kept or not (the reference's scatter would clamp it
+    onto position 0, as ``dynamic_update_slice`` does: no caller has such
+    a row)."""
+    from deeplearning4j_tpu.ops.flash_attention import (
+        decode_attention_reference, flash_decode_attention,
+        masked_cache_write_reference)
+
+    b, h_kv, d, L = 8, 2, 16, 512
+    ks = jax.random.split(jax.random.PRNGKey(group), 5)
+    q = jax.random.normal(ks[0], (b, group * h_kv, 1, d), dtype)
+    k, v = (jax.random.normal(kk, (b, h_kv, L, d), dtype) for kk in ks[1:3])
+    kn, vn = (jax.random.normal(kk, (b, h_kv, 1, d), dtype) for kk in ks[3:])
+    pos = jnp.asarray([0, 127, 128, 255, 256, L - 1, 300, -1], jnp.int32)
+    keep = jnp.asarray([True] * 6 + [False, True])
+    stale = np.arange(L)[None, :] > np.asarray(pos)[:, None]
+    k, v = (jnp.where(stale[:, None, :, None], jnp.nan, a) for a in (k, v))
+    out, k2, v2 = flash_decode_attention(q, k, v, pos, new=(kn, vn),
+                                         write_mask=keep, interpret=True)
+    writes = keep & (pos >= 0)
+    want_k = masked_cache_write_reference(k, kn, pos, writes)
+    want_v = masked_cache_write_reference(v, vn, pos, writes)
+    bits = {jnp.float32: np.uint32, jnp.bfloat16: np.uint16}[dtype]
+    for got, want, old in ((k2, want_k, k), (v2, want_v, v)):
+        got, want, old = (np.asarray(a).view(bits) for a in (got, want, old))
+        np.testing.assert_array_equal(got, want)
+        np.testing.assert_array_equal(got[6:], old[6:])  # kept off; at -1
+    for r in range(6):  # the kept rows' entries are the new ones
+        np.testing.assert_array_equal(
+            np.asarray(k2[r, :, pos[r]]).view(bits),
+            np.asarray(kn[r, :, 0]).view(bits))
+    ref = np.asarray(decode_attention_reference(
+        q, jnp.nan_to_num(want_k), jnp.nan_to_num(want_v), pos), np.float32)
+    got = np.asarray(out, np.float32)
+    assert got.shape == (b, group * h_kv, 1, d)
+    assert np.isfinite(got).all() and not got[7].any()
+    np.testing.assert_allclose(got, ref, atol=tol, rtol=0)
+
+
+def test_the_write_fuses_only_on_the_flash_path_over_an_unpadded_plane():
+    """``decode_write_fuses``: the Pallas kernel selected, one query row, a
+    floating-point plane, and a length of whole blocks and whole tiles
+    (GPT-2's 1,024, LFM2's 6,144); everything else writes on its own."""
+    from deeplearning4j_tpu.ops import decode_write_fuses
+
+    def fuses(L, t=1, dtype=jnp.bfloat16):
+        return decode_write_fuses(jnp.zeros((2, 4, t, 8), dtype),
+                                  jnp.zeros((2, 4, L, 8), dtype))
+
+    set_attention_impl("flash")
+    try:
+        assert fuses(1024) and fuses(6144) and fuses(128) and fuses(512)
+        assert fuses(1024, dtype=jnp.float32)
+        assert not (fuses(600) or fuses(384) or fuses(16) or fuses(1000))
+        assert not fuses(1024, t=4)
+        assert not fuses(1024, dtype=jnp.int8)
+        set_attention_impl("xla")
+        assert not fuses(1024)
+    finally:
+        set_attention_impl("auto")
+    assert not fuses(1024)  # "auto" off the chip is the XLA spelling

@@ -195,6 +195,8 @@ def test_engine_equals_the_session_and_its_counters_a_hand_count(lm):
         handles = [eng.submit(p, max_tokens=10, greedy=True) for p in prompts]
         outs = [h.result(timeout=120) for h in handles]
         assert eng.stats()["steps_ahead"] > 0
+        # the latent planes are written by writes of their own
+        assert eng.stats()["kv_write_fused_share"] == 0.0
     finally:
         eng.shutdown(drain=True)
     sess = GenerationSession(model, max_len=64)

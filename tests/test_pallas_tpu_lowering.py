@@ -57,34 +57,45 @@ def test_flash_forward_and_backward_lower(dtype, b, h, t, d, causal):
         "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
 
 
+def _decode_kernels(b, h, h_kv, L, d, dtype, write):
+    """Kernel names of a decode call lowered for TPU; ``write``: the call
+    that also writes the step's K and V entries into the caches."""
+    def call(q, k, v, p, kn, vn, m):
+        if not write:
+            return flash_decode_attention(q, k, v, p, interpret=False)
+        return flash_decode_attention(q, k, v, p, interpret=False,
+                                      new=(kn, vn), write_mask=m)
+
+    kv, new = _spec(b, h_kv, L, d, dtype=dtype), _spec(b, h_kv, 1, d,
+                                                       dtype=dtype)
+    return _kernels(call, _spec(b, h, 1, d, dtype=dtype), kv, kv,
+                    _spec(b, dtype=jnp.int32), new, new,
+                    _spec(b, dtype=jnp.bool_))
+
+
 @pytest.mark.parametrize("dtype", DTYPES)
-@pytest.mark.parametrize("b,L", [
-    (8, 1024), (8, 600),
-    (128, 1024),                # the serve cell's own step: 128 slots
+@pytest.mark.parametrize("b,L,write", [
+    pytest.param(8, 1024, False, id="8-1024"),
+    pytest.param(8, 600, False, id="8-600"),
+    # the serve cell's own step: 128 slots; and with the step's write
+    pytest.param(128, 1024, False, id="128-1024"),
+    pytest.param(128, 1024, True, id="128-1024-write"),
 ])
-def test_flash_decode_lowers(dtype, b, L):
-    h, d = 12, 64
-    names = _kernels(
-        lambda q, k, v, p: flash_decode_attention(q, k, v, p,
-                                                  interpret=False),
-        _spec(b, h, 1, d, dtype=dtype), _spec(b, h, L, d, dtype=dtype),
-        _spec(b, h, L, d, dtype=dtype), _spec(b, dtype=jnp.int32))
-    assert names == ["flash_decode"]
+def test_flash_decode_lowers(dtype, b, L, write):
+    assert _decode_kernels(b, 12, 12, L, 64, dtype, write) == ["flash_decode"]
 
 
 @pytest.mark.parametrize("dtype", DTYPES)
-@pytest.mark.parametrize("h, h_kv", [(32, 8), (4, 2)])
-def test_grouped_flash_decode_lowers(dtype, h, h_kv):
+@pytest.mark.parametrize("h, h_kv, write", [
+    pytest.param(32, 8, False, id="32-8"), pytest.param(4, 2, False, id="4-2"),
+    pytest.param(32, 8, True, id="32-8-write"),
+])
+def test_grouped_flash_decode_lowers(dtype, h, h_kv, write):
     """K and V of fewer heads than the queries (LFM2's 32 over 8 at the
     cell's 128 rows x 6,144 positions): the group's queries are rows of
-    one product."""
-    b, L, d = 128, 6144, 64
-    names = _kernels(
-        lambda q, k, v, p: flash_decode_attention(q, k, v, p,
-                                                  interpret=False),
-        _spec(b, h, 1, d, dtype=dtype), _spec(b, h_kv, L, d, dtype=dtype),
-        _spec(b, h_kv, L, d, dtype=dtype), _spec(b, dtype=jnp.int32))
-    assert names == ["flash_decode"]
+    one product; with the step's write, the caches aliased to outputs."""
+    assert _decode_kernels(128, h, h_kv, 6144, 64, dtype, write) == [
+        "flash_decode"]
 
 
 @pytest.mark.parametrize("dtype", DTYPES)

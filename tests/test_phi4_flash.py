@@ -225,6 +225,8 @@ def test_decode_engine_serves_what_the_full_forward_gives(lm):
         served = [[e["token"] for e in h.events(timeout=120)
                    if "token" in e] for h in handles]
         assert eng.stats()["kv_fetch_valid_share"] is not None
+        # the one cache's and the rings' planes: writes of their own
+        assert eng.stats()["kv_write_fused_share"] == 0.0
     finally:
         eng.shutdown(drain=False)
     for r, (n, toks) in enumerate(zip(starts, served)):

@@ -106,6 +106,50 @@ def test_grouped_flash_decode_compiled_matches_reference(tpu_device, h, h_kv,
     assert (np.asarray(again, np.float32) == np.asarray(got, np.float32)).all()
 
 
+@pytest.mark.parametrize("h,h_kv,b,L", [
+    (12, 12, 8, 1024),
+    (12, 12, 128, 1024),        # the GPT-2 cell's step
+    (32, 8, 128, 6144),         # the LFM2 cell's step
+])
+def test_flash_decode_write_compiled_matches_the_separate_writes(
+        tpu_device, h, h_kv, b, L):
+    """The decode kernel given the step's new K and V entries: both planes
+    equal the masked scatter's bit for bit (kept rows at their positions,
+    clamped past the end; a row kept off and a row at -1 write nothing),
+    and the output equals the reference's over the written planes."""
+    from deeplearning4j_tpu.ops.flash_attention import (
+        masked_cache_write_reference)
+
+    d, g = 64, h // h_kv
+    q, k, v = (_rand(0, b, h, 1, d), _rand(1, b, h_kv, L, d),
+               _rand(2, b, h_kv, L, d))
+    kn, vn = _rand(3, b, h_kv, 1, d), _rand(4, b, h_kv, 1, d)
+    if b == 8:
+        pos = np.asarray([0, 127, 128, 255, 256, L - 1, L + 3, -1])
+    else:
+        pos = np.random.RandomState(11).permutation(
+            np.linspace(16, L - 1, b).astype(np.int32))
+        pos[4] = -1
+    keep = np.ones((b,), bool)
+    keep[[2, 9 % b]] = False
+    start, mask = jnp.asarray(pos, jnp.int32), jnp.asarray(keep)
+    run = jax.jit(lambda *a: flash_decode_attention(
+        *a[:4], interpret=False, new=a[4:6], write_mask=a[6]))
+    want_k, want_v = (jax.jit(masked_cache_write_reference)(
+        c, n, start, mask & (start >= 0)) for c, n in ((k, kn), (v, vn)))
+    got, k2, v2 = run(q, k, v, start, kn, vn, mask)
+    for a, want in ((k2, want_k), (v2, want_v)):
+        assert (np.asarray(a).view(np.uint16)
+                == np.asarray(want).view(np.uint16)).all()
+    ref = np.concatenate([np.asarray(_highest(
+        decode_attention_reference, q[r:r + 16],
+        jnp.repeat(want_k[r:r + 16], g, axis=1),
+        jnp.repeat(want_v[r:r + 16], g, axis=1),
+        start[r:r + 16])) for r in range(0, b, 16)])
+    assert _rel(got, ref) < FWD_TOL
+    assert not np.asarray(got, np.float32)[4 if b > 8 else 7].any()
+
+
 @pytest.mark.parametrize("t,dtype", [(1, jnp.bfloat16), (1, jnp.int8),
                                      (4, jnp.bfloat16)])
 def test_masked_cache_write_compiled_drops_idle_rows(tpu_device, t, dtype):
