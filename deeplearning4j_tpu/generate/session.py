@@ -28,6 +28,7 @@ re-forward at every position) is enforced in tier-1
 
 from __future__ import annotations
 
+import contextlib
 import struct
 from typing import Dict, List, Optional, Sequence
 
@@ -36,6 +37,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..nn.layers.base import fresh_rows
 from ..nn.layers.output import BaseOutputLayer
 from ..nn.activations import Activation
 from .sampling import sample_tokens, speculative_accept
@@ -53,6 +55,16 @@ def bucket_length(n: int, limit: int) -> int:
 
 
 CACHE_DTYPES = (None, "int8")
+
+# the columns of a self-speculating row's device-side image
+# (:meth:`GenerationSession.mtp_step`): the last committed token (the next
+# input), the draft after it, the tokens emitted so far (the sampling key's
+# step), and what the step that wrote it committed: how many, the stack's
+# token at the first position and at the second, whether a draft was
+# verified and whether it was kept
+(SV_LAST, SV_DRAFT, SV_EMITTED, SV_N, SV_TOK0, SV_TOK1, SV_PROPOSED,
+ SV_ACCEPTED) = range(8)
+SV_WIDTH = 8
 
 
 # one prompt's admission as one int32 vector: its length, the slot it is for,
@@ -136,6 +148,10 @@ class GenerationSession:
         # an output layer that knows its own next-token logits (several
         # prediction heads, of which decoding reads the first)
         self._head = getattr(last, "decode_logits", None)
+        #: whether the output layer holds a multi-token-prediction module
+        #: that drafts the token after next (``MtpOutputLayer``): the model
+        #: can speculate with its own module (:meth:`mtp_step`)
+        self.mtp = callable(getattr(last, "draft", None))
         self._fns: Dict = {}
         # at least one layer must expose decode state, otherwise "decode"
         # would silently re-run from scratch each step
@@ -220,6 +236,12 @@ class GenerationSession:
             params, state, x, train=False, rng=None, mask=mask,
             rnn_state=carry,
             upto=None if self._head is None else len(model.layers) - 1)
+        head = self._layer_names[-1]
+        if self._head is not None and head in carry:
+            # the head's own decode state (an MTP module's plane) passes
+            # through a forward that stops before the head
+            new = {**new, head: {k: v for k, v in carry[head].items()
+                                 if k != "write_mask"}}
         return out, new
 
     def _logits(self, out: jax.Array, params=None) -> jax.Array:
@@ -253,8 +275,12 @@ class GenerationSession:
             n = ids.shape[1]
             at = start + jnp.arange(n, dtype=jnp.int32)[None, :]
             mask = (at < lengths[:, None]).astype(self.model.dtype)
-            out, new = self._forward(params, state, self._prep(ids), mask,
-                                     carry)
+            # the first window stands on nothing; a later one on those
+            # before it (its start is traced)
+            with (fresh_rows() if isinstance(start, int) and start == 0
+                  else contextlib.nullcontext()):
+                out, new = self._forward(params, state, self._prep(ids),
+                                         mask, carry)
             idx = jnp.clip(lengths - 1 - start, 0, n - 1)[:, None, None]
             if self._head is not None:  # the head at the one position read
                 return new, self._logits(jnp.take_along_axis(
@@ -293,6 +319,107 @@ class GenerationSession:
             spec[3:4] != 0, bits(spec[4:5], jnp.float32), spec[5:6],
             bits(spec[6:7], jnp.float32))
         return row, tok[0], self.summed_counts(row)
+
+    # ----- self-speculation: the model's own MTP module drafts ----------
+    def _mtp_params(self, params, like):
+        params, _ = self.model._to_compute(params, like)
+        return self.model.layer_params(params, len(self._layer_names) - 1)
+
+    def _mtp_draft(self, params, state, out, nxt, at, mask=None):
+        """The MTP module over the main stack's outputs ``out [b, n_in, t]``
+        and the next ids ``nxt [b, t]`` on the head's state -> ``(the
+        draft's logits at position ``at [b]`` of the call [b, V], the
+        module's new state)``."""
+        head = self.model.layers[-1]
+        hp = self._mtp_params(params, out)
+        g, new = head.draft(hp, state, out, nxt, mask)
+        g = jnp.take_along_axis(g, at[:, None, None], axis=1)
+        return head.draft_logits(hp, g)[:, 0], new
+
+    def mtp_prefill_row(self, params, state, ids, spec):
+        """:meth:`prefill_row` for a model that drafts with its own MTP
+        module -> ``(row, first token, draft, counts)``: the module runs
+        over the prompt too (position ``i`` pairs the stack's output with
+        the id at ``i + 1``, the last with the first token), so that the
+        row's first step already verifies a draft, the token after the
+        first."""
+        bits = jax.lax.bitcast_convert_type
+        n = spec[0:1]
+        t = ids.shape[1]
+        mask = (jnp.arange(t, dtype=jnp.int32)[None, :]
+                < n[:, None]).astype(self.model.dtype)
+        row = self.decode_state(1)
+        with fresh_rows():
+            out, row = self._forward(params, state, self._prep(ids), mask,
+                                     row)
+        at = jnp.clip(n - 1, 0, t - 1)
+        last = self._logits(jnp.take_along_axis(
+            out, at[:, None, None], axis=2), params)[:, :, 0]
+        tok = sample_tokens(
+            last, bits(spec[2:3], jnp.uint32), jnp.zeros((1,), jnp.int32),
+            spec[3:4] != 0, bits(spec[4:5], jnp.float32), spec[5:6],
+            bits(spec[6:7], jnp.float32))
+        nxt = jnp.concatenate([ids[:, 1:], ids[:, :1]], axis=1)
+        nxt = jnp.where(jnp.arange(t)[None, :] == at[:, None],
+                        tok[:, None], nxt)
+        name = self._layer_names[-1]
+        with fresh_rows():
+            logits, row[name] = self._mtp_draft(params, row[name], out, nxt,
+                                                at, mask)
+        draft = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return row, tok[0], draft[0], self.summed_counts(row)
+
+    def mtp_step(self, params, state, carry, sv, rows):
+        """One self-speculative step at depth 1 over a batch ``carry``. ``sv
+        [b, SV_WIDTH]`` is each row's device-side image (:data:`SV_WIDTH`:
+        its last committed token, the draft, the tokens emitted so far...),
+        ``rows`` the host's ``int32 [7, b]``: active, seed, greedy,
+        temperature, top-k, top-p (the floats and the seed as their bits),
+        and the row's limit of emitted tokens. The stack verifies ``[last,
+        draft]`` at two positions; a greedy row with room for two keeps the
+        draft where it is the stack's own next token, and then the token
+        after (two committed), else the stack's token alone (one). The MTP
+        module then runs over both positions with the tokens they commit,
+        and drafts from the last one committed. Every plane advanced two
+        positions and is rewound to the committed frontier by its ``pos``.
+        -> ``(carry, sv, counts)``; ``sv`` says what the step committed."""
+        from .paged import freeze_rows, mask_inactive_writes
+
+        bits = jax.lax.bitcast_convert_type
+        last, draft, emitted = (sv[:, SV_LAST], sv[:, SV_DRAFT],
+                                sv[:, SV_EMITTED])
+        room = rows[6] - emitted
+        active = (rows[0] != 0) & (room > 0)
+        gmask = rows[2] != 0
+        spec = active & gmask & (room >= 2)
+        fwd = mask_inactive_writes(carry, active, self.planes)
+        with jax.named_scope("verify"):
+            out, new = self._forward(
+                params, state, self._prep(jnp.stack([last, draft], axis=1)),
+                None, fwd)
+            logits = self._logits(out, params)                 # [b, V, 2]
+        with jax.named_scope("sample"):
+            tok0 = sample_tokens(
+                logits[:, :, 0], bits(rows[1], jnp.uint32), emitted,
+                gmask | ~active, bits(rows[3], jnp.float32), rows[4],
+                bits(rows[5], jnp.float32))
+            tok1 = jnp.argmax(logits[:, :, 1], axis=-1).astype(jnp.int32)
+        acc = spec & (tok0 == draft)
+        n = jnp.where(active, 1 + acc.astype(jnp.int32), 0)
+        name = self._layer_names[-1]
+        dlogits, new[name] = self._mtp_draft(
+            params, fwd[name], out, jnp.stack([tok0, tok1], axis=1),
+            jnp.maximum(n - 1, 0))
+        nxt_draft = jnp.argmax(dlogits, axis=-1).astype(jnp.int32)
+        with jax.named_scope("freeze_rows"):
+            counts = self.summed_counts(new, active)
+            new = rewind_carry(freeze_rows(new, fwd, active, self.planes),
+                               jnp.where(active, 2 - n, 0))
+        sv = jnp.stack([
+            jnp.where(active, jnp.where(acc, tok1, tok0), last),
+            jnp.where(active, nxt_draft, draft), emitted + n, n, tok0,
+            tok1, spec.astype(jnp.int32), acc.astype(jnp.int32)], axis=1)
+        return new, sv.astype(jnp.int32), counts
 
     # ----- jitted steps -----------------------------------------------
     def _prefill_fn(self, t_bucket: int):
@@ -421,25 +548,29 @@ class GenerationSession:
 # speculative decoding
 # ---------------------------------------------------------------------------
 
+# ``latent``: a latent-attention plane (a window of drafted tokens attends
+# it through ``mla_verify``); ``moe_choices``: the counts of the last call
 _REWINDABLE_KEYS = frozenset({"cache_k", "cache_v", "pos",
                               "cache_k_scale", "cache_v_scale",
-                              "block_table"})
+                              "block_table", "latent", "moe_choices"})
 
 
 def _check_rewindable(session: GenerationSession, role: str) -> None:
     """Speculative decode writes ``k+1`` positions ahead and must be able
     to roll the uncommitted suffix back after a rejection. That is only
     possible when every decode-state leaf is position-indexed (K/V caches
-    masked by a ``pos`` counter): a recurrent ``h``/``c`` carry has no
-    position to rewind, so those models are rejected up front."""
+    and latent planes masked by a ``pos`` counter): a recurrent ``h``/``c``
+    carry, a rolling convolution or a scan state has no position to rewind,
+    so those models are rejected up front."""
     for name, st in session.decode_state(1).items():
         keys = set(st.keys())
         if "pos" not in keys or not keys <= _REWINDABLE_KEYS:
             raise ValueError(
                 f"speculative decoding requires position-indexed decode "
-                f"caches; {role} layer {name!r} carries state "
-                f"{sorted(keys)}, which cannot be rewound past a rejected "
-                "draft (recurrent h/c carries have no position counter)")
+                f"caches (K/V or latent planes under a pos counter); {role} "
+                f"layer {name!r} carries state {sorted(keys)}, which cannot "
+                "be rewound past a rejected draft (recurrent and rolling "
+                "states have no position counter)")
 
 
 def rewind_carry(carry, delta):
@@ -523,10 +654,10 @@ class SpeculativeGenerationSession:
                 cur, feed = redirect_inactive_writes(dcarry, active), last
                 toks, logits_list = [], []
                 for i in range(k + 1):
-                    out, _, cur = dsess.model.forward_pure(
-                        dparams, dstate, dsess._prep(feed[:, None]),
-                        train=False, rng=None, mask=None, rnn_state=cur)
-                    logits_i = dsess._logits(out)[:, :, 0]
+                    out, cur = dsess._forward(
+                        dparams, dstate, dsess._prep(feed[:, None]), None,
+                        cur)
+                    logits_i = dsess._logits(out, dparams)[:, :, 0]
                     if i < k:
                         tok = sample_tokens(logits_i, seeds, steps + i,
                                             gmask, temps, ks, ps)
@@ -536,12 +667,12 @@ class SpeculativeGenerationSession:
                 d_toks = jnp.stack(toks, axis=1)
                 d_logits = jnp.stack(logits_list, axis=1)
                 # ---- verify: ONE tq=k+1 target forward through the
-                # cached-attention path (the multi-token "prefill" shape)
+                # cached-attention path (a window over the filled caches)
                 tokens_in = jnp.concatenate([last[:, None], d_toks], axis=1)
-                out, _, tnew = tsess.model.forward_pure(
-                    tparams, tstate, tsess._prep(tokens_in), train=False,
-                    rng=None, mask=None, rnn_state=tfwd)
-                t_logits = tsess._logits(out).transpose(0, 2, 1)  # [b,t,V]
+                out, tnew = tsess._forward(
+                    tparams, tstate, tsess._prep(tokens_in), None, tfwd)
+                t_logits = tsess._logits(out, tparams).transpose(
+                    0, 2, 1)                                     # [b,t,V]
                 # ---- accept (exact), freeze idle rows, rewind both
                 otoks, n_acc, n_emit = speculative_accept(
                     d_toks, d_logits, t_logits, seeds, steps, spec_ks,

@@ -22,6 +22,7 @@ from .vgg16 import AlexNet, VGG16, VGG19
 from .xception import Xception
 from .nasnet import NASNet
 from .phi4_flash import Phi4FlashLM
+from .pangu_ultra_moe import PanguUltraMoeLM
 
 __all__ = [
     "AlexNet",
@@ -50,4 +51,5 @@ __all__ = [
     "Xception",
     "NASNet",
     "Phi4FlashLM",
+    "PanguUltraMoeLM",
 ]

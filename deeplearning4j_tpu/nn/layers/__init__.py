@@ -74,6 +74,7 @@ from .longcat import LongCatBlockLayer
 from .mamba import MambaMixerLayer
 from .mla import LatentAttentionLayer
 from .moe import ExpertShareMoELayer, MixtureOfExpertsLayer
+from .mtp import MtpOutputLayer
 from .samediff_layer import SameDiffLambdaLayer, SameDiffLayer
 from .short_conv import ShortConvLayer
 from .recurrent import (

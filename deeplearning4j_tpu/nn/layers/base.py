@@ -24,7 +24,9 @@ codepath (and its class of fwd/bwd mismatch bugs).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import threading
 from typing import Any, ClassVar, Dict, Optional, Tuple
 
 import jax
@@ -36,6 +38,31 @@ from ..weights import Distribution, WeightInit
 
 Params = Dict[str, Any]
 State = Dict[str, Any]
+
+_TRACE = threading.local()
+
+
+@contextlib.contextmanager
+def fresh_rows():
+    """Trace the multi-token calls made inside as PREFILLS of fresh rows:
+    each row of a decode state stands at position 0 with nothing before it,
+    so a mixer may attend the call's tokens alone. Outside it, a
+    multi-token call over a decode state is a window over what the state
+    holds (a speculative verify), which the mixer attends. Read while a
+    program is traced (:func:`rows_are_fresh`): enter it inside the
+    function that is jitted, so that its compiled program is always traced
+    the same way."""
+    depth = getattr(_TRACE, "fresh", 0)
+    _TRACE.fresh = depth + 1
+    try:
+        yield
+    finally:
+        _TRACE.fresh = depth
+
+
+def rows_are_fresh() -> bool:
+    """Whether a :func:`fresh_rows` block is open on this thread."""
+    return getattr(_TRACE, "fresh", 0) > 0
 
 
 @dataclasses.dataclass(frozen=True)

@@ -2,6 +2,11 @@
 
     h = x + Op(N(x; op_gn));   y = h + FF(N(h; ff_gn))
 
+or, with ``sandwich`` (Pangu Ultra, arXiv:2504.07866), a norm of its own on
+each part's output before the residual add:
+
+    h = x + N(Op(N(x; op_gn)); po_gn);   y = h + N(FF(N(h; ff_gn)); pf_gn)
+
 ``N`` is an RMSNorm with a gain of its own each time (``norm="layer"``: a
 LayerNorm with a gain and a bias, ``op_gb`` / ``ff_gb``), ``Op`` and ``FF`` are
 the block's two PARTS, layers of their own that the block is configured
@@ -110,10 +115,19 @@ class DecoderBlockLayer(Layer):
     ffn: Optional[Layer] = None
     eps: float = 1e-5
     norm: str = "rms"      # "rms" | "layer" (a LayerNorm with a bias)
+    sandwich: bool = False
 
     @property
     def _norm_params(self) -> Tuple[str, ...]:
         return ("gn", "gb") if self.norm == "layer" else ("gn",)
+
+    @property
+    def _post_norms(self) -> Tuple[str, ...]:
+        """The sandwich's two output norms' parameters."""
+        if not self.sandwich:
+            return ()
+        return tuple(f"{part}_{n}" for part in ("po", "pf")
+                     for n in self._norm_params)
 
     def output_type(self, input_type: InputType) -> InputType:
         return RecurrentType(size=self.n_in, timesteps=input_type.timesteps)
@@ -132,7 +146,8 @@ class DecoderBlockLayer(Layer):
         return tuple(f"op_{n}" for n in self._norm_params) + tuple(
             f"op_{n}" for n in self.mixer.trainable_param_names()) \
             + tuple(f"ff_{n}" for n in self._norm_params) + tuple(
-            f"ff_{n}" for n in self.ffn.trainable_param_names())
+            f"ff_{n}" for n in self.ffn.trainable_param_names()) \
+            + self._post_norms
 
     def weight_param_names(self) -> Tuple[str, ...]:
         return tuple(f"op_{n}" for n in self.mixer.weight_param_names()) \
@@ -145,6 +160,9 @@ class DecoderBlockLayer(Layer):
         if self.norm == "layer":
             out |= {"op_gb": jnp.zeros((self.n_in,), dtype),
                     "ff_gb": jnp.zeros((self.n_in,), dtype)}
+        for name in self._post_norms:
+            out[name] = (jnp.zeros if name.endswith("gb") else jnp.ones)(
+                (self.n_in,), dtype)
         out |= {f"op_{n}": v for n, v in
                 self.mixer.init(k_op, dtype).items()}
         return out | {f"ff_{n}": v for n, v in
@@ -202,26 +220,39 @@ class DecoderBlockLayer(Layer):
         u = self._normed(params, xt, "op").astype(cd)
         o, new, *more = (mix or self.mixer.mix)(
             sub_params(params, "op_"), state, u, mask)
+        if self.sandwich:
+            o = self._normed(params, o.astype(xt.dtype), "po")
         h1 = xt + o.astype(xt.dtype)
         # float32 into the part: an expert layer's router reads it as it is
         u = self._normed(params, h1, "ff")
         token_mask = None if mask is None else mask.reshape(b * t)
         m, counts = self.ffn.feed(sub_params(params, "ff_"),
                                   u.reshape(b * t, h), token_mask)
-        return h1 + m.reshape(b, t, h).astype(xt.dtype), new, counts, more
+        m = m.reshape(b, t, h).astype(xt.dtype)
+        if self.sandwich:
+            m = self._normed(params, m, "pf")
+        return h1 + m.astype(xt.dtype), new, counts, more
 
-    def apply(self, params: Params, state: State, x: jax.Array, ctx: LayerContext) -> Tuple[jax.Array, State]:
-        x = apply_input_dropout(self, x, ctx)
-        xt = x.transpose(0, 2, 1)                            # [b, t, h]
-        xt = xt.astype(jnp.promote_types(xt.dtype, _F32))    # the residual
+    def run(self, params: Params, state: State, xt: jax.Array,
+            mask) -> Tuple[jax.Array, State]:
+        """:meth:`block` over ``xt [b, t, n_in]`` with the layer's decode
+        state as the layer keeps it -> ``(y [b, t, n_in], new state)``: the
+        mixer's, and where the feed-forward counts, ``moe_choices``."""
         b, t, h = xt.shape
         # the block keeps no state between calls but its decode state
         sub = {k: v for k, v in state.items() if k != "moe_choices"}
-        y, new, counts, _ = self.block(params, sub, xt, ctx.mask)
+        y, new, counts, _ = self.block(params, sub, xt, mask)
         new_state = state
         if sub:
             new_state = dict(new)
             if counts is not None:
                 new_state["moe_choices"] = jnp.sum(
                     counts.reshape(b, t, -1), axis=1)
+        return y, new_state
+
+    def apply(self, params: Params, state: State, x: jax.Array, ctx: LayerContext) -> Tuple[jax.Array, State]:
+        x = apply_input_dropout(self, x, ctx)
+        xt = x.transpose(0, 2, 1)                            # [b, t, h]
+        xt = xt.astype(jnp.promote_types(xt.dtype, _F32))    # the residual
+        y, new_state = self.run(params, state, xt, ctx.mask)
         return y.transpose(0, 2, 1), new_state

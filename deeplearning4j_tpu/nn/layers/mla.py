@@ -1,7 +1,7 @@
 """Multi-head latent attention (MLA; DeepSeek-V2, arXiv:2405.04434, as
-LongCat-Flash uses it): low-rank query and key/value projections, a head
-split into a non-rotary and a rotary part, and a decode state that is ONE
-latent entry a position whatever the number of heads.
+LongCat-Flash and openPangu-Ultra-MoE use it): low-rank query and key/value
+projections, a head split into a non-rotary and a rotary part, and a decode
+state that is ONE latent entry a position whatever the number of heads.
 
     q        = Wqb (N(Wqa x; gq) (n_in/q_lora_rank)^1/2), per head
                qk_nope_head_dim non-rotary then qk_rope_head_dim rotary
@@ -11,16 +11,23 @@ latent entry a position whatever the number of heads.
     scores   = (q_nope.k_nope + rot(q_rope).rot(kr)) (nope + rope)^-1/2
     o        = Wo [heads x v_head_dim]
 
+The two ``(n_in/rank)^1/2`` factors are LongCat's (``lora_scales``); the
+DeepSeek-V3 layout (openPangu) has neither.
+
 The mixer OWNS its decode state: ``latent`` ``[b, 1, max_len, kv_lora_rank +
 qk_rope_head_dim]``, the entry ``[c'; rot(kr)]`` of every position, declared
 as a plane written in place (:meth:`LatentAttentionLayer.decode_planes`), so
 the engine's fused step masks an idle row's write and selects over nothing.
-Two paths. A multi-token call (a PREFILL) expands the fresh entries to
-per-head keys and values and attends them causally. A one-token call (a
-decode STEP) attends the plane itself: the up-projection goes into the query
-(``q~_h = Wkvb_k,h^T q_nope,h``) and the output (``o_h = Wkvb_v,h sum_t p_t
-c'_t``), so a step reads one entry a position and not a key and a value a
-head (``ops/mla_attention.py``).
+Two forms. A multi-token call of fresh rows (a PREFILL, traced inside
+:func:`~deeplearning4j_tpu.nn.layers.base.fresh_rows`) expands the fresh
+entries to per-head keys and values and attends them causally. Every other
+call with a decode state (a decode STEP of one token, or a speculative
+VERIFICATION window of several) attends the plane itself: the up-projection
+goes into the query (``q~_h = Wkvb_k,h^T q_nope,h``) and the output (``o_h =
+Wkvb_v,h sum_t p_t c'_t``), so a call reads one entry a position and not a
+key and a value a head, and a window's ``t`` query positions, causal among
+themselves, share one read of the plane (``ops/mla_attention.py``:
+``mla_decode``, ``mla_verify``); the plane rewinds by its ``pos`` alone.
 """
 
 from __future__ import annotations
@@ -36,7 +43,8 @@ from ...core.config import register_config
 from ..input_type import InputType, RecurrentType
 from ..weights import WeightInit, init_weights
 from .attention import _merge_heads, _split_heads
-from .base import Layer, LayerContext, Params, State, apply_input_dropout
+from .base import (Layer, LayerContext, Params, State, apply_input_dropout,
+                   rows_are_fresh)
 from .eva import rotary_positions
 from .norm import rms_norm
 
@@ -51,12 +59,13 @@ class LatentAttentionLayer(Layer):
     operands take the parameters' type, the norms' statistics and the
     softmax float32.
 
-    A multi-token call with a decode state is a prefill of rows that stand
-    at their state's position with nothing before it that they attend (a
-    fresh row, position 0): the tokens attend each other causally, and
-    their entries are written from the rows' positions on. A multi-token
-    window over a filled cache (speculative verification) is not expressed,
-    and the speculative session refuses the layer's state."""
+    A multi-token call with a decode state inside ``fresh_rows`` is a
+    prefill of rows that stand at their state's position with nothing
+    before it that they attend (a fresh row, position 0): the tokens attend
+    each other causally, and their entries are written from the rows'
+    positions on. Any other multi-token call is a window over the filled
+    plane, whatever its length: its entries are written first, then each of
+    its queries attends the plane up to its own position."""
 
     n_in: int = 0
     n_heads: int = 1
@@ -67,6 +76,7 @@ class LatentAttentionLayer(Layer):
     kv_lora_rank: int = 512
     rope_theta: float = 1e7
     eps: float = 1e-5
+    lora_scales: bool = True
 
     def output_type(self, input_type: InputType) -> InputType:
         return RecurrentType(size=self.n_in, timesteps=input_type.timesteps)
@@ -128,13 +138,15 @@ class LatentAttentionLayer(Layer):
         [b, t, rank + rope])``, the entries ``[c'; rot(kr)]``."""
         cd = params["Wqa"].dtype
         dn, rkv = self.qk_nope_head_dim, self.kv_lora_rank
-        cq = rms_norm(x @ params["Wqa"], params["gq"], self.eps) \
-            * math.sqrt(self.n_in / self.q_lora_rank)
+        cq = rms_norm(x @ params["Wqa"], params["gq"], self.eps)
+        if self.lora_scales:
+            cq = cq * math.sqrt(self.n_in / self.q_lora_rank)
         q = _split_heads(cq.astype(cd) @ params["Wqb"], self.n_heads)
         q_rope = rotary_positions(q[..., dn:], at, self.rope_theta)
         ckr = x @ params["Wkva"]
-        c = rms_norm(ckr[..., :rkv], params["gkv"], self.eps) \
-            * math.sqrt(self.n_in / rkv)
+        c = rms_norm(ckr[..., :rkv], params["gkv"], self.eps)
+        if self.lora_scales:
+            c = c * math.sqrt(self.n_in / rkv)
         kr = rotary_positions(ckr[:, None, :, rkv:], at, self.rope_theta)[:, 0]
         return q[..., :dn], q_rope, jnp.concatenate(
             [c.astype(cd), kr.astype(cd)], axis=-1)
@@ -156,21 +168,27 @@ class LatentAttentionLayer(Layer):
             scale=(dn + self.qk_rope_head_dim) ** -0.5)
 
     def _absorbed(self, params: Params, q_nope, q_rope, plane, lengths):
-        """The step's form: one query a row attends the plane's first
-        ``lengths`` entries directly -> ``[b, h, 1, v]``."""
+        """The absorbed form: ``t`` queries a row (a step's one, or a
+        verify window's), the last at the plane's ``lengths``, attend the
+        plane's entries directly -> ``[b, h, t, v]``, in one kernel call
+        (``mla_decode``, or ``mla_verify`` for ``t > 1``)."""
         from ...ops.mla_attention import mla_decode_attention
 
         n, dn, rkv = self.n_heads, self.qk_nope_head_dim, self.kv_lora_rank
+        b, _, t, _ = q_nope.shape
         cd = plane.dtype
         wkvb = params["Wkvb"].reshape(rkv, n, dn + self.v_head_dim)
-        qt = jnp.einsum("bhn,chn->bhc", q_nope[:, :, 0], wkvb[..., :dn],
+        qt = jnp.einsum("bhtn,chn->bthc", q_nope, wkvb[..., :dn],
                         preferred_element_type=_F32).astype(cd)
-        ctx = mla_decode_attention(
-            jnp.concatenate([qt, q_rope[:, :, 0].astype(cd)], axis=-1),
-            plane, lengths, rkv, scale=(dn + self.qk_rope_head_dim) ** -0.5)
-        o = jnp.einsum("bhc,chv->bhv", ctx.astype(wkvb.dtype),
+        q = jnp.concatenate([qt, q_rope.transpose(0, 2, 1, 3).astype(cd)],
+                            axis=-1).reshape(b, t * n, self.latent_width)
+        ctx = mla_decode_attention(q, plane, lengths, rkv,
+                                   scale=(dn + self.qk_rope_head_dim) ** -0.5,
+                                   tq=t)
+        o = jnp.einsum("bthc,chv->bhtv",
+                       ctx.reshape(b, t, n, rkv).astype(wkvb.dtype),
                        wkvb[..., dn:], preferred_element_type=_F32)
-        return o.astype(q_nope.dtype)[:, :, None]
+        return o.astype(q_nope.dtype)
 
     def mix(self, params: Params, state: State, x: jax.Array,
             mask) -> Tuple[jax.Array, State]:
@@ -191,15 +209,20 @@ class LatentAttentionLayer(Layer):
             keep = jnp.ones(pos.shape, bool)
         at = pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
         q_nope, q_rope, entries = self._projections(params, x, at)
-        plane = masked_cache_write(state["latent"], entries[:, None], pos,
-                                   keep)
-        if t == 1:
-            o = self._absorbed(params, q_nope, q_rope, plane, pos + 1)
-            valid = jnp.ones((b,), jnp.int32)
-        else:
+        if t > 1 and rows_are_fresh():
+            plane = masked_cache_write(state["latent"], entries[:, None], pos,
+                                       keep)
             o = self._expanded(params, q_nope, q_rope, entries, mask)
-            valid = (jnp.full((b,), t, jnp.int32) if mask is None
-                     else jnp.sum(mask > 0, axis=1).astype(jnp.int32))
+        else:
+            # a window's few entries one at a time: the one-entry write is
+            # the in-place kernel, a scatter of t is a loop over the rows
+            plane = state["latent"]
+            for j in range(t):
+                plane = masked_cache_write(plane, entries[:, None, j:j + 1],
+                                           pos + j, keep)
+            o = self._absorbed(params, q_nope, q_rope, plane, pos + t)
+        valid = (jnp.full((b,), t, jnp.int32) if mask is None
+                 else jnp.sum(mask > 0, axis=1).astype(jnp.int32))
         new = {k: v for k, v in state.items() if k != "write_mask"}
         new.update(latent=plane, pos=pos + valid)
         return _merge_heads(o) @ params["Wo"], new

@@ -520,10 +520,15 @@ class ExpertShareMoELayer(Layer):
         for an absent one, and ``E_e(u) = u`` for e >= n_routed_experts
         (a ZERO-COMPUTE expert: it holds no weights and lives on every
         chip)
+        + S(u) with ``n_shared_experts``: the SHARED expert, a gated FFN of
+        ``n_shared_experts * hidden`` that every token passes at weight 1,
+        outside the router; every chip computes it for its own rows, so it
+        is no part of a share (:meth:`shared`)
 
     Params: ``Wr [n_in, E + Z]``, ``br [E + Z]``, ``Eg``/``Eu`` ``[held,
-    n_in, hidden]``, ``Ed [held, hidden, n_in]``. Shapes are static for the
-    worst load (every token to one held expert). Up to ``expert_rows``
+    n_in, hidden]``, ``Ed [held, hidden, n_in]``; with a shared expert
+    ``Sg``/``Su`` ``[n_in, n_shared_experts * hidden]``, ``Sd``. Shapes
+    are static for the worst load (every token to one held expert). Up to ``expert_rows``
     tokens every held expert runs over every token and the weights (nought
     where a token did not choose it) fold into the down-projection: at a
     decode step's rows that product is under the time the expert weights
@@ -552,6 +557,7 @@ class ExpertShareMoELayer(Layer):
     expert_rows: int = 128
     scoring: str = "softmax"         # or "sigmoid"
     norm_topk_prob: bool = False
+    n_shared_experts: int = 0
 
     def __post_init__(self) -> None:
         width = self.n_routed_experts + self.zero_expert_num
@@ -597,11 +603,15 @@ class ExpertShareMoELayer(Layer):
     def has_params(self) -> bool:
         return True
 
+    @property
+    def _shared_names(self) -> Tuple[str, ...]:
+        return ("Sg", "Su", "Sd") if self.n_shared_experts else ()
+
     def trainable_param_names(self) -> Tuple[str, ...]:
-        return ("Wr", "br", "Eg", "Eu", "Ed")
+        return ("Wr", "br", "Eg", "Eu", "Ed") + self._shared_names
 
     def weight_param_names(self) -> Tuple[str, ...]:
-        return ("Wr", "Eg", "Eu", "Ed")
+        return ("Wr", "Eg", "Eu", "Ed") + self._shared_names
 
     def init_state(self, dtype: Any) -> State:
         return {"choice_counts": jnp.zeros((self.held + 2,), jnp.float32)}
@@ -610,17 +620,24 @@ class ExpertShareMoELayer(Layer):
         e, d, f = self.held, self.n_in, self.hidden
         width = self.n_routed_experts + self.zero_expert_num
         wi = self.weight_init or WeightInit.XAVIER
-        kr, kg, ku, kd = jax.random.split(key, 4)
+        kr, kg, ku, kd, ks = jax.random.split(key, 5)
 
         def mats(k, rows, cols):
             return init_weights(k, (e, rows, cols), wi, fan_in=rows,
                                 fan_out=cols, dtype=dtype)
 
-        return {"Wr": init_weights(kr, (d, width), wi, fan_in=d,
-                                   fan_out=width, dtype=dtype),
-                "br": jnp.zeros((width,), dtype),
-                "Eg": mats(kg, d, f), "Eu": mats(ku, d, f),
-                "Ed": mats(kd, f, d)}
+        out = {"Wr": init_weights(kr, (d, width), wi, fan_in=d,
+                                  fan_out=width, dtype=dtype),
+               "br": jnp.zeros((width,), dtype),
+               "Eg": mats(kg, d, f), "Eu": mats(ku, d, f),
+               "Ed": mats(kd, f, d)}
+        if self.n_shared_experts:
+            fs = self.n_shared_experts * f
+            k1, k2, k3 = jax.random.split(ks, 3)
+            out |= {"Sg": init_weights(k1, (d, fs), wi, d, fs, None, dtype),
+                    "Su": init_weights(k2, (d, fs), wi, d, fs, None, dtype),
+                    "Sd": init_weights(k3, (fs, d), wi, fs, d, None, dtype)}
+        return out
 
     # ---- the share ----------------------------------------------------------
     def _hidden_act(self, params: Params, eq: str, x: jax.Array) -> jax.Array:
@@ -722,10 +739,28 @@ class ExpertShareMoELayer(Layer):
                     lambda: self._held_each(params, x2, vals, local))
         return held, zero, counts
 
+    def shared(self, params: Params, x2: jax.Array) -> jax.Array:
+        """The shared expert over the tokens ``x2 [n, n_in]`` -> float32
+        ``[n, n_in]`` (nought without one): what every chip computes for
+        its own rows, outside every share."""
+        if not self.n_shared_experts:
+            return jnp.zeros(x2.shape, jnp.float32)
+        f32 = jnp.float32
+        x2 = x2.astype(params["Sg"].dtype)
+        with jax.named_scope("moe_shared"):
+            h = jax.nn.silu(jnp.dot(x2, params["Sg"],
+                                    preferred_element_type=f32)) \
+                * jnp.dot(x2, params["Su"], preferred_element_type=f32)
+            return jnp.dot(h.astype(x2.dtype), params["Sd"],
+                           preferred_element_type=f32)
+
     def share(self, params: Params, x2: jax.Array,
               token_mask: Optional[jax.Array] = None):
-        """``(y [n, n_in] float32, counts [n, held + 2])``."""
+        """``(y [n, n_in] float32, counts [n, held + 2])``: the held and
+        zero-compute parts, and the shared expert."""
         held, zero, counts = self.parts(params, x2, token_mask)
+        if self.n_shared_experts:
+            return held + zero + self.shared(params, x2), counts
         return held + zero, counts
 
     def feed(self, params: Params, x2: jax.Array,

@@ -44,7 +44,13 @@ batching observable):
   rows near ``max_len`` (or with per-request ``speculative_k=0``) take
   the plain path in the same turn. :class:`DecodeAIMD` adapts the
   current ``k`` and the active-slot admission target against a
-  per-token p95 budget (``adaptive=True``).
+  per-token p95 budget (``adaptive=True``). A model whose head holds a
+  multi-token-prediction module (``MtpOutputLayer``) drafts with it
+  instead, at depth 1 and inside the run-ahead loop
+  (``GenerationSession.mtp_step``): a step verifies each row's draft at two
+  positions, commits one or two tokens and drafts the next, all on the
+  device; the host learns a row's count when it lands the step, one step
+  late, and until then reckons one a row.
 
 Failures run through a :class:`CircuitBreaker`: a poisoned decode step
 fails the affected requests and opens the breaker, so new submits shed
@@ -107,8 +113,12 @@ from ..generate.paged import (
     paged_decode_state,
 )
 from ..generate.sampling import PATHS, sample_tokens, sampler_path
-from ..generate.session import (ROW_SPEC_WORDS, GenerationSession,
-                                SpeculativeGenerationSession, pack_row_spec)
+from ..generate.session import (ROW_SPEC_WORDS, SV_ACCEPTED, SV_N,
+                                SV_PROPOSED, SV_TOK0, SV_TOK1, SV_WIDTH,
+                                GenerationSession,
+                                SpeculativeGenerationSession,
+                                _check_rewindable, pack_row_spec)
+from ..nn.layers.base import fresh_rows
 from ..ops.flash_attention import decode_fetched_entries, kv_write_tally
 from ..ops.paged_attention import pack_row_blocks
 from ..obs.metrics import MetricsRegistry, get_registry
@@ -455,7 +465,11 @@ class DecodeEngine:
         for its USED tokens, not ``max_len``, so short sequences stop
         paying for headroom they never touch. Greedy streams are
         token-identical to the static layout; composes with
-        ``cache_dtype="int8"`` (per-block scale planes)."""
+        ``cache_dtype="int8"`` (per-block scale planes). A model whose head
+        holds a multi-token-prediction module speculates with it when no
+        ``draft_model`` is given and ``speculative_k`` is at least 1, at
+        depth 1 whatever ``speculative_k`` says (the module drafts one
+        token); ``speculative_k=0`` serves it plainly."""
         if draft_model is not None:
             self._spec = SpeculativeGenerationSession(
                 model, draft_model, max_len=max_len,
@@ -465,6 +479,11 @@ class DecodeEngine:
             self._spec = None
             self.session = GenerationSession(model, max_len=max_len,
                                              cache_dtype=cache_dtype)
+        #: the model drafts with its own MTP module (self-speculation)
+        self._mtp = (draft_model is None and self.session.mtp
+                     and int(speculative_k) >= 1)
+        if self._mtp:
+            _check_rewindable(self.session, "target")
         self.cache_dtype = cache_dtype
         self.max_len = int(max_len)
         self.slots = int(slots)
@@ -495,7 +514,8 @@ class DecodeEngine:
         # decode-side AIMD knobs: current speculation depth (clamped to
         # the construction-time ceiling) and the active-slot target
         self.max_speculative_k = (max(1, int(speculative_k))
-                                  if self._spec is not None else 0)
+                                  if self._spec is not None
+                                  else 1 if self._mtp else 0)
         self._spec_k = self.max_speculative_k
         self._slot_target = self.slots
         # what the layers say of their decode state beyond its shape: the
@@ -571,8 +591,10 @@ class DecodeEngine:
         # every row's next input token, on the device: what the last step
         # sampled, and the first token of each prefill since, written there
         # beside the install. ``_fresh`` marks the rows whose token the host
-        # set instead (``_last``: a speculative turn commits on the host)
-        self._toks = jnp.zeros((self.slots,), jnp.int32)
+        # set instead (``_last``: a speculative turn commits on the host).
+        # A self-speculating engine keeps each row's image there instead
+        # (``SV_WIDTH`` columns: ``GenerationSession.mtp_step``)
+        self._toks = self._fresh_toks()
         self._fresh = np.zeros((self.slots,), bool)
         # whether, for how long and under which phase of a turn the device
         # ran dry (``_toks`` is the newest program's output wherever the
@@ -918,6 +940,11 @@ class DecodeEngine:
                                   f"kv block pool exhausted: {e}")
         return rows
 
+    def _fresh_toks(self):
+        """A zeroed token vector (a self-speculating engine's rows' image)."""
+        return jnp.zeros((self.slots, SV_WIDTH) if self._mtp
+                         else (self.slots,), jnp.int32)
+
     @property
     def _row_template(self):
         """A zeroed one-row carry: the shapes a handoff is held to, and what
@@ -942,12 +969,22 @@ class DecodeEngine:
         if key not in self._fns:
             sess = self.session
             bs = self.block_size
+            mtp = self._mtp
             ids_at = ROW_SPEC_WORDS + self._table_width
 
             def fn(params, state, carry, toks, adm):
                 spec = adm[:ROW_SPEC_WORDS]
-                row, tok, counts = sess.prefill_row(
-                    params, state, adm[None, ids_at:], spec)
+                if mtp:  # the row's image: its first token, the draft
+                    row, tok, draft, counts = sess.mtp_prefill_row(
+                        params, state, adm[None, ids_at:], spec)
+                    image = jnp.zeros((SV_WIDTH,), jnp.int32)
+                    image = image.at[:3].set(jnp.stack(
+                        [tok.astype(jnp.int32), draft, jnp.ones((),
+                                                               jnp.int32)]))
+                else:
+                    row, tok, counts = sess.prefill_row(
+                        params, state, adm[None, ids_at:], spec)
+                    image = tok.astype(toks.dtype)
                 slot = spec[1]
                 if bs is None:
                     carry = install_row(carry, row, slot)
@@ -956,8 +993,7 @@ class DecodeEngine:
                                           adm[ROW_SPEC_WORDS:ids_at], slot, bs)
                 # what the layers counted of the prompt comes home with
                 # its first token ({} for a model that counts nothing)
-                return (carry, toks.at[slot].set(tok.astype(toks.dtype)),
-                        tok, counts)
+                return carry, toks.at[slot].set(image), tok, counts
 
             # the profiler's "XLA Modules" line shows jit_<name>
             fn.__name__ = f"prefill_{tb}"
@@ -993,9 +1029,9 @@ class DecodeEngine:
             def fn(params, state, row_carry, ids, lengths):
                 mask = (jnp.arange(tb, dtype=jnp.int32)[None, :]
                         < lengths[:, None]).astype(model.dtype)
-                _, _, new_rnn = model.forward_pure(
-                    params, state, sess._prep(ids), train=False, rng=None,
-                    mask=mask, rnn_state=row_carry)
+                with fresh_rows():
+                    _, new_rnn = sess._forward(params, state, sess._prep(ids),
+                                               mask, row_carry)
                 return new_rnn
 
             fn.__name__ = f"draft_prefill_{tb}"
@@ -1003,6 +1039,19 @@ class DecodeEngine:
         return self._fns[key]
 
     def _decode_step_fn(self):
+        if "decode" not in self._fns and self._mtp:
+            sess = self.session
+
+            def decode_step(params, state, carry, toks, image):
+                # a self-speculating step (``_step_args``): verify, commit,
+                # draft, every row's image staying on the device
+                with kv_write_tally() as tally:
+                    new, toks, counts = sess.mtp_step(params, state, carry,
+                                                      toks, image)
+                self._kv_writes = (tally["fused"], tally["separate"])
+                return new, toks, counts
+
+            self._fns["decode"] = jax.jit(decode_step, donate_argnums=2)
         if "decode" not in self._fns:
             sess = self.session
             model = sess.model
@@ -1190,6 +1239,10 @@ class DecodeEngine:
         prompt, sampled first token, per-layer cache slices and the
         sampling law. The decode stream continues token-identically to a
         local :meth:`submit` of the same prompt/sampling."""
+        if self._mtp:
+            raise ValueError(
+                "a self-speculating engine takes no handed-over row: the "
+                "handoff carries no draft and no plane of the MTP module")
         prompt = [int(t) for t in handoff.get("prompt", ())]
         if not prompt:
             raise ValueError("empty prompt in handoff")
@@ -1496,7 +1549,7 @@ class DecodeEngine:
         if not lost and not self._drain():
             return  # the landing failed, and has failed them all
         self._flight, self._first = None, []
-        self._toks = jnp.zeros((self.slots,), jnp.int32)
+        self._toks = self._fresh_toks()
         self._dry.enqueued(self._toks)
         self._breaker.record_failure()
         # before any caller hears of the failure
@@ -1579,7 +1632,7 @@ class DecodeEngine:
             dry.look("dispatch")
             self._carry, self._toks, counts = self._decode_step_fn()(
                 sess.model.params, sess.model.state, self._carry, *args,
-                self._device_table())
+                *(() if self._mtp else (self._device_table(),)))
             dry.enqueued(self._toks)
         with span("loop.account", parent=parent):
             dry.phase = "account"
@@ -1611,9 +1664,14 @@ class DecodeEngine:
         after the call that asked for it returns, and the host writes its
         arrays again (the next dispatch, the next admission) while the
         step is still in flight: the image is built anew every time and
-        never written again, so it is the copy that makes that safe."""
+        never written again, so it is the copy that makes that safe. A
+        self-speculating engine's image is ``GenerationSession.mtp_step``'s
+        seven rows: the device holds each row's tokens and count."""
         arrays = (self._last, self._fresh, rows, self._seeds, self._steps,
                   self._greedy, self._temps, self._ks, self._ps)
+        if self._mtp:
+            arrays = (rows, self._seeds, self._greedy, self._temps, self._ks,
+                      self._ps, self._limit)
         image = np.empty((len(arrays), self.slots), np.int32)
         # every entry is 4 bytes or fewer: the seeds and the two float
         # rows ride as their bits, and ``decode_step`` takes them apart
@@ -1647,39 +1705,64 @@ class DecodeEngine:
         timed = parent is not NULL_SPAN
         clock = time.perf_counter_ns
         put = count = retire = t0 = t1 = t2 = 0
+        # a self-speculating step lands each row's image: one or two
+        # committed tokens (none for a row complete on the device), where
+        # the host reckoned one at the dispatch
+        mtp = self._mtp
+        proposed = accepted = committed = 0
         with span("loop.emit", parent=parent):
             dry.look("emit")
             slots = np.nonzero(step.rows)[0]
-            for n, slot in enumerate(slots):
-                if n % _EMIT_LOOK_ROWS == 0 and n:
+            for i, slot in enumerate(slots):
+                if i % _EMIT_LOOK_ROWS == 0 and i:
                     dry.look()
                 req = step.reqs[slot]
                 if req is not self._requests[slot]:
                     self._c_dropped.inc()
                     continue
-                tok = int(toks_h[slot])
-                emitted = len(req.handle.tokens)
-                if timed:
-                    t0 = clock()
-                req.handle._emit(emitted, tok)
-                if timed:
-                    t1 = clock()
-                self._last[slot] = tok
-                # the step wrote position len(prompt) + emitted - 1
-                if self._window and \
-                        (len(req.prompt) + emitted) % self._window == 0:
-                    self._c_windows.inc()
-                self._c_tokens.inc()
-                self._h_token.observe(dt)
-                if timed:
-                    t2 = clock()
-                self._retire_if_done(slot, tok, emitted + 1)
-                if timed:
-                    put += t1 - t0
-                    count += t2 - t1
-                    retire += clock() - t2
+                if mtp:
+                    row = toks_h[slot]
+                    n = int(row[SV_N])
+                    self._steps[slot] += n - 1
+                    self._pos[slot] += n - 1
+                    proposed += int(row[SV_PROPOSED])
+                    accepted += int(row[SV_ACCEPTED])
+                    committed += n
+                    landed = (int(row[SV_TOK0]), int(row[SV_TOK1]))[:n]
+                else:
+                    landed = (int(toks_h[slot]),)
+                for tok in landed:
+                    emitted = len(req.handle.tokens)
+                    if timed:
+                        t0 = clock()
+                    req.handle._emit(emitted, tok)
+                    if timed:
+                        t1 = clock()
+                    self._last[slot] = tok
+                    # the step wrote position len(prompt) + emitted - 1
+                    if self._window and \
+                            (len(req.prompt) + emitted) % self._window == 0:
+                        self._c_windows.inc()
+                    self._c_tokens.inc()
+                    self._h_token.observe(dt / len(landed))
+                    if timed:
+                        t2 = clock()
+                    self._retire_if_done(slot, tok, emitted + 1)
+                    if timed:
+                        put += t1 - t0
+                        count += t2 - t1
+                        retire += clock() - t2
+                    if self._requests[slot] is not req:
+                        break
+            if mtp:
+                self._c_spec_steps.inc()
+                self._c_spec_proposed.inc(proposed)
+                self._c_spec_accepted.inc(accepted)
+            attrs = parent.attributes
+            if mtp:
+                parent.set_attribute("committed",
+                                     attrs.get("committed", 0) + committed)
             if timed:
-                attrs = parent.attributes
                 for key, n in (("emit_rows", len(slots)),
                                ("emit_put_ms", put * 1e-6),
                                ("emit_count_ms", count * 1e-6),
@@ -1916,7 +1999,8 @@ class DecodeEngine:
                 spec = self._spec is not None
                 dry.mark("step")
                 with span("loop.step", parent=turn,
-                          attrs={"spec": spec, "ahead": 0}) as step:
+                          attrs={"spec": spec or self._mtp,
+                                 "ahead": 0}) as step:
                     if spec:
                         self._spec_step(step)
                     else:
@@ -1956,8 +2040,9 @@ class DecodeEngine:
     # ----- decode-side AIMD control -----------------------------------
     @property
     def speculative_k(self) -> int:
-        """Current speculation depth (0 when no draft model)."""
-        return self._spec_k if self._spec is not None else 0
+        """Current speculation depth (0 when no draft model and no MTP
+        module drafts)."""
+        return self._spec_k if self._spec is not None or self._mtp else 0
 
     @property
     def slot_target(self) -> int:
@@ -2074,7 +2159,8 @@ class DecodeEngine:
             # 0.0, before any speculative traffic
             "per_token_p95_s": self.token_p95(),
             "speculative": {
-                "enabled": self._spec is not None,
+                "enabled": self._spec is not None or self._mtp,
+                "self_draft": self._mtp,
                 "current_k": self.speculative_k,
                 "max_k": self.max_speculative_k,
                 "steps": spec_steps,

@@ -29,6 +29,7 @@ test file); the file's tests are skipped where it cannot be described.
 import os
 import re
 
+import numpy as np
 import pytest
 
 import jax
@@ -66,13 +67,14 @@ def _compile_step_and_install(model, params, slots, max_len, one_chip,
         return jax.ShapeDtypeStruct(a.shape if shape is None else shape,
                                     a.dtype, sharding=one_chip)
 
-    def vec(dtype):
-        return jax.ShapeDtypeStruct((slots,), dtype, sharding=one_chip)
-
     tm = jax.tree_util.tree_map
     out = {}
     eng = DecodeEngine(model, max_len=max_len, slots=1,
                        registry=MetricsRegistry())
+    # the token vector (a self-speculating engine's rows' image) and the
+    # host's image of the rows, at ``slots`` rows
+    toks = spec(eng._toks, (slots,) + eng._toks.shape[1:])
+    rows = len(eng._step_args(np.ones((1,), bool))[1])
     try:
         carry = tm(lambda a: spec(a, (slots,) + a.shape[1:]), eng._carry)
         planes = sum(l.size * l.dtype.itemsize
@@ -84,12 +86,12 @@ def _compile_step_and_install(model, params, slots, max_len, one_chip,
                 "decode_step": eng._decode_step_fn().lower(
                     tm(spec, params), tm(spec, model.state), carry,
                     # the step before's tokens, the host's image of the rows
-                    vec(jnp.int32), jax.ShapeDtypeStruct(
-                        (9, slots), jnp.int32, sharding=one_chip)),
+                    toks, jax.ShapeDtypeStruct(
+                        (rows, slots), jnp.int32, sharding=one_chip)),
                 # the step's tokens again, then the admission's one array
                 f"prefill_{bucket}": eng._prefill_fn(bucket).lower(
                     tm(spec, params), tm(spec, model.state), carry,
-                    vec(jnp.int32), jax.ShapeDtypeStruct(
+                    toks, jax.ShapeDtypeStruct(
                         (ROW_SPEC_WORDS + bucket,), jnp.int32,
                         sharding=one_chip)),
                 "install_row": eng._write_row_fn().lower(
@@ -481,3 +483,78 @@ def test_phi4f_temporaries_fit_beside_weights_and_carry(phi4f_programs,
                                                         name):
     ma = phi4f_programs[name][1]
     assert ma.temp_size_in_bytes < PF_ROOM, ma.temp_size_in_bytes
+
+
+# ------------------------------------------------------------------- Pangu
+PG_SLOTS, PG_MAX_LEN, PG_HEADS, PG_WIDE = 128, 2560, 128, 576
+PG_PLANE = rf"bf16\[{PG_SLOTS},1,{PG_MAX_LEN},{PG_WIDE}\]"
+# what the cell's reckoning leaves beside 12.08 GB of weights and 2.26 GB of
+# latent planes on a chip of 16.9 GB (PERF.md section 4)
+PG_ROOM = 2_500_000_000
+
+
+@pytest.fixture(scope="module")
+def pangu_programs(one_chip):
+    """The self-speculating cell's step (verify at two positions, commit,
+    MTP draft), install and largest prefill (the 1,024 bucket, the MTP
+    module over the prompt too) at openPangu-Ultra-MoE's published widths
+    (the cell's: 128 slots x 2,560 positions, 128 heads over one 576-wide
+    latent plane a layer, 16 held experts of 256 and a shared one; one
+    dense and one expert layer instead of 1 + 4, and the MTP module),
+    shapes only."""
+    from deeplearning4j_tpu.model.zoo import PanguUltraMoeLM
+    from deeplearning4j_tpu.nn.sequential import MultiLayerNetwork
+
+    tm = jax.tree_util.tree_map
+    with jax.enable_x64(False):
+        model = MultiLayerNetwork(PanguUltraMoeLM(
+            vocab_size=19200, hidden=7680, n_layers=2, n_dense_layers=1,
+            n_heads=PG_HEADS, qk_nope_head_dim=128, qk_rope_head_dim=64,
+            v_head_dim=128, q_lora_rank=1536, kv_lora_rank=512,
+            ffn_size=18432, expert_ffn_size=2048, n_routed_experts=256,
+            n_held_experts=16, n_shared_experts=1, top_k=8,
+            routed_scaling_factor=2.5, expert_rows=256,
+            dtype="bfloat16").conf())
+        params = jax.eval_shape(lambda: model.init().params)
+        model.params = tm(lambda a: jnp.zeros((), a.dtype), params)
+        model._initialized = True
+        model.state = jax.eval_shape(lambda: model.init().state)
+        model._persistent_keys = {n: () for n in model.layer_names()}
+        return _compile_step_and_install(model, params, PG_SLOTS, PG_MAX_LEN,
+                                         one_chip, 1024)
+
+
+@pytest.mark.parametrize("name", ["decode_step", "install_row",
+                                  "prefill_1024"])
+def test_pangu_latent_planes_are_aliased_and_none_is_copied(pangu_programs,
+                                                            name):
+    text, ma, planes = pangu_programs[name]
+    # two layers and the MTP module: 3 x 128 x 2,560 x 576 x 2 B = 1.13 GB
+    assert planes == 3 * PG_SLOTS * PG_MAX_LEN * PG_WIDE * 2
+    assert ma.alias_size_in_bytes >= planes, (ma.alias_size_in_bytes, planes)
+    lines = text.splitlines()
+    for op in ("copy", "select", "transpose"):
+        hits = [l[:160] for l in lines
+                if re.search(rf"= {PG_PLANE}\S* {op}\(", l)]
+        assert not hits, hits[:3]
+
+
+def test_pangu_step_verifies_every_plane_through_its_kernel(pangu_programs):
+    """The step's two positions attend each layer's plane and the MTP
+    module's through ``mla_verify``, once a plane, and write their two
+    entries by two calls of the in-place one-entry write (a scatter of two
+    entries a row compiles to a ``%while`` over the rows); nothing else is
+    a kernel, and no loop is left."""
+    text = pangu_programs["decode_step"][0]
+    names = sorted(re.match(r"\s*%([a-z_]+)", l).group(1)
+                   for l in text.splitlines()
+                   if " custom-call(" in l and "tpu_custom_call" in l)
+    assert names == ["kv_cache_write"] * 6 + ["mla_verify"] * 3, names
+    assert not re.search(r" while\(", text)
+
+
+@pytest.mark.parametrize("name", ["decode_step", "prefill_1024"])
+def test_pangu_temporaries_fit_beside_weights_and_planes(pangu_programs,
+                                                         name):
+    ma = pangu_programs[name][1]
+    assert ma.temp_size_in_bytes < PG_ROOM, ma.temp_size_in_bytes

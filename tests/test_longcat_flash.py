@@ -29,6 +29,7 @@ from deeplearning4j_tpu.generate.session import (
     GenerationSession, SpeculativeGenerationSession)
 from deeplearning4j_tpu.model.zoo import LongCatFlashLM
 from deeplearning4j_tpu.nn.layers import LatentAttentionLayer
+from deeplearning4j_tpu.nn.layers.base import fresh_rows
 from deeplearning4j_tpu.obs.metrics import MetricsRegistry
 from deeplearning4j_tpu.obs.tracing import Tracer
 from deeplearning4j_tpu.parallel.decode import DecodeEngine
@@ -123,7 +124,8 @@ def test_absorbed_step_equals_the_expanded_form():
     x = jax.random.normal(jax.random.PRNGKey(4), (2, 12, 64), jnp.float32)
     whole, _ = mixer.mix(params, {}, x, None)
     state = mixer.decode_state(2, 16, jnp.float32)
-    first, state = mixer.mix(params, state, x[:, :5], None)
+    with fresh_rows():  # the prefill's own form
+        first, state = mixer.mix(params, state, x[:, :5], None)
     steps = []
     for t in range(5, 12):
         o, state = mixer.mix(params, state, x[:, t:t + 1], None)

@@ -126,6 +126,20 @@ def test_mla_decode_lowers(dtype):
 
 
 @pytest.mark.parametrize("dtype", DTYPES)
+def test_mla_verify_lowers(dtype):
+    """openPangu-Ultra-MoE's verify window at its published widths: 128
+    rows, 2 positions of 128 heads over one plane of 2,560 entries of 512 +
+    64 numbers."""
+    b, tq, h, L, rank, rope = 128, 2, 128, 2560, 512, 64
+    names = _kernels(
+        lambda q, plane, n: mla_decode_attention_pallas(
+            q, plane, n, rank, 192 ** -0.5, interpret=False, tq=tq),
+        _spec(b, tq * h, rank + rope, dtype=dtype),
+        _spec(b, 1, L, rank + rope, dtype=dtype), _spec(b, dtype=jnp.int32))
+    assert names == ["mla_verify"]
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
 @pytest.mark.parametrize("L,name", [(10240, "diff_decode"),
                                     (512, "diff_decode_window")])
 def test_diff_decode_lowers(dtype, L, name):
