@@ -32,7 +32,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import (_DECODE_BLOCK_K, _NEG, attention_impl,
-                              decode_fetched_entries)
+                              count_kv, decode_fetched_entries)
 
 _F32 = jnp.float32
 
@@ -75,17 +75,47 @@ def diff_decode_attention_reference(q, k, v, lengths, lam, scale=None):
     return o.reshape(b, hq // 2, 2 * d)
 
 
-def _diff_decode_kernel(len_ref, lam_ref, q_ref, k_ref, v_ref, o_ref, m_scr,
-                        l_scr, acc_scr, *, scale, block_k, r, precision):
-    """One (row, pair block, k-block) grid step: the ``4 r`` query rows of
-    every pair of the block (``r`` first maps, ``r`` second maps, then the
-    same again: eight sublanes) against ``block_k`` entries of the pair's
-    keys and values, both ``[2 d, block_k]``. The online softmax runs per
-    row; the second product takes the weights in two parts, as
-    ``flash_decode`` does: rows ``0 .. 2 r`` the weights rounded to V's
-    precision, rows ``2 r .. 4 r`` what the rounding lost."""
-    ki = pl.program_id(2)
-    length = len_ref[pl.program_id(0)]
+def decode_live_schedule(lengths, max_len: int, block_k: int):
+    """The (row, block) pairs a single-query decode kernel visits for rows
+    of ``lengths`` valid entries in a cache of ``max_len`` (``lengths``
+    within ``[0, max_len]``): each row's blocks in order, whole blocks up to
+    the one that holds its last entry and one block for a row with none
+    (:func:`~.flash_attention.decode_fetched_entries`), then the next
+    row's. ``(n, rows, blocks)``: the count of live pairs and two
+    ``int32[b * max_len / block_k + 1]`` vectors whose first ``n`` entries
+    are the pairs; the entries past ``n`` repeat the last pair, so that
+    what a look-ahead past the end names is already there. No loop and no
+    gather: a step's row is the number of rows that end at or before it,
+    and its block its distance from the last of those ends."""
+    b = lengths.shape[0]
+    counts = decode_fetched_entries(lengths, max_len, block_k) // block_k
+    ids = jnp.arange(b, dtype=jnp.int32)
+    ends = jnp.sum(jnp.where(ids[None, :] <= ids[:, None], counts[None, :],
+                             0), axis=1, dtype=jnp.int32)
+    n = ends[b - 1]
+    steps = jnp.minimum(
+        jnp.arange(b * (max_len // block_k) + 1, dtype=jnp.int32), n - 1)
+    before = ends[None, :] <= steps[:, None]
+    rows = jnp.sum(before, axis=1, dtype=jnp.int32)
+    starts = jnp.max(jnp.where(before, ends[None, :], 0), axis=1)
+    return n, rows, steps - starts
+
+
+def _diff_decode_kernel(len_ref, rows_ref, blocks_ref, lam_ref, q_ref, k_ref,
+                        v_ref, o_ref, m_scr, l_scr, acc_scr, *, scale,
+                        block_k, r, precision):
+    """One live (row, block) step of a pair block (``decode_live_schedule``):
+    the ``4 r`` query rows of every pair of the block (``r`` first maps,
+    ``r`` second maps, then the same again: eight sublanes) against
+    ``block_k`` entries of the pair's keys and values, both ``[2 d,
+    block_k]``. The online softmax runs per row, from the row's first
+    block to the one that holds its last entry; the second product takes
+    the weights in two parts, as ``flash_decode`` does: rows ``0 .. 2 r``
+    the weights rounded to V's precision, rows ``2 r .. 4 r`` what the
+    rounding lost."""
+    step = pl.program_id(1)
+    ki = blocks_ref[step]
+    length = len_ref[rows_ref[step]]
     n = 2 * r
 
     @pl.when(ki == 0)
@@ -119,7 +149,7 @@ def _diff_decode_kernel(len_ref, lam_ref, q_ref, k_ref, v_ref, o_ref, m_scr,
             p2.astype(vt.dtype), vt, (((2,), (2,)), ((0,), (0,))),
             precision=precision, preferred_element_type=_F32)  # [pb, 8, 2d]
 
-    @pl.when(ki == pl.num_programs(2) - 1)
+    @pl.when((ki + 1) * block_k >= length)              # the row's last
     def _():
         acc = acc_scr[...]
         o = (acc[:, 0:n, :] + acc[:, n:2 * n, :]) / jnp.maximum(
@@ -135,12 +165,14 @@ def diff_decode_attention_pallas(q, k, v, lengths, lam, scale=None, *,
                                  interpret: Optional[bool] = None):
     """The Pallas kernel (same contract as
     :func:`diff_decode_attention_reference`). ``L`` is a multiple of
-    ``block_k`` or smaller than it; only the blocks a row's length makes
-    valid are moved (:func:`~.flash_attention.decode_fetched_entries`), and
-    a dead step names the next row's first block, as in ``flash_decode``.
-    ``name`` is the ``pallas_call``'s, so that two uses of the kernel
-    (a ring of a window, a whole cache) each have their own time in a
-    trace."""
+    ``block_k`` or smaller than it. The grid is ``(pair blocks, n)``, ``n``
+    the number of live (row, block) pairs of :func:`decode_live_schedule`,
+    a length the program reads at run time: no step exists for a block
+    past a row's last entry, each row's blocks come in their order, and
+    the pipeline's look-ahead brings the next row's first block in under
+    this row's last. ``name`` is the ``pallas_call``'s, so that two uses of
+    the kernel (a ring of a window, a whole cache) each have their own
+    time in a trace."""
     b, hq, d = q.shape
     hk, L = k.shape[1], k.shape[2]
     r = hq // hk
@@ -156,6 +188,7 @@ def diff_decode_attention_pallas(q, k, v, lengths, lam, scale=None, *,
     block_k = min(block_k, L)
     if L % block_k:
         raise ValueError(f"diff_decode: {L} entries in blocks of {block_k}")
+    count_kv("live")
     # the query rows of a pair: q1 ++ 0 for each head, 0 ++ q2 for each,
     # twice over (the weights' two parts), as the eight sublanes
     qp = q.reshape(b, pairs, r, 2, d)
@@ -171,20 +204,14 @@ def diff_decode_attention_pallas(q, k, v, lengths, lam, scale=None, *,
     vt = jnp.swapaxes(v, 2, 3).reshape(b, pairs, wide, L)
     pb = max(g for g in range(1, pairs + 1) if pairs % g == 0 and (
         g == 1 or g * 2 * wide * block_k * k.dtype.itemsize <= 4 << 20))
-    groups = pairs // pb
-    lens = jnp.maximum(lengths.astype(jnp.int32), 0)
+    lens = jnp.clip(lengths.astype(jnp.int32), 0, L)
+    n, row_of, block_of = decode_live_schedule(lens, L, block_k)
 
-    def kv_block(i, g, ki, lens):
-        live = ki * block_k < lens[i]
-        nxt = i * groups + g + 1
-        stay = live | (nxt == b * groups)
-        own_last = decode_fetched_entries(lens[i], L, block_k) // block_k - 1
-        return (jnp.where(stay, i, nxt // groups),
-                jnp.where(stay, g, nxt % groups), 0,
-                jnp.where(live, ki, jnp.where(stay, own_last, 0)))
+    def kv_block(g, step, lens, row_of, block_of):
+        return (row_of[step], g, 0, block_of[step])
 
-    def row_block(i, g, ki, lens):
-        return (i, g, 0, 0)
+    def row_block(g, step, lens, row_of, block_of):
+        return (row_of[step], g, 0, 0)
 
     kern = functools.partial(
         _diff_decode_kernel, scale=float(scale), block_k=block_k, r=r,
@@ -194,10 +221,10 @@ def diff_decode_attention_pallas(q, k, v, lengths, lam, scale=None, *,
     out = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(b, groups, L // block_k),
+            num_scalar_prefetch=3,
+            grid=(pairs // pb, n),
             in_specs=[
-                pl.BlockSpec((8, wide), lambda i, g, ki, lens: (0, 0), **kw),
+                pl.BlockSpec((8, wide), lambda g, step, *_: (0, 0), **kw),
                 pl.BlockSpec((1, pb, 8, wide), row_block, **kw),
                 pl.BlockSpec((1, pb, wide, block_k), kv_block, **kw),
                 pl.BlockSpec((1, pb, wide, block_k), kv_block, **kw),
@@ -210,9 +237,11 @@ def diff_decode_attention_pallas(q, k, v, lengths, lam, scale=None, *,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, pairs, r, wide), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name=name,
-    )(lens, lam_block, q8.astype(k.dtype), kt, vt)
+    )(lens, row_of, block_of, lam_block, q8.astype(k.dtype), kt, vt)
     return out.reshape(b, hq // 2, wide)
 
 

@@ -38,7 +38,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _NEG, attention_impl, mha_attention
+from .flash_attention import _NEG, attention_impl, count_kv, mha_attention
 
 
 def chunk_summaries(k, v, mu, phi, valid=None):
@@ -162,6 +162,7 @@ def eva_decode_attention_pallas(q, k, v, n_sum, n_win, win_start, scale=None,
     bk = _entry_block(win_start, L - win_start, block_k)
     hb = max(g for g in range(1, min(h, head_block) + 1) if h % g == 0)
     sb, nb = win_start // bk, L // bk
+    count_kv("full")
 
     def entry(r, g, ki, nsum, nwin):
         ns = (nsum[r] + bk - 1) // bk      # summary blocks the row attends

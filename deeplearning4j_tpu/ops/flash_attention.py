@@ -878,6 +878,7 @@ def flash_decode_attention(
         raise ValueError(
             f"flash_decode_attention: {hq} query heads over {h} K/V heads "
             "(a group of 1, 2 or 4 fits the kernel's eight sublanes)")
+    count_kv("full")
     if group > 1:  # the group's queries, twice over, as the eight sublanes
         q = jnp.tile(q.reshape(b, h, group, d), (1, 1, 8 // group, 1))
     rows = 8 if group > 1 else 1
@@ -965,7 +966,7 @@ def _flash_decode_write(kern, q, kp, vp, new, write_mask, lengths, start_pos,
     if not _write_fits(L, block_k):
         raise ValueError(f"flash_decode_attention: a write into a cache of "
                          f"{L} positions needs no pad (decode_write_fuses)")
-    count_kv_writes("fused", 2)
+    count_kv("fused", 2)
     keep = (jnp.ones((b,), bool) if write_mask is None
             else write_mask.astype(bool))
     pos = start_pos.astype(jnp.int32)
@@ -1162,12 +1163,15 @@ _KV_TALLIES = threading.local()
 
 
 @contextlib.contextmanager
-def kv_write_tally():
-    """Count the cache planes that the program traced inside the block
-    writes, by how: ``{"fused": n, "separate": m}`` (the decode kernel's
-    own write of K and V, or a write of a plane of its own). Tracing is
-    where the count is made: a compiled program is not counted again."""
-    tally = {"fused": 0, "separate": 0}
+def kv_tally():
+    """Count what the program traced inside the block does with its cache
+    planes: the planes it writes, by how (``"fused"``: the decode kernel's
+    own write of K and V; ``"separate"``: a write of a plane of its own),
+    and the calls of a decode kernel that read them, by its grid
+    (``"live"``: it visits a row's live blocks only; ``"full"``: its grid
+    spans the cache). Tracing is where the count is made: a compiled
+    program is not counted again."""
+    tally = {"fused": 0, "separate": 0, "live": 0, "full": 0}
     stack = _KV_TALLIES.__dict__.setdefault("open", [])
     stack.append(tally)
     try:
@@ -1176,11 +1180,11 @@ def kv_write_tally():
         stack.pop()
 
 
-def count_kv_writes(path: str, planes: int = 1) -> None:
-    """Add ``planes`` cache planes written ``path`` ("fused" or
-    "separate") to every :func:`kv_write_tally` open on this thread."""
+def count_kv(key: str, n: int = 1) -> None:
+    """Add ``n`` to ``key`` (see :func:`kv_tally`) of every tally open on
+    this thread."""
     for tally in getattr(_KV_TALLIES, "open", ()):
-        tally[path] += planes
+        tally[key] += n
 
 
 def _decode_impl() -> str:
@@ -1196,7 +1200,7 @@ def masked_cache_write(cache: jax.Array, new: jax.Array, pos: jax.Array,
     step (mirrors :func:`decode_attention`): the Pallas in-place kernel
     when "flash" is selected (or automatically on TPU) and one token is
     written, the builtin scatter otherwise."""
-    count_kv_writes("separate")
+    count_kv("separate")
     if _decode_impl() == "flash" and new.shape[2] == 1:
         return flash_masked_cache_write(cache, new, pos, write_mask)
     return masked_cache_write_reference(cache, new, pos, write_mask)

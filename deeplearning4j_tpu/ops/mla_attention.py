@@ -32,7 +32,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import attention_impl, decode_fetched_entries
+from .flash_attention import (attention_impl, count_kv,
+                              decode_fetched_entries)
 
 _F32 = jnp.float32
 _NEG = -1e30
@@ -143,6 +144,7 @@ def mla_decode_attention_pallas(q: jax.Array, plane: jax.Array,
     if L % block_k:
         raise ValueError(f"max_len {L} is not a multiple of {block_k}")
     lengths = jnp.maximum(lengths.astype(jnp.int32), 0)
+    count_kv("full")
 
     def entries(r, ki, lens):
         live = ki * block_k < lens[r]

@@ -31,7 +31,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from .flash_attention import count_kv_writes, decode_attention
+from .flash_attention import count_kv, decode_attention
 
 
 def pack_row_blocks(x: jax.Array, block_size: int) -> jax.Array:
@@ -65,7 +65,7 @@ def paged_cache_write(pool: jax.Array, new: jax.Array,
     ``dynamic_update_slice`` write. Positions past the table's capacity
     clamp to the last slot (the engine retires rows before that happens;
     the clamp only keeps indices in range for frozen/done rows)."""
-    count_kv_writes("separate")
+    count_kv("separate")
     b, t = new.shape[0], new.shape[2]
     bs = pool.shape[2]
     cap = block_table.shape[1] * bs

@@ -119,7 +119,7 @@ from ..generate.session import (ROW_SPEC_WORDS, SV_ACCEPTED, SV_N,
                                 SpeculativeGenerationSession,
                                 _check_rewindable, pack_row_spec)
 from ..nn.layers.base import fresh_rows
-from ..ops.flash_attention import decode_fetched_entries, kv_write_tally
+from ..ops.flash_attention import decode_fetched_entries, kv_tally
 from ..ops.paged_attention import pack_row_blocks
 from ..obs.metrics import MetricsRegistry, get_registry
 from ..obs.compiles import watch_compiles
@@ -536,9 +536,9 @@ class DecodeEngine:
         self._static_kv = (self.block_size is None and cache_dtype != "int8"
                            and any(l.pages_decode_planes
                                    for l in self.session.model.layers))
-        # cache planes a decode step writes (fused, separate): set when its
-        # program is traced
-        self._kv_writes = (0, 0)
+        # cache planes a decode step writes (fused, separate) and the decode
+        # kernels' reads of them (live, full): set when its program is traced
+        self._kv_counts = (0, 0, 0, 0)
         self._init_metrics(registry if registry is not None else get_registry())
 
         # device-side batch state: one preallocated carry, per-row specs.
@@ -744,8 +744,16 @@ class DecodeEngine:
             "own: kv_cache_write or a scatter); tallied where the step's "
             "program is traced, added once a dispatched step",
             ("engine", "path"))
-        self._c_kv_writes = [writes.labels(inst, path)
-                             for path in ("fused", "separate")]
+        reads = reg.counter(
+            "dl4j_tpu_decode_kv_reads_total",
+            "Calls of a decode kernel that read the cache planes in the "
+            "dispatched decode steps, by its grid: live (it visits the blocks "
+            "a row's length makes live, and no step past them) or full (its "
+            "grid spans the cache); tallied where the step's program is "
+            "traced, added once a dispatched step", ("engine", "grid"))
+        # in the order of ops.flash_attention.kv_tally's keys
+        self._c_kv = ([writes.labels(inst, p) for p in ("fused", "separate")]
+                      + [reads.labels(inst, g) for g in ("live", "full")])
         calls = reg.counter(
             "dl4j_tpu_decode_device_calls_total",
             "What the engine's loop asked of the device, by kind of call: "
@@ -1045,10 +1053,10 @@ class DecodeEngine:
             def decode_step(params, state, carry, toks, image):
                 # a self-speculating step (``_step_args``): verify, commit,
                 # draft, every row's image staying on the device
-                with kv_write_tally() as tally:
+                with kv_tally() as tally:
                     new, toks, counts = sess.mtp_step(params, state, carry,
                                                       toks, image)
-                self._kv_writes = (tally["fused"], tally["separate"])
+                self._kv_counts = tuple(tally.values())
                 return new, toks, counts
 
             self._fns["decode"] = jax.jit(decode_step, donate_argnums=2)
@@ -1072,12 +1080,13 @@ class DecodeEngine:
                 # blocks, and one of a static carry writes nothing
                 fwd = mask_inactive_writes(
                     attach_block_table(carry, table), active, sess.planes)
-                with jax.named_scope("forward"), kv_write_tally() as tally:
+                with jax.named_scope("forward"), kv_tally() as tally:
                     out, new_rnn = sess._forward(
                         params, state, sess._prep(tokens[:, None]), None, fwd)
-                # the cache planes the step writes, by how: counted as the
-                # program is traced, added once a dispatched step
-                self._kv_writes = (tally["fused"], tally["separate"])
+                # the cache planes the step writes, by how, and its kernels'
+                # reads of them, by grid: counted as the program is traced,
+                # added once a dispatched step
+                self._kv_counts = tuple(tally.values())
                 with jax.named_scope("logits"):
                     logits = sess._logits(out, params)[:, :, 0]
                 # a slot keeps its last request's spec after it ends: an
@@ -1648,9 +1657,9 @@ class DecodeEngine:
             if self._ring:
                 self._c_window_attended.inc(int(np.minimum(
                     self._pos[rows] + 1, self._ring).sum()))
-            for c, planes in zip(self._c_kv_writes, self._kv_writes):
-                if planes:
-                    c.inc(planes)
+            for c, n in zip(self._c_kv, self._kv_counts):
+                if n:
+                    c.inc(n)
             self._fresh[rows] = False
             self._steps[rows] += 1
             self._pos[rows] += 1
@@ -2103,7 +2112,7 @@ class DecodeEngine:
         accepted = int(self._c_spec_accepted.value)
         spec_steps = int(self._c_spec_steps.value)
         kv_fetched = self._c_kv_fetched.value
-        fused, separate = (c.value for c in self._c_kv_writes)
+        fused, separate, live, full = (c.value for c in self._c_kv)
         sampler_steps = [c.value for c in self._c_sampler]
         counts.update({
             "in_flight": self._admission.pending,
@@ -2150,6 +2159,10 @@ class DecodeEngine:
             # decode kernel wrote itself
             "kv_write_fused_share": (
                 fused / (fused + separate) if fused + separate else None),
+            # of the decode kernels' reads of the planes, the share that
+            # visited a row's live blocks only
+            "kv_read_live_share": (
+                live / (live + full) if live + full else None),
             # of the plain steps dispatched, the share whose sampler sorted
             "sampler_sort_share": (
                 sampler_steps[PATHS.index("sort")] / sum(sampler_steps)

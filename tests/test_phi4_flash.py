@@ -34,7 +34,9 @@ from deeplearning4j_tpu.nn.layers import (CrossDecoderLayer,
 from deeplearning4j_tpu.obs.metrics import MetricsRegistry
 from deeplearning4j_tpu.ops import set_attention_impl
 from deeplearning4j_tpu.ops.diff_attention import (
-    diff_decode_attention_pallas, diff_decode_attention_reference)
+    decode_live_schedule, diff_decode_attention_pallas,
+    diff_decode_attention_reference)
+from deeplearning4j_tpu.ops.flash_attention import decode_fetched_entries
 from deeplearning4j_tpu.ops.selective_scan import (selective_scan_pallas,
                                                    selective_scan_reference)
 from deeplearning4j_tpu.parallel.decode import DecodeEngine
@@ -180,6 +182,16 @@ def test_selective_scan_kernel_equals_the_scan(t):
 @pytest.mark.parametrize("b,hq,hk,L,d,lengths", [
     (3, 8, 4, 512, 16, (1, 512, 300)),   # two heads a K/V pair, two blocks
     (2, 4, 4, 256, 16, (256, 77)),       # one head a pair
+    # a lone row at each edge of a block, in a cache of eight blocks
+    (1, 8, 4, 2048, 16, (0,)),
+    (1, 8, 4, 2048, 16, (1,)),
+    (1, 8, 4, 2048, 16, (255,)),
+    (1, 8, 4, 2048, 16, (256,)),
+    (1, 8, 4, 2048, 16, (257,)),
+    (1, 8, 4, 2048, 16, (2048,)),
+    (7, 8, 4, 2048, 16, (257, 0, 2048, 1, 256, 1000, 255)),   # all at once
+    (3, 4, 4, 4096, 16, (4096, 0, 3001)),                     # sixteen
+    (2, 40, 40, 512, 64, (300, 0)),      # two pair blocks: a 2-D grid
 ])
 def test_diff_decode_equals_the_xla_spelling(b, hq, hk, L, d, lengths):
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
@@ -191,9 +203,57 @@ def test_diff_decode_equals_the_xla_spelling(b, hq, hk, L, d, lengths):
     got = diff_decode_attention_pallas(q, k, v, n, 0.37, block_k=256,
                                        interpret=True)
     np.testing.assert_allclose(got, want, atol=2e-6)
-    # both maps are in it: the second one's weight moves the result
+    # both maps are in it: the second one's weight moves the result by a
+    # fifth of its size (rows with no entry give 0 either way)
     other = diff_decode_attention_reference(q, k, v, n, 0.0)
-    assert np.abs(np.asarray(other) - np.asarray(want)).max() > 0.05
+    gap = np.abs(np.asarray(other) - np.asarray(want)).max()
+    assert gap > 0.2 * np.abs(np.asarray(want)).max() or not any(lengths)
+
+
+@pytest.mark.parametrize("L,lengths", [
+    (2048, (0, 1, 255, 256, 257, 2048)),
+    (2048, (2048,) * 4),
+    (512, (0, 0, 0)),
+    (256, (256, 3)),
+    (10240, tuple(np.random.default_rng(45).integers(0, 10241, 64))),
+])
+def test_the_live_schedule_names_each_rows_live_blocks_once(L, lengths):
+    """Every (row, block) pair up to the block that holds a row's last
+    entry (one block for a row with none), in order, and nothing past it;
+    the entries past the count repeat the last pair."""
+    bk = min(256, L)
+    lens = np.asarray(lengths, np.int32)
+    n, rows, blocks = (np.asarray(a) for a in decode_live_schedule(
+        jnp.asarray(lens), L, bk))
+    counts = decode_fetched_entries(lens, L) // bk
+    assert n == counts.sum()
+    assert rows.shape == blocks.shape == (len(lens) * L // bk + 1,)
+    assert list(zip(rows[:n], blocks[:n])) == [
+        (r, j) for r in range(len(lens)) for j in range(counts[r])]
+    assert set(rows[:n]) == set(range(len(lens)))
+    assert (rows[n:] == rows[n - 1]).all() and (
+        blocks[n:] == blocks[n - 1]).all()
+
+
+def test_diff_decode_grid_is_the_live_count():
+    """The kernel's grid has as many steps as the rows' live blocks: its
+    second dimension is read at run time, and it is the schedule's count."""
+    b, hq, hk, L, d = 5, 8, 4, 2048, 16
+    lens = jnp.asarray([0, 1, 257, 2048, 700], jnp.int32)
+    q = jnp.zeros((b, hq, d), jnp.float32)
+    k = jnp.zeros((b, hk, L, d), jnp.float32)
+    closed = jax.make_jaxpr(
+        lambda q, k, v, n: diff_decode_attention_pallas(
+            q, k, v, n, 0.37, interpret=True))(q, k, k, lens)
+    jaxpr = closed.jaxpr
+    (i, eqn), = [(i, e) for i, e in enumerate(jaxpr.eqns)
+                 if e.primitive.name == "pallas_call"]
+    grid = eqn.params["grid_mapping"].grid
+    assert grid[0] == 1 and not isinstance(grid[1], int)
+    assert eqn.params["grid_mapping"].num_dynamic_grid_bounds == 1
+    head = jaxpr.replace(eqns=jaxpr.eqns[:i], outvars=[eqn.invars[0]])
+    (steps,) = jax.core.eval_jaxpr(head, closed.consts, q, k, k, lens)
+    assert int(steps) == 1 + 1 + 2 + 8 + 3 < b * L // 256
 
 
 def test_the_kernels_give_what_the_xla_spelling_gives(lm):
@@ -241,6 +301,41 @@ def test_decode_engine_serves_what_the_full_forward_gives(lm):
     (w_count,) = [c.value for _, c in window.items()]
     (c_count,) = [c.value for _, c in cache.items()]
     assert 0 < w_count < c_count  # rings stop at 16, the cache does not
+
+
+@pytest.mark.parametrize("family", ["phi4f", "gpt2"])
+def test_the_engine_counts_its_kernels_reads_by_grid(lm, family):
+    """Under the step's TPU spelling (interpreted): Phi-4-mini-flash's two
+    rings and two readers of the one cache are read by ``diff_decode``,
+    which visits live blocks only, GPT-2's two layers by ``flash_decode``,
+    whose grid spans the cache; the counter rises by the step's reads once
+    a dispatched step, and the share is None before one."""
+    if family == "phi4f":
+        model, max_len, reads, grid = lm[0], MAX_LEN, 4, "live"
+    else:
+        from deeplearning4j_tpu.model.zoo import TransformerLM
+        model = TransformerLM(vocab_size=MODEL["vocab_size"], hidden=32,
+                              n_layers=2, n_heads=4, max_len=128).init()
+        max_len, reads, grid = 128, 2, "full"
+    reg = MetricsRegistry()
+    set_attention_impl("flash")
+    eng = DecodeEngine(model, max_len=max_len, slots=2, registry=reg,
+                       name="kvr")
+    try:
+        assert eng.stats()["kv_read_live_share"] is None
+        m = 4
+        eng.submit(list(range(1, 12)), max_tokens=m, greedy=True).result(
+            timeout=300)
+        share = eng.stats()["kv_read_live_share"]
+    finally:
+        eng.shutdown(drain=False)
+        set_attention_impl("auto")
+    c = reg.get("dl4j_tpu_decode_kv_reads_total")
+    counted = {g: c.labels("kvr", g).value for g in ("live", "full")}
+    steps = m - 1  # the prefill hands out the first token
+    assert counted == {g: reads * steps if g == grid else 0
+                       for g in ("live", "full")}
+    assert share == (1.0 if grid == "live" else 0.0)
 
 
 # ----------------------------------------------------- faults that must fail

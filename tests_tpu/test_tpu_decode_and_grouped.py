@@ -264,3 +264,37 @@ def test_mla_decode_compiled_matches_reference(tpu_device, b, block_k):
     again = run(q, jnp.where(stale[:, None, :, None], jnp.nan, plane),
                 lengths)
     assert (np.asarray(again, np.float32) == np.asarray(got, np.float32)).all()
+
+
+@pytest.mark.parametrize("L,name", [(10240, "diff_decode"),
+                                    (512, "diff_decode_window")])
+def test_diff_decode_compiled_matches_reference(tpu_device, L, name):
+    """Phi-4-mini-flash's step at its published widths: 64 rows, 40 query
+    heads over 20 K/V heads of 64 in pairs, the shared cache of 10,240
+    entries and a window's ring of 512, rows from 0 to ``L`` entries (the
+    kernel's grid is the rows' live blocks). A row of length 0 attends
+    nothing; what lies past a row's length may be anything, NaN included.
+    The reference runs eight rows at a time (in float32 the whole batch's
+    planes would not fit beside the kernel's)."""
+    from deeplearning4j_tpu.ops.diff_attention import (
+        diff_decode_attention_pallas, diff_decode_attention_reference)
+
+    b, hq, hk, d, lam = 64, 40, 20, 64, 0.37
+    q, k, v = _rand(0, b, hq, d), _rand(1, b, hk, L, d), _rand(2, b, hk, L, d)
+    n = np.random.RandomState(45).permutation(
+        np.linspace(0, L, b).astype(np.int32))
+    n[:6] = [0, 1, 255, 256, 257, L]
+    lengths = jnp.asarray(n, jnp.int32)
+    run = jax.jit(lambda q, k, v, n: diff_decode_attention_pallas(
+        q, k, v, n, lam, name=name, interpret=False))
+    got = np.asarray(run(q, k, v, lengths), np.float32)
+    ref = np.concatenate([np.asarray(_highest(
+        lambda q, k, v, n: diff_decode_attention_reference(q, k, v, n, lam),
+        q[i:i + 8], k[i:i + 8], v[i:i + 8], lengths[i:i + 8]), np.float32)
+        for i in range(0, b, 8)])
+    assert _rel(got, ref) < FWD_TOL
+    assert not got[0].any()
+    stale = jnp.asarray(np.arange(L)[None, :] >= n[:, None])[:, None, :, None]
+    again = run(q, jnp.where(stale, jnp.nan, k), jnp.where(stale, jnp.nan, v),
+                lengths)
+    assert (np.asarray(again, np.float32) == got).all()
